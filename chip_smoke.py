@@ -1,0 +1,355 @@
+"""Does paddle-tpu start on the attached TPU?  `python chip_smoke.py`
+
+Drives the two normal entry points once at the full widths of
+`LlamaConfig()` (h=4096, ffn 11008, 32x128 heads, vocab 32000) with depth
+cut to N_LAYERS and seeded random weights:
+
+  serve  LLMEngine(kv_layout="paged") answers 12 requests (README sequence)
+  kernel paged_decode_attention vs its own dense path at the engine's shapes
+  train  paddle.jit.TrainStep + AdamW, 3 steps at batch 4 x seq 2048
+  mesh   ShardedTrainStep(zero_stage=2) on sharding=2 x mp=2 (>= 4 chips)
+
+ONE process touches JAX; no network, nothing tracked written.  The script
+starts no child.  The package starts one, once per fresh checkout: the g++
+build of its host library (core/native), which LLMEngine.warmup() triggers
+and which never imports JAX.  Any failed check raises: there is no retry at
+a smaller size and no error field.  Off the chip (JAX_PLATFORMS=cpu, no TPU)
+it exits non-zero before running anything.  On success the last stdout line
+is {"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}.
+"""
+from __future__ import annotations
+
+import gc
+import json
+import math
+import sys
+import time
+
+import numpy as np
+
+N_LAYERS = 4          # the only cut: LlamaConfig() is 32 layers deep
+SEQ = 2048
+T0 = time.perf_counter()
+
+
+def log(msg):
+    print(f"[{time.perf_counter() - T0:7.1f}s] {msg}", flush=True)
+
+
+def check(ok, what):
+    if not ok:
+        raise AssertionError(f"chip_smoke: FAILED: {what}")
+    log(f"ok: {what}")
+
+
+def device_gate():
+    """Refuse anything but a TPU both peak tables know."""
+    import jax
+
+    devs = jax.devices()
+    dev = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+           "count": len(devs)}
+    log(f"jax {jax.__version__} devices: {dev}")
+    if dev["platform"] != "tpu":
+        sys.exit(f"chip_smoke: needs a TPU, JAX found platform="
+                 f"{dev['platform']!r} ({dev['kind']}, {dev['count']} "
+                 f"device(s)); nothing was run")
+    from importlib import metadata
+
+    from paddle_tpu import cost_model
+
+    log(f"libtpu {metadata.version('libtpu')}")
+    flops = cost_model.peak_flops_per_device()
+    hbm = cost_model.peak_hbm_bytes_per_sec()
+    check(flops > 0 and hbm > 0,
+          f"peak tables know {dev['kind']!r}: {flops / 1e12:.0f} TFLOP/s, "
+          f"{hbm / 1e9:.0f} GB/s")
+    return dev
+
+
+def _free():
+    """Between phases: the finished phase's model, engine and executables
+    are garbage only once its frame is gone (layers hold reference cycles),
+    and the next phase needs the HBM."""
+    import jax
+
+    gc.collect()
+    jax.clear_caches()
+
+
+def _metric(name):
+    """{label values: count} of one registry family."""
+    from paddle_tpu.observability import metrics
+
+    fam = metrics.REGISTRY.get(name)
+    return {labels: child.value for labels, child in fam.series()} \
+        if fam is not None else {}
+
+
+def _llama(n_layers, tensor_parallel, **overrides):
+    import paddle_tpu as paddle
+    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+
+    paddle.seed(0)
+    cfg = LlamaConfig(num_hidden_layers=n_layers, dtype="bfloat16",
+                      tensor_parallel=tensor_parallel, **overrides)
+    return cfg, LlamaForCausalLM(cfg).bfloat16()
+
+
+# ------------------------------------------------------------------- serve
+def serve_phase(n_layers=N_LAYERS, max_seq_len=SEQ, prompt_lens=(100, 1500),
+                n_requests=12, new_tokens=32, timeout=600.0, **overrides):
+    """README §"LLM serving" sequence; returns the engine geometry the
+    kernel phase reuses."""
+    from paddle_tpu.inference import LLMEngine
+
+    cfg, model = _llama(n_layers, tensor_parallel=False, **overrides)
+    model.eval()
+    page, slots = 128, 8
+    eng = LLMEngine(model, kv_layout="paged", page_size=page,
+                    prefill_chunk=256, max_seq_len=max_seq_len,
+                    max_batch_slots=slots)
+    log(f"serve: {n_layers} layers, warmup() ...")
+    log(f"serve: warmup took {eng.warmup():.1f}s")
+    compiles = _metric("jit_compiles_total")
+
+    rng = np.random.default_rng(0)
+    lens = np.linspace(*prompt_lens, n_requests).astype(int)
+    prompts = [rng.integers(0, cfg.vocab_size, n, dtype=np.int32)
+               for n in lens]
+    # requests 1-3 open with one "system prompt" of 2 pages + 44 tokens: the
+    # prefix cache must serve those pages once the first has prefilled
+    system = rng.integers(0, cfg.vocab_size, 2 * page + 44, dtype=np.int32)
+    for i in (1, 2, 3):
+        tail = prompts[i][:max(len(prompts[i]) - len(system), 8)]
+        prompts[i] = np.concatenate([system, tail])
+    eng.start()
+    try:
+        futures = [eng.submit(p, max_new_tokens=new_tokens) for p in prompts]
+        outs = [f.result(timeout=timeout) for f in futures]
+    finally:
+        eng.stop()
+    check(all(len(o) == new_tokens for o in outs),
+          f"{n_requests} requests ({min(map(len, prompts))}.."
+          f"{max(map(len, prompts))} prompt tokens, {slots} slots) each "
+          f"returned {new_tokens} tokens")
+    check(all(0 <= t < cfg.vocab_size for o in outs for t in o),
+          "every token id is in the vocabulary")
+    after = _metric("jit_compiles_total")
+    check(after == compiles,
+          f"no compile between warmup() and stop() (jit_compiles_total "
+          f"{sum(compiles.values())} -> {sum(after.values())})")
+    attn = _metric("llm_attn_kernel_total")
+    paths = {labels[0] for labels in attn}
+    check("paged_kernel" in paths and "paged_dense" not in paths,
+          f"every paged attention site compiled to the Pallas kernel: {attn}")
+    prefix = eng.stats()["prefix_cache"]
+    check(prefix["hit_tokens"] > 0,
+          f"prefix cache served {prefix['hit_tokens']} of "
+          f"{prefix['prompt_tokens']} prompt tokens")
+
+    # reported, not asserted: random weights flip argmax on rounding
+    import paddle_tpu as paddle
+
+    ref = np.asarray(model.generate(
+        paddle.to_tensor(prompts[0][None]), max_new_tokens=new_tokens)._value)[0]
+    agree = int(np.cumprod(ref == np.asarray(outs[0])).sum())
+    log(f"serve: request 0 agrees with model.generate() for {agree} of "
+        f"{new_tokens} leading tokens")
+    geom = dict(heads=cfg.num_attention_heads,
+                kv_heads=cfg.num_key_value_heads,
+                head_dim=cfg.hidden_size // cfg.num_attention_heads,
+                page=page, slots=slots, num_pages=eng.num_pages,
+                max_pages=eng.M, chunk=eng.prefill_chunk)
+    return geom
+
+
+# ------------------------------------------------------------------ kernel
+#: max |kernel - dense| allowed, outputs O(1).  Both paths round the
+#: probabilities to bf16 before the PV matmul (relative 2^-9) and emit bf16;
+#: the dense path also rounds the scores to bf16, the kernel keeps them f32.
+#: A score error of 2^-9 * |s| at |s| <= ~5 moves a probability by ~1%, and
+#: outputs are probability-weighted means of N(0,1) values: ~1e-2 worst
+#: case over a few thousand outputs.  int8 adds the dense path's bf16
+#: rounding of (int8 * scale) per key, a second ~2^-9 relative score error.
+KERNEL_TOL = {"bf16": 3e-2, "int8": 5e-2}
+
+
+def kernel_phase(heads, kv_heads, head_dim, page, slots, num_pages,
+                 max_pages, chunk):
+    """The ragged paged kernel against the same call forced dense, at the
+    engine's own shapes: S=1 decode, one S=chunk prefill chunk at a
+    non-zero offset, and the S=5 speculative-verify ladder."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.models.kv_cache import _quantize_kv
+    from paddle_tpu.ops import decode_attention as da
+
+    rng = np.random.default_rng(1)
+    normal = lambda *s: jnp.asarray(  # noqa: E731
+        rng.standard_normal(s, np.float32), jnp.bfloat16)
+    kp, vp = (normal(num_pages, kv_heads, page, head_dim) for _ in range(2))
+    kq, ks = _quantize_kv(kp)
+    vq, vs = _quantize_kv(vp)
+    L = max_pages * page
+    errs = {}
+
+    def attend(path, q, offs, tbl, pools):
+        # the path is chosen at trace time: a fresh jit per call
+        da._FORCE_PATH = path
+        try:
+            out = jax.jit(lambda q, off, tbl, *p: da.paged_decode_attention(
+                q, p[0], p[1], off, tbl, *p[2:]))(q, offs, tbl, *pools)
+        finally:
+            da._FORCE_PATH = None
+        return np.asarray(out, np.float32)
+
+    for S, B in ((1, slots), (chunk, 1), (5, slots)):
+        # one prefill chunk: the third of its prompt.  A batch: ragged cached
+        # lengths — empty, one short of a page, exactly a page (the first
+        # query token then lands ONE TOKEN INTO A NEW PAGE), deep, full
+        offs = np.array([2 * chunk] if B == 1 else np.resize(
+            [0, page - 1, page, 2 * page + 1, L // 2 + 3, L - S - page,
+             L - S - 1, L - S], B), np.int32).clip(0, L - S)
+        tbl = rng.permutation(np.arange(1, num_pages))[:B * max_pages] \
+            .reshape(B, max_pages).astype(np.int32)
+        q = normal(B, S, heads, head_dim)
+        for name, pools in (("bf16", (kp, vp)), ("int8", (kq, vq, ks, vs))):
+            got = attend(None, q, offs, tbl, pools)
+            want = attend("dense", q, offs, tbl, pools)
+            err = float(np.max(np.abs(got - want)))
+            errs[f"S{S}_{name}"] = err
+            check(np.isfinite(got).all() and err < KERNEL_TOL[name],
+                  f"paged kernel vs dense, S={S} B={B} {name}: max abs err "
+                  f"{err:.2e} < {KERNEL_TOL[name]}")
+    return errs
+
+
+# ------------------------------------------------------------------- train
+def _train_setup(n_layers, tensor_parallel, batch, seq, **overrides):
+    import paddle_tpu as paddle
+
+    cfg, model = _llama(n_layers, tensor_parallel,
+                        max_position_embeddings=seq, **overrides)
+    opt = paddle.optimizer.AdamW(learning_rate=3e-4, weight_decay=0.01,
+                                 parameters=model.parameters())
+
+    def loss_fn(ids, labels):
+        return paddle.nn.functional.cross_entropy(
+            model(ids).reshape([-1, cfg.vocab_size]), labels.reshape([-1]))
+
+    rng = np.random.default_rng(2)
+    ids, labels = (paddle.to_tensor(
+        rng.integers(0, cfg.vocab_size, (batch, seq), dtype=np.int32))
+        for _ in range(2))
+    return cfg, model, opt, loss_fn, ids, labels
+
+
+def _check_losses(tag, losses, vocab):
+    check(all(math.isfinite(x) for x in losses) and losses[-1] < losses[0],
+          f"{tag}: losses {[round(x, 4) for x in losses]} finite and falling "
+          f"(ln vocab = {math.log(vocab):.3f})")
+
+
+def _check_flash_in_hlo(tag, hlo):
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    found = {k for k in ("flash_fwd", "flash_dq", "flash_dkv")
+             if any(k in ln for ln in calls)}
+    check(len(found) == 3, f"{tag}: compiled step holds the Mosaic flash "
+          f"kernels {sorted(found)} ({len(calls)} custom calls)")
+
+
+def train_phase(n_layers=N_LAYERS, batch=4, seq=SEQ, steps=3, **overrides):
+    """bench.py's _bench_llama_h4096 step: TrainStep + AdamW, one repeated
+    seeded batch.  Returns the losses."""
+    import paddle_tpu as paddle
+
+    cfg, model, opt, loss_fn, ids, labels = _train_setup(
+        n_layers, False, batch, seq, **overrides)
+    step = paddle.jit.TrainStep(model, loss_fn, opt)
+    log(f"train: {n_layers} layers, batch {batch} x seq {seq}, compiling ...")
+    losses = [float(step(ids, labels).item()) for _ in range(steps)]
+    _check_losses("train", losses, cfg.vocab_size)
+    # the same program again, ahead of time, for its text (a persistent-
+    # cache hit when enable_compile_cache() is on)
+    import jax.numpy as jnp
+
+    from paddle_tpu.framework import random as _random
+
+    params, buffers = model.functional_state()
+    hlo = step._jitted.lower(
+        params, buffers, step._opt_state, step._scaler_state,
+        jnp.asarray(opt.get_lr(), jnp.float32), _random.get_rng_key(),
+        ids._value, labels._value).compile().as_text()
+    _check_flash_in_hlo("train", hlo)
+    return losses
+
+
+# -------------------------------------------------------------------- mesh
+def _check_memory(devs):
+    """Parameters are built on device 0 and only then placed: report every
+    device, and device 0's peak."""
+    mem = [d.memory_stats() for d in devs]
+    log("mesh: bytes_in_use " + ", ".join(
+        f"dev{d.id}={m['bytes_in_use'] / 2**20:.0f}MiB "
+        f"(peak {m['peak_bytes_in_use'] / 2**20:.0f})"
+        for d, m in zip(devs, mem)))
+    check(min(m["bytes_in_use"] for m in mem) > 2**20,
+          "mesh: every device holds state")
+
+
+def mesh_phase(ref_loss, n_layers=N_LAYERS, batch=4, seq=SEQ, steps=3,
+               **overrides):
+    """ShardedTrainStep(zero_stage=2) over sharding=2 x mp=2: the flash
+    kernel must survive a real mesh (sharding_ctx.shard_kernel)."""
+    import jax
+
+    from paddle_tpu.distributed import ShardedTrainStep, build_mesh
+
+    if len(jax.devices()) < 4:
+        log(f"mesh: skipped, {len(jax.devices())} device")
+        return None
+    mesh = build_mesh(sharding=2, mp=2)
+    devs = list(mesh.devices.flat)
+    log(f"mesh: {dict(mesh.shape)} over device ids {[d.id for d in devs]}")
+    cfg, model, opt, loss_fn, ids, labels = _train_setup(
+        n_layers, True, batch, seq, **overrides)
+    step = ShardedTrainStep(model, loss_fn, opt, mesh, zero_stage=2)
+    losses = [float(step(ids, labels).item()) for _ in range(steps)]
+    _check_losses("mesh", losses, cfg.vocab_size)
+    check(abs(losses[0] - ref_loss) < 0.1,
+          f"mesh: step-1 loss {losses[0]:.4f} within 0.1 of the one-chip "
+          f"{ref_loss:.4f}")
+    _check_memory(devs)
+    hlo = step._compile_for_analysis(ids, labels).as_text()
+    _check_flash_in_hlo("mesh", hlo)
+    return losses
+
+
+def main():
+    dev = device_gate()
+    import paddle_tpu
+    from paddle_tpu.core import native
+    from paddle_tpu.core.device import enable_compile_cache
+
+    log(f"compile cache: {enable_compile_cache()}")
+    geom = serve_phase()
+    _free()
+    errs = kernel_phase(**geom)
+    _free()
+    losses = train_phase()
+    _free()
+    mesh_losses = mesh_phase(losses[0])
+    # built_this_run=False with AVAILABLE=True means a library that was
+    # already on disk was loaded: not built from this checkout's sources
+    log(f"native: AVAILABLE={native.AVAILABLE} "
+        f"built_this_run={native.BUILT_THIS_RUN}")
+    log(f"summary: layers={N_LAYERS} kernel_err={errs} train={losses} "
+        f"mesh={mesh_losses} paddle_tpu={paddle_tpu.__version__} "
+        f"wall={time.perf_counter() - T0:.0f}s")
+    print(json.dumps({"ok": True, "device": dev}))
+
+
+if __name__ == "__main__":
+    main()

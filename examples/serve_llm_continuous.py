@@ -1,8 +1,9 @@
 """Continuous-batching LLM serving with paddle_tpu.inference.LLMEngine.
 
-Run (CPU works; on TPU use a real checkpoint via model.set_state_dict):
+Run (tiny random weights, any backend; `chip_smoke.py` at the repo root is
+the same engine at 7B widths on the attached TPU):
 
-    python examples/serve_llm_continuous.py
+    JAX_PLATFORMS=cpu python examples/serve_llm_continuous.py
 
 Demonstrates: slot-pool serving with one compiled decode step for every
 in-flight request, bucketed prefill admission, per-request sampling knobs,

@@ -23,6 +23,7 @@ _LIB_PATH = os.path.join(_BUILD_DIR, "libpaddle_tpu_native.so")
 _lib = None
 _lib_lock = threading.Lock()
 AVAILABLE = None  # resolved on first load_library() call
+BUILT_THIS_RUN = False  # True once build() compiled native.cc in this process
 
 
 def _needs_rebuild() -> bool:
@@ -33,6 +34,7 @@ def _needs_rebuild() -> bool:
 
 def build(verbose: bool = False) -> str:
     """Compile native.cc -> libpaddle_tpu_native.so (cached by mtime)."""
+    global BUILT_THIS_RUN
     os.makedirs(_BUILD_DIR, exist_ok=True)
     if not _needs_rebuild():
         return _LIB_PATH
@@ -40,6 +42,7 @@ def build(verbose: bool = False) -> str:
            _SRC, "-o", _LIB_PATH + ".tmp"]
     subprocess.run(cmd, check=True, capture_output=not verbose)
     os.replace(_LIB_PATH + ".tmp", _LIB_PATH)
+    BUILT_THIS_RUN = True
     return _LIB_PATH
 
 

@@ -134,10 +134,7 @@ class CostModel:
         {'flops': ..., 'bytes accessed': ..., 'optimal_seconds': ...} (keys as
         reported by the backend; missing entries are 0.0)."""
         lowered = jax.jit(fn).lower(*_unwrap(args), **kwargs)
-        analysis = lowered.compile().cost_analysis()
-        if isinstance(analysis, (list, tuple)):  # older jax: one dict per device
-            analysis = analysis[0] if analysis else {}
-        out = dict(analysis or {})
+        out = dict(lowered.compile().cost_analysis() or {})
         for key in ("flops", "bytes accessed", "optimal_seconds"):
             out.setdefault(key, 0.0)
         return out
@@ -149,10 +146,7 @@ class CostModel:
         and the timed calls."""
         raw = _unwrap(args)
         compiled = jax.jit(fn).lower(*raw, **kwargs).compile()
-        analysis = compiled.cost_analysis()
-        if isinstance(analysis, (list, tuple)):
-            analysis = analysis[0] if analysis else {}
-        analysis = dict(analysis or {})
+        analysis = dict(compiled.cost_analysis() or {})
         r = None
         for _ in range(warmup):
             r = compiled(*raw, **kwargs)

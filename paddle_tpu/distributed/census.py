@@ -75,10 +75,7 @@ def collective_census(compiled):
                 break
     flops = None
     try:
-        ca = compiled.cost_analysis()
-        if isinstance(ca, list):
-            ca = ca[0]
-        flops = float(ca.get("flops", 0.0))
+        flops = float(compiled.cost_analysis().get("flops", 0.0))
     except Exception:
         pass
     return {

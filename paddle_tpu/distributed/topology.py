@@ -74,12 +74,13 @@ def build_mesh(dp=1, mp=1, pp=1, sharding=1, sep=1, devices=None) -> Mesh:
     innermost so tensor-parallel collectives ride the fastest ICI links
     (scaling-book recipe).
 
-    On real TPU topologies the assignment goes through
-    mesh_utils.create_device_mesh (single slice: ICI-nearest-neighbor
-    placement per axis) or create_hybrid_device_mesh (multi-host with DCN:
-    the outermost data axes span hosts, mp/sep stay inside a slice) instead
-    of a naive flat reshape — the reshape order is only correct by accident
-    on some topologies."""
+    When the mesh uses EVERY visible TPU device the assignment goes through
+    mesh_utils.create_device_mesh (single slice: ICI-aware placement per
+    axis) or create_hybrid_device_mesh (multi-host with DCN: the outermost
+    data axes span hosts, mp/sep stay inside a slice); a shape mesh_utils
+    cannot place raises — pass `devices=` to choose the order yourself.  A
+    mesh over a subset of the visible devices, an explicit `devices=` list
+    and every non-TPU backend take the devices in the order given."""
     shape = (pp, dp, sharding, sep, mp)
     need = int(np.prod(shape))
     if devices is None:
@@ -87,35 +88,27 @@ def build_mesh(dp=1, mp=1, pp=1, sharding=1, sep=1, devices=None) -> Mesh:
         if all_devs[0].platform == "tpu" and len(all_devs) == need:
             from jax.experimental import mesh_utils
 
-            try:
-                n_hosts = max(getattr(d, "process_index", 0) for d in all_devs) + 1
-                if n_hosts > 1:
-                    per_host = len(all_devs) // n_hosts
-                    # split each axis into a DCN (cross-host) and ICI part:
-                    # data-like axes absorb the host dimension outermost
-                    dcn = [1] * len(shape)
-                    ici = list(shape)
-                    rest = n_hosts
-                    for i in (1, 2, 0):        # dp, sharding, then pp over DCN
-                        g = int(np.gcd(ici[i], rest))
-                        dcn[i] *= g
-                        ici[i] //= g
-                        rest //= g
-                        if rest == 1:
-                            break
-                    if rest == 1 and per_host == int(np.prod(ici)):
-                        dev = mesh_utils.create_hybrid_device_mesh(
-                            tuple(ici), tuple(dcn), devices=all_devs)
-                        return Mesh(dev, AXIS_ORDER)
-                dev = mesh_utils.create_device_mesh(shape, devices=all_devs)
-                return Mesh(dev, AXIS_ORDER)
-            except Exception as e:
-                import warnings
-
-                warnings.warn(
-                    f"mesh_utils device assignment failed ({e!r}); falling "
-                    "back to flat reshape — axis-to-ICI placement may be "
-                    "suboptimal on this topology", stacklevel=2)
+            n_hosts = max(getattr(d, "process_index", 0) for d in all_devs) + 1
+            if n_hosts > 1:
+                per_host = len(all_devs) // n_hosts
+                # split each axis into a DCN (cross-host) and ICI part:
+                # data-like axes absorb the host dimension outermost
+                dcn = [1] * len(shape)
+                ici = list(shape)
+                rest = n_hosts
+                for i in (1, 2, 0):        # dp, sharding, then pp over DCN
+                    g = int(np.gcd(ici[i], rest))
+                    dcn[i] *= g
+                    ici[i] //= g
+                    rest //= g
+                    if rest == 1:
+                        break
+                if rest == 1 and per_host == int(np.prod(ici)):
+                    dev = mesh_utils.create_hybrid_device_mesh(
+                        tuple(ici), tuple(dcn), devices=all_devs)
+                    return Mesh(dev, AXIS_ORDER)
+            dev = mesh_utils.create_device_mesh(shape, devices=all_devs)
+            return Mesh(dev, AXIS_ORDER)
         devices = np.array(all_devs)
     if len(devices) < need:
         raise ValueError(f"need {need} devices, have {len(devices)}")

@@ -67,9 +67,6 @@ def flops(net, input_size, custom_ops=None, print_detail=False):
             return out._value if isinstance(out, Tensor) else out
 
         lowered = jax.jit(f).lower(params, buffers, x)
-        cost = lowered.compile().cost_analysis()
-        if isinstance(cost, list):
-            cost = cost[0]
-        return int(cost.get("flops", 0))
+        return int(lowered.compile().cost_analysis().get("flops", 0))
     except Exception:
         return 0

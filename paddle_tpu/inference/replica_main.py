@@ -114,8 +114,11 @@ def _build_engine(args):
         return _StubEngine(args.port)
     import paddle_tpu as paddle
     from .llm_server import LLMEngine
+    from ..core.device import enable_compile_cache
     from ..models import LlamaConfig, LlamaForCausalLM
 
+    # every (re)start of a replica compiles the same programs: load them
+    enable_compile_cache()
     paddle.seed(args.seed)
     cfg = LlamaConfig.tiny(tensor_parallel=False, use_flash_attention=False,
                            max_position_embeddings=max(256,

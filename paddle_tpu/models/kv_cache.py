@@ -45,14 +45,14 @@ the decode loop) before the scatter at axis 2.
 Both LlamaAttention and GPTBlock call the helpers here so the layout and
 quantization contracts live in one place.
 
-RECOMMENDATION (measured on v5e, 738M model, b8/p1024, r4): with the Pallas
-decode kernel the int8 cache is now FASTER than bf16 at small batch (3.51
-vs 3.96 ms/token — it streams half the kv bytes and dequantizes in VMEM)
-and doubles the max decode batch/context at fixed HBM
-(kv_int8_max_batch_gain ~1.9 in BENCH_r04: 114 -> 214 max batch at 1152
-context).  Default to cache_dtype="int8" for serving whenever the model
-tolerates the ~absmax/254 per-element roundtrip error (logit drift <5% on
-the parity test); keep bf16 for exact-parity evaluation runs.
+RECOMMENDATION: with the Pallas decode kernel the int8 cache streams half
+the kv bytes (it dequantizes in VMEM) and by the same arithmetic nearly
+doubles the max decode batch/context at fixed HBM (int8 payload + f32
+per-token scales ~0.52x the bf16 bytes).  Its speed against bf16 has not
+been measured on the attached chip (PERF.md; the earlier timings went with
+their records in PR 21).  Default to cache_dtype="int8" for serving whenever
+the model tolerates the ~absmax/254 per-element roundtrip error (logit drift
+<5% on the parity test); keep bf16 for exact-parity evaluation runs.
 """
 from __future__ import annotations
 

@@ -47,74 +47,71 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None, dropout_p=0.
                                  is_causal=False, training=True, scale=None, backend="auto", name=None):
     """query/key/value: [batch, seq, num_heads, head_dim] (paddle layout)."""
 
+    from ...core.device import is_tpu_backend
+    from ...distributed.sharding_ctx import (local_shape, shard_index,
+                                             shard_kernel)
+
+    qv = _unwrap(query)
+    kv = _unwrap(key)
+    bshd = "b-h-"  # [batch, seq, heads, head_dim]: see sharding_ctx.shard_kernel
+
     # fused short-sequence path (encoder workloads: BERT/ERNIE S<=512): one
     # Pallas kernel per step with probs + dropout masks held in VMEM — the
     # dense path's [B,H,S,S] logits/probs/mask HBM round-trips disappear
     # (ops/encoder_attention.py; ref fused_attention_op.cu regime)
-    if backend == "auto" and attn_mask is None:
-        from ...core.device import is_tpu_backend
+    if backend == "auto" and attn_mask is None and qv.ndim == 4 \
+            and is_tpu_backend():
         from ...ops import encoder_attention as _enc
 
-        qv = _unwrap(query)
-        kv = _unwrap(key)
-        use_enc = (qv.ndim == 4 and is_tpu_backend()
-                   and _enc.supported(qv.shape[0] * qv.shape[2], qv.shape[1],
-                                      qv.shape[-1], kv.shape[1]))
-        if use_enc:
+        b, s, h, d = local_shape(qv.shape, bshd)
+        if _enc.supported(b * h, s, d, kv.shape[1]):
             rate = float(dropout_p) if (dropout_p and training) else 0.0
-            sc = scale
 
             def _f(q, k, v):
-                seed = None
-                if rate > 0.0:
-                    seed = jax.random.bits(_random.get_rng_key(), (2,),
-                                           jnp.uint32).astype(jnp.int32)
-                return _enc.encoder_attention(q, k, v, seed=seed, scale=sc,
-                                              dropout_rate=rate,
-                                              causal=is_causal)
+                # drawn OUTSIDE the shard_map: the RNG stream is global state
+                seed = jax.random.bits(
+                    _random.get_rng_key(), (2,), jnp.uint32
+                ).astype(jnp.int32) if rate > 0.0 else jnp.zeros((2,), jnp.int32)
+                return shard_kernel(
+                    lambda q, k, v, seed: _enc.encoder_attention(
+                        q, k, v, seed=seed + shard_index(), scale=scale,
+                        dropout_rate=rate, causal=is_causal),
+                    (bshd, bshd, bshd, ""), bshd)(q, k, v, seed)
 
             return apply_op(_f, (query, key, value), name="encoder_attention")
 
     use_flash = False
     if backend in ("auto", "flash"):
-        try:
-            qv = _unwrap(query)
-            kv = _unwrap(key)
-            seq = qv.shape[1]
-            seq_k = kv.shape[1]
-            hd = qv.shape[-1]
-            from ...core.device import is_tpu_backend
+        seq = qv.shape[1]
+        seq_k = kv.shape[1]
+        hd = qv.shape[-1]
+        no_drop = dropout_p == 0.0 or not training
+        if backend == "flash" and not no_drop:
+            import warnings
 
-            on_tpu = is_tpu_backend()
-            no_drop = dropout_p == 0.0 or not training
-            if backend == "flash" and not no_drop:
-                import warnings
+            warnings.warn(
+                "backend='flash' with active attention dropout falls back to the "
+                "dense SDPA path (the Pallas flash kernel has no dropout); full "
+                "[B,H,S,S] attention probs will be materialized")
+        from ...ops.flash_attention import supports_seq
 
-                warnings.warn(
-                    "backend='flash' with active attention dropout falls back to the "
-                    "dense SDPA path (the Pallas flash kernel has no dropout); full "
-                    "[B,H,S,S] attention probs will be materialized")
-            from ...ops.flash_attention import supports_seq
-
-            blocks_ok = supports_seq(seq) and supports_seq(seq_k)
-            causal_ok = not is_causal or seq <= seq_k
-            # blocks_ok gates BOTH paths: an explicit backend='flash' request
-            # with an untileable length falls back to dense instead of raising
-            # deep inside _auto_block
-            use_flash = (backend == "flash" and no_drop and causal_ok
-                         and blocks_ok) or (
-                on_tpu and seq >= 1024 and blocks_ok and causal_ok
-                and hd in (64, 128, 256) and attn_mask is None and no_drop
-            )
-        except Exception:
-            use_flash = False
+        blocks_ok = supports_seq(seq) and supports_seq(seq_k)
+        causal_ok = not is_causal or seq <= seq_k
+        # blocks_ok gates BOTH paths: an explicit backend='flash' request
+        # with an untileable length falls back to dense instead of raising
+        # deep inside _auto_block
+        use_flash = (backend == "flash" and no_drop and causal_ok
+                     and blocks_ok) or (
+            is_tpu_backend() and seq >= 1024 and blocks_ok and causal_ok
+            and hd in (64, 128, 256) and attn_mask is None and no_drop
+        )
 
     if use_flash:
         from ...ops.flash_attention import flash_attention as _flash
 
-        def _f(q, k, v):
-            return _flash(q, k, v, causal=is_causal, scale=scale)
-
+        _f = shard_kernel(
+            lambda q, k, v: _flash(q, k, v, causal=is_causal, scale=scale),
+            (bshd, bshd, bshd), bshd)
         return apply_op(_f, (query, key, value), name="flash_attention")
 
     def _f(q, k, v, m):

@@ -37,10 +37,9 @@ def _conv_padding(padding, nd):
 # When True, channel-first convs are internally rewritten to channel-last
 # ("NHWC"/"HWIO") with boundary transposes; when False the NCHW dimension numbers
 # are handed to XLA directly (its layout assignment picks physical layouts anyway).
-# Benchmarked on v5e (bench.py, r3 RTT-corrected timing): direct NCHW wins
-# (2245 vs 2198 img/s on ResNet-50 train; XLA's layout assignment already
-# picks physical layouts), so the default is False; kept as a switch for
-# future autotuning.
+# Direct NCHW is the default: XLA's layout assignment already picks physical
+# layouts (an earlier A/B on v5e read 2245 vs 2198 img/s on ResNet-50 train;
+# its record was deleted in PR 21 and it has not been retaken).
 _INTERNAL_CHANNEL_LAST = False
 
 

@@ -5,6 +5,8 @@ calling Layer — keeping the computation pure so whole steps jit cleanly.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -131,16 +133,18 @@ def fused_dropout_add_layer_norm(x, residual, weight, bias, p=0.0, epsilon=1e-5,
     eps = float(epsilon)
 
     def _f(xb, res, w, b):
-        h = xb.shape[-1]
-        n = 1
-        for d in xb.shape[:-1]:
-            n *= d
         from ...core.device import is_tpu_backend
 
-        if is_tpu_backend() and w is not None and b is not None:
+        if is_tpu_backend() and w is not None and b is not None \
+                and xb.ndim >= 2:
+            from ...distributed.sharding_ctx import (local_shape, shard_index,
+                                                     shard_kernel)
             from ...ops import fused_ln as _k
 
-            if _k.supported(n, h):
+            rows = "b" + "-" * (xb.ndim - 1)  # leading dim is the batch
+            # rows the kernel sees: per shard when a mesh is active
+            n = math.prod(local_shape(xb.shape, rows)[:-1])
+            if _k.supported(n, xb.shape[-1]):
                 if rate > 0.0:
                     key = _random.get_rng_key()
                     seed = jax.random.bits(key, (2,), jnp.uint32).astype(jnp.int32)
@@ -148,8 +152,11 @@ def fused_dropout_add_layer_norm(x, residual, weight, bias, p=0.0, epsilon=1e-5,
                     # no dropout -> no RNG stream advance (keeps seed-for-seed
                     # parity with the composed/CPU path in eval mode)
                     seed = jnp.zeros((2,), jnp.int32)
-                return _k.fused_dropout_add_layer_norm(xb, res, w, b, seed,
-                                                       rate, eps)
+                return shard_kernel(
+                    lambda xb, res, w, b, seed:
+                        _k.fused_dropout_add_layer_norm(
+                            xb, res, w, b, seed + shard_index(), rate, eps),
+                    (rows, rows, "", "", ""), rows)(xb, res, w, b, seed)
         # composed path: identical math, jax.random mask
         xv = xb
         if rate > 0.0:

@@ -158,6 +158,7 @@ def _attn_fwd(q, k, v, seed, scale, rate, causal):
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         interpret=interpret,
         compiler_params=_params(interpret),
+        name="encoder_attention_fwd",
     )(seed, q, k, v)
     return out, (q, k, v, seed)
 
@@ -190,6 +191,7 @@ def _attn_bwd(scale, rate, causal, res, do):
         ],
         interpret=interpret,
         compiler_params=_params(interpret),
+        name="encoder_attention_bwd",
     )(seed, q, k, v, do)
     return dq, dk, dv, None
 

@@ -7,9 +7,10 @@ cuDNN-style fused BN-stats/apply epilogues its CUDA kernels rely on
 contract).  This is the TRAINING-mode analog, designed for the TPU memory
 system rather than translated.
 
-The measured ResNet-50 train step is HBM-bound end to end (44.8 GB/step at
-~780 GB/s; conv MXU time is ~17.5 ms of a 47.5 ms step — RESNET_BREAKDOWN.md).
-Every win here is a removed full-tensor memory pass:
+The ResNet-50 train step is HBM-bound by its schedule (about 44.8 GB moved
+per step at batch 128 against ~17.5 ms of conv MXU time; the timed breakdown
+that said so was deleted with its records in PR 21 and has not been retaken
+on the attached chip).  Every win here is a removed full-tensor memory pass:
 
 - forward "fold": the PREVIOUS BatchNorm's normalize + ReLU is applied on the
   fly to the conv input as it streams from HBM, so the normalized activation

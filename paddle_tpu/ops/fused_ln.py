@@ -132,6 +132,7 @@ def _fused_fwd(x, y, gamma, beta, seed, rate, eps, upscale):
         ],
         interpret=interpret,
         compiler_params=_params(interpret),
+        name="fused_ln_fwd",
     )(seed, x, y, gamma.reshape(1, h), beta.reshape(1, h))
     return out, (s, gamma, seed)
 
@@ -166,6 +167,7 @@ def _fused_bwd(rate, eps, upscale, res, dz):
         ],
         interpret=interpret,
         compiler_params=_params(interpret),
+        name="fused_ln_bwd",
     )(seed, s, gamma.reshape(1, h), dz)
     dg = jnp.sum(dgp.reshape(nb, 8, h)[:, 0], axis=0).astype(gamma.dtype)
     db = jnp.sum(dbp.reshape(nb, 8, h)[:, 0], axis=0).astype(gamma.dtype)

@@ -81,8 +81,8 @@ class Tensor:
         try:
             devs = self._value.devices()
             dev = next(iter(devs))
-            kind = _device._kind(dev)
-            return _device.TPUPlace(dev.id) if kind == "tpu" else _device.CPUPlace(dev.id)
+            return _device.TPUPlace(dev.id) if dev.platform == "tpu" \
+                else _device.CPUPlace(dev.id)
         except Exception:
             return _device._get_place()
 

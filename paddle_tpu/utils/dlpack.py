@@ -15,7 +15,7 @@ __all__ = ["to_dlpack", "from_dlpack"]
 
 def to_dlpack(x):
     """Export a Tensor (or jax.Array) as a DLPack capsule.  Devices whose PJRT
-    plugin cannot hand out external buffer references (e.g. tunneled TPU)
+    plugin cannot hand out external buffer references
     fall back to a host copy — correct, just not zero-copy."""
     import numpy as np
 

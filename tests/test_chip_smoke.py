@@ -1,0 +1,271 @@
+"""Bring-up checks that need no chip (ISSUE 21).
+
+Tier-1: chip_smoke.py refuses to run off the chip; Pallas kernels called
+through `sharding_ctx.shard_kernel` on the 8-device CPU mesh equal the
+unsharded call.  `slow`: every Pallas kernel AOT-compiles for a v5e 2x2
+topology with Mosaic on (the only way short of a chip to see "Mosaic
+kernels cannot be automatically partitioned"), and the smoke's own phase
+functions run end to end at a tiny size.
+"""
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from paddle_tpu.distributed import build_mesh
+from paddle_tpu.distributed.sharding_ctx import (local_shape, mesh_scope,
+                                                 shard_index, shard_kernel)
+from paddle_tpu.ops import encoder_attention as enc
+from paddle_tpu.ops import fused_ln
+from paddle_tpu.ops.flash_attention import flash_attention
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BSHD = "b-h-"
+
+
+@pytest.fixture
+def smoke(monkeypatch):
+    monkeypatch.syspath_prepend(REPO)
+    import chip_smoke
+
+    return chip_smoke
+
+
+def test_smoke_refuses_off_chip(smoke, capsys):
+    """In-process (the suite runs under JAX_PLATFORMS=cpu): main() must exit
+    non-zero at the device gate, name the platform, and print no result."""
+    with pytest.raises(SystemExit) as exc:
+        smoke.main()
+    assert exc.value.code not in (0, None) and "'cpu'" in str(exc.value.code)
+    assert '"ok"' not in capsys.readouterr().out
+
+
+def _grads(fn, args, n_diff):
+    def loss(*a):
+        o = fn(*a)
+        return jnp.sum(o * jnp.cos(o))
+
+    return jax.jit(jax.value_and_grad(loss, argnums=tuple(range(n_diff))))(*args)
+
+
+def _assert_same(got, want, tol):
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=tol, atol=tol)
+
+
+@pytest.fixture(scope="module")
+def mesh():
+    return build_mesh(dp=2, sharding=2, mp=2)
+
+
+def test_flash_through_mesh_helper(mesh):
+    rng = np.random.RandomState(0)
+    q, k, v = (jnp.asarray(rng.randn(4, 128, 4, 64).astype(np.float32))
+               for _ in range(3))
+    kern = lambda q, k, v: flash_attention(q, k, v, causal=True)  # noqa: E731
+    want = _grads(kern, (q, k, v), 3)
+    with mesh_scope(mesh):
+        got = _grads(shard_kernel(kern, (BSHD,) * 3, BSHD), (q, k, v), 3)
+    _assert_same(got, want, 2e-4)
+
+
+def test_encoder_attention_through_mesh_helper(mesh):
+    rng = np.random.RandomState(1)
+    q, k, v = (jnp.asarray(rng.randn(4, 128, 2, 64).astype(np.float32))
+               for _ in range(3))
+    seed = jnp.zeros((2,), jnp.int32)
+    kern = lambda q, k, v, s: enc.encoder_attention(q, k, v, seed=s)  # noqa: E731
+    want = _grads(kern, (q, k, v, seed), 3)
+    with mesh_scope(mesh):
+        got = _grads(shard_kernel(kern, (BSHD,) * 3 + ("",), BSHD),
+                     (q, k, v, seed), 3)
+    _assert_same(got, want, 2e-4)
+
+
+def test_fused_ln_through_mesh_helper(mesh):
+    rng = np.random.RandomState(2)
+    x, res = (jnp.asarray(rng.randn(8, 16, 128).astype(np.float32))
+              for _ in range(2))
+    g = jnp.asarray(rng.rand(128).astype(np.float32) + 0.5)
+    b = jnp.asarray(rng.randn(128).astype(np.float32))
+    seed = jnp.zeros((2,), jnp.int32)
+    rows = "b--"
+
+    def kern(x, res, g, b, seed, rate=0.0):
+        return fused_ln.fused_dropout_add_layer_norm(
+            x, res, g, b, seed + shard_index(), rate, 1e-5)
+
+    want = _grads(kern, (x, res, g, b, seed), 4)
+    with mesh_scope(mesh):
+        wrap = lambda f: shard_kernel(f, (rows, rows, "", "", ""), rows)  # noqa: E731
+        got = _grads(wrap(kern), (x, res, g, b, seed), 4)
+        # identical rows on every data shard, dropout on: shard_index() must
+        # give each shard its own mask (the block id restarts at 0 per shard)
+        same = jnp.broadcast_to(x[:1], x.shape)
+        out = jax.jit(wrap(lambda *a: kern(*a, rate=0.5)))(
+            same, jnp.zeros_like(res), g, b, seed)
+    _assert_same(got, want, 2e-4)
+    out = np.asarray(out)
+    assert not np.array_equal(out[:2], out[2:4])
+
+
+def test_mesh_helper_rejects_non_dividing_shapes(mesh):
+    q = jnp.zeros((4, 128, 3, 64), jnp.float32)  # 3 heads over mp=2
+    kern = lambda q, k, v: flash_attention(q, k, v)  # noqa: E731
+    assert shard_kernel(kern, (BSHD,) * 3, BSHD) is kern  # no mesh: identity
+    assert local_shape(q.shape, BSHD) == q.shape
+    with mesh_scope(mesh):
+        assert local_shape((4, 128, 4, 64), BSHD) == (1, 128, 2, 64)
+        with pytest.raises(ValueError, match=r"\(4, 128, 3, 64\).*heads"):
+            shard_kernel(kern, (BSHD,) * 3, BSHD)(q, q, q)
+        with pytest.raises(ValueError, match=r"\(6, 128, 4, 64\).*batch"):
+            local_shape((6, 128, 4, 64), BSHD)
+
+
+# --------------------------------------------------------------- slow: AOT
+@pytest.fixture(scope="module")
+def v5e_devices():
+    """The four devices of a v5e 2x2 the installed libtpu can compile for
+    with no chip attached."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                            platform="tpu")
+    except Exception as e:  # no libtpu, or one that cannot describe a v5e
+        pytest.skip(f"cannot create a v5e:2x2 topology here: {e!r}")
+    return topo.devices
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    """Every dispatch site and kernel behaves as on the chip: Mosaic
+    compilation (interpret=False), TPU-only kernels selected."""
+    from paddle_tpu.core import device
+
+    monkeypatch.setattr(device, "is_tpu_backend", lambda: True)
+
+
+def _mosaic_calls(fn, sharding, *shapes_dtypes):
+    """AOT-compile fn for the sharding's (chipless) TPU devices; return the
+    op_name of each Mosaic custom call in the optimized HLO."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+            for s, d in shapes_dtypes]
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    return [re.search(r'op_name="([^"]*)"', ln).group(1)
+            for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+
+
+def _one_device(devs):
+    return NamedSharding(Mesh(np.array(devs[:1]), ("x",)), P())
+
+
+def _names(calls, *want):
+    return all(any(w in c for c in calls) for w in want)
+
+
+@pytest.mark.slow
+def test_aot_flash_and_helper_under_2x2_mesh(v5e_devices, as_on_tpu):
+    bf = jnp.bfloat16
+    qkv = [((4, 2048, 32, 128), bf)] * 3
+
+    def loss(q, k, v, wrap=lambda f: f):
+        o = wrap(lambda q, k, v: flash_attention(q, k, v, causal=True))(q, k, v)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    grad = jax.grad(loss, argnums=(0, 1, 2))
+    calls = _mosaic_calls(grad, _one_device(v5e_devices), *qkv)
+    assert _names(calls, "flash_fwd", "flash_dq", "flash_dkv"), calls
+
+    from paddle_tpu.distributed.topology import AXIS_ORDER
+
+    mesh = Mesh(np.array(v5e_devices).reshape(1, 1, 2, 1, 2), AXIS_ORDER)
+    sharded = NamedSharding(mesh, P("sharding", None, "mp", None))
+    # plain GSPMD over a real mesh: the failure ShardedTrainStep used to hit
+    with pytest.raises(NotImplementedError, match="Mosaic kernels cannot be "
+                       "automatically partitioned"):
+        _mosaic_calls(grad, sharded, *qkv)
+
+    def grad_through_helper(q, k, v):
+        with mesh_scope(mesh):
+            return jax.grad(lambda *a: loss(*a, wrap=lambda f: shard_kernel(
+                f, (BSHD,) * 3, BSHD)), argnums=(0, 1, 2))(q, k, v)
+
+    calls = _mosaic_calls(grad_through_helper, sharded, *qkv)
+    assert _names(calls, "flash_fwd", "flash_dq", "flash_dkv"), calls
+
+
+@pytest.mark.slow
+def test_aot_encoder_kernels(v5e_devices, as_on_tpu):
+    one = _one_device(v5e_devices)
+    bf, i32 = jnp.bfloat16, jnp.int32
+    for causal, rate in ((False, 0.1), (True, 0.0)):
+        def attn(q, k, v, seed):
+            o = enc.encoder_attention(q, k, v, seed=seed, dropout_rate=rate,
+                                      causal=causal)
+            return jnp.sum(o.astype(jnp.float32) ** 2)
+
+        calls = _mosaic_calls(jax.grad(attn, argnums=(0, 1, 2)), one,
+                              *[((32, 128, 12, 64), bf)] * 3, ((2,), i32))
+        assert _names(calls, "encoder_attention_fwd",
+                      "encoder_attention_bwd"), calls
+    for rate in (0.0, 0.1):
+        def ln(x, res, g, b, seed):
+            o = fused_ln.fused_dropout_add_layer_norm(x, res, g, b, seed,
+                                                      rate, 1e-5)
+            return jnp.sum(o.astype(jnp.float32) ** 2)
+
+        calls = _mosaic_calls(
+            jax.grad(ln, argnums=(0, 1, 2, 3)), one,
+            ((4096, 768), bf), ((4096, 768), bf), ((768,), bf), ((768,), bf),
+            ((2,), i32))
+        assert _names(calls, "fused_ln_fwd", "fused_ln_bwd"), calls
+
+
+@pytest.mark.slow
+def test_aot_decode_kernels(v5e_devices, as_on_tpu):
+    from paddle_tpu.ops import decode_attention as da
+
+    one = _one_device(v5e_devices)
+    bf, i8, f32, i32 = jnp.bfloat16, jnp.int8, jnp.float32, jnp.int32
+    H, D, page, pages, M, B = 32, 128, 128, 129, 16, 8
+    for S, b in ((1, B), (5, B), (256, 1)):
+        for kv, scales in ((bf, ()), (i8, (((pages, H, page), f32),) * 2)):
+            calls = _mosaic_calls(
+                lambda q, k, v, off, tbl, *sc: da.paged_decode_attention(
+                    q, k, v, off, tbl, *sc), one,
+                ((b, S, H, D), bf), ((pages, H, page, D), kv),
+                ((pages, H, page, D), kv), ((b,), i32), ((b, M), i32), *scales)
+            assert _names(calls, "paged_attention"), (S, kv, calls)
+    L = 1024
+    for kv, scales in ((bf, ()), (i8, (((B, 16, L), f32),) * 2)):
+        calls = _mosaic_calls(
+            lambda q, k, v, off, *sc: da.decode_attention(q, k, v, off, *sc),
+            one, ((B, 1, 16, D), bf), ((B, 16, L, D), kv), ((B, 16, L, D), kv),
+            ((B,), i32), *scales)
+        assert _names(calls, "decode_attention"), (kv, calls)
+
+
+# --------------------------------------------- slow: the smoke's control flow
+@pytest.mark.slow
+def test_smoke_phases_at_tiny_size_on_cpu(smoke, monkeypatch):
+    """chip_smoke.py's own phase functions, head_dim and page size kept at
+    128 so the ragged paged kernel (interpret mode) is the path taken.  The
+    two checks only a chip can pass are stubbed."""
+    monkeypatch.setattr(smoke, "_check_flash_in_hlo", lambda *a: None)
+    monkeypatch.setattr(smoke, "_check_memory", lambda *a: None)
+    tiny = dict(hidden_size=256, intermediate_size=512, vocab_size=512,
+                num_attention_heads=2, num_key_value_heads=2)
+    geom = smoke.serve_phase(n_layers=2, max_seq_len=512,
+                             prompt_lens=(40, 440), new_tokens=8,
+                             max_position_embeddings=512, **tiny)
+    assert set(smoke.kernel_phase(**geom)) == {
+        "S1_bf16", "S1_int8", "S256_bf16", "S256_int8", "S5_bf16", "S5_int8"}
+    losses = smoke.train_phase(n_layers=2, batch=4, seq=128, **tiny)
+    assert smoke.mesh_phase(losses[0], n_layers=2, batch=4, seq=128,
+                            **tiny) is not None

@@ -95,6 +95,9 @@ def main(argv=None) -> int:
     ap.add_argument("--root", default=os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))))
     args = ap.parse_args(argv)
+    if not os.path.exists(os.path.join(args.root, "PROJECTION.md")):
+        print("docs_lint: no PROJECTION.md, nothing to check")
+        return 0
     findings = check(args.root)
     for path, line, msg in findings:
         print(f"{path}:{line}: docs-stale {msg}")
