@@ -82,6 +82,7 @@ from ..observability import metrics as _obs
 from ..observability import profiling as _profiling
 from ..observability import slo as _slo
 from ..observability import tracing as _tracing
+from ..observability.spans import PhaseClock as _PhaseClock
 from ..observability.spans import span as _span
 from ..ops.sampling import sample_rows as _sample_rows
 from ..ops.sampling import spec_accept as _spec_accept
@@ -208,6 +209,38 @@ _M_KV_PROMOTE_S = _obs.histogram(
     "llm_kv_promote_seconds",
     "One batched promotion (tier reads + a single host->device upload)")
 
+#: The pump's tick, cut into mutually exclusive phases in tick order (a
+#: speculative tick runs the spec_* phases in place of decode_*).  Every
+#: second between the tick's first line and its last is in exactly one.
+_TICK_PHASES = (
+    "expire",            # _expire_queued, _expire_slots
+    "admit",             # _start_prefill: pop, prefix match, tiers, pages
+    "prefill_stage",     # COW fork, the chunk's host arrays
+    "prefill_dispatch",  # the chunk program's (asynchronous) call
+    "first_token_sync",  # np.asarray(logits) + host select + activation
+    "decode_stage",      # page growth, uploads, rng split, knobs, masks
+    "decode_dispatch",   # the decode program's call, until it returns
+    "decode_sync",       # np.asarray(nxt_dev): the wait for the device
+    "bookkeep",          # gauges, token loop, _finish, span flushes
+    "spec_draft", "spec_stage", "spec_dispatch", "spec_sync", "spec_accept")
+#: phases in which the host only waits for the device; every other phase
+#: is the host's own work (stats()["tick_phases"]["host_s"])
+_SYNC_PHASES = ("first_token_sync", "decode_sync", "spec_sync")
+_M_TICK_PHASE_S = _obs.counter(
+    "llm_tick_phase_seconds_total",
+    "Pump seconds per exclusive tick phase (the phases add up to "
+    "llm_decode_tick_duration_seconds' sum)", labelnames=("phase",))
+_TICK_PHASE_SERIES = {p: _M_TICK_PHASE_S.labels(phase=p)
+                      for p in _TICK_PHASES}
+#: why a tick left the queue head waiting: exactly one reason a tick
+_BLOCK_REASONS = ("prefill_busy", "no_slot", "no_pages", "no_adapter_page")
+_M_ADM_BLOCKED = _obs.counter(
+    "llm_admission_blocked_ticks_total",
+    "Ticks that ended with a request queued and none admitted, by what "
+    "held the queue head", labelnames=("reason",))
+_ADM_BLOCKED_SERIES = {r: _M_ADM_BLOCKED.labels(reason=r)
+                       for r in _BLOCK_REASONS}
+
 
 def _attn_dispatch_series():
     """[(label values, count)] for every `llm_attn_kernel_total` child.
@@ -224,9 +257,10 @@ _SLO_SERIES = {"ttft": "llm_ttft", "e2e": "llm_e2e",
                "queue_wait": "llm_queue_wait", "tick": "llm_tick",
                "verify": "llm_verify", "promote": "llm_promote"}
 
-#: Decode ticks coalesce into ONE trace summary span per this many ticks
-#: (and per admission episode) — a 10k-token decode contributes a bounded
-#: handful of spans to its request trace, never 10k.
+#: Decode ticks coalesce into ONE trace summary span per this many tokens
+#: (= ticks at one token a tick; and per admission episode) — a 10k-token
+#: decode contributes a bounded handful of spans to its request trace,
+#: never 10k.
 _DECODE_SPAN_TICKS = 256
 
 
@@ -299,9 +333,11 @@ class _Request:
     adm_episode: int = 0            # admission attempts (requeues re-admit)
     requeue_reason: str | None = None  # why the LAST requeue happened —
                                     # stamped on the next admission span
-    dec_ticks: int = 0              # coalesced decode-summary window
-    dec_tokens: int = 0
-    dec_t0: float | None = None
+    token_ts: list = field(default_factory=list)  # perf_counter stamp of
+                                    # every emitted token (tokens of one
+                                    # tick share one); survives requeues
+    dec_i0: int = 0                 # token_ts index where the current
+                                    # coalesced decode-summary window begins
     adm_skips: int = 0              # cache-aware admission passed this
                                     # request over (aging/fairness cap)
     spec_drafted: int = 0           # speculative-decode window counters,
@@ -311,6 +347,7 @@ class _Request:
     on_admit: object = None         # fired once at first slot admission —
                                     # the router's admission ack (after it,
                                     # the request is no longer retry-safe)
+    on_token: object = None         # cb(index, token, t) per emitted token
     adapter_id: object = None       # LoRA adapter id (None = base model)
     adapter_page: int = 0           # pool page pinned while in a slot;
                                     # 0 = none held (page 0 is the zero
@@ -330,9 +367,11 @@ def _select_rows(logits, key, do_sample, temperature, top_k, top_p,
     fused sampler (ops/sampling.sample_rows), which generation._select
     also delegates to, so the engine and the solo loop share one masking
     + categorical implementation.  ``token_mask`` (bool [B, V]) is the
-    constrained-decoding path; all-True rows are exact no-ops."""
-    return _sample_rows(logits, key, do_sample, temperature, top_k, top_p,
-                        token_mask=token_mask)
+    constrained-decoding path; all-True rows are exact no-ops.  The
+    named scope only labels the ops ("sampler" in the profiler's op_name)."""
+    with jax.named_scope("sampler"):
+        return _sample_rows(logits, key, do_sample, temperature, top_k,
+                            top_p, token_mask=token_mask)
 
 
 def _lora_ctx(pool, tree, rows):
@@ -654,6 +693,11 @@ class LLMEngine:
         # conservation invariant (sum(buckets) == wall span) holds after
         # every tick — tests assert it via self._goodput.check()
         self._goodput = _goodput.TimeLedger("serve")
+        # the tick's exclusive phases (one clock read a boundary, the same
+        # boundaries the goodput carves use) and what held the queue head
+        self._phases = _PhaseClock("llm_tick", _TICK_PHASES)
+        self._phase_pub = dict.fromkeys(_TICK_PHASES, 0.0)
+        self._adm_blocked = dict.fromkeys(_BLOCK_REASONS, 0)
         self.cache_aware = bool(cache_aware_admission)
         self.admission_age_cap = max(1, int(admission_age_cap))
         if self.cache_aware and (not self.paged or self._prefix is None):
@@ -856,7 +900,7 @@ class LLMEngine:
     def submit(self, prompt_ids, max_new_tokens=32, do_sample=False,
                temperature=1.0, top_k=0, top_p=1.0, timeout=None,
                trace_id=None, on_admit=None, adapter_id=None,
-               constraint=None):
+               constraint=None, on_token=None):
         """Queue one prompt; returns a Future of the generated id list.
         Sampling knobs are PER REQUEST — including ``top_k``: slots with
         different settings decode in the same compiled step (the fused
@@ -877,7 +921,12 @@ class LLMEngine:
         ``on_admit`` is a zero-arg callback fired ONCE when the request
         first lands in a batch slot — the admission ack after which the
         request must not be retried elsewhere (it will produce output
-        here).
+        here).  ``on_token(index, token, t)`` fires from the pump once per
+        generated token, in order, with the ``time.perf_counter()`` stamp
+        the engine keeps for it (tokens of one tick share one stamp): the
+        per-token clock for streaming and inter-token gaps.  It runs under
+        the engine lock, so it must not block; a raising callback is
+        swallowed like ``on_admit``'s.
 
         ``adapter_id`` decodes the request through a LoRA adapter
         registered on the engine's ``adapters=`` registry (per-request —
@@ -933,7 +982,7 @@ class LLMEngine:
                            "llm_request", trace_id=trace_id,
                            prompt_tokens=int(arr.size),
                            max_new_tokens=int(max_new_tokens)),
-                       on_admit=on_admit,
+                       on_admit=on_admit, on_token=on_token,
                        adapter_id=adapter_id, constraint=cst,
                        cursor=cst.cursor() if cst is not None else None)
         if self._draining:
@@ -1058,6 +1107,16 @@ class LLMEngine:
             "adapters": self.adapters.stats()
             if self.adapters is not None else None,
             "admission_reorders": self._adm_reorders,
+            # ticks that left the queue head waiting, by what held it
+            "admission_blocked": dict(self._adm_blocked),
+            # the pump's exclusive phases, cumulative since construction;
+            # host_s leaves out the phases that only wait for the device
+            "tick_phases": {
+                "seconds": dict(self._phases.seconds),
+                "count": dict(self._phases.count),
+                "host_s": sum(v for p, v in self._phases.seconds.items()
+                              if p not in _SYNC_PHASES),
+            },
             "prefill_in_progress": self._prefilling is not None,
             "pump_alive": self._thread.is_alive()
             if self._thread is not None else False,
@@ -1309,16 +1368,20 @@ class LLMEngine:
         summary span (ticks + tokens attributes) — called at the window
         bound, at finish, and before any requeue/expiry, so a trace holds
         a bounded number of decode spans no matter how long it decoded."""
-        if req.dec_ticks:
+        window = req.token_ts[req.dec_i0:]
+        if window and req.trace:
+            # the window's ticks are its distinct stamps: the tokens of
+            # one tick (a decode chunk, a verify ladder) share one
+            ticks = len(set(window))
             req.trace.add_span(
                 "decode",
-                duration_s=max(0.0, time.perf_counter() - req.dec_t0),
-                ticks=int(req.dec_ticks), tokens=int(req.dec_tokens))
+                duration_s=max(0.0, time.perf_counter() - window[0]),
+                ticks=ticks, tokens=len(window))
             if req.spec_drafted:
                 # speculative summary triplet for the same window: the
                 # spec envelope plus its draft/verify phase breakdown,
                 # each carrying the window's mean accepted_len
-                acc_len = round(req.spec_accepted / req.dec_ticks, 3)
+                acc_len = round(req.spec_accepted / ticks, 3)
                 req.trace.add_span(
                     "spec",
                     duration_s=req.spec_draft_s + req.spec_verify_s,
@@ -1329,9 +1392,7 @@ class LLMEngine:
                                    tokens=int(req.spec_drafted))
                 req.trace.add_span("verify", duration_s=req.spec_verify_s,
                                    accepted_len=acc_len)
-        req.dec_ticks = 0
-        req.dec_tokens = 0
-        req.dec_t0 = None
+        req.dec_i0 = len(req.token_ts)
         req.spec_drafted = 0
         req.spec_accepted = 0
         req.spec_draft_s = 0.0
@@ -1345,8 +1406,40 @@ class LLMEngine:
         if req.adm_span is not None:
             req.adm_span.close(error=None if status == "ok" else status)
             req.adm_span = None
+        ts = req.token_ts
+        if ts and req.trace:
+            attrs["ttft_s"] = req.trace.rel_s(ts[0])
+            if len(ts) > 1:
+                attrs["token_gap_max_s"] = max(
+                    b - a for a, b in zip(ts, ts[1:]))
+                attrs["token_gap_mean_s"] = (ts[-1] - ts[0]) / (len(ts) - 1)
         req.trace.end(status=status, generated_tokens=len(req.tokens),
                       **attrs)
+
+    @staticmethod
+    def _emit_token(req, tok, t):
+        """One generated token leaves the pump: kept with its stamp ``t``
+        (``time.perf_counter()``), streamed to ``on_token``."""
+        req.tokens.append(tok)
+        req.token_ts.append(t)
+        if req.on_token is not None:
+            try:
+                req.on_token(len(req.tokens) - 1, tok, t)
+            except Exception:
+                pass  # a failing stream callback must never kill the pump
+
+    def _first_token_out(self, req, first):
+        """The admission's final step, both layouts: the first token has
+        just been emitted.  Closes the admission span; on the request's
+        FIRST admission stamps ``ttft_s`` on the trace (the same instant,
+        relative to the trace's start) and observes ``llm_ttft_seconds``."""
+        req.dec_i0 = len(req.token_ts)  # decode windows hold decode tokens
+        if req.adm_span is not None:
+            req.adm_span.close()
+            req.adm_span = None
+        if first and req.submit_ts is not None:
+            req.trace.set_attr("ttft_s", req.trace.rel_s(req.token_ts[0]))
+            self._observe_ttft(req)
 
     def _trace_queue_wait(self, req):
         """First-admission queue-wait: histogram (+trace exemplar), SLO
@@ -1399,7 +1492,7 @@ class LLMEngine:
         real token's logits and the head-major k/v rows."""
         model = self.model
 
-        def run(params, buffers, ids, last_index):
+        def llm_prefill(params, buffers, ids, last_index):
             restore = model.bind_functional_state(params, buffers)
             try:
                 with tape.no_grad():
@@ -1413,7 +1506,7 @@ class LLMEngine:
                    for (k, v) in caches]
             return logits._value, kvs
 
-        return jax.jit(run)
+        return jax.jit(llm_prefill)
 
     def _get_prefill(self, Lb):
         if Lb not in self._prefill_jit:
@@ -1462,6 +1555,13 @@ class LLMEngine:
                         raise
             finally:
                 self._adm_inflight -= 1
+        if not free and not self._pending.empty():
+            self._blocked("no_slot")
+
+    def _blocked(self, reason):
+        """This tick leaves the queue head waiting: count what held it."""
+        self._adm_blocked[reason] += 1
+        _ADM_BLOCKED_SERIES[reason].inc()
 
     def _admit_one(self, req, slot):
         req.admit_ts = self._clock()
@@ -1477,11 +1577,12 @@ class LLMEngine:
             jnp.asarray(n - 1, jnp.int32))
         # causal attention: positions >= n never influence position n-1,
         # so the padded prefill's first n k/v rows are exact
+        self._phases.switch("first_token_sync")
         tok = self._host_select(np.asarray(logits)[0, 0], req)
         self.caches = self._get_slot_writer(Lb)(
             self.caches, kvs, jnp.asarray(slot, jnp.int32))
         req.slot = slot
-        req.tokens = [tok]
+        self._emit_token(req, tok, time.perf_counter())
         self.slot_req[slot] = req
         self.slot_pos[slot] = n
         self.last_token[slot] = tok
@@ -1489,10 +1590,8 @@ class LLMEngine:
         # decode-tick emission
         self._goodput.count_tokens("useful", 1)
         _M_ADMITTED.inc()
-        req.adm_span.close()
-        req.adm_span = None
-        if req.submit_ts is not None:
-            self._observe_ttft(req)
+        self._first_token_out(req, first=True)
+        self._phases.switch("admit")
         if tok == self.eos or req.max_new_tokens <= 1:
             self._finish(slot)
 
@@ -2076,8 +2175,8 @@ class LLMEngine:
         model = self.model
         pool = self.adapters.pool if self.adapters is not None else None
 
-        def run(params, buffers, caches, page_row, ids, off, last_index,
-                lora_tree, lora_rows):
+        def llm_prefill_chunk(params, buffers, caches, page_row, ids, off,
+                              last_index, lora_tree, lora_rows):
             restore = model.bind_functional_state(params, buffers)
             try:
                 with tape.no_grad(), _lora_ctx(pool, lora_tree, lora_rows):
@@ -2096,7 +2195,7 @@ class LLMEngine:
                 restore()
             return logits._value, raw
 
-        return jax.jit(run, donate_argnums=(2,))
+        return jax.jit(llm_prefill_chunk, donate_argnums=(2,))
 
     def _get_chunk_prefill(self):
         if "chunk" not in self._prefill_jit:
@@ -2112,6 +2211,8 @@ class LLMEngine:
         decode token."""
         if self._prefilling is None:
             self._start_prefill()
+        elif not self._pending.empty():
+            self._blocked("prefill_busy")
         if self._prefilling is not None:
             self._prefill_tick()
 
@@ -2159,6 +2260,8 @@ class LLMEngine:
 
     def _start_prefill(self):
         free = [i for i, r in enumerate(self.slot_req) if r is None]
+        if not free and not self._pending.empty():
+            self._blocked("no_slot")
         while free and not self._pending.empty():
             # _adm_inflight (incremented BEFORE the pop) covers the window
             # where the request is out of the queue but not yet in
@@ -2232,6 +2335,7 @@ class LLMEngine:
                     self._release_pages(slot)
                     with self._pending.mutex:
                         self._pending.queue.appendleft(req)
+                    self._blocked("no_pages")
                     return
                 if req.adapter_id is not None and not req.adapter_page:
                     page = self.adapters.acquire(req.adapter_id)
@@ -2243,6 +2347,7 @@ class LLMEngine:
                         self._release_pages(slot)
                         with self._pending.mutex:
                             self._pending.queue.appendleft(req)
+                        self._blocked("no_adapter_page")
                         return
                     req.adapter_page = page
                 # first admission EVER (admit_ts is stamped once and
@@ -2293,6 +2398,8 @@ class LLMEngine:
     def _prefill_tick(self):
         """Run ONE prefill chunk of the admitting request; on the final
         chunk emit the first token and activate the slot."""
+        pc = self._phases
+        pc.switch("prefill_stage")
         req, slot, done = self._prefilling
         if req.future.done() or (req.deadline is not None
                                  and self._clock() > req.deadline):
@@ -2354,7 +2461,12 @@ class LLMEngine:
                 self._pt_host[slot:slot + 1].copy(), chunk,
                 np.full((1,), done, np.int32), np.int32(m - 1)) \
             + self._lora_args([req.adapter_page])
-        t_pf = time.perf_counter()
+        # the call is asynchronous: this phase, like the llm_prefill_chunk
+        # span inside it, is the host's time to DISPATCH the chunk.  The
+        # wait for the chunk shows where the host next reads a result:
+        # first_token_sync below on a final chunk, else this tick's
+        # decode_sync (the device runs the programs in order)
+        t_pf = pc.switch("prefill_dispatch")
         try:
             jit = self._get_chunk_prefill()
             if _obs.enabled():
@@ -2377,13 +2489,14 @@ class LLMEngine:
                 # misleading error — escalate to the pump watchdog instead
                 raise
             return
-        _M_PREFILL_CHUNKS.inc()
         # goodput ledger: a first-episode chunk is productive prefill; a
         # re-admission (adm_episode > 1: page-pool-dry, mid-verify or
-        # COW-starved requeue) recomputes kv it already computed once
+        # COW-starved requeue) recomputes kv it already computed once.
+        # Its seconds are the prefill_dispatch phase's, boundary for boundary
         self._goodput.carve(
             "preempt_recompute_waste" if req.adm_episode > 1 else "prefill",
-            time.perf_counter() - t_pf)
+            pc.switch("bookkeep") - t_pf)
+        _M_PREFILL_CHUNKS.inc()
         done += m
         if done < n:
             self._prefilling = (req, slot, done)
@@ -2394,10 +2507,13 @@ class LLMEngine:
         # sees the tail page as shared and forks it)
         self._cache_insert(slot, req.prompt, trace_id=req.trace.trace_id,
                            adapter_id=req.adapter_id)
+        # the tick's first wait for the device: the chunk (and whatever
+        # was queued before it) must finish before its logits can be read
+        pc.switch("first_token_sync")
         tok = self._host_select(np.asarray(logits)[0, 0], req)
         first = not req.tokens  # re-admission after preemption continues
         req.slot = slot
-        req.tokens.append(tok)
+        self._emit_token(req, tok, time.perf_counter())
         # the final prefill chunk emits one token (both on first admission
         # and on a post-preemption re-admission): useful either way
         self._goodput.count_tokens("useful", 1)
@@ -2410,11 +2526,7 @@ class LLMEngine:
         # this request still about to decode
         self._prefilling = None
         _M_ADMITTED.inc()
-        if req.adm_span is not None:
-            req.adm_span.close()
-            req.adm_span = None
-        if first and req.submit_ts is not None:
-            self._observe_ttft(req)
+        self._first_token_out(req, first)
         if tok == self.eos or len(req.tokens) >= req.max_new_tokens:
             self._finish(slot)
 
@@ -2566,9 +2678,9 @@ class LLMEngine:
             # ride the cached all-True mask (an exact sampler no-op), and
             # adapter swaps change only the gathered rows — so turning
             # either feature on after warmup() never recompiles
-            def run(params, buffers, caches, page_tbl, tokens, pos,
-                    do_sample, temperature, top_k, top_p, token_mask,
-                    keys, lora_tree, lora_rows):
+            def llm_decode(params, buffers, caches, page_tbl, tokens, pos,
+                           do_sample, temperature, top_k, top_p, token_mask,
+                           keys, lora_tree, lora_rows):
                 restore = model.bind_functional_state(params, buffers)
                 try:
                     with tape.no_grad(), _lora_ctx(pool, lora_tree,
@@ -2604,10 +2716,10 @@ class LLMEngine:
                     restore()
                 return toks.T, caches  # [B, chunk]
 
-            return jax.jit(run, donate_argnums=(2,))
+            return jax.jit(llm_decode, donate_argnums=(2,))
 
-        def run(params, buffers, caches, tokens, pos, do_sample, temperature,
-                top_k, top_p, keys):
+        def llm_decode(params, buffers, caches, tokens, pos, do_sample,
+                       temperature, top_k, top_p, keys):
             restore = model.bind_functional_state(params, buffers)
             try:
                 with tape.no_grad():
@@ -2637,7 +2749,7 @@ class LLMEngine:
                 restore()
             return toks.T, caches  # [B, chunk]
 
-        return jax.jit(run, donate_argnums=(2,))
+        return jax.jit(llm_decode, donate_argnums=(2,))
 
     def _verify_fn(self):
         """ONE compiled speculative verify: score K drafts + one bonus
@@ -2651,9 +2763,9 @@ class LLMEngine:
         pool = self.adapters.pool if self.adapters is not None else None
 
         if self.paged:
-            def run(params, buffers, caches, page_tbl, tokens, drafts, pos,
-                    do_sample, temperature, top_k, top_p, key,
-                    lora_tree, lora_rows):
+            def llm_spec_verify(params, buffers, caches, page_tbl, tokens,
+                                drafts, pos, do_sample, temperature, top_k,
+                                top_p, key, lora_tree, lora_rows):
                 restore = model.bind_functional_state(params, buffers)
                 try:
                     with tape.no_grad(), _lora_ctx(pool, lora_tree,
@@ -2679,10 +2791,10 @@ class LLMEngine:
                     restore()
                 return out, n_acc, raw
 
-            return jax.jit(run, donate_argnums=(2,))
+            return jax.jit(llm_spec_verify, donate_argnums=(2,))
 
-        def run(params, buffers, caches, tokens, drafts, pos,
-                do_sample, temperature, top_k, top_p, key):
+        def llm_spec_verify(params, buffers, caches, tokens, drafts, pos,
+                            do_sample, temperature, top_k, top_p, key):
             restore = model.bind_functional_state(params, buffers)
             try:
                 with tape.no_grad():
@@ -2702,7 +2814,7 @@ class LLMEngine:
                 restore()
             return out, n_acc, raw
 
-        return jax.jit(run, donate_argnums=(2,))
+        return jax.jit(llm_spec_verify, donate_argnums=(2,))
 
     def _get_verify(self):
         if self._verify_jit is None:
@@ -2725,9 +2837,19 @@ class LLMEngine:
             # it, so queue_drain holds only the drain's overhead slice
             drain_sec = (self._goodput.section("queue_drain")
                          if self._draining else _goodput.NULL)
+            pc = self._phases
             with drain_sec:
                 with _span("llm_decode_tick", _M_TICK_SECONDS) as sp:
-                    emitted = self._step_locked()
+                    pc.begin()
+                    try:
+                        emitted = self._step_locked()
+                    finally:
+                        pc.end()  # no phase stays open across ticks
+            for phase, child in _TICK_PHASE_SERIES.items():
+                d = pc.seconds[phase] - self._phase_pub[phase]
+                if d > 0.0:
+                    child.inc(d)
+                    self._phase_pub[phase] = pc.seconds[phase]
             self._first_tick_done = True
             if emitted:
                 self._goodput.count_tokens("useful", emitted)
@@ -2739,13 +2861,18 @@ class LLMEngine:
             return emitted
 
     def _step_locked(self):
+        pc = self._phases
+        pc.switch("expire")
         self._expire_queued()
         self._expire_slots()
+        pc.switch("admit")
         if self.paged:
             self._admit_paged()
+            pc.switch("bookkeep")
             self._update_page_gauges()
         else:
             self._admit()
+            pc.switch("bookkeep")
         _M_QUEUE_DEPTH.set(self._pending.qsize())
         active = [i for i, r in enumerate(self.slot_req) if r is not None]
         _M_ACTIVE_SLOTS.set(len(active))
@@ -2767,14 +2894,15 @@ class LLMEngine:
             r is not None and r.cursor is not None for r in self.slot_req)
         if constrained:
             eff = 1
+        t_dec = pc.switch("decode_stage")
         if self.paged:
             # grow page tables to cover this tick's writes; slots the pool
             # cannot cover any longer are preempted (shed, not wedged)
             active = self._ensure_decode_pages(active, eff)
             self._update_page_gauges()
             if not active:
+                self._goodput.carve("decode", pc.switch("bookkeep") - t_dec)
                 return 0
-        t_dec = time.perf_counter()
         jit = self._decode_jit.get(eff)
         if jit is None:
             _profiling.record_compile("decode")
@@ -2808,32 +2936,29 @@ class LLMEngine:
                 token_mask = jnp.asarray(mask_np)
             else:
                 token_mask = self._mask_all_true
-            rows = [r.adapter_page if r is not None else 0 for r in reqs]
-            nxt_dev, new_caches = jit(
-                *args, tokens, pos, do_s, temp, topk, topp, token_mask,
-                keys, *self._lora_args(rows))
+            args += (tokens, pos, do_s, temp, topk, topp, token_mask, keys,
+                     *self._lora_args(
+                         [r.adapter_page if r is not None else 0
+                          for r in reqs]))
         else:
-            nxt_dev, new_caches = jit(
-                *args, tokens, pos, do_s, temp, topk, topp, keys)
+            args += (tokens, pos, do_s, temp, topk, topp, keys)
+        pc.switch("decode_dispatch")
+        nxt_dev, new_caches = jit(*args)
         # the returned tuples carry advanced pos at slot [2], but the
         # engine's [B] slot_pos vector stays authoritative — each tick
         # rebuilds the per-slot positions (finished slots do not advance)
         self.caches = new_caches
+        pc.switch("decode_sync")
         nxt = np.asarray(nxt_dev).astype(np.int32)  # [B, eff]
-        # goodput ledger: arg staging + compiled call + the host sync that
-        # materializes it — productive decode seconds (token bookkeeping
-        # below stays in the idle/queue_drain residual)
-        self._goodput.carve("decode", time.perf_counter() - t_dec)
-        if _obs.enabled():
-            # per-request decode accounting for the coalesced trace
-            # summary spans: one stamp per tick, not per token
-            now_pc = time.perf_counter()
-            for i in active:
-                r = self.slot_req[i]
-                if r is not None:
-                    if r.dec_t0 is None:
-                        r.dec_t0 = now_pc
-                    r.dec_ticks += 1
+        # every token of this tick carries this stamp: the instant the
+        # host had them.  It is also the boundary into bookkeeping, and
+        # the end of the goodput ledger's productive decode seconds (arg
+        # staging + compiled call + the host sync = the three decode_*
+        # phases; the bookkeeping below stays in the idle/queue_drain
+        # residual)
+        t_end = pc.switch("bookkeep")
+        self._goodput.carve("decode", t_end - t_dec)
+        now_pc = t_end or time.perf_counter()  # the clock is off: read it
         emitted = 0
         for j in range(eff):
             for i in list(active):
@@ -2841,13 +2966,12 @@ class LLMEngine:
                 if req is None:
                     continue  # finished earlier in this chunk: surplus
                 tok = int(nxt[i, j])
-                req.tokens.append(tok)
+                self._emit_token(req, tok, now_pc)
                 if req.cursor is not None:
                     # host automaton tracks the device-selected token; the
                     # NEXT tick's mask upload reads the advanced state
                     req.cursor.advance(tok)
                     _constrain.count_masked_token()
-                req.dec_tokens += 1
                 self.last_token[i] = tok
                 self.slot_pos[i] += 1
                 emitted += 1
@@ -2858,7 +2982,8 @@ class LLMEngine:
                     self._finish(i)
         for i in active:
             req = self.slot_req[i]
-            if req is not None and req.dec_ticks >= _DECODE_SPAN_TICKS:
+            if req is not None \
+                    and len(req.token_ts) - req.dec_i0 >= _DECODE_SPAN_TICKS:
                 self._flush_decode_span(req)  # bound spans per episode
         # inactive slots scatter garbage k/v at their stale position during
         # the shared step — harmless: a decode WRITES row `pos` before any
@@ -2872,6 +2997,8 @@ class LLMEngine:
         back — the slot position simply stops at the accept point, and
         (paged) pages holding only rejected rows return to the pool."""
         K = self.spec_k
+        pc = self._phases
+        pc.switch("spec_stage")
         if self.paged:
             # the verify writes rows pos .. pos+K: grow/COW the page
             # tables for all K+1 rows up front; a slot the pool cannot
@@ -2880,15 +3007,16 @@ class LLMEngine:
                                                origin="verify")
             self._update_page_gauges()
             if not active:
+                pc.switch("bookkeep")
                 return 0
-        t0 = time.perf_counter()
+        t0 = pc.switch("spec_draft")
         drafts = np.zeros((self.n_slots, K), np.int32)
         for i in active:
             req = self.slot_req[i]
             ctx = np.concatenate(
                 [req.prompt, np.asarray(req.tokens, np.int32)])
             drafts[i] = self._drafter.propose(ctx, K)
-        draft_s = time.perf_counter() - t0
+        draft_s = pc.switch("spec_stage") - t0
         reqs = self.slot_req
         do_s, temp, topk, topp = self._sampling_knobs()
         from ..framework import random as _fr
@@ -2909,20 +3037,20 @@ class LLMEngine:
             args += self._lora_args(
                 [r.adapter_page if r is not None else 0 for r in reqs])
         jit = self._get_verify()
-        t1 = time.perf_counter()
-        if _obs.enabled():
-            with _span("llm_spec_verify", _M_SPEC_VERIFY_S) as sp:
-                out_dev, n_dev, self.caches = jit(*args)
-                out = np.asarray(out_dev).astype(np.int32)
-                n_acc = np.asarray(n_dev).astype(np.int32)
-            if sp.duration:
-                _slo.track("llm_verify", sp.duration)
-        else:
+        # the verify window (the span, the goodput carve, the request's
+        # verify_s) is spec_dispatch + spec_sync, boundary for boundary
+        with _span("llm_spec_verify", _M_SPEC_VERIFY_S) as sp:
+            t1 = pc.switch("spec_dispatch")
             out_dev, n_dev, self.caches = jit(*args)
+            pc.switch("spec_sync")
             out = np.asarray(out_dev).astype(np.int32)
             n_acc = np.asarray(n_dev).astype(np.int32)
-        verify_s = time.perf_counter() - t1
-        now_pc = time.perf_counter()
+            t_end = pc.switch("spec_accept")
+        if sp.duration:
+            _slo.track("llm_verify", sp.duration)
+        verify_s = t_end - t1
+        # every token of this tick carries this stamp
+        now_pc = t_end or time.perf_counter()  # the clock is off: read it
         emitted = 0
         drafted_tick = 0
         accepted_tick = 0
@@ -2932,9 +3060,6 @@ class LLMEngine:
             if req is None:
                 continue
             if _obs.enabled():
-                if req.dec_t0 is None:
-                    req.dec_t0 = now_pc
-                req.dec_ticks += 1
                 req.spec_drafted += K
                 req.spec_accepted += int(n_acc[i])
                 req.spec_draft_s += draft_s
@@ -2945,8 +3070,7 @@ class LLMEngine:
             # one correction/bonus token (so every verify makes progress)
             for j in range(int(n_acc[i]) + 1):
                 tok = int(out[i, j])
-                req.tokens.append(tok)
-                req.dec_tokens += 1
+                self._emit_token(req, tok, now_pc)
                 self.last_token[i] = tok
                 self.slot_pos[i] += 1
                 emitted += 1
@@ -2990,7 +3114,8 @@ class LLMEngine:
             self._update_page_gauges()
         for i in active:
             req = self.slot_req[i]
-            if req is not None and req.dec_ticks >= _DECODE_SPAN_TICKS:
+            if req is not None \
+                    and len(req.token_ts) - req.dec_i0 >= _DECODE_SPAN_TICKS:
                 self._flush_decode_span(req)
         return emitted
 

@@ -12,6 +12,7 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass, field
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -101,8 +102,6 @@ def apply_rope(x, cos, sin, position_offset=0):
         x1, x2 = x[..., :D // 2], x[..., D // 2:]
         return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
     else:
-        import jax
-
         c = jax.lax.dynamic_slice_in_dim(cos, position_offset, S, 0)
         s = jax.lax.dynamic_slice_in_dim(sin, position_offset, S, 0)
     c = c[None, :, None, :]  # [1,S,1,D/2]
@@ -335,13 +334,17 @@ class LlamaDecoderLayer(nn.Layer):
         self.post_attention_layernorm = nn.RMSNorm(config.hidden_size, config.rms_norm_eps)
 
     def forward(self, x, rope, attn_mask=None, cache=None, use_cache=False):
-        h = self.input_layernorm(x)
-        if use_cache:
-            attn_out, new_cache = self.self_attn(h, rope, attn_mask, cache, use_cache=True)
-        else:
-            attn_out = self.self_attn(h, rope, attn_mask)
-        x = x + attn_out
-        x = x + self.mlp(self.post_attention_layernorm(x))
+        # named scopes label the ops for the profiler (op_name
+        # ".../attention/...", ".../mlp/..."); they change no computation
+        with jax.named_scope("attention"):
+            h = self.input_layernorm(x)
+            if use_cache:
+                attn_out, new_cache = self.self_attn(h, rope, attn_mask, cache, use_cache=True)
+            else:
+                attn_out = self.self_attn(h, rope, attn_mask)
+            x = x + attn_out
+        with jax.named_scope("mlp"):
+            x = x + self.mlp(self.post_attention_layernorm(x))
         if use_cache:
             return x, new_cache
         return x
@@ -379,7 +382,8 @@ class LlamaModel(nn.Layer):
                 new_caches.append(c)
             else:
                 x = layer(x, rope, attn_mask)
-        x = self.norm(x)
+        with jax.named_scope("final_norm"):
+            x = self.norm(x)
         if use_cache:
             return x, new_caches
         return x
@@ -399,9 +403,13 @@ class LlamaForCausalLM(nn.Layer):
         else:
             self.lm_head = nn.Linear(config.hidden_size, config.vocab_size, bias_attr=False)
 
+    def _head(self, hidden):
+        with jax.named_scope("lm_head"):
+            return self.lm_head(hidden)
+
     def forward(self, input_ids, labels=None):
         hidden = self.llama(input_ids)
-        logits = self.lm_head(hidden)
+        logits = self._head(hidden)
         if labels is not None:
             loss = F.cross_entropy(
                 logits.reshape([-1, self.config.vocab_size]),
@@ -420,7 +428,7 @@ class LlamaForCausalLM(nn.Layer):
     def generate_step(self, input_ids, caches=None):
         """Prefill (caches=None) or single-token decode step (inference path)."""
         hidden, caches = self.llama(input_ids, caches=caches, use_cache=True)
-        return self.lm_head(hidden[:, -1:]), caches
+        return self._head(hidden[:, -1:]), caches
 
     def verify_step(self, input_ids, caches):
         """Speculative-decoding verify: score S = K+1 tokens in ONE pass
@@ -430,20 +438,18 @@ class LlamaForCausalLM(nn.Layer):
         generate_step keeps only the last, but the accept/rollback
         decision needs the whole ladder (ops/sampling spec_accept)."""
         hidden, caches = self.llama(input_ids, caches=caches, use_cache=True)
-        return self.lm_head(hidden), caches
+        return self._head(hidden), caches
 
     def prefill_step(self, input_ids, last_index):
         """Bucket-padded prefill (serving admission): the prompt is padded
         PAST `last_index`, so the next-token logits live there, not at -1
         (causal attention keeps positions <= last_index exact under the
         padding).  Returns (logits [B, 1, V], caches)."""
-        import jax
-
         hidden, caches = self.llama(input_ids, caches=None, use_cache=True)
         last = apply_op(
             lambda h: jax.lax.dynamic_slice_in_dim(h, last_index, 1, 1),
             (hidden,), name="prefill_last")
-        return self.lm_head(last), caches
+        return self._head(last), caches
 
     def prefill_chunk_step(self, input_ids, caches, last_index):
         """One CHUNK of an incremental (paged) prefill: input_ids [B, C] are
@@ -456,13 +462,11 @@ class LlamaForCausalLM(nn.Layer):
         per-bucket prefill zoo).  On tile-aligned shapes the chunk's
         attention is the ragged paged Pallas kernel — the per-slot chunk
         offset rides the kernel's prefetched lengths vector."""
-        import jax
-
         hidden, caches = self.llama(input_ids, caches=caches, use_cache=True)
         last = apply_op(
             lambda h: jax.lax.dynamic_slice_in_dim(h, last_index, 1, 1),
             (hidden,), name="prefill_chunk_last")
-        return self.lm_head(last), caches
+        return self._head(last), caches
 
     def generate(self, input_ids, max_new_tokens=32, do_sample=False,
                  temperature=1.0, top_k=0, top_p=1.0, eos_token_id=None,

@@ -10,6 +10,18 @@ the correlation the README's Observability section documents.
 ``metrics.disable()`` turns spans into no-ops too (one dict lookup on
 enter), so instrumented hot paths stay benchmark-clean.
 
+A span times the HOST between enter and exit.  Around an asynchronous
+device call (``llm_prefill_chunk`` around the chunk program) that is the
+call's dispatch, not the device's work: the wait shows wherever the host
+next reads a result (the pump's ``first_token_sync`` or ``decode_sync``
+phase).
+
+:class:`PhaseClock` is the span's cheaper sibling for the INSIDE of a
+loop body: mutually exclusive phases that exhaust one tick, switched on a
+single clock read each, accumulated per owner and written to the
+profiler's timeline as bare ``TraceAnnotation`` s (no histogram, no native
+host-trace buffer, no flight-recorder event per phase).
+
 Every span close also lands one structured event in the process-global
 flight recorder (``observability.flight_recorder``): after a crash the
 black-box dump shows WHICH span was running and how long it had been —
@@ -22,7 +34,7 @@ import time
 from . import metrics as _metrics
 from . import flight_recorder as _flight
 
-__all__ = ["span"]
+__all__ = ["span", "PhaseClock"]
 
 _record_event_cls = None
 
@@ -105,3 +117,83 @@ class span:
                 fields["error"] = err
             _flight.record_event("span", **fields)
         return False
+
+
+class PhaseClock:
+    """Exclusive phases of one loop tick, on the profiler's clock.
+
+    The owning thread calls ``begin()`` once a tick, ``switch(phase)`` at
+    every boundary and ``end()`` when the tick is over.  ``switch`` closes
+    the running phase and opens the next on ONE ``time.perf_counter()``
+    read, so phases never overlap and their seconds add up to the time
+    between the first ``switch`` and ``end``.  Each phase adds to
+    ``seconds[phase]`` / ``count[phase]`` (cumulative since construction;
+    plain dicts a monitoring thread may read without a lock) and is a
+    ``jax.profiler.TraceAnnotation`` named ``<prefix>.<phase>``, which
+    nests inside whatever annotation the caller holds open (the engine's
+    ``llm_decode_tick`` span), in the same XPlane host line as the device
+    trace's launches.
+
+    ``switch`` and ``end`` return their clock read (0.0 while off), so the
+    caller derives other timings from the same boundaries instead of
+    reading the clock again.  ``begin()`` does the one
+    ``metrics.enabled()`` dict lookup of the tick; while off, a switch is
+    an attribute test.  Phases are fixed at construction: an unknown name
+    is a ``KeyError``, not a new series.
+    """
+
+    __slots__ = ("seconds", "count", "_names", "_on", "_phase", "_t0",
+                 "_ann", "_ann_cls")
+
+    def __init__(self, prefix, phases):
+        self._names = {p: f"{prefix}.{p}" for p in phases}
+        self.seconds = {p: 0.0 for p in phases}
+        self.count = {p: 0 for p in phases}
+        self._on = False
+        self._phase = None
+        self._t0 = 0.0
+        self._ann = None
+        self._ann_cls = None
+
+    def begin(self):
+        self._on = _metrics._runtime["enabled"]
+        if self._on and self._ann_cls is None:
+            # lazily, like RecordEvent: the registry stays importable
+            # without jax
+            try:
+                from jax.profiler import TraceAnnotation
+                self._ann_cls = TraceAnnotation
+            except Exception:
+                self._ann_cls = False
+
+    def switch(self, phase):
+        if not self._on:
+            return 0.0
+        name = self._names[phase]  # KeyError: not a phase of this clock
+        now = time.perf_counter()
+        if self._phase is not None:
+            self._close(now)
+        self.count[phase] += 1
+        self._phase = phase
+        self._t0 = now
+        if self._ann_cls:
+            self._ann = self._ann_cls(name)
+            self._ann.__enter__()
+        return now
+
+    def end(self):
+        """Close the running phase (also on the way out of a tick that
+        raised: a phase must not stay open across ticks)."""
+        self._on = False
+        if self._phase is None:
+            return 0.0
+        now = time.perf_counter()
+        self._close(now)
+        return now
+
+    def _close(self, now):
+        self.seconds[self._phase] += now - self._t0
+        self._phase = None
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None

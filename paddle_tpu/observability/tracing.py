@@ -188,6 +188,11 @@ class Trace:
     def _now_s(self):
         return time.perf_counter() - self._t0
 
+    def rel_s(self, t):
+        """A ``time.perf_counter()`` stamp as seconds since the trace's
+        start: the clock its spans' ``start_s`` are on."""
+        return t - self._t0
+
     def _open(self, name, attrs):
         sp = Span(name, self._now_s(), attrs)
         parent = self._stack[-1] if self._stack else self.root
@@ -389,6 +394,9 @@ class _NullTrace:
 
     def add_span(self, name, duration_s, start_s=None, **attrs):
         return None
+
+    def rel_s(self, t):
+        return 0.0
 
     def set_attr(self, key, value):
         pass

@@ -659,3 +659,375 @@ def test_trace_store_dump_sibling_on_flight_dump(tmp_path):
     doc = json.load(open(tmp_path / sib[0]))
     ids = {x["trace_id"] for x in doc["traces"]}
     assert t.trace_id in ids and tp.trace_id in ids
+
+
+# ------------------------------------------- inside a tick (ISSUE 25)
+from paddle_tpu.observability.spans import PhaseClock  # noqa: E402
+
+
+class _CheckedClock(PhaseClock):
+    """The test's own debug flag: every closed phase is logged with its
+    interval, and a phase opened while another runs is an error."""
+
+    __slots__ = ("log",)
+
+    def __init__(self, prefix, phases):
+        super().__init__(prefix, phases)
+        self.log = []
+
+    def switch(self, phase):
+        t = super().switch(phase)
+        assert not self._on or self._phase == phase
+        return t
+
+    def _close(self, now):
+        assert self._phase is not None and now >= self._t0
+        self.log.append((self._phase, self._t0, now))
+        super()._close(now)
+
+
+def _tick_hist():
+    h = obs.REGISTRY.get("llm_decode_tick_duration_seconds")._solo()
+    return h.sum, h.count
+
+
+def _paged_engine(model, tracer=None, slots=2, checked=False, **kw):
+    from paddle_tpu.inference import llm_server
+
+    kw.setdefault("page_size", 32)
+    kw.setdefault("prefill_chunk", 32)
+    eng = LLMEngine(model, max_batch_slots=slots, max_seq_len=128,
+                    kv_layout="paged", tracer=tracer or _tracer(), **kw)
+    if checked:
+        eng._phases = _CheckedClock("llm_tick", llm_server._TICK_PHASES)
+    return eng
+
+
+def _prompts(seed, *lengths):
+    rng = np.random.RandomState(seed)
+    return [rng.randint(0, 1024, n).astype(np.int32) for n in lengths]
+
+
+def test_phase_clock_switches_on_one_read_and_stays_still_when_off():
+    pc = _CheckedClock("t", ("a", "b"))
+    assert pc.switch("a") == 0.0 and pc.end() == 0.0  # begin() never ran
+    assert pc.count == {"a": 0, "b": 0}
+    pc.begin()
+    t0 = pc.switch("a")
+    t1 = pc.switch("b")
+    t2 = pc.switch("a")
+    t3 = pc.end()
+    assert t0 <= t1 <= t2 <= t3 and pc.end() == 0.0
+    assert [(p, a, b) for p, a, b in pc.log] == [
+        ("a", t0, t1), ("b", t1, t2), ("a", t2, t3)]  # shared boundaries
+    assert pc.count == {"a": 2, "b": 1}
+    assert sum(pc.seconds.values()) == pytest.approx(t3 - t0, abs=1e-9)
+    with pytest.raises(KeyError):
+        pc.begin()
+        pc.switch("not_a_phase")
+    pc.end()
+    obs.disable()
+    try:
+        before = dict(pc.seconds), dict(pc.count)
+        pc.begin()
+        assert pc.switch("a") == 0.0 and pc.end() == 0.0
+        assert (pc.seconds, pc.count) == before
+    finally:
+        obs.enable()
+
+
+def test_tick_phases_exhaust_the_tick_and_feed_the_goodput_carves(model):
+    """50+ ticks with admissions, a three-chunk prompt and finishing
+    requests: the phases add up to the tick histogram, every count is
+    right, no two phases overlap, and the goodput carves are sums of
+    phases, boundary for boundary."""
+    eng = _paged_engine(model, checked=True)
+    s0, c0 = _tick_hist()
+    chunks0 = obs.REGISTRY.get("llm_prefill_chunks_total").value
+    lens, news = (70, 10, 20, 40, 33, 5), (5, 30, 3, 9, 14, 25)
+    futs = [eng.submit(p, max_new_tokens=k)
+            for p, k in zip(_prompts(1, *lens), news)]
+    eng.run_until_complete()
+    assert [len(f.result(timeout=1)) for f in futs] == list(news)
+    s1, c1 = _tick_hist()
+    ph = eng.stats()["tick_phases"]
+    ticks = c1 - c0
+    assert ticks >= 50
+    total = sum(ph["seconds"].values())
+    assert total == pytest.approx(s1 - s0, rel=0.02)
+    assert total <= s1 - s0  # the phases lie INSIDE the tick's span
+    cnt = ph["count"]
+    assert cnt["expire"] == cnt["admit"] == ticks
+    assert cnt["first_token_sync"] == len(futs)  # one an admission
+    n_chunks = obs.REGISTRY.get("llm_prefill_chunks_total").value - chunks0
+    assert cnt["prefill_dispatch"] == cnt["prefill_stage"] == n_chunks == 10
+    # one token a decode tick and a slot: the decode ticks are the ticks
+    # in which some request was past its first token
+    assert cnt["decode_stage"] == cnt["decode_dispatch"] == cnt["decode_sync"]
+    assert 0 < cnt["decode_sync"] <= ticks
+    assert all(cnt[p] == 0 for p in cnt if p.startswith("spec_"))
+    assert ph["host_s"] == pytest.approx(
+        total - ph["seconds"]["decode_sync"]
+        - ph["seconds"]["first_token_sync"], abs=1e-9)
+    # exclusive and exhaustive: within a tick each phase starts on the
+    # clock read that closed the one before it
+    log = eng._phases.log
+    assert len(log) == sum(cnt.values())
+    gaps = [b[1] - a[2] for a, b in zip(log, log[1:])]
+    assert all(g >= 0 for g in gaps)  # never overlap
+    assert sum(g == 0 for g in gaps) == len(log) - ticks  # chained in-tick
+    # one source of timing: the ledger's buckets ARE the phases
+    sec = ph["seconds"]
+    buckets = eng._goodput.check()["buckets"]
+    assert buckets["decode"] == pytest.approx(
+        sec["decode_stage"] + sec["decode_dispatch"] + sec["decode_sync"],
+        abs=2e-6)
+    assert buckets["prefill"] == pytest.approx(sec["prefill_dispatch"],
+                                               abs=2e-6)
+    # /metrics carries the same seconds
+    fam = obs.REGISTRY.get("llm_tick_phase_seconds_total")
+    pub = {lv[0]: ch.value for lv, ch in fam.series()}
+    assert set(pub) == set(sec)
+
+
+def test_tick_phase_counter_family_follows_the_engines_accumulators(model):
+    fam = obs.REGISTRY.get("llm_tick_phase_seconds_total")
+    before = {lv[0]: ch.value for lv, ch in fam.series()}
+    eng = _paged_engine(model)
+    eng.submit(_prompts(2, 12)[0], max_new_tokens=4)
+    eng.run_until_complete()
+    sec = eng.stats()["tick_phases"]["seconds"]
+    after = {lv[0]: ch.value for lv, ch in fam.series()}
+    for p, v in sec.items():
+        assert after[p] - before[p] == pytest.approx(v, abs=1e-9)
+
+
+def test_tick_phases_of_a_speculative_and_of_a_dense_engine(model):
+    eng = _paged_engine(model, spec_k=3, checked=True)
+    s0, _ = _tick_hist()
+    f = eng.submit(_prompts(3, 20)[0], max_new_tokens=12)
+    eng.run_until_complete()
+    assert len(f.result(timeout=1)) == 12
+    s1, _ = _tick_hist()
+    ph = eng.stats()["tick_phases"]
+    cnt, sec = ph["count"], ph["seconds"]
+    assert cnt["spec_dispatch"] == cnt["spec_sync"] == cnt["spec_accept"] \
+        == cnt["spec_draft"] == eng.stats()["spec"]["verify_calls"] > 0
+    assert sum(sec.values()) == pytest.approx(s1 - s0, rel=0.02)
+    b = eng._goodput.check()["buckets"]
+    assert b["verify"] + b["spec_rollback_waste"] == pytest.approx(
+        sec["spec_draft"] + sec["spec_dispatch"] + sec["spec_sync"],
+        abs=2e-6)
+    dense = LLMEngine(model, max_batch_slots=2, max_seq_len=128,
+                      tracer=_tracer())
+    futs = [dense.submit(p, max_new_tokens=4) for p in _prompts(4, 9, 17)]
+    dense.run_until_complete()
+    assert all(len(f.result(timeout=1)) == 4 for f in futs)
+    cnt = dense.stats()["tick_phases"]["count"]
+    assert cnt["first_token_sync"] == 2 and cnt["prefill_dispatch"] == 0
+    assert cnt["decode_sync"] == 3
+    dense._goodput.check()
+
+
+def test_tick_phases_do_not_move_with_metrics_disabled(model):
+    eng = _paged_engine(model)
+    eng.submit(_prompts(5, 12)[0], max_new_tokens=3)
+    eng.run_until_complete()
+    before = eng.stats()["tick_phases"]
+    obs.disable()
+    try:
+        f = eng.submit(_prompts(5, 40)[0], max_new_tokens=6)
+        eng.run_until_complete()
+        assert len(f.result(timeout=1)) == 6
+    finally:
+        obs.enable()
+    assert eng.stats()["tick_phases"] == before
+    eng._goodput.check()
+
+
+def test_admission_blocked_names_what_held_the_queue_head(model):
+    eng = _paged_engine(model, slots=1)
+    long_, short = _prompts(6, 60, 8)  # 60 tokens = two 32-token chunks
+    eng.submit(long_, max_new_tokens=3)
+    eng.submit(short, max_new_tokens=2)
+    blocked = lambda: eng.stats()["admission_blocked"]  # noqa: E731
+    eng.step()  # tick 1 pops the long prompt: nothing waited on entry
+    assert blocked() == {"prefill_busy": 0, "no_slot": 0, "no_pages": 0,
+                         "no_adapter_page": 0}
+    eng.step()  # tick 2: its second chunk holds the prefill lane
+    assert blocked()["prefill_busy"] == 1 and blocked()["no_slot"] == 0
+    eng.step()  # tick 3: the only slot is decoding (and finishes)
+    assert blocked() == {"prefill_busy": 1, "no_slot": 1, "no_pages": 0,
+                         "no_adapter_page": 0}
+    eng.run_until_complete()  # tick 4 admits the short one: no more waits
+    assert blocked() == {"prefill_busy": 1, "no_slot": 1, "no_pages": 0,
+                         "no_adapter_page": 0}
+    fam = obs.REGISTRY.get("llm_admission_blocked_ticks_total")
+    assert {lv[0] for lv, _ in fam.series()} == set(blocked())
+    # a pool too small for the queue head: no_pages, one reason a tick
+    tight = _paged_engine(model, slots=2, num_pages=4, prefix_cache=False)
+    a, b = _prompts(7, 60, 60)  # 2 pages each (+1 token), 3 allocatable
+    fa = tight.submit(a, max_new_tokens=6)
+    fb = tight.submit(b, max_new_tokens=2)
+    tight.run_until_complete()
+    assert len(fa.result(timeout=1)) == 6 and len(fb.result(timeout=1)) == 2
+    held = tight.stats()["admission_blocked"]
+    assert held["no_pages"] >= 3 and held["no_slot"] == 0  # a slot was free
+
+
+def test_token_stamps_ttft_attribute_and_on_token(model):
+    tracer = _tracer()
+    eng = _paged_engine(model, tracer=tracer)
+    ttft = obs.REGISTRY.get("llm_ttft_seconds")._solo()
+    sum0 = ttft.sum
+    seen = []
+
+    def boom(i, tok, t):
+        seen.append((i, tok, t))
+        raise RuntimeError("a stream callback must not kill the pump")
+
+    f = eng.submit(_prompts(8, 40)[0], max_new_tokens=9, on_token=boom,
+                   trace_id="stamps")
+    eng.run_until_complete()
+    toks = f.result(timeout=1)
+    assert [i for i, _, _ in seen] == list(range(9))
+    assert [tok for _, tok, _ in seen] == toks
+    ts = [t for _, _, t in seen]
+    assert all(b >= a for a, b in zip(ts, ts[1:]))
+    tr = tracer.store.get_trace("stamps")
+    a = tr.root.attrs
+    adm = tr.find_spans("admission")[0]
+    assert a["ttft_s"] == pytest.approx(adm.start_s + adm.duration_s,
+                                        abs=1e-3)
+    assert a["ttft_s"] == pytest.approx(ttft.sum - sum0, abs=1e-3)
+    gaps = [b - a for a, b in zip(ts, ts[1:])]
+    assert a["token_gap_max_s"] == pytest.approx(max(gaps), abs=1e-9)
+    assert a["token_gap_mean_s"] == pytest.approx(sum(gaps) / 8, abs=1e-9)
+    # the decode summary is derived from the same stamps
+    dec = tr.find_spans("decode")[0]
+    assert dec.attrs == {"ticks": 8, "tokens": 8}
+
+
+def test_preempted_request_keeps_its_earlier_token_stamps(model):
+    """test_preempted_request_one_trace_both_episodes' engine: the pool
+    runs dry, one request requeues and re-prefills; its stamps and its
+    on_token indices run on through the requeue."""
+    tracer = _tracer()
+    eng = _paged_engine(model, tracer=tracer, num_pages=3,
+                        prefix_cache=False)
+    seen = {"a": [], "b": []}
+    pa, pb = _prompts(25, 30, 30)
+    fa = eng.submit(pa, max_new_tokens=4, trace_id="a",
+                    on_token=lambda i, tok, t: seen["a"].append((i, tok, t)))
+    fb = eng.submit(pb, max_new_tokens=4, trace_id="b",
+                    on_token=lambda i, tok, t: seen["b"].append((i, tok, t)))
+    eng.run_until_complete()
+    victim = next(k for k in "ab" if tracer.store.get_trace(k)
+                  .root.attrs.get("preempt_requeues"))
+    tr = tracer.store.get_trace(victim)
+    assert len(tr.find_spans("admission")) == 2
+    for k, f in (("a", fa), ("b", fb)):
+        assert [i for i, _, _ in seen[k]] == [0, 1, 2, 3]
+        assert [tok for _, tok, _ in seen[k]] == f.result(timeout=1)
+    ts = [t for _, _, t in seen[victim]]
+    assert all(b >= a for a, b in zip(ts, ts[1:]))
+    first_adm = tr.find_spans("admission")[0]
+    # ttft_s is still the FIRST episode's first token, not the requeue's
+    assert tr.root.attrs["ttft_s"] == pytest.approx(
+        first_adm.start_s + first_adm.duration_s, abs=1e-3)
+    assert tr.root.attrs["token_gap_max_s"] >= \
+        tr.find_spans("admission")[1].duration_s  # the gap spans the requeue
+
+
+def test_programs_and_scopes_are_named_and_change_no_token(model,
+                                                            monkeypatch):
+    """Names are metadata: each compiled program carries its own module
+    name and the model's scopes, and greedy output is what an engine
+    built with the scopes switched off emits."""
+    import contextlib
+
+    import jax
+
+    eng = _paged_engine(model, spec_k=2)
+    eng.warmup()
+    B, M, C, K = eng.n_slots, eng.M, eng.prefill_chunk, eng.spec_k
+    i32 = np.int32
+    knobs = (np.zeros(B, bool), np.ones(B, np.float32), np.zeros(B, i32),
+             np.ones(B, np.float32))
+    key = jax.random.PRNGKey(0)
+    head = (eng._params, eng._buffers, eng.caches)
+    lora = eng._lora_args([0] * B)
+    programs = {
+        "llm_decode": (eng._decode_jit[1], head + (
+            np.zeros((B, M), i32), np.zeros((B, 1), i32), np.zeros(B, i32),
+            *knobs, eng._mask_all_true, jax.random.split(key, 1), *lora)),
+        "llm_prefill_chunk": (eng._get_chunk_prefill(), head + (
+            np.zeros((1, M), i32), np.zeros((1, C), i32), np.zeros(1, i32),
+            i32(0), *eng._lora_args([0]))),
+        "llm_spec_verify": (eng._get_verify(), head + (
+            np.zeros((B, M), i32), np.zeros((B, 1), i32),
+            np.zeros((B, K), i32), np.zeros(B, i32), *knobs, key, *lora)),
+    }
+    for name, (jit, args) in programs.items():
+        hlo = jit.lower(*args).compile().as_text()
+        assert hlo.startswith(f"HloModule jit_{name}"), hlo[:80]
+        for scope in ("attention", "mlp", "final_norm", "lm_head"):
+            assert f"jit({name})/" in hlo and f"/{scope}/" in hlo, \
+                (name, scope)
+    assert "/sampler/" in programs["llm_decode"][0].lower(
+        *programs["llm_decode"][1]).compile().as_text()
+
+    def greedy():
+        e = _paged_engine(model)
+        futs = [e.submit(p, max_new_tokens=k) for p, k in
+                zip(_prompts(1, 70, 10, 20, 40), (5, 12, 3, 9))]
+        e.run_until_complete()
+        return [f.result(timeout=1) for f in futs]
+
+    named = greedy()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    assert greedy() == named
+
+
+def test_profiler_trace_holds_phases_nested_in_the_tick_on_one_line(
+        model, tmp_path):
+    """A short jax.profiler trace of the tiny engine: llm_tick.decode_sync
+    events lie inside llm_decode_tick events of the SAME host line, so the
+    phases are on the clock the device's gaps are read on."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    eng = _paged_engine(model)
+    eng.warmup()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        eng.submit(_prompts(9, 40)[0], max_new_tokens=6)
+        eng.run_until_complete()
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))[-1]
+    found = 0
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            ev = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                  for e in line.events]
+            ticks = [(a, b) for n, a, b in ev if n == "llm_decode_tick"]
+            syncs = [(a, b) for n, a, b in ev
+                     if n == "llm_tick.decode_sync"]
+            if not syncs:
+                continue
+            assert ticks, "phases on a line that has no tick"
+            for a, b in syncs:
+                assert any(ta <= a and b <= tb for ta, tb in ticks)
+            names = {n for n, _, _ in ev if n.startswith("llm_tick.")}
+            assert {"llm_tick.admit", "llm_tick.prefill_dispatch",
+                    "llm_tick.first_token_sync", "llm_tick.decode_stage",
+                    "llm_tick.bookkeep"} <= names
+            found += len(syncs)
+    assert found == 5  # 6 tokens: one from the prefill, five decode ticks
