@@ -5,6 +5,15 @@ Reference: `phi/core/generator.h:23` (stateful per-device Generator) and
 stateful key that is split on every draw.  Under `to_static`/jit tracing, the traced
 program receives a fresh key argument each call via `push_key` so dropout masks are not
 baked in as constants.
+
+The generator also keeps a host-side offset beside its key, as the reference's generator
+keeps a (seed, offset) pair.  `Generator.fork()` hands out `(key, offset)` and advances
+only the offset, a Python integer: no device work.  The serving programs
+(`inference/llm_server.py`) take that pair as arguments and derive their keys INSIDE the
+compiled program (`split(fold_in(key, offset), n)`), so a decode tick dispatches no eager
+device program for its randomness.  `split()` / `get_rng_key()` are eager device calls:
+they belong to initialisers, dropout and the train step, never between a tick's compiled
+calls.
 """
 from __future__ import annotations
 
@@ -51,6 +60,7 @@ class Generator:
     def __init__(self, seed: int = 0):
         self._seed = int(seed)
         self._key = None  # lazy: don't touch the backend at import time
+        self._offset = 0  # host-side draw counter under the current key
 
     @property
     def key(self):
@@ -61,6 +71,7 @@ class Generator:
     def manual_seed(self, seed: int):
         self._seed = int(seed)
         self._key = jax.random.key(self._seed, impl=_rng_impl())
+        self._offset = 0
         return self
 
     def initial_seed(self) -> int:
@@ -68,10 +79,21 @@ class Generator:
 
     def set_key(self, key):
         self._key = key
+        self._offset = 0
 
     def split(self):
         self._key, sub = jax.random.split(self.key)
         return sub
+
+    def fork(self):
+        """`(key, offset)` for a compiled program that derives its own keys
+        (`jax.random.fold_in(key, offset)` inside the program), then advance
+        the offset.  Host-only: the resident key is handed out as it is and
+        `split()`'s stream does not move.  The offset wraps at 2**32, the
+        width `fold_in` takes."""
+        offset = self._offset
+        self._offset = (offset + 1) & 0xFFFFFFFF
+        return self.key, np.uint32(offset)
 
 
 _default_generator = Generator(np.random.randint(0, 2**31 - 1))

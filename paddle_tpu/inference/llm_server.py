@@ -75,6 +75,7 @@ import jax
 import jax.numpy as jnp
 
 from ..autograd import tape
+from ..framework import random as _fr
 from ..ops import lora as _oplora
 from ..observability import flight_recorder as _flight
 from ..observability import goodput as _goodput
@@ -720,6 +721,9 @@ class LLMEngine:
                 raise TypeError(
                     "adapters= must be a models.lora.AdapterRegistry, got "
                     f"{type(adapters).__name__}")
+        # _lora_args' tail for an engine with no adapter pool, built ONCE:
+        # a jnp.zeros per compiled call would be an eager device program
+        self._no_lora = ((), jnp.zeros((0,), jnp.int32))
         self._vocab = int(cfg.vocab_size)
         self._constraint_vocab = (list(constraint_vocab)
                                   if constraint_vocab is not None else None)
@@ -1573,14 +1577,13 @@ class LLMEngine:
         padded = np.full((1, Lb), self.pad, np.int32)
         padded[0, :n] = req.prompt
         logits, kvs = self._get_prefill(Lb)(
-            self._params, self._buffers, jnp.asarray(padded),
-            jnp.asarray(n - 1, jnp.int32))
+            self._params, self._buffers, padded, np.int32(n - 1))
         # causal attention: positions >= n never influence position n-1,
         # so the padded prefill's first n k/v rows are exact
         self._phases.switch("first_token_sync")
         tok = self._host_select(np.asarray(logits)[0, 0], req)
         self.caches = self._get_slot_writer(Lb)(
-            self.caches, kvs, jnp.asarray(slot, jnp.int32))
+            self.caches, kvs, np.int32(slot))
         req.slot = slot
         self._emit_token(req, tok, time.perf_counter())
         self.slot_req[slot] = req
@@ -1752,9 +1755,10 @@ class LLMEngine:
             new = self._free_pages.pop()
             self._page_ref[new] = 1
             try:
+                # numpy scalars: jnp.asarray(int, dtype) is an eager
+                # convert_element_type program, two a fork (_sampling_knobs)
                 self.caches = self._get_cow_copy()(
-                    self.caches, jnp.asarray(old, jnp.int32),
-                    jnp.asarray(new, jnp.int32))
+                    self.caches, np.int32(old), np.int32(new))
             except Exception:
                 # the copy donates self.caches; the caller's _caches_alive
                 # check escalates a consumed-buffer failure to the watchdog
@@ -2026,12 +2030,12 @@ class LLMEngine:
         The tree is the pool's live device arrays (a jit ARGUMENT —
         loading/evicting adapters swaps data, never the program) and
         ``pages`` the per-row pool pages (0 = the reserved zero adapter:
-        its epilogue contributes exact zeros).  Dummies keep the call
-        signature stable when the engine has no adapter pool."""
+        its epilogue contributes exact zeros), a HOST array.  Dummies
+        (built once, in __init__) keep the call signature stable when the
+        engine has no adapter pool."""
         if self.adapters is None:
-            return ((), jnp.zeros((0,), jnp.int32))
-        return (self.adapters.pool.tree(),
-                jnp.asarray(np.asarray(pages, np.int32)))
+            return self._no_lora
+        return self.adapters.pool.tree(), np.asarray(pages, np.int32)
 
     def _release_adapter(self, req):
         """Drop a request's adapter-pool reference (idempotent: requests
@@ -2539,8 +2543,12 @@ class LLMEngine:
         the chunk program serves every prompt length).  Runs the real
         compiled calls against the engine's own idle cache state: the
         garbage rows land in the trash page (paged) or in rows admission
-        rewrites wholesale (dense).  Returns the wall seconds spent and
-        publishes them on llm_warmup_compile_seconds."""
+        rewrites wholesale (dense).  The decode, verify and chunk calls get
+        what a tick gives them — host arrays, and the default generator's
+        resident key with a host offset in place of keys — so the warmed
+        programs are the ones the ticks call; the offset is not advanced
+        (warmup draws nothing a request sees).  Returns the wall seconds
+        spent and publishes them on llm_warmup_compile_seconds."""
         t0 = time.perf_counter()
         # every tick's span records into the native host-trace buffer, whose
         # library is BUILT on first use in a fresh checkout (a g++ run):
@@ -2557,60 +2565,44 @@ class LLMEngine:
                 C = self.prefill_chunk
                 _, self.caches = self._get_chunk_prefill()(
                     params, buffers, self.caches,
-                    jnp.zeros((1, self.M), jnp.int32),
-                    jnp.full((1, C), self.pad, jnp.int32),
-                    jnp.zeros((1,), jnp.int32), jnp.asarray(0, jnp.int32),
+                    np.zeros((1, self.M), np.int32),
+                    np.full((1, C), self.pad, np.int32),
+                    np.zeros((1,), np.int32), np.int32(0),
                     *self._lora_args([0]))
                 # the COW fork program too: a warm engine's first
                 # shared-prefix fork must not compile (and must not trip
                 # recompile_storm).  A trash-page self-copy is harmless.
                 self.caches = self._get_cow_copy()(
-                    self.caches, jnp.asarray(0, jnp.int32),
-                    jnp.asarray(0, jnp.int32))
+                    self.caches, np.int32(0), np.int32(0))
             else:
                 for Lb in (buckets if buckets is not None else self.buckets):
                     Lb = int(Lb)
-                    ids = jnp.full((1, Lb), self.pad, jnp.int32)
+                    ids = np.full((1, Lb), self.pad, np.int32)
                     _, kvs = self._get_prefill(Lb)(
-                        params, buffers, ids, jnp.asarray(Lb - 1, jnp.int32))
+                        params, buffers, ids, np.int32(Lb - 1))
                     self.caches = self._get_slot_writer(Lb)(
-                        self.caches, kvs, jnp.asarray(0, jnp.int32))
+                        self.caches, kvs, np.int32(0))
             eff = max(1, min(self.decode_chunk, self.L - 1))
-            jit = self._decode_jit.get(eff)
-            if jit is None:
-                _profiling.record_compile("decode")
-                jit = self._decode_jit[eff] = self._decode_fn()
-            from ..framework import random as _fr
-
-            keys = jax.random.split(_fr.get_rng_key(), eff)
             B = self.n_slots
+            tokens = np.full((B, 1), self.pad, np.int32)
+            pos = np.zeros((B,), np.int32)
+            knobs = self._sampling_knobs()  # idle engine: all greedy
+            rng = (_fr.default_generator().key, np.uint32(0))
             args = (params, buffers, self.caches)
             if self.paged:
                 args += (self._pt_host.copy(),)
-            args += (jnp.asarray(np.full((B, 1), self.pad, np.int32)),
-                     jnp.zeros((B,), jnp.int32),
-                     jnp.zeros((B,), bool),
-                     jnp.ones((B,), jnp.float32),
-                     jnp.zeros((B,), jnp.int32),
-                     jnp.ones((B,), jnp.float32))
+            args += (tokens, pos, *knobs)
             if self.paged:
-                args += (self._mask_all_true, keys)
-                args += self._lora_args([0] * B)
+                args += (self._mask_all_true, *rng, *self._lora_args([0] * B))
             else:
-                args += (keys,)
-            _, self.caches = jit(*args)
+                args += rng
+            _, self.caches = self._get_decode(eff)(*args)
             if self.spec_k:
                 vargs = (params, buffers, self.caches)
                 if self.paged:
                     vargs += (self._pt_host.copy(),)
-                vargs += (jnp.asarray(np.full((B, 1), self.pad, np.int32)),
-                          jnp.zeros((B, self.spec_k), jnp.int32),
-                          jnp.zeros((B,), jnp.int32),
-                          jnp.zeros((B,), bool),
-                          jnp.ones((B,), jnp.float32),
-                          jnp.zeros((B,), jnp.int32),
-                          jnp.ones((B,), jnp.float32),
-                          _fr.get_rng_key())
+                vargs += (tokens, np.zeros((B, self.spec_k), np.int32), pos,
+                          *knobs, *rng)
                 if self.paged:
                     vargs += self._lora_args([0] * B)
                 _, _, self.caches = self._get_verify()(*vargs)
@@ -2658,7 +2650,15 @@ class LLMEngine:
         """Per-slot (do_sample, temperature, top_k, top_p) as HOST arrays the
         compiled step takes as arguments.  numpy on purpose: a
         jnp.asarray(list, dtype) converts ON DEVICE, an eager op whose first
-        use compiles after warmup() has declared the process warm."""
+        use compiles after warmup() has declared the process warm.
+
+        The rule for everything a tick stages: NO eager device call belongs
+        between a tick's compiled calls.  Each one is a one-op program the
+        host dispatches with the device idle (0.5-0.9 ms apiece on a v5e,
+        PERF.md PR 25).  Arguments are host arrays (the compiled call
+        uploads them) or arrays already resident on the device; what has to
+        be computed per tick — the sampler's keys — is computed inside the
+        program from ``Generator.fork()``'s (key, offset)."""
         B = self.n_slots
         do_s, temp = np.zeros(B, bool), np.ones(B, np.float32)
         topk, topp = np.zeros(B, np.int32), np.ones(B, np.float32)
@@ -2668,7 +2668,20 @@ class LLMEngine:
                 topk[i], topp[i] = r.top_k, r.top_p
         return do_s, temp, topk, topp
 
-    def _decode_fn(self):
+    def _get_decode(self, eff):
+        jit = self._decode_jit.get(eff)
+        if jit is None:
+            _profiling.record_compile("decode")
+            jit = self._decode_jit[eff] = self._decode_fn(eff)
+        return jit
+
+    def _decode_fn(self, eff):
+        """The compiled decode step over ``eff`` tokens a row (static: one
+        program per scan length).  Randomness arrives as ``(base_key,
+        offset)`` — the default generator's resident key and a host integer
+        (``Generator.fork()``) — and the per-token keys are derived HERE, in
+        the program: an eager ``jax.random.split`` on the host would be a
+        train of one-op device programs before every tick's dispatch."""
         model = self.model
         pool = self.adapters.pool if self.adapters is not None else None
 
@@ -2680,7 +2693,9 @@ class LLMEngine:
             # either feature on after warmup() never recompiles
             def llm_decode(params, buffers, caches, page_tbl, tokens, pos,
                            do_sample, temperature, top_k, top_p, token_mask,
-                           keys, lora_tree, lora_rows):
+                           base_key, offset, lora_tree, lora_rows):
+                keys = jax.random.split(
+                    jax.random.fold_in(base_key, offset), eff)
                 restore = model.bind_functional_state(params, buffers)
                 try:
                     with tape.no_grad(), _lora_ctx(pool, lora_tree,
@@ -2719,7 +2734,8 @@ class LLMEngine:
             return jax.jit(llm_decode, donate_argnums=(2,))
 
         def llm_decode(params, buffers, caches, tokens, pos, do_sample,
-                       temperature, top_k, top_p, keys):
+                       temperature, top_k, top_p, base_key, offset):
+            keys = jax.random.split(jax.random.fold_in(base_key, offset), eff)
             restore = model.bind_functional_state(params, buffers)
             try:
                 with tape.no_grad():
@@ -2758,14 +2774,17 @@ class LLMEngine:
         the ragged Pallas kernel walking the page tables, not a gathered
         dense pass) and run the accept/rollback decision on device
         (ops/sampling.spec_accept) — only the [B, K+1] token ladder and
-        the [B] accept counts are copied to the host."""
+        the [B] accept counts are copied to the host.  Randomness arrives
+        as ``(base_key, offset)`` like _decode_fn's."""
         model = self.model
         pool = self.adapters.pool if self.adapters is not None else None
 
         if self.paged:
             def llm_spec_verify(params, buffers, caches, page_tbl, tokens,
                                 drafts, pos, do_sample, temperature, top_k,
-                                top_p, key, lora_tree, lora_rows):
+                                top_p, base_key, offset, lora_tree,
+                                lora_rows):
+                key = jax.random.fold_in(base_key, offset)
                 restore = model.bind_functional_state(params, buffers)
                 try:
                     with tape.no_grad(), _lora_ctx(pool, lora_tree,
@@ -2794,7 +2813,9 @@ class LLMEngine:
             return jax.jit(llm_spec_verify, donate_argnums=(2,))
 
         def llm_spec_verify(params, buffers, caches, tokens, drafts, pos,
-                            do_sample, temperature, top_k, top_p, key):
+                            do_sample, temperature, top_k, top_p, base_key,
+                            offset):
+            key = jax.random.fold_in(base_key, offset)
             restore = model.bind_functional_state(params, buffers)
             try:
                 with tape.no_grad():
@@ -2903,17 +2924,16 @@ class LLMEngine:
             if not active:
                 self._goodput.carve("decode", pc.switch("bookkeep") - t_dec)
                 return 0
-        jit = self._decode_jit.get(eff)
-        if jit is None:
-            _profiling.record_compile("decode")
-            jit = self._decode_jit[eff] = self._decode_fn()
-        tokens = jnp.asarray(self.last_token.reshape(-1, 1))
-        pos = jnp.asarray(self.slot_pos)
+        jit = self._get_decode(eff)
+        # host arrays straight into the compiled call, and (key, offset)
+        # for the keys it derives itself: no eager device call here (see
+        # _sampling_knobs).  Copies: bookkeeping writes these in place
+        tokens = self.last_token.reshape(-1, 1).copy()
+        pos = self.slot_pos.copy()
         reqs = self.slot_req
         do_s, temp, topk, topp = self._sampling_knobs()
-        from ..framework import random as _fr
-
-        keys = jax.random.split(_fr.get_rng_key(), eff)
+        # read each tick, not held: paddle.seed() on a live engine governs
+        rng = _fr.default_generator().fork()
         args = (self._params, self._buffers, self.caches)
         if self.paged:
             # decode sees a table with INACTIVE slots masked to the trash
@@ -2924,24 +2944,23 @@ class LLMEngine:
             for i, r in enumerate(self.slot_req):
                 if r is None:
                     pt[i, :] = 0
-            args += (jnp.asarray(pt),)
+            args += (pt,)
         if self.paged:
             if constrained:
                 # per-row [V] masks from each constrained row's automaton
                 # state; unconstrained rows stay all-True (exact no-op)
-                mask_np = np.ones((self.n_slots, self._vocab), bool)
+                token_mask = np.ones((self.n_slots, self._vocab), bool)
                 for i, r in enumerate(reqs):
                     if r is not None and r.cursor is not None:
-                        mask_np[i] = r.cursor.mask()
-                token_mask = jnp.asarray(mask_np)
+                        token_mask[i] = r.cursor.mask()
             else:
                 token_mask = self._mask_all_true
-            args += (tokens, pos, do_s, temp, topk, topp, token_mask, keys,
+            args += (tokens, pos, do_s, temp, topk, topp, token_mask, *rng,
                      *self._lora_args(
                          [r.adapter_page if r is not None else 0
                           for r in reqs]))
         else:
-            args += (tokens, pos, do_s, temp, topk, topp, keys)
+            args += (tokens, pos, do_s, temp, topk, topp, *rng)
         pc.switch("decode_dispatch")
         nxt_dev, new_caches = jit(*args)
         # the returned tuples carry advanced pos at slot [2], but the
@@ -3019,8 +3038,6 @@ class LLMEngine:
         draft_s = pc.switch("spec_stage") - t0
         reqs = self.slot_req
         do_s, temp, topk, topp = self._sampling_knobs()
-        from ..framework import random as _fr
-
         args = (self._params, self._buffers, self.caches)
         if self.paged:
             # same inactive-slot masking as decode: a mid-prefill slot's
@@ -3029,10 +3046,11 @@ class LLMEngine:
             for i, r in enumerate(self.slot_req):
                 if r is None:
                     pt[i, :] = 0
-            args += (jnp.asarray(pt),)
-        args += (jnp.asarray(self.last_token.reshape(-1, 1)),
-                 jnp.asarray(drafts), jnp.asarray(self.slot_pos),
-                 do_s, temp, topk, topp, _fr.get_rng_key())
+            args += (pt,)
+        # host arrays and (key, offset), as the decode tick stages them
+        args += (self.last_token.reshape(-1, 1).copy(), drafts,
+                 self.slot_pos.copy(), do_s, temp, topk, topp,
+                 *_fr.default_generator().fork())
         if self.paged:
             args += self._lora_args(
                 [r.adapter_page if r is not None else 0 for r in reqs])

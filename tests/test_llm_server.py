@@ -591,3 +591,118 @@ def test_drain_deadline_fails_remainder_loudly(model):
     assert eng.drain(deadline_s=60.0) is True
     assert len(f_ok.result(timeout=1)) == 2
     assert ls._M_DRAIN_EXPIRED.value == before + 2  # no new expiries
+
+
+# ------------------------------------------- randomness without eager calls
+# A tick stages its compiled calls with no eager device program: the
+# sampler's keys are derived INSIDE llm_decode / llm_spec_verify from the
+# default generator's resident key and a host offset (Generator.fork()).
+_RNG_ENGINES = {
+    "paged": dict(kv_layout="paged", page_size=32, prefill_chunk=16),
+    "dense": dict(prompt_buckets=(8, 32)),
+    "spec-paged": dict(kv_layout="paged", page_size=32, prefill_chunk=16,
+                       spec_k=2),
+    "spec-dense": dict(prompt_buckets=(8, 32), spec_k=2),
+}
+_rng_engines = pytest.mark.parametrize("kind", sorted(_RNG_ENGINES))
+
+
+def _rng_engine(model, kind):
+    return LLMEngine(model, max_batch_slots=2, max_seq_len=128,
+                     **_RNG_ENGINES[kind])
+
+
+def _compiles():
+    from paddle_tpu import observability as obs
+
+    fam = obs.snapshot().get("jit_compiles_total")
+    return sum(s["value"] for s in fam["series"]) if fam else 0.0
+
+
+@_rng_engines
+def test_tick_issues_no_eager_device_call(model, kind, monkeypatch):
+    """With every eager source of keys, and the dispatch of any eager
+    primitive (a jnp.zeros, a jnp.asarray(int, dtype)), patched to raise
+    while step() runs, a warmed engine serves a greedy and a sampled row
+    side by side — COW forks of the prefix cache's tail pages included."""
+    import jax
+    from jax._src import dispatch
+
+    from paddle_tpu.framework import random as fr
+
+    rng = np.random.RandomState(60)
+    p1 = rng.randint(0, 1024, 10).astype(np.int32)
+    p2 = rng.randint(0, 1024, 7).astype(np.int32)
+    eng = _rng_engine(model, kind)
+    eng.warmup()
+    f1 = eng.submit(p1, max_new_tokens=6)
+    f2 = eng.submit(p2, max_new_tokens=6, do_sample=True, temperature=2.0,
+                    top_k=50, top_p=0.95)
+
+    def boom(*a, **k):
+        raise AssertionError("eager device call inside a tick")
+
+    offset0 = fr.default_generator()._offset
+    with monkeypatch.context() as m:
+        m.setattr(jax.random, "split", boom)
+        m.setattr(fr.Generator, "split", boom)
+        m.setattr(fr, "get_rng_key", boom)
+        m.setattr(dispatch, "xla_primitive_callable", boom)
+        for _ in range(40):
+            if f1.done() and f2.done():
+                break
+            eng.step()
+    assert f1.result(timeout=1) == _oracle(model, p1, 6)
+    got = f2.result(timeout=1)
+    assert len(got) == 6 and all(0 <= t < 1024 for t in got)
+    # every decode or verify call took one (key, offset) pair
+    assert fr.default_generator()._offset > offset0
+    if eng.paged:
+        assert eng.stats()["prefix_cache"]["cow_copies"] > 0
+
+
+@_rng_engines
+def test_seed_after_engine_build_governs_sampled_stream(model, kind):
+    """The engine reads the default generator each tick: paddle.seed(s) on
+    a LIVE engine restarts the stream (same seed, same tokens; another
+    seed, other tokens).  The admission token is the engine's own host
+    draw, pinned here so the runs differ only by the seed."""
+    rng = np.random.RandomState(61)
+    p = rng.randint(0, 1024, 10).astype(np.int32)
+    eng = _rng_engine(model, kind)
+    eng.warmup()
+
+    def run(seed):
+        paddle.seed(seed)
+        eng._rng = np.random.default_rng(5)
+        return eng.generate(p, max_new_tokens=12, do_sample=True,
+                            temperature=5.0, top_p=0.99)
+
+    a, again, b = run(101), run(101), run(202)
+    assert len(a) == len(b) == 12
+    assert a == again
+    assert a[0] == b[0] and a != b
+
+
+@_rng_engines
+def test_warmed_engine_first_requests_compile_nothing(model, kind):
+    """warmup() ran the programs with what a tick passes them, (key, offset)
+    included: the first greedy and the first sampled request compile
+    nothing, the engine's jits and the argument staging alike."""
+    from paddle_tpu.observability import profiling as prof
+
+    rng = np.random.RandomState(62)
+    p = rng.randint(0, 1024, 13).astype(np.int32)
+    eng = _rng_engine(model, kind)
+    want = _oracle(model, p, 5)  # the oracle's own compiles come first
+    try:
+        eng.warmup()
+        quiet = _compiles()
+        assert eng.generate(p, max_new_tokens=5) == want
+        assert _compiles() == quiet
+        got = eng.generate(p, max_new_tokens=5, do_sample=True,
+                           temperature=3.0, top_k=40, top_p=0.9)
+        assert len(got) == 5
+        assert _compiles() == quiet
+    finally:
+        prof.mark_warm(False)

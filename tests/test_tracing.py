@@ -960,13 +960,14 @@ def test_programs_and_scopes_are_named_and_change_no_token(model,
     programs = {
         "llm_decode": (eng._decode_jit[1], head + (
             np.zeros((B, M), i32), np.zeros((B, 1), i32), np.zeros(B, i32),
-            *knobs, eng._mask_all_true, jax.random.split(key, 1), *lora)),
+            *knobs, eng._mask_all_true, key, np.uint32(0), *lora)),
         "llm_prefill_chunk": (eng._get_chunk_prefill(), head + (
             np.zeros((1, M), i32), np.zeros((1, C), i32), np.zeros(1, i32),
             i32(0), *eng._lora_args([0]))),
         "llm_spec_verify": (eng._get_verify(), head + (
             np.zeros((B, M), i32), np.zeros((B, 1), i32),
-            np.zeros((B, K), i32), np.zeros(B, i32), *knobs, key, *lora)),
+            np.zeros((B, K), i32), np.zeros(B, i32), *knobs, key,
+            np.uint32(0), *lora)),
     }
     for name, (jit, args) in programs.items():
         hlo = jit.lower(*args).compile().as_text()
