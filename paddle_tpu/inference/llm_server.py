@@ -87,6 +87,7 @@ from ..observability.spans import PhaseClock as _PhaseClock
 from ..observability.spans import span as _span
 from ..ops.sampling import sample_rows as _sample_rows
 from ..ops.sampling import spec_accept as _spec_accept
+from ..models.kv_cache import SlotRows as _SlotRows
 from ..tensor.tensor import Tensor
 from . import constrain as _constrain
 
@@ -209,6 +210,24 @@ _M_KV_HOST_BYTES = _obs.gauge(
 _M_KV_PROMOTE_S = _obs.histogram(
     "llm_kv_promote_seconds",
     "One batched promotion (tier reads + a single host->device upload)")
+
+_M_MOE_PAIRS = _obs.counter(
+    "llm_moe_pairs_total",
+    "(token, expert) pairs routed by the expert layers of the compiled "
+    "serving programs, by whether this device holds the expert and by "
+    "program (real rows only)", labelnames=("where", "program"))
+_M_MOE_TOUCHED = _obs.counter(
+    "llm_moe_experts_touched_total",
+    "Held experts that had at least one pair, summed over expert-layer calls",
+    labelnames=("program",))
+_M_MOE_CALLS = _obs.counter(
+    "llm_moe_layer_calls_total",
+    "Expert-layer calls (one a layer a decode step or prefill chunk)",
+    labelnames=("program",))
+_M_MOE_MAX_LOAD = _obs.gauge(
+    "llm_moe_max_expert_load_count",
+    "Most pairs one held expert got in a layer of the latest decode tick")
+_MOE_PROGRAMS = ("decode", "prefill")  # the planes of the device accumulator
 
 #: The pump's tick, cut into mutually exclusive phases in tick order (a
 #: speculative tick runs the spec_* phases in place of decode_*).  Every
@@ -384,6 +403,42 @@ def _lora_ctx(pool, tree, rows):
     return _oplora.activate(pool.site_pools(tree), rows)
 
 
+def _to_model_caches(kinds, caches, pos, page_tbl, slot_rows=None):
+    """The per-layer caches a model's step takes, from what the engine keeps.
+    Engine-side entries hold only what persists (the page POOLS (k, v[, ks,
+    vs]), a recurrent layer's per-slot state, nothing); pos, the page table
+    and the batch's rows are threaded in here, so the donated pytree never
+    aliases the shared table once a layer.  ``kinds`` is the model's
+    ``cache_kinds()``, or None for a model whose every layer pages k/v."""
+    def paged(c):
+        return (Tensor(c[0]), Tensor(c[1]), pos, Tensor(page_tbl)) \
+            + tuple(Tensor(x) for x in c[2:])
+
+    if kinds is None:
+        return [paged(c) for c in caches]
+    return [paged(c) if k.kind == "paged_kv"
+            else tuple(c) + (slot_rows,) if k.kind == "recurrent"
+            else slot_rows
+            for k, c in zip(kinds, caches)]
+
+
+def _from_model_caches(kinds, new_caches):
+    """Back again: (engine-side caches, what the expert layers reported)."""
+    raw, aux = [], []
+    for i, c in enumerate(new_caches):
+        kind = "paged_kv" if kinds is None else kinds[i].kind
+        if kind == "paged_kv":
+            vals = tuple(x._value if isinstance(x, Tensor) else x for x in c)
+            raw.append((vals[0], vals[1]) + vals[4:])
+        elif kind == "recurrent":
+            raw.append(tuple(c))
+        else:
+            raw.append(())
+            if kinds[i].experts_held:
+                aux.append(c)
+    return raw, aux
+
+
 class LLMEngine:
     def __init__(self, model, max_batch_slots=4, max_seq_len=512,
                  cache_dtype=None, eos_token_id=None, pad_token_id=0,
@@ -519,7 +574,30 @@ class LLMEngine:
         staged blocks back with one batched host->device upload and
         prefills from the first truly-uncached token — eviction becomes
         a copy at PCIe/DRAM rates, not a re-prefill, and greedy decode
-        stays bitwise identical to tiers off."""
+        stays bitwise identical to tiers off.
+
+        WHAT EACH LAYER KEEPS (paged only) is the model's to say.  A model
+        with ``cache_kinds()`` returns one ``models.kv_cache.CacheKind`` a
+        layer, and the engine allocates by it: K/V page pools at the
+        layer's own head count and ``head_dim``, fixed-size state a SLOT
+        (``"recurrent"``), or nothing (feed-forward and expert layers);
+        ``stats()["cache_kinds"]`` gives layers and bytes by kind.  A
+        model without the method (Llama, GPT) pages K/V in every layer.
+        ``models.nemotron_h.NemotronHForCausalLM`` keeps RECURRENT STATE in
+        its Mamba-2 layers (``stats()["recurrent_state"]``): it is a
+        slot's, not a page's — zeroed inside ``llm_prefill_chunk`` where a
+        request's first chunk runs (a preempted and requeued request
+        recomputes from nothing), carried from chunk to chunk, untouched by
+        a final chunk's padded tail, and not advanced by a decode tick for
+        slots that are idle or between chunks.  A shared page says nothing
+        of the state at its end and a rejected draft cannot be rolled back
+        out of one, so for such a model ``prefix_cache`` defaults to off
+        and ``prefix_cache=True``, ``host_cache_pages > 0``, ``spec_k >
+        0`` and the dense layout raise ``ValueError``.  Expert layers that
+        hold a share of the experts report their (token, expert) pairs:
+        both programs add them to one device-resident total that comes
+        back with the decode tick's tokens (``llm_moe_*``,
+        ``stats()["moe"]``)."""
         cfg = model.config
         self.model = model
         self.n_slots = int(max_batch_slots)
@@ -530,6 +608,37 @@ class LLMEngine:
                 f"kv_layout must be None, 'dense' or 'paged', got {kv_layout!r}")
         self.paged = kv_layout == "paged"
         self.kv_layout = "paged" if self.paged else "dense"
+        # what each layer keeps: the model says (one CacheKind a layer), or
+        # every layer pages k/v (Llama, GPT)
+        kinds = model.cache_kinds() if hasattr(model, "cache_kinds") else None
+        self._cache_kinds = kinds
+        self._recurrent = kinds is not None and any(
+            k.kind == "recurrent" for k in kinds)
+        if kinds is not None and not self.paged:
+            raise ValueError(
+                f"{type(model).__name__} declares per-layer cache kinds; only "
+                "kv_layout='paged' allocates them (the dense layout keeps one "
+                "[slots, max_seq_len] k/v buffer in every layer)")
+        if self._recurrent:
+            # a shared page says nothing of the recurrent state at its end,
+            # and a state cannot be rolled back past a rejected draft: until
+            # state is checkpointed at block boundaries (ROADMAP R5) these
+            # are off for such a model
+            why = (f"{type(model).__name__} keeps recurrent state in some "
+                   "layers: ")
+            if prefix_cache:
+                raise ValueError(
+                    why + "prefix_cache=True would map shared K/V pages but "
+                    "has no state at the shared prefix's end to resume from")
+            if host_cache_pages:
+                raise ValueError(
+                    why + "host_cache_pages stages prefix-cache pages, and the "
+                    "prefix cache is off for this model")
+            if spec_k:
+                raise ValueError(
+                    why + "spec_k > 0 would need a rejected draft rolled back "
+                    "out of the state, and a state has no past to return to")
+            prefix_cache = False
         if prefix_cache and not self.paged:
             raise ValueError(
                 "prefix_cache requires kv_layout='paged' (sharing rides on "
@@ -560,7 +669,9 @@ class LLMEngine:
         self._params, self._buffers = model.functional_state()
         # GQA models declare num_key_value_heads; MHA families (GPT) do not
         H = getattr(cfg, "num_key_value_heads", cfg.num_attention_heads)
-        D = cfg.hidden_size // cfg.num_attention_heads
+        # a model may state its head size (it need not divide the hidden one)
+        D = getattr(cfg, "head_dim", None) \
+            or cfg.hidden_size // cfg.num_attention_heads
         nl = cfg.num_hidden_layers
         B, L = self.n_slots, self.L
         kv_dtype = jnp.bfloat16 if str(
@@ -574,18 +685,25 @@ class LLMEngine:
                 else self.n_slots * self.M + 1
             P = max(P, 2)  # trash page + at least one allocatable page
             self.num_pages = P
-            if cache_dtype == "int8":
-                self.caches = [
-                    (jnp.zeros((P, H, ps, D), jnp.int8),
-                     jnp.zeros((P, H, ps, D), jnp.int8),
-                     jnp.full((P, H, ps), 1e-8, jnp.float32),
-                     jnp.full((P, H, ps), 1e-8, jnp.float32))
-                    for _ in range(nl)]
+            def pools(H, D):
+                if cache_dtype == "int8":
+                    return (jnp.zeros((P, H, ps, D), jnp.int8),
+                            jnp.zeros((P, H, ps, D), jnp.int8),
+                            jnp.full((P, H, ps), 1e-8, jnp.float32),
+                            jnp.full((P, H, ps), 1e-8, jnp.float32))
+                return (jnp.zeros((P, H, ps, D), kv_dtype),
+                        jnp.zeros((P, H, ps, D), kv_dtype))
+
+            if kinds is None:
+                self.caches = [pools(H, D) for _ in range(nl)]
             else:
+                # one entry a layer, by its kind: page pools at the layer's
+                # own head count and size, zeroed state a SLOT, or nothing
                 self.caches = [
-                    (jnp.zeros((P, H, ps, D), kv_dtype),
-                     jnp.zeros((P, H, ps, D), kv_dtype))
-                    for _ in range(nl)]
+                    pools(k.kv_heads, k.head_dim) if k.kind == "paged_kv"
+                    else tuple(jnp.zeros((B,) + tuple(shape), dt)
+                               for _, shape, dt in k.state)
+                    for k in kinds]
             # host-side allocator: page 0 is the trash page, never handed
             # out; pop() order is deterministic (highest id first).  Pages
             # are REFCOUNTED: a page may be held by several slots (shared
@@ -652,6 +770,35 @@ class LLMEngine:
                  jnp.zeros((B, H, L, D), kv_dtype),
                  jnp.zeros((B,), jnp.int32))
                 for _ in range(nl)]
+        # expert layers report their pairs by held expert: a running total
+        # on the device, [program, expert layer, held experts + touched],
+        # that both programs add to and the decode tick brings back with its
+        # tokens (one transfer); the pump publishes what was added since
+        self._moe_kinds = [k for k in (kinds or ()) if k.experts_held]
+        self._moe_acc = None
+        if self._moe_kinds:
+            if len({k.experts_held for k in self._moe_kinds}) != 1:
+                raise ValueError("expert layers must hold the same number of "
+                                 "experts (one accumulator row a layer)")
+            shape = (len(_MOE_PROGRAMS), len(self._moe_kinds),
+                     self._moe_kinds[0].experts_held + 1)
+            self._moe_acc = jnp.zeros(shape, jnp.int32)
+            self._moe_seen = np.zeros(shape, np.uint32)
+            # rows x top_k and layer calls dispatched since the last fetch
+            self._moe_pending = np.zeros((len(_MOE_PROGRAMS), 2), np.int64)
+            self._moe_stats = {
+                p: {"pairs_held": 0, "pairs_absent": 0, "experts_touched": 0,
+                    "layer_calls": 0} for p in _MOE_PROGRAMS}
+            self._moe_max_load = 0
+        # what the layers keep, for stats(): {kind: {"layers", "bytes"}}
+        self._cache_stats = {}
+        for i, c in enumerate(self.caches):
+            d = self._cache_stats.setdefault(
+                "paged_kv" if kinds is None else kinds[i].kind,
+                {"layers": 0, "bytes": 0})
+            d["layers"] += 1
+            d["bytes"] += sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                              for x in c)
         self._prefilling = None  # (request, slot, prompt tokens consumed)
         self.slot_pos = np.zeros(B, np.int32)       # valid tokens per slot
         self.slot_req: list[_Request | None] = [None] * B
@@ -1097,11 +1244,28 @@ class LLMEngine:
                 "acceptance_ratio": self._spec_accepted / self._spec_drafted
                 if self._spec_drafted else 0.0,
             }
+        cache_kinds = {k: dict(v) for k, v in self._cache_stats.items()}
+        rec = cache_kinds.get("recurrent")
+        moe = None
+        if self._moe_kinds:
+            moe = {p: dict(v) for p, v in self._moe_stats.items()}
+            moe.update(expert_layers=len(self._moe_kinds),
+                       experts_held=self._moe_kinds[0].experts_held,
+                       max_expert_load=self._moe_max_load)
         return {
             "queue_depth": self._pending.qsize(),
             "active_slots": sum(r is not None for r in self.slot_req),
             "n_slots": self.n_slots,
             "kv_layout": self.kv_layout,
+            # what the layers keep: {kind: {"layers", "bytes"}} over
+            # paged_kv, recurrent and none (the dense layout reads paged_kv)
+            "cache_kinds": cache_kinds,
+            # per-slot state of the recurrent layers; None without any
+            "recurrent_state": None if rec is None else {
+                "bytes": rec["bytes"], "slots": self.n_slots,
+                "layers": rec["layers"]},
+            # expert layers' routed pairs by program; None without any
+            "moe": moe,
             "llm_kv_pages_in_use": pages_used,
             "kv_pages_total": pages_total,
             "kv_page_utilization": pages_used / pages_total
@@ -2179,33 +2343,90 @@ class LLMEngine:
         model = self.model
         pool = self.adapters.pool if self.adapters is not None else None
 
+        kinds = self._cache_kinds
+
         def llm_prefill_chunk(params, buffers, caches, page_row, ids, off,
-                              last_index, lora_tree, lora_rows):
+                              last_index, lora_tree, lora_rows, *slot_and_acc):
+            # a model that declares its layers' kinds also gets the chunk's
+            # SLOT: recurrent state is a slot's, zero where the request's
+            # first chunk runs (off == 0, so a requeued request recomputes
+            # from nothing), carried from chunk to chunk, and not advanced
+            # by the padded tail past last_index
+            rows = None if kinds is None else _SlotRows(
+                slot_and_acc[0].reshape(1), off == 0,
+                (last_index + 1).reshape(1))
             restore = model.bind_functional_state(params, buffers)
             try:
                 with tape.no_grad(), _lora_ctx(pool, lora_tree, lora_rows):
-                    t_caches = [
-                        (Tensor(c[0]), Tensor(c[1]), off, Tensor(page_row))
-                        + tuple(Tensor(x) for x in c[2:])
-                        for c in caches]
                     logits, new_caches = model.prefill_chunk_step(
-                        Tensor(ids), t_caches, last_index)
-                    raw = []
-                    for c in new_caches:
-                        vals = tuple(x._value if isinstance(x, Tensor) else x
-                                     for x in c)
-                        raw.append((vals[0], vals[1]) + vals[4:])
+                        Tensor(ids),
+                        _to_model_caches(kinds, caches, off, page_row, rows),
+                        last_index)
+                    raw, aux = _from_model_caches(kinds, new_caches)
             finally:
                 restore()
+            if aux:
+                return logits._value, raw, \
+                    slot_and_acc[1].at[1].add(jnp.stack(aux))
             return logits._value, raw
 
-        return jax.jit(llm_prefill_chunk, donate_argnums=(2,))
+        return jax.jit(llm_prefill_chunk, donate_argnums=(
+            (2, 10) if self._moe_acc is not None else (2,)))
 
     def _get_chunk_prefill(self):
         if "chunk" not in self._prefill_jit:
             _profiling.record_compile("chunk_prefill")
             self._prefill_jit["chunk"] = self._chunk_prefill_fn()
         return self._prefill_jit["chunk"]
+
+    def _chunk_extra(self, slot):
+        """The chunk program's trailing arguments for a model that declares
+        its layers' cache kinds: the slot, and the expert accumulator."""
+        if self._cache_kinds is None:
+            return ()
+        if self._moe_acc is None:
+            return (np.int32(slot),)
+        return (np.int32(slot), self._moe_acc)
+
+    def _took(self, out):
+        """Keep what a decode or chunk program hands back — the caches and,
+        with expert layers, the accumulator; returns its first result."""
+        first, self.caches = out[:2]
+        if self._moe_acc is not None:
+            self._moe_acc = out[2]
+        return first
+
+    def _moe_dispatched(self, program, rows, calls):
+        """`rows` real rows went through every expert layer `calls` times."""
+        n = len(self._moe_kinds)
+        self._moe_pending[_MOE_PROGRAMS.index(program)] += (
+            rows * calls * self._moe_kinds[0].top_k * n, calls * n)
+
+    def _publish_moe(self, total):
+        """`total`: the device accumulator as the decode tick brought it
+        back, flat.  Publishes what the programs added since the last one:
+        the counters, stats()["moe"] and the largest load of the tick."""
+        total = total.astype(np.uint32).reshape(self._moe_seen.shape)
+        delta = (total - self._moe_seen).astype(np.int64)  # wraps like int32
+        self._moe_seen = total
+        for i, prog in enumerate(_MOE_PROGRAMS):
+            pairs, calls = (int(x) for x in self._moe_pending[i])
+            if not calls:
+                continue
+            held = int(delta[i, :, :-1].sum())
+            touched = int(delta[i, :, -1].sum())
+            _M_MOE_PAIRS.labels(where="held", program=prog).inc(held)
+            _M_MOE_PAIRS.labels(where="absent", program=prog).inc(pairs - held)
+            _M_MOE_TOUCHED.labels(program=prog).inc(touched)
+            _M_MOE_CALLS.labels(program=prog).inc(calls)
+            st = self._moe_stats[prog]
+            st["pairs_held"] += held
+            st["pairs_absent"] += pairs - held
+            st["experts_touched"] += touched
+            st["layer_calls"] += calls
+        self._moe_pending[:] = 0
+        self._moe_max_load = int(delta[0, :, :-1].max())
+        _M_MOE_MAX_LOAD.set(self._moe_max_load)
 
     def _admit_paged(self):
         """Chunked-prefill admission: at most ONE prompt chunk per tick, so
@@ -2464,7 +2685,7 @@ class LLMEngine:
         args = (self._params, self._buffers, self.caches,
                 self._pt_host[slot:slot + 1].copy(), chunk,
                 np.full((1,), done, np.int32), np.int32(m - 1)) \
-            + self._lora_args([req.adapter_page])
+            + self._lora_args([req.adapter_page]) + self._chunk_extra(slot)
         # the call is asynchronous: this phase, like the llm_prefill_chunk
         # span inside it, is the host's time to DISPATCH the chunk.  The
         # wait for the chunk shows where the host next reads a result:
@@ -2477,9 +2698,11 @@ class LLMEngine:
                 with _span("llm_prefill_chunk", _M_PREFILL_CHUNK_S,
                            trace=req.trace,
                            attrs={"index": done // C, "tokens": int(m)}):
-                    logits, self.caches = jit(*args)
+                    logits = self._took(jit(*args))
             else:
-                logits, self.caches = jit(*args)
+                logits = self._took(jit(*args))
+            if self._moe_acc is not None:
+                self._moe_dispatched("prefill", m, 1)
         except Exception as e:
             self._prefilling = None
             self._release_pages(slot)
@@ -2563,17 +2786,22 @@ class LLMEngine:
             params, buffers = self._params, self._buffers
             if self.paged:
                 C = self.prefill_chunk
-                _, self.caches = self._get_chunk_prefill()(
+                # last_index -1: no token of the warm-up chunk is real, so
+                # slot 0's recurrent state and the expert counts stay put
+                self._took(self._get_chunk_prefill()(
                     params, buffers, self.caches,
                     np.zeros((1, self.M), np.int32),
                     np.full((1, C), self.pad, np.int32),
-                    np.zeros((1,), np.int32), np.int32(0),
-                    *self._lora_args([0]))
-                # the COW fork program too: a warm engine's first
-                # shared-prefix fork must not compile (and must not trip
-                # recompile_storm).  A trash-page self-copy is harmless.
-                self.caches = self._get_cow_copy()(
-                    self.caches, np.int32(0), np.int32(0))
+                    np.zeros((1,), np.int32),
+                    np.int32(0 if self._cache_kinds is None else -1),
+                    *self._lora_args([0]), *self._chunk_extra(0)))
+                if not self._recurrent:
+                    # the COW fork program too: a warm engine's first
+                    # shared-prefix fork must not compile (and must not trip
+                    # recompile_storm).  A trash-page self-copy is harmless.
+                    # (A model with recurrent state shares no page: no fork.)
+                    self.caches = self._get_cow_copy()(
+                        self.caches, np.int32(0), np.int32(0))
             else:
                 for Lb in (buckets if buckets is not None else self.buckets):
                     Lb = int(Lb)
@@ -2594,9 +2822,11 @@ class LLMEngine:
             args += (tokens, pos, *knobs)
             if self.paged:
                 args += (self._mask_all_true, *rng, *self._lora_args([0] * B))
+                if self._moe_acc is not None:
+                    args += (self._moe_acc,)
             else:
                 args += rng
-            _, self.caches = self._get_decode(eff)(*args)
+            self._took(self._get_decode(eff)(*args))
             if self.spec_k:
                 vargs = (params, buffers, self.caches)
                 if self.paged:
@@ -2691,47 +2921,49 @@ class LLMEngine:
             # ride the cached all-True mask (an exact sampler no-op), and
             # adapter swaps change only the gathered rows — so turning
             # either feature on after warmup() never recompiles
+            kinds = self._cache_kinds
+
             def llm_decode(params, buffers, caches, page_tbl, tokens, pos,
                            do_sample, temperature, top_k, top_p, token_mask,
-                           base_key, offset, lora_tree, lora_rows):
+                           base_key, offset, lora_tree, lora_rows, *moe_acc):
                 keys = jax.random.split(
                     jax.random.fold_in(base_key, offset), eff)
+                # the tick masks the table rows of idle and mid-prefill
+                # slots to the trash page: such a row is computed like the
+                # others but advances no recurrent state and counts nowhere
+                rows = None if kinds is None else _SlotRows(
+                    None, None, (page_tbl[:, 0] != 0).astype(jnp.int32))
                 restore = model.bind_functional_state(params, buffers)
                 try:
                     with tape.no_grad(), _lora_ctx(pool, lora_tree,
                                                    lora_rows):
                         def tick(carry, key):
-                            caches, tok, p = carry
-                            # engine-side caches hold only the page POOLS
-                            # (k, v[, ks, vs]); pos and the page table are
-                            # threaded in here so the donated pytree never
-                            # aliases the shared table nl times
-                            t_caches = [
-                                (Tensor(c[0]), Tensor(c[1]), p,
-                                 Tensor(page_tbl))
-                                + tuple(Tensor(x) for x in c[2:])
-                                for c in caches]
+                            caches, tok, p = carry[:3]
                             logits, new_caches = model.generate_step(
-                                Tensor(tok), caches=t_caches)
-                            raw = []
-                            for c in new_caches:
-                                vals = tuple(
-                                    x._value if isinstance(x, Tensor) else x
-                                    for x in c)
-                                raw.append((vals[0], vals[1]) + vals[4:])
+                                Tensor(tok), caches=_to_model_caches(
+                                    kinds, caches, p, page_tbl, rows))
+                            raw, aux = _from_model_caches(kinds, new_caches)
                             nxt = _select_rows(logits._value[:, -1], key,
                                                do_sample, temperature,
                                                top_k, top_p,
                                                token_mask=token_mask)
-                            return (raw, nxt[:, None], p + 1), nxt
+                            acc = tuple(a.at[0].add(jnp.stack(aux))
+                                        for a in carry[3:])
+                            return (raw, nxt[:, None], p + 1) + acc, nxt
 
-                        (caches, _, _), toks = jax.lax.scan(
-                            tick, (caches, tokens, pos), keys)
+                        carry, toks = jax.lax.scan(
+                            tick, (caches, tokens, pos) + moe_acc, keys)
                 finally:
                     restore()
-                return toks.T, caches  # [B, chunk]
+                if moe_acc:
+                    # the pair counts ride home with the tokens: one array
+                    return jnp.concatenate(
+                        [toks.T.reshape(-1), carry[3].reshape(-1)]), \
+                        carry[0], carry[3]
+                return toks.T, carry[0]  # [B, chunk]
 
-            return jax.jit(llm_decode, donate_argnums=(2,))
+            return jax.jit(llm_decode, donate_argnums=(
+                (2, 15) if self._moe_acc is not None else (2,)))
 
         def llm_decode(params, buffers, caches, tokens, pos, do_sample,
                        temperature, top_k, top_p, base_key, offset):
@@ -2789,20 +3021,12 @@ class LLMEngine:
                 try:
                     with tape.no_grad(), _lora_ctx(pool, lora_tree,
                                                    lora_rows):
-                        t_caches = [
-                            (Tensor(c[0]), Tensor(c[1]), pos,
-                             Tensor(page_tbl))
-                            + tuple(Tensor(x) for x in c[2:])
-                            for c in caches]
                         ids_in = jnp.concatenate([tokens, drafts], axis=1)
                         logits, new_caches = model.verify_step(
-                            Tensor(ids_in), caches=t_caches)
-                        raw = []
-                        for c in new_caches:
-                            vals = tuple(
-                                x._value if isinstance(x, Tensor) else x
-                                for x in c)
-                            raw.append((vals[0], vals[1]) + vals[4:])
+                            Tensor(ids_in), caches=_to_model_caches(
+                                self._cache_kinds, caches, pos, page_tbl))
+                        raw, _ = _from_model_caches(self._cache_kinds,
+                                                    new_caches)
                         out, n_acc = _spec_accept(
                             logits._value, drafts, key, do_sample,
                             temperature, top_k, top_p)
@@ -2961,14 +3185,21 @@ class LLMEngine:
                           for r in reqs]))
         else:
             args += (tokens, pos, do_s, temp, topk, topp, *rng)
+        moe = self._moe_acc is not None
+        if moe:
+            args += (self._moe_acc,)
+            self._moe_dispatched("decode", len(active), eff)
         pc.switch("decode_dispatch")
-        nxt_dev, new_caches = jit(*args)
         # the returned tuples carry advanced pos at slot [2], but the
         # engine's [B] slot_pos vector stays authoritative — each tick
         # rebuilds the per-slot positions (finished slots do not advance)
-        self.caches = new_caches
+        nxt_dev = self._took(jit(*args))
         pc.switch("decode_sync")
         nxt = np.asarray(nxt_dev).astype(np.int32)  # [B, eff]
+        if moe:
+            # the expert layers' pair counts came in the same array
+            counts = nxt[self.n_slots * eff:]
+            nxt = nxt[:self.n_slots * eff].reshape(self.n_slots, eff)
         # every token of this tick carries this stamp: the instant the
         # host had them.  It is also the boundary into bookkeeping, and
         # the end of the goodput ledger's productive decode seconds (arg
@@ -2978,6 +3209,8 @@ class LLMEngine:
         t_end = pc.switch("bookkeep")
         self._goodput.carve("decode", t_end - t_dec)
         now_pc = t_end or time.perf_counter()  # the clock is off: read it
+        if moe:
+            self._publish_moe(counts)
         emitted = 0
         for j in range(eff):
             for i in list(active):

@@ -1,7 +1,10 @@
 """Shared static + paged kv-cache layouts for the compiled decode loops.
 
-Four layouts, distinguished by tuple length (see generation.generate and
-inference/llm_server.py):
+Which layers keep a k/v cache at all is a model's ``cache_kinds()`` (one
+``CacheKind`` a layer, below; ``SlotRows`` is what the layers with per-slot
+state are handed); a model without it keeps one in every layer.  The k/v
+layouts themselves are four, distinguished by tuple length (see
+generation.generate and inference/llm_server.py):
   (k_buf, v_buf, pos)                      — plain static, cache dtype = kv dtype
   (k_pages, v_pages, pos, page_tbl)        — PAGED plain: global page pool
                                              [P, H, page_size, D] + per-slot
@@ -59,7 +62,46 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from dataclasses import dataclass
+from typing import Any, NamedTuple
+
 from ..tensor.tensor import apply_op
+
+
+@dataclass(frozen=True)
+class CacheKind:
+    """What ONE layer keeps between the tokens of a sequence, as a model's
+    ``cache_kinds()`` tells the serving engine (one entry a layer).  A model
+    without that method keeps a pair of K/V page pools in every layer.
+
+      "paged_kv"   page pools [pages, kv_heads, page_size, head_dim]; a
+                   token's rows are found through its slot's page table
+      "recurrent"  fixed-size state a SLOT, not a page: ``state`` lists
+                   (name, shape of one slot's, dtype)
+      "none"       nothing (a feed-forward or expert layer); with
+                   ``experts_held`` > 0 the layer reports, per call, its
+                   pairs by held expert and the experts touched
+                   ([experts_held + 1] int32) in place of a cache
+    """
+    kind: str
+    kv_heads: int = 0
+    head_dim: int = 0
+    state: tuple = ()
+    experts_held: int = 0
+    top_k: int = 0
+
+
+class SlotRows(NamedTuple):
+    """The rows of a batch against the engine's slots, handed to the layers
+    whose state is a slot's (and to expert layers, which count real rows)."""
+    rows: Any     # int32 [b]: the slot behind each row; None = row i is slot i
+    fresh: Any    # bool [b]: the row opens a sequence, its state starts at
+                  # zero; None = no row does
+    n_valid: Any  # int32 [b]: leading tokens of the row that are real; a row
+                  # with 0 leaves its slot's state exactly as it was.  The
+                  # engine's decode program has no other word for "this row
+                  # is idle": it reads 0 where the tick masked the row's page
+                  # table to the trash page (page_tbl[:, 0] == 0), 1 elsewhere
 
 
 def _quantize_kv(kv):

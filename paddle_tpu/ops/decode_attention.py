@@ -41,8 +41,8 @@ NEG_INF = -1e30
 #: shape-static), so the counter ticks once per attention call site per
 #: compiled program — a fallback regression shows up as `path="paged_dense"`
 #: increments on /metrics the moment the offending program compiles, not as
-#: a silent latency cliff.  reason ∈ {tile_aligned, off_tile,
-#: query_rows_over_vmem, grid_too_large, forced}.
+#: a silent latency cliff.  reason ∈ {tile_aligned, query_blocks, off_tile,
+#: grid_too_large, forced}.
 _M_ATTN_DISPATCH = _obs.counter(
     "llm_attn_kernel_total",
     "Attention dispatch decisions at trace time: which path (Pallas kernel "
@@ -539,8 +539,8 @@ def paged_decode_attention(q, k_pages, v_pages, offset, page_tbl,
     scale pools [P, Hkv, page_size].  Any S >= 1 rides the ONE ragged
     Pallas kernel on tile-aligned shapes — S=1 decode, prefill chunks,
     and the K+1 spec-verify ladder; the gathered dense path survives only
-    for CPU-odd shapes (D/page off the 128 tile, mismatched head counts)
-    or a query block too large for VMEM.  Returns [B, S, H, D] in q's
+    for CPU-odd shapes (D/page off the 128 tile, mismatched head counts);
+    a query block too large for VMEM goes through it as sub-blocks.  Returns [B, S, H, D] in q's
     dtype."""
     B, S, H, D = q.shape
     Hkv, ps = k_pages.shape[1], k_pages.shape[2]
@@ -553,21 +553,30 @@ def paged_decode_attention(q, k_pages, v_pages, offset, page_tbl,
     # ps % 128 == 0 keeps every page block (and the reshaped scale pages)
     # on clean (sublane, 128-lane) tiles; anything else is fallback-only
     tile_ok = D % 128 == 0 and ps % 128 == 0 and H % Hkv == 0
-    # even at G=1 the S*rep query rows of q/m/l/acc state must fit VMEM
-    rows_ok = tile_ok and _paged_state_bytes(
-        S * (H // Hkv), D) <= 6 * 1024 * 1024
+    # the S*rep query rows of q/m/l/acc state must fit VMEM even at G=1.  A
+    # block of many queries at a wide group (256 x 16 query heads a kv head)
+    # overflows it and still rides the kernel, as sub-blocks of `sub`
+    # queries: block [s0, s0 + sub) with lengths offset + s0 + sub IS a
+    # ragged block of its own (the kernel's causal end is valid - S + s + 1).
+    # One query's rows always fit, so a divisor is always found.
+    sub = max(d for d in range(1, S + 1) if S % d == 0 and (
+        d == 1 or _paged_state_bytes(d * (H // Hkv), D) <= 6 * 1024 * 1024))
     if _FORCE_PATH == "dense":
         reason, use_kernel = "forced", False
     elif not tile_ok:
         reason, use_kernel = "off_tile", False
-    elif not rows_ok:
-        reason, use_kernel = "query_rows_over_vmem", False
     else:
-        reason, use_kernel = "tile_aligned", True
+        reason, use_kernel = ("tile_aligned" if sub == S else "query_blocks"), True
     if use_kernel:
         _note("paged_kernel", reason)
-        return _paged_pallas(q, k_pages, v_pages, lengths, page_tbl,
-                             k_scale, v_scale, scale, interpret)
+        if sub == S:
+            return _paged_pallas(q, k_pages, v_pages, lengths, page_tbl,
+                                 k_scale, v_scale, scale, interpret)
+        return jnp.concatenate([
+            _paged_pallas(q[:, s0:s0 + sub], k_pages, v_pages,
+                          lengths - (S - s0 - sub), page_tbl,
+                          k_scale, v_scale, scale, interpret)
+            for s0 in range(0, S, sub)], axis=1)
     _note("paged_dense", reason)
     return _paged_dense(q, k_pages, v_pages, offset, page_tbl,
                         k_scale, v_scale, scale)

@@ -251,6 +251,39 @@ def test_aot_decode_kernels(v5e_devices, as_on_tpu):
         assert _names(calls, "decode_attention"), (kv, calls)
 
 
+@pytest.mark.slow
+def test_aot_hybrid_kernels_at_published_widths(v5e_devices, as_on_tpu):
+    """Nemotron-3-Nano's widths: the grouped expert matmul at a decode tick's
+    and a prefill chunk's rows, the Mamba-2 state update over 64 slots, and
+    the paged kernel at 32 query heads on 2 K/V heads (a chunk of 256 rides
+    it as two sub-blocks)."""
+    from paddle_tpu.ops import decode_attention as da
+    from paddle_tpu.ops import moe_experts as moe
+    from paddle_tpu.ops import ssm_update as ssm
+
+    one = _one_device(v5e_devices)
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    h, F, held = 2688, 1856, 64
+    for T in (64, 256):
+        calls = _mosaic_calls(
+            lambda x, w1, w2, ex, wt: moe.moe_experts(x, w1, w2, ex, wt, 0)[0],
+            one, ((T, h), bf), ((held, F, h), bf), ((held, F, h), bf),
+            ((T, 6), i32), ((T, 6), f32))
+        assert _names(calls, "moe_experts"), (T, calls)
+    B, H, P, N, G = 64, 64, 64, 128, 8
+    calls = _mosaic_calls(
+        lambda s, xdt, dA, bm, cm: ssm._state_pallas(s, xdt, dA, bm, cm, False),
+        one, ((B, H, P * N), f32), ((B, H, P), f32), ((B, H, N), f32),
+        ((B, G, N), f32), ((B, G, N), f32))
+    assert _names(calls, "ssm_update"), calls
+    for S, b, n in ((1, 64, 1), (256, 1, 2)):
+        calls = _mosaic_calls(
+            lambda q, k, v, off, tbl: da.paged_decode_attention(q, k, v, off, tbl),
+            one, ((b, S, 32, 128), bf), ((513, 2, 128, 128), bf),
+            ((513, 2, 128, 128), bf), ((b,), i32), ((b, 8), i32))
+        assert sum("paged_attention" in c for c in calls) == n, (S, calls)
+
+
 # --------------------------------------------- slow: the smoke's control flow
 @pytest.mark.slow
 def test_smoke_phases_at_tiny_size_on_cpu(smoke, monkeypatch):

@@ -208,6 +208,27 @@ def test_paged_kernel_ragged_chunk_gqa():
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("S,off,reason", [(1, 300, "tile_aligned"),
+                                          (256, 200, "query_blocks")])
+def test_paged_kernel_wide_group_two_kv_heads(S, off, reason):
+    """32 query heads on 2 K/V heads (16 : 1): a decode row, and a prefill
+    chunk of 256 queries whose 4096 rows a K/V head ride the kernel as two
+    sub-blocks of 128."""
+    from paddle_tpu.observability import REGISTRY
+
+    fam = REGISTRY.get("llm_attn_kernel_total")
+    count = lambda: {l: c.value for l, c in fam.series()}.get(  # noqa: E731
+        ("paged_kernel", reason), 0.0)
+    q, kp, vp, pt, lens = _mk_paged(H=32, Hkv=2, lens=(off + S,) * 3)
+    qs = _mk_ragged_q(3, S, 32, seed=11)
+    before = count()
+    got = paged_decode_attention(qs, kp, vp, off, pt, interpret=True)
+    assert count() == before + 1
+    want = _paged_dense(qs, kp, vp, off, pt, None, None, 1 / 128 ** 0.5)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
 def test_paged_kernel_ragged_int8():
     """int8 dequant-in-VMEM with a ragged S=3 block and per-slot offsets."""
     S = 3
@@ -262,11 +283,16 @@ def test_paged_dispatcher_ragged_reasons_and_counter():
     want = _paged_dense(qs, kp, vp, lens, pt, None, None, 1 / 128 ** 0.5)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
-    # a ragged block whose S*rep rows of VMEM state cannot fit -> dense
+    # a ragged block whose S*rep rows of VMEM state cannot fit rides the
+    # kernel as sub-blocks of queries (here 2 x 800), each a ragged block
     huge = _mk_ragged_q(3, 1600, 8, seed=9)  # 3200 rows > 6MB state cap
-    b = counts().get(("paged_dense", "query_rows_over_vmem"), 0.0)
-    paged_decode_attention(huge, kp, vp, lens, pt, interpret=True)
-    assert counts()[("paged_dense", "query_rows_over_vmem")] == b + 1
+    b = counts().get(("paged_kernel", "query_blocks"), 0.0)
+    got = paged_decode_attention(huge, kp, vp, lens, pt, interpret=True)
+    assert counts()[("paged_kernel", "query_blocks")] == b + 1
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(_paged_dense(
+            huge, kp, vp, lens, pt, None, None, 1 / 128 ** 0.5)),
+        rtol=2e-5, atol=2e-5)
     # the test/bench override pins the fallback for A/B runs
     b = counts().get(("paged_dense", "forced"), 0.0)
     da._FORCE_PATH = "dense"
