@@ -85,6 +85,7 @@ from ..observability import slo as _slo
 from ..observability import tracing as _tracing
 from ..observability.spans import PhaseClock as _PhaseClock
 from ..observability.spans import span as _span
+from ..ops.sampling import has_threshold as _has_threshold
 from ..ops.sampling import sample_rows as _sample_rows
 from ..ops.sampling import spec_accept as _spec_accept
 from ..models.kv_cache import SlotRows as _SlotRows
@@ -260,6 +261,16 @@ _M_ADM_BLOCKED = _obs.counter(
     "held the queue head", labelnames=("reason",))
 _ADM_BLOCKED_SERIES = {r: _M_ADM_BLOCKED.labels(reason=r)
                        for r in _BLOCK_REASONS}
+#: how much of the fused sampler a tick's knobs make the device run: the
+#: argmax alone, a draw without a sort, or the one sort of the vocabulary
+_SAMPLER_PATHS = ("greedy", "draw", "threshold")
+_M_SAMPLER_TICKS = _obs.counter(
+    "llm_sampler_ticks_total",
+    "Decode and verify ticks by the part of the sampler their knobs "
+    "engage (the host's count of the predicate the program branches on)",
+    labelnames=("path",))
+_SAMPLER_SERIES = {p: _M_SAMPLER_TICKS.labels(path=p)
+                   for p in _SAMPLER_PATHS}
 
 
 def _attn_dispatch_series():
@@ -387,8 +398,18 @@ def _select_rows(logits, key, do_sample, temperature, top_k, top_p,
     fused sampler (ops/sampling.sample_rows), which generation._select
     also delegates to, so the engine and the solo loop share one masking
     + categorical implementation.  ``token_mask`` (bool [B, V]) is the
-    constrained-decoding path; all-True rows are exact no-ops.  The
-    named scope only labels the ops ("sampler" in the profiler's op_name)."""
+    constrained-decoding path; all-True rows are exact no-ops.
+
+    What a step pays follows the knob ARRAYS, decided on the device inside
+    this one program (``lax.cond``): with every row greedy the argmax alone
+    runs; rows that draw without a live top_k / top_p add the scaling and
+    the noise; only a threshold on a row that draws sorts the vocabulary,
+    and then once — the entries under the k-th value are a suffix of that
+    sort, so it is already the sort of the top-k survivors that top-p sums
+    over, and the draws are bitwise those of sorting twice.  Nothing is
+    compiled when the first sampled request arrives after warmup().  The
+    named scope only labels the ops ("sampler" in the profiler's op_name);
+    both branches keep it."""
     with jax.named_scope("sampler"):
         return _sample_rows(logits, key, do_sample, temperature, top_k,
                             top_p, token_mask=token_mask)
@@ -846,6 +867,7 @@ class LLMEngine:
         self._phases = _PhaseClock("llm_tick", _TICK_PHASES)
         self._phase_pub = dict.fromkeys(_TICK_PHASES, 0.0)
         self._adm_blocked = dict.fromkeys(_BLOCK_REASONS, 0)
+        self._sampler_ticks = dict.fromkeys(_SAMPLER_PATHS, 0)
         self.cache_aware = bool(cache_aware_admission)
         self.admission_age_cap = max(1, int(admission_age_cap))
         if self.cache_aware and (not self.paged or self._prefix is None):
@@ -1277,6 +1299,14 @@ class LLMEngine:
             "admission_reorders": self._adm_reorders,
             # ticks that left the queue head waiting, by what held it
             "admission_blocked": dict(self._adm_blocked),
+            # ticks by what their knobs made the sampler run: sampled =
+            # some row drew, threshold = the vocabulary was sorted
+            "sampler": {
+                "ticks": sum(self._sampler_ticks.values()),
+                "sampled_ticks": self._sampler_ticks["draw"]
+                + self._sampler_ticks["threshold"],
+                "threshold_ticks": self._sampler_ticks["threshold"],
+            },
             # the pump's exclusive phases, cumulative since construction;
             # host_s leaves out the phases that only wait for the device
             "tick_phases": {
@@ -2898,6 +2928,20 @@ class LLMEngine:
                 topk[i], topp[i] = r.top_k, r.top_p
         return do_s, temp, topk, topp
 
+    def _count_sampler_tick(self, do_s, topk, topp):
+        """Which part of the sampler this tick's knobs engage, by the
+        predicate the program branches on (ops/sampling.py): only rows
+        that draw count, so a greedy request's stale top_p sorts nothing.
+        Counted from the host's own arrays; nothing is read back."""
+        if not do_s.any():
+            path = "greedy"
+        elif (do_s & _has_threshold(topk, topp, self._vocab)).any():
+            path = "threshold"
+        else:
+            path = "draw"
+        self._sampler_ticks[path] += 1
+        _SAMPLER_SERIES[path].inc()
+
     def _get_decode(self, eff):
         jit = self._decode_jit.get(eff)
         if jit is None:
@@ -3156,6 +3200,7 @@ class LLMEngine:
         pos = self.slot_pos.copy()
         reqs = self.slot_req
         do_s, temp, topk, topp = self._sampling_knobs()
+        self._count_sampler_tick(do_s, topk, topp)
         # read each tick, not held: paddle.seed() on a live engine governs
         rng = _fr.default_generator().fork()
         args = (self._params, self._buffers, self.caches)
@@ -3271,6 +3316,7 @@ class LLMEngine:
         draft_s = pc.switch("spec_stage") - t0
         reqs = self.slot_req
         do_s, temp, topk, topp = self._sampling_knobs()
+        self._count_sampler_tick(do_s, topk, topp)
         args = (self._params, self._buffers, self.caches)
         if self.paged:
             # same inactive-slot masking as decode: a mid-prefill slot's

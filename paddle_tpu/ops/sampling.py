@@ -12,8 +12,22 @@ Per-row knobs ride as device ARRAYS (one entry per batch slot), so slots
 with different sampling settings share one compiled program.  ``top_k`` is
 per-row too: the k-th largest value is read out of the descending sort the
 top-p mask needs anyway (``take_along_axis`` at index ``k-1``), so a
-per-slot k never changes the program shape — the restriction the serving
-engine used to document is gone.
+per-slot k never changes the program shape.
+
+The cost follows the knobs.  The same arrays decide, ON DEVICE and inside
+the one program (``lax.cond``; no second program, no flag), how much of the
+sampler a step runs:
+
+- no row with ``do_sample``: the ``argmax`` alone — no division by the
+  temperature, no sort, no soft-max, no noise over ``[B, V]``;
+- a row samples but no SAMPLING row has a live threshold (``top_k`` in
+  ``[1, V)`` or ``top_p < 1``; a greedy row may carry a stale ``top_p``):
+  scale and draw, no sort;
+- else one sort of the vocabulary serves both thresholds.  The entries
+  below the k-th value are a suffix of the descending sort, so masking that
+  suffix in the sorted row IS the sort of the top-k survivors the top-p
+  mass is summed over: the masked logits are bitwise those of sorting
+  twice, and with the same key every draw is the same token.
 
 Contracts the repo's parity tests pin down:
 
@@ -34,10 +48,42 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["mask_logits", "sample_rows", "spec_accept"]
+__all__ = ["has_threshold", "mask_logits", "sample_rows", "spec_accept"]
 
 
-def mask_logits(logits, temperature, top_k, top_p, token_mask=None):
+def has_threshold(top_k, top_p, vocab):
+    """Per row: does a knob cut the candidate set?  ``top_k`` in
+    ``[1, vocab)`` or ``top_p < 1``.  Plain comparisons, so the serving
+    engine's host count (numpy knobs) and the compiled sampler (traced
+    arrays) decide by the same line."""
+    return ((top_k > 0) & (top_k < vocab)) | (top_p < 1.0)
+
+
+def _cut(lt, k, top_p):
+    """top-k by VALUE, then top-p over the survivors, from ONE sort."""
+    V = lt.shape[-1]
+    use_k, use_p = (k > 0) & (k < V), top_p < 1.0
+    sorted_lt = jnp.sort(lt, axis=-1)[..., ::-1]
+    # k-th largest value per row; masking by VALUE (< kth) keeps ties at
+    # the threshold, exactly like generation._select's lax.top_k variant
+    kth = jnp.take_along_axis(
+        sorted_lt, jnp.clip(k - 1, 0, V - 1)[:, None], axis=-1)
+    lt = jnp.where(use_k[:, None] & (lt < kth), -jnp.inf, lt)
+    # top-p over the top-k SURVIVORS.  The entries below the k-th value are
+    # a suffix of the descending sort, so masking that suffix IS the sort
+    # of the masked row: no second sort
+    sorted_lt = jnp.where(
+        use_k[:, None] & (sorted_lt < kth), -jnp.inf, sorted_lt)
+    probs = jax.nn.softmax(sorted_lt, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    # keep the smallest set with cumulative prob >= top_p (always >= 1 tok)
+    cutoff_idx = jnp.sum(cum < top_p[:, None], axis=-1, keepdims=True)
+    cutoff = jnp.take_along_axis(sorted_lt, cutoff_idx, axis=-1)
+    return jnp.where(use_p[:, None] & (lt < cutoff), -jnp.inf, lt)
+
+
+def mask_logits(logits, temperature, top_k, top_p, token_mask=None,
+                rows=None):
     """Temperature/top-k/top-p masking, vectorized per row.
 
     logits ``[B, V]``; ``temperature``/``top_p`` f32 ``[B]``; ``top_k``
@@ -51,29 +97,21 @@ def mask_logits(logits, temperature, top_k, top_p, token_mask=None):
     statistical knobs then act on.  An all-True mask is an exact no-op
     (``jnp.where`` returns the untouched lane), preserving bitwise parity
     for unconstrained rows.
+
+    The vocabulary is sorted only if some row carries a live threshold
+    (a ``lax.cond`` on the knob arrays), and then once.  ``rows``
+    (optional bool ``[B]``) names the rows whose result the caller reads:
+    a threshold on any other row (a greedy request that carries
+    ``top_p=0.9``) does not count, and such a row's result is unspecified.
     """
-    V = logits.shape[-1]
     lt = logits.astype(jnp.float32) / jnp.maximum(temperature, 1e-6)[:, None]
     if token_mask is not None:
         lt = jnp.where(token_mask, lt, -jnp.inf)
     k = jnp.asarray(top_k, jnp.int32)
-    use_k = (k > 0) & (k < V)
-    # k-th largest value per row; masking by VALUE (< kth) keeps ties at
-    # the threshold, exactly like generation._select's lax.top_k variant
-    sorted_lt = jnp.sort(lt, axis=-1)[..., ::-1]
-    kth = jnp.take_along_axis(
-        sorted_lt, jnp.clip(k - 1, 0, V - 1)[:, None], axis=-1)
-    lt = jnp.where(use_k[:, None] & (lt < kth), -jnp.inf, lt)
-    # top-p over the top-k SURVIVORS (re-sort: the -inf entries must fall
-    # out of the cumulative mass, generation._select's order of operations)
-    use_p = top_p < 1.0
-    sorted_lt = jnp.sort(lt, axis=-1)[..., ::-1]
-    probs = jax.nn.softmax(sorted_lt, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    # keep the smallest set with cumulative prob >= top_p (always >= 1 tok)
-    cutoff_idx = jnp.sum(cum < top_p[:, None], axis=-1, keepdims=True)
-    cutoff = jnp.take_along_axis(sorted_lt, cutoff_idx, axis=-1)
-    return jnp.where(use_p[:, None] & (lt < cutoff), -jnp.inf, lt)
+    live = has_threshold(k, top_p, logits.shape[-1])
+    if rows is not None:
+        live = live & rows
+    return jax.lax.cond(jnp.any(live), lambda: _cut(lt, k, top_p), lambda: lt)
 
 
 def sample_rows(logits, key, do_sample, temperature, top_k, top_p,
@@ -82,7 +120,9 @@ def sample_rows(logits, key, do_sample, temperature, top_k, top_p,
 
     Each row carries its own ``(do_sample, temperature, top_k, top_p)``;
     greedy rows take the raw argmax (no masking touches them), sampled
-    rows draw categorically from the masked distribution.
+    rows draw categorically from the masked distribution.  With no
+    sampled row the program runs the argmax alone; the scaling, the noise
+    and (see :func:`mask_logits`) the sort run only when a row needs them.
 
     ``token_mask`` (bool ``[B, V]``) constrains BOTH paths: greedy rows
     argmax over the masked logits (a constrained greedy row must emit an
@@ -92,9 +132,14 @@ def sample_rows(logits, key, do_sample, temperature, top_k, top_p,
     greedy_src = logits if token_mask is None else jnp.where(
         token_mask, logits, -jnp.inf)
     greedy = jnp.argmax(greedy_src, axis=-1).astype(jnp.int32)
-    masked = mask_logits(logits, temperature, top_k, top_p, token_mask)
-    sampled = jax.random.categorical(key, masked, axis=-1).astype(jnp.int32)
-    return jnp.where(do_sample, sampled, greedy)
+
+    def draw():
+        masked = mask_logits(logits, temperature, top_k, top_p, token_mask,
+                             rows=do_sample)
+        sampled = jax.random.categorical(key, masked, axis=-1)
+        return jnp.where(do_sample, sampled.astype(jnp.int32), greedy)
+
+    return jax.lax.cond(jnp.any(do_sample), draw, lambda: greedy)
 
 
 def spec_accept(logits, drafts, key, do_sample, temperature, top_k, top_p):
@@ -131,34 +176,43 @@ def spec_accept(logits, drafts, key, do_sample, temperature, top_k, top_p):
     ladder = jnp.argmax(logits, axis=-1).astype(jnp.int32)        # [B, K+1]
     g_match = (ladder[:, :K] == drafts).astype(jnp.int32)
     g_acc = jnp.sum(jnp.cumprod(g_match, axis=-1), axis=-1)       # [B]
+
     # ---- sampled path: one-hot-q rejection sampling on masked logits
-    flat = mask_logits(
-        logits.reshape(B * S, V),
-        jnp.repeat(temperature, S), jnp.repeat(top_k, S),
-        jnp.repeat(top_p, S))
-    masked = flat.reshape(B, S, V)
-    p = jax.nn.softmax(masked, axis=-1)
-    p_draft = jnp.take_along_axis(
-        p[:, :K], drafts[..., None], axis=-1)[..., 0]             # [B, K]
-    key_u, key_r = jax.random.split(key)
-    u = jax.random.uniform(key_u, (B, K), jnp.float32)
-    s_match = (u < p_draft).astype(jnp.int32)
-    s_acc = jnp.sum(jnp.cumprod(s_match, axis=-1), axis=-1)       # [B]
-    n_acc = jnp.where(do_sample, s_acc, g_acc).astype(jnp.int32)
-    # correction/bonus token for sampled rows, drawn at column n_acc:
-    # a rejection (n_acc < K) zeroes the rejected draft out of the
-    # residual; a clean run (n_acc == K) samples the bonus unmodified
-    col = jnp.take_along_axis(masked, n_acc[:, None, None], axis=1)[:, 0]
-    rej_draft = jnp.take_along_axis(
-        drafts, jnp.clip(n_acc, 0, K - 1)[:, None], axis=-1)[:, 0]
-    rejected = n_acc < K
-    col = jnp.where(
-        rejected[:, None] & (jnp.arange(V)[None, :] == rej_draft[:, None]),
-        -jnp.inf, col)
-    corr = jax.random.categorical(key_r, col, axis=-1).astype(jnp.int32)
-    s_out = jnp.concatenate(
-        [drafts, jnp.zeros((B, 1), jnp.int32)], axis=-1)          # [B, K+1]
-    s_out = jnp.where(
-        jnp.arange(K + 1)[None, :] == n_acc[:, None], corr[:, None], s_out)
-    out = jnp.where(do_sample[:, None], s_out, ladder)
+    def sampled():
+        flat = mask_logits(
+            logits.reshape(B * S, V),
+            jnp.repeat(temperature, S), jnp.repeat(top_k, S),
+            jnp.repeat(top_p, S), rows=jnp.repeat(do_sample, S))
+        masked = flat.reshape(B, S, V)
+        p = jax.nn.softmax(masked, axis=-1)
+        p_draft = jnp.take_along_axis(
+            p[:, :K], drafts[..., None], axis=-1)[..., 0]         # [B, K]
+        key_u, key_r = jax.random.split(key)
+        u = jax.random.uniform(key_u, (B, K), jnp.float32)
+        s_match = (u < p_draft).astype(jnp.int32)
+        s_acc = jnp.sum(jnp.cumprod(s_match, axis=-1), axis=-1)   # [B]
+        n_acc = jnp.where(do_sample, s_acc, g_acc).astype(jnp.int32)
+        # correction/bonus token for sampled rows, drawn at column n_acc:
+        # a rejection (n_acc < K) zeroes the rejected draft out of the
+        # residual; a clean run (n_acc == K) samples the bonus unmodified
+        col = jnp.take_along_axis(masked, n_acc[:, None, None], axis=1)[:, 0]
+        rej_draft = jnp.take_along_axis(
+            drafts, jnp.clip(n_acc, 0, K - 1)[:, None], axis=-1)[:, 0]
+        rejected = n_acc < K
+        col = jnp.where(
+            rejected[:, None]
+            & (jnp.arange(V)[None, :] == rej_draft[:, None]), -jnp.inf, col)
+        corr = jax.random.categorical(key_r, col, axis=-1).astype(jnp.int32)
+        s_out = jnp.concatenate(
+            [drafts, jnp.zeros((B, 1), jnp.int32)], axis=-1)      # [B, K+1]
+        s_out = jnp.where(
+            jnp.arange(K + 1)[None, :] == n_acc[:, None], corr[:, None],
+            s_out)
+        return jnp.where(do_sample[:, None], s_out, ladder), n_acc
+
+    # with every row greedy the ladder is the answer: the sampled path
+    # (masking, soft-max, noise over [B*(K+1), V]) runs only if a row draws
+    out, n_acc = jax.lax.cond(
+        jnp.any(do_sample), sampled,
+        lambda: (ladder, g_acc.astype(jnp.int32)))
     return out.astype(jnp.int32), n_acc

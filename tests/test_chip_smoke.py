@@ -284,6 +284,36 @@ def test_aot_hybrid_kernels_at_published_widths(v5e_devices, as_on_tpu):
         assert sum("paged_attention" in c for c in calls) == n, (S, calls)
 
 
+@pytest.mark.slow
+def test_aot_sampler_keeps_its_conditionals_at_a_64k_vocabulary(v5e_devices):
+    """The v5e's compiler leaves the fused sampler's two conditionals in the
+    decode scan, with the ONE sort of `[64, 65536]` inside the inner branch:
+    a greedy tick can skip it (the chip run that showed it does: PERF.md §6,
+    PR 28)."""
+    from paddle_tpu.inference.llm_server import _select_rows
+
+    B, V = 64, 65536
+    one = _one_device(v5e_devices)
+
+    def decode(logits, key, do_s, temp, k, p, mask):
+        def tick(c, key):
+            nxt = _select_rows(logits * c.astype(jnp.bfloat16), key, do_s,
+                               temp, k, p, token_mask=mask)
+            return c + nxt.sum(), nxt
+        return jax.lax.scan(tick, jnp.int32(1), jax.random.split(key, 2))
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in (
+        ((B, V), jnp.bfloat16), ((2,), jnp.uint32), ((B,), jnp.bool_),
+        ((B,), jnp.float32), ((B,), jnp.int32), ((B,), jnp.float32),
+        ((B, V), jnp.bool_))]
+    hlo = jax.jit(decode).lower(*args).compile().as_text()
+    assert len(re.findall(r" conditional\(", hlo)) == 2
+    sorts = [ln for ln in hlo.splitlines()
+             if re.search(r" sort\(", ln) and f"[{B},{V}]" in ln]
+    assert len(sorts) == 1
+    assert "sampler/cond/branch_1_fun/cond/branch_1_fun" in sorts[0]
+
+
 # --------------------------------------------- slow: the smoke's control flow
 @pytest.mark.slow
 def test_smoke_phases_at_tiny_size_on_cpu(smoke, monkeypatch):

@@ -127,6 +127,62 @@ def test_per_request_sampling_knobs(model):
     assert a != b  # 1024-way vocab at T=5: collision of 12 draws ~ never
 
 
+def _sampler_series():
+    from paddle_tpu import observability as obs
+
+    fam = obs.snapshot().get("llm_sampler_ticks_total")
+    return {s["labels"]["path"]: s["value"] for s in fam["series"]}
+
+
+@pytest.mark.parametrize("kind", ["dense", "paged"])
+def test_sampler_counter_follows_the_knobs_of_rows_that_draw(model, kind):
+    """stats()["sampler"] classifies each tick by the predicate the compiled
+    sampler branches on: greedy traffic never reaches the sort (not even
+    with a stale top_p on a greedy request), a temperature-only request
+    draws without one, and a top_p request sorts on exactly the ticks it
+    decodes.  The registry's family counts the same ticks, and none of it
+    compiles a program after warmup()."""
+    kw = dict(kv_layout="paged", page_size=32, prefill_chunk=16) \
+        if kind == "paged" else dict(prompt_buckets=(8, 32))
+    rng = np.random.RandomState(16)
+    p = [rng.randint(0, 1024, n).astype(np.int32) for n in (10, 14, 7)]
+    eng = LLMEngine(model, max_batch_slots=2, max_seq_len=128, **kw)
+    eng.warmup()
+    s0, r0, quiet = eng.stats()["sampler"], _sampler_series(), _compiles()
+    assert s0 == {"ticks": 0, "sampled_ticks": 0, "threshold_ticks": 0}
+
+    def served(**knobs):
+        before = eng.stats()["sampler"]
+        futs = [eng.submit(p[0], max_new_tokens=6, **knobs),
+                eng.submit(p[1], max_new_tokens=4)]  # a greedy slotmate
+        eng.run_until_complete()
+        assert [len(f.result(timeout=1)) for f in futs] == [6, 4]
+        after = eng.stats()["sampler"]
+        return {k: after[k] - before[k] for k in after}
+
+    # the first token is the host's; 5 decode ticks serve the longer row
+    assert served() == {
+        "ticks": 5, "sampled_ticks": 0, "threshold_ticks": 0}
+    assert served(top_p=0.9, top_k=5) == {       # greedy, stale knobs
+        "ticks": 5, "sampled_ticks": 0, "threshold_ticks": 0}
+    assert served(do_sample=True, temperature=1.5) == {
+        "ticks": 5, "sampled_ticks": 5, "threshold_ticks": 0}
+    assert served(do_sample=True, top_k=1024) == {   # k = V cuts nothing
+        "ticks": 5, "sampled_ticks": 5, "threshold_ticks": 0}
+    assert served(do_sample=True, top_p=0.9) == {
+        "ticks": 5, "sampled_ticks": 5, "threshold_ticks": 5}
+    # a sampled row of 3 tokens beside a greedy row of 6: 2 ticks sort
+    f = [eng.submit(p[0], max_new_tokens=6),
+         eng.submit(p[2], max_new_tokens=3, do_sample=True, top_k=8)]
+    eng.run_until_complete()
+    assert [len(x.result(timeout=1)) for x in f] == [6, 3]
+    s1, r1 = eng.stats()["sampler"], _sampler_series()
+    assert s1 == {"ticks": 30, "sampled_ticks": 17, "threshold_ticks": 7}
+    assert {k: r1[k] - r0.get(k, 0) for k in r1} == {
+        "greedy": 13, "draw": 10, "threshold": 7}
+    assert _compiles() == quiet
+
+
 def test_chunked_decode_matches_per_token(model):
     """decode_chunk=4 (multi-step scheduling: 4 tokens per compiled call)
     produces the same greedy outputs, including eos mid-chunk with the
