@@ -6,9 +6,10 @@ the same engine at 7B widths on the attached TPU):
     JAX_PLATFORMS=cpu python examples/serve_llm_continuous.py
 
 Demonstrates: slot-pool serving with one compiled decode step for every
-in-flight request, bucketed prefill admission, per-request sampling knobs,
-the int8 kv-cache (half footprint + half decode stream via the Pallas
-decode kernel), and chunked multi-step scheduling for high-latency hosts.
+in-flight request, chunked-prefill admission into a paged kv cache,
+per-request sampling knobs, int8 pages (half footprint + half decode stream
+via the Pallas decode kernel), and chunked multi-step scheduling for
+high-latency hosts.
 """
 import os
 import sys
@@ -35,7 +36,6 @@ def main():
         max_batch_slots=4,        # concurrent decode lanes
         max_seq_len=256,
         cache_dtype="int8",       # capacity + bandwidth lever
-        prompt_buckets=(32, 64, 128),
         decode_chunk=4,           # 4 tokens per compiled call
     ).start()                     # background pump; omit and call
     #                               eng.run_until_complete() for sync use

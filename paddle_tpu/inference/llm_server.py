@@ -4,29 +4,31 @@ Reference gap: the v2.3-era AnalysisPredictor serves one fixed-shape model
 program per request (analysis_predictor.h) — there is no decode server.
 This engine is the TPU-native design the kv-cache stack invites:
 
-- a FIXED pool of batch slots over head-major static caches
-  [slots, H, L, D] (models/kv_cache.py layouts, bf16 or int8);
+- a FIXED pool of batch slots over a PAGED kv cache (the Ragged Paged
+  Attention design, models/kv_cache.py paged contract): one global page
+  pool a layer plus per-slot page tables, so capacity follows the actual
+  sequence lengths, not slots x max_seq_len reserved rows;
 - ONE compiled decode step for the whole pool per token: each slot carries
   its own position, so the rope offsets, cache scatters and the Pallas
   decode-attention masks are all per-slot vectors — requests at different
   depths decode together with no recompilation and no padding restarts;
-- admission by PREFILL into a free slot: prompts pad up to a small set of
-  bucket lengths (one compile per bucket), the prefill's k/v rows are
-  copied into the slot, and the request joins the next decode tick;
-- completion by eos/max-tokens frees the slot for the next queued request.
+- admission by CHUNKED PREFILL into a free slot, gated by FREE PAGES:
+  prompts prefill in fixed-size chunks interleaved with decode ticks
+  through ONE compiled chunk program (no compile per prompt length), so a
+  long prompt never stalls running slots for more than one chunk step,
+  and the request joins the next decode tick after its final chunk;
+- completion by eos/max-tokens frees the slot and its pages for the next
+  queued request; when the pool runs dry an in-flight request is preempted
+  recompute-style (requeued with what it generated so far).
 
-``kv_layout="paged"`` swaps the dense per-slot buffers for a PAGED cache
-(the Ragged Paged Attention design, kv_cache.py paged contract): a global
-page pool + per-slot page tables, admission gated by FREE PAGES instead of
-reserved max_seq_len rows, page reclamation on finish/expiry,
-recompute-style preemption when the pool runs dry, and CHUNKED PREFILL —
-prompts prefill in fixed-size chunks interleaved with decode ticks through
-ONE compiled chunk program (no per-bucket compile zoo), so a long prompt
-never stalls running slots for more than one chunk step.  ``warmup()``
-pre-compiles either layout's programs so the first request pays no compile
-latency.
+``warmup()`` pre-compiles the programs so the first request pays no
+compile latency.  There is one engine: ``kv_layout`` is accepted for the
+callers that still pass ``"paged"`` and selects nothing.  The dense
+per-slot layout this file once also held is gone; the dense STATIC cache
+of ``models.generation.generate()`` is the reference the parity tests
+hold this engine to.
 
-On top of the paged layout sits the PREFIX CACHE (on by default,
+On top of the page pool sits the PREFIX CACHE (on by default,
 ``prefix_cache=False`` to disable): a radix index over chained hashes of
 page-aligned prompt blocks (inference/prefix_cache.py) remembers which
 pages hold which prefixes.  Admission maps the cached pages straight into
@@ -43,7 +45,7 @@ itself (causal attention — a token's kv never depends on what follows it).
 (prompt-lookup n-gram by default, or a small draft model —
 models/spec_decode.py) proposes K tokens per slot per tick and ONE
 compiled verify pass scores all K+1 positions through the same
-dense/paged cache paths, emitting the longest valid prefix plus a
+paged cache path, emitting the longest valid prefix plus a
 correction token — up to (K+1)x fewer serial model passes at identical
 greedy output.  Rollback rides the existing machinery: the slot position
 stops at the accept point, rejected rows are overwritten before any read,
@@ -61,6 +63,7 @@ batched server, not a positional error.
 """
 from __future__ import annotations
 
+import math
 import os
 import queue
 import threading
@@ -138,7 +141,7 @@ _M_PREFILL_CHUNK_S = _obs.histogram(
     "llm_prefill_chunk_seconds", "One compiled prefill-chunk call")
 _M_PAGES_IN_USE = _obs.gauge(
     "llm_kv_pages_in_use_count",
-    "KV-cache pages currently allocated to slots (paged layout)")
+    "KV-cache pages currently allocated to slots")
 _M_PAGE_UTIL = _obs.gauge(
     "llm_kv_page_utilization_ratio",
     "Allocated fraction of the allocatable kv page pool")
@@ -171,7 +174,7 @@ _M_SPEC_ROLLED_BACK = _obs.counter(
     "Draft tokens rejected and rolled back by speculative verify steps")
 _M_SPEC_RB_PAGES = _obs.counter(
     "llm_spec_rolled_back_pages_total",
-    "KV pages reclaimed by speculative rollback trims (paged layout)")
+    "KV pages reclaimed by speculative rollback trims")
 _M_SPEC_ACCEPT_RATIO = _obs.gauge(
     "llm_spec_acceptance_ratio",
     "Cumulative accepted/drafted fraction of speculative decoding")
@@ -342,6 +345,9 @@ class _Request:
     top_p: float = 1.0
     deadline: float | None = None
     slot: int = -1
+    regrown: int = 0          # generated tokens a preemption has already
+                              # appended to ``prompt`` (the next one appends
+                              # only what came after them)
     skip_cache: bool = False  # set on preemption: re-admission goes fully
                               # private so a COW-starved request can never
                               # re-match the same contended pages forever
@@ -463,8 +469,8 @@ def _from_model_caches(kinds, new_caches):
 class LLMEngine:
     def __init__(self, model, max_batch_slots=4, max_seq_len=512,
                  cache_dtype=None, eos_token_id=None, pad_token_id=0,
-                 prompt_buckets=(32, 64, 128, 256), decode_chunk=1,
-                 max_queue_len=None, clock=None, kv_layout=None,
+                 decode_chunk=1, max_queue_len=None, clock=None,
+                 kv_layout=None,
                  page_size=128, num_pages=None, prefill_chunk=None,
                  prefix_cache=None, metrics_port=None, slo_targets=None,
                  flight_recorder_dir=None, healthy_heartbeat_age=60.0,
@@ -480,20 +486,18 @@ class LLMEngine:
         rewritten at the next admission), and admission/eos decisions
         happen every k tokens instead of every token.
 
-        ``kv_layout="paged"`` replaces the dense per-slot cache with a
-        PAGED one: a global page pool of ``num_pages`` pages of
-        ``page_size`` tokens (page 0 reserved as the trash page) plus
-        per-slot page tables.  Admission is by FREE PAGES, capacity scales
-        with actual sequence lengths, pages reclaim on finish/expiry, and
-        prompts prefill in ``prefill_chunk``-token chunks interleaved with
-        decode ticks — ONE compiled prefill program (no per-bucket zoo) and
-        a long prompt never stalls running slots for more than one chunk.
-        ``num_pages`` defaults to full dense capacity
-        (slots * max_seq_len / page_size + trash); size it by HBM budget to
-        oversubscribe.  A slot whose decode outruns the pool is preempted
-        with ServerOverloadedError (llm_page_preemptions_total).
+        The page pool holds ``num_pages`` pages of ``page_size`` tokens
+        (page 0 is the trash page); ``num_pages`` defaults to what slots *
+        max_seq_len reserved rows would take (slots * max_seq_len /
+        page_size + trash): size it by HBM budget to oversubscribe.
+        Prompts prefill in ``prefill_chunk``-token chunks (default 128), one
+        a tick.  A slot whose decode outruns the pool is preempted and
+        requeued (llm_page_preemptions_total); a request that could never
+        fit fails with ServerOverloadedError.  ``kv_layout`` selects
+        nothing: ``None`` and ``"paged"`` are this one engine, ``"dense"``
+        raises ``ValueError`` (``generate()`` keeps the dense static cache).
 
-        ``prefix_cache`` (paged only; default on) shares kv pages across
+        ``prefix_cache`` (default on) shares kv pages across
         requests with a common prompt prefix: admission matches the prompt
         against a radix index of page-block hashes, maps the hit pages
         into the slot's table (refcounted), charges admission only for the
@@ -545,13 +549,13 @@ class LLMEngine:
         host-side drafter (``spec_draft``: "ngram" prompt-lookup by
         default, any object with ``.propose``, or a small draft model —
         models/spec_decode.py) proposes K tokens per active slot and ONE
-        compiled verify pass (S = K+1 through the same dense/paged cache
-        paths) scores them all; the longest valid prefix plus one
+        compiled verify pass (S = K+1 through the same paged cache
+        path) scores them all; the longest valid prefix plus one
         correction token is emitted, so a tick advances each slot by 1 to
         K+1 tokens.  Greedy outputs stay bitwise identical to spec_k=0;
         sampled slots use rejection sampling (distribution-preserving).
         Rollback is free: the slot's logical position simply does not
-        advance past the accept point, and (paged) pages holding only
+        advance past the accept point, and pages holding only
         rejected rows are decref'd back to the pool each tick
         (llm_spec_rolled_back_pages_total).  A verify that outruns the
         page pool preempts recompute-style exactly like decode.
@@ -559,7 +563,7 @@ class LLMEngine:
         amortizes the host round-trip; stacking the two schedulers is
         unsupported).
 
-        ``cache_aware_admission=True`` (paged + prefix cache only) lets
+        ``cache_aware_admission=True`` (needs the prefix cache) lets
         admission pick among the first few queued requests the one with
         the LONGEST cached prompt prefix instead of strict FIFO —
         back-to-back warm requests admit while a cold miss would have
@@ -568,7 +572,7 @@ class LLMEngine:
         ``admission_age_cap`` the head admits next regardless of cache
         affinity (llm_admission_reorders_total counts the bypasses).
 
-        ``adapters=`` (paged only) attaches a shared
+        ``adapters=`` attaches a shared
         ``models.lora.AdapterRegistry``: requests submitted with
         ``adapter_id=`` decode through that adapter's paged LoRA weight
         blocks — per-slot page rows gather into ONE compiled program, so
@@ -582,7 +586,7 @@ class LLMEngine:
         be compiled replica-side; pre-compiled ``TokenConstraint``
         objects work without it.
 
-        ``host_cache_pages > 0`` (paged + prefix cache) turns on the
+        ``host_cache_pages > 0`` (needs the prefix cache) turns on the
         HIERARCHICAL KV tiers (README §Serving, "Hierarchical KV"): a
         background worker stages cold cached prefix pages device->host
         into a ``kv_host_cache.HostKVPool`` whenever the free-page ratio
@@ -597,7 +601,7 @@ class LLMEngine:
         a copy at PCIe/DRAM rates, not a re-prefill, and greedy decode
         stays bitwise identical to tiers off.
 
-        WHAT EACH LAYER KEEPS (paged only) is the model's to say.  A model
+        WHAT EACH LAYER KEEPS is the model's to say.  A model
         with ``cache_kinds()`` returns one ``models.kv_cache.CacheKind`` a
         layer, and the engine allocates by it: K/V page pools at the
         layer's own head count and ``head_dim``, fixed-size state a SLOT
@@ -613,8 +617,8 @@ class LLMEngine:
         slots that are idle or between chunks.  A shared page says nothing
         of the state at its end and a rejected draft cannot be rolled back
         out of one, so for such a model ``prefix_cache`` defaults to off
-        and ``prefix_cache=True``, ``host_cache_pages > 0``, ``spec_k >
-        0`` and the dense layout raise ``ValueError``.  Expert layers that
+        and ``prefix_cache=True``, ``host_cache_pages > 0`` and ``spec_k >
+        0`` raise ``ValueError``.  Expert layers that
         hold a share of the experts report their (token, expert) pairs:
         both programs add them to one device-resident total that comes
         back with the decode tick's tokens (``llm_moe_*``,
@@ -622,24 +626,29 @@ class LLMEngine:
         cfg = model.config
         self.model = model
         self.n_slots = int(max_batch_slots)
-        # pad L to the decode kernel's 128 tile
-        self.L = ((int(max_seq_len) + 127) // 128) * 128
-        if kv_layout not in (None, "dense", "paged"):
+        if kv_layout == "dense":
             raise ValueError(
-                f"kv_layout must be None, 'dense' or 'paged', got {kv_layout!r}")
-        self.paged = kv_layout == "paged"
-        self.kv_layout = "paged" if self.paged else "dense"
+                "kv_layout='dense' was removed: LLMEngine has one engine, the "
+                "paged one (pass kv_layout=None or 'paged'); the dense static "
+                "cache lives on in models.generation.generate(), the "
+                "reference the engine's tokens are held to")
+        if kv_layout not in (None, "paged"):
+            raise ValueError(
+                f"kv_layout must be None or 'paged', got {kv_layout!r}")
+        lacks = [a for a in ("_supports_paged_cache", "prefill_chunk_step",
+                             "generate_step") if not getattr(model, a, False)]
+        if lacks:
+            raise ValueError(
+                f"{type(model).__name__} cannot be served by LLMEngine: it "
+                f"lacks {', '.join(lacks)} (the paged kv-cache surface: "
+                "attention that reads and writes page pools through a page "
+                "table, models/kv_cache.py)")
         # what each layer keeps: the model says (one CacheKind a layer), or
         # every layer pages k/v (Llama, GPT)
         kinds = model.cache_kinds() if hasattr(model, "cache_kinds") else None
         self._cache_kinds = kinds
         self._recurrent = kinds is not None and any(
             k.kind == "recurrent" for k in kinds)
-        if kinds is not None and not self.paged:
-            raise ValueError(
-                f"{type(model).__name__} declares per-layer cache kinds; only "
-                "kv_layout='paged' allocates them (the dense layout keeps one "
-                "[slots, max_seq_len] k/v buffer in every layer)")
         if self._recurrent:
             # a shared page says nothing of the recurrent state at its end,
             # and a state cannot be rolled back past a rejected draft: until
@@ -660,33 +669,15 @@ class LLMEngine:
                     why + "spec_k > 0 would need a rejected draft rolled back "
                     "out of the state, and a state has no past to return to")
             prefix_cache = False
-        if prefix_cache and not self.paged:
-            raise ValueError(
-                "prefix_cache requires kv_layout='paged' (sharing rides on "
-                "the page tables)")
-        if host_cache_pages and not self.paged:
-            raise ValueError(
-                "host_cache_pages requires kv_layout='paged' (the kv tiers "
-                "stage and re-map page-pool pages)")
-        self._prefix = None  # set by the paged branch below
         self.ps = int(page_size)
-        if self.paged:
-            if not getattr(model, "_supports_paged_cache", False):
-                raise ValueError(
-                    f"{type(model).__name__} does not support the paged "
-                    "kv-cache layout; use kv_layout=None")
-            if self.ps < 1:
-                raise ValueError(f"page_size must be >= 1, got {page_size}")
-            import math
-
-            # keep L a whole number of pages AND of 128-lane kernel tiles
-            unit = self.ps * 128 // math.gcd(self.ps, 128)
-            self.L = ((self.L + unit - 1) // unit) * unit
+        if self.ps < 1:
+            raise ValueError(f"page_size must be >= 1, got {page_size}")
+        # pad L to a whole number of pages AND of 128-lane kernel tiles
+        unit = math.lcm(self.ps, 128)
+        self.L = -(-int(max_seq_len) // unit) * unit
         self.cache_dtype = cache_dtype
         self.eos = -1 if eos_token_id is None else int(eos_token_id)
         self.pad = int(pad_token_id)
-        self.buckets = tuple(b for b in sorted(prompt_buckets)
-                             if b <= self.L) or (self.L,)
         self._params, self._buffers = model.functional_state()
         # GQA models declare num_key_value_heads; MHA families (GPT) do not
         H = getattr(cfg, "num_key_value_heads", cfg.num_attention_heads)
@@ -694,103 +685,89 @@ class LLMEngine:
         D = getattr(cfg, "head_dim", None) \
             or cfg.hidden_size // cfg.num_attention_heads
         nl = cfg.num_hidden_layers
-        B, L = self.n_slots, self.L
+        B = self.n_slots
         kv_dtype = jnp.bfloat16 if str(
             next(iter(jax.tree_util.tree_leaves(self._params))).dtype
         ) == "bfloat16" else jnp.float32
-        self._kv_dtype = kv_dtype
-        if self.paged:
-            ps = self.ps
-            self.M = self.L // ps  # page-table width (max pages per slot)
-            P = int(num_pages) if num_pages is not None \
-                else self.n_slots * self.M + 1
-            P = max(P, 2)  # trash page + at least one allocatable page
-            self.num_pages = P
-            def pools(H, D):
-                if cache_dtype == "int8":
-                    return (jnp.zeros((P, H, ps, D), jnp.int8),
-                            jnp.zeros((P, H, ps, D), jnp.int8),
-                            jnp.full((P, H, ps), 1e-8, jnp.float32),
-                            jnp.full((P, H, ps), 1e-8, jnp.float32))
-                return (jnp.zeros((P, H, ps, D), kv_dtype),
-                        jnp.zeros((P, H, ps, D), kv_dtype))
+        ps = self.ps
+        self.M = self.L // ps  # page-table width (max pages per slot)
+        P = int(num_pages) if num_pages is not None \
+            else self.n_slots * self.M + 1
+        P = max(P, 2)  # trash page + at least one allocatable page
+        self.num_pages = P
 
-            if kinds is None:
-                self.caches = [pools(H, D) for _ in range(nl)]
-            else:
-                # one entry a layer, by its kind: page pools at the layer's
-                # own head count and size, zeroed state a SLOT, or nothing
-                self.caches = [
-                    pools(k.kv_heads, k.head_dim) if k.kind == "paged_kv"
-                    else tuple(jnp.zeros((B,) + tuple(shape), dt)
-                               for _, shape, dt in k.state)
-                    for k in kinds]
-            # host-side allocator: page 0 is the trash page, never handed
-            # out; pop() order is deterministic (highest id first).  Pages
-            # are REFCOUNTED: a page may be held by several slots (shared
-            # prefix) and/or by one prefix-cache node; it returns to the
-            # free list only when the last holder decrefs.
-            self._free_pages = list(range(1, P))
-            self._page_ref = np.zeros(P, np.int32)
-            self._page_cached = np.zeros(P, bool)  # held by a cache node
-            self._slot_pages: list[list[int]] = [[] for _ in range(B)]
-            self._pt_host = np.zeros((B, self.M), np.int32)
-            self.prefill_chunk = max(1, min(
-                int(prefill_chunk) if prefill_chunk is not None else 128,
-                self.L))
-            if prefix_cache is None:
-                prefix_cache = True  # the fleet default: share prefixes
-            if prefix_cache:
-                from .prefix_cache import PrefixCache
+        def pools(H, D):
+            if cache_dtype == "int8":
+                return (jnp.zeros((P, H, ps, D), jnp.int8),
+                        jnp.zeros((P, H, ps, D), jnp.int8),
+                        jnp.full((P, H, ps), 1e-8, jnp.float32),
+                        jnp.full((P, H, ps), 1e-8, jnp.float32))
+            return (jnp.zeros((P, H, ps, D), kv_dtype),
+                    jnp.zeros((P, H, ps, D), kv_dtype))
 
-                self._prefix = PrefixCache(self.ps)
-            self._prefix_hit_tokens = 0
-            self._prefix_prompt_tokens = 0
-            # engine-local mirrors of the process-global counters, so
-            # stats() stays per-engine (two engines in one process must not
-            # read each other's forks/evictions)
-            self._cow_copies = 0
-            self._prefix_evictions = 0
-            self._prefix_epoch = 0  # bumped on insert/evict: invalidates
-                                    # requests' memoized match results
-            self._cow_jit = None
-            # ---- hierarchical kv tiers (host RAM + disk under the radix
-            # index): demotion stages pages AHEAD of eviction, promotion
-            # re-uploads them at admission — README §Serving
-            self._host_kv = None
-            if host_cache_pages:
-                if self._prefix is None:
-                    raise ValueError(
-                        "host_cache_pages requires the prefix cache (the "
-                        "tiers are keyed by its chained block hashes)")
-                from .kv_host_cache import HostKVPool
-
-                self._host_kv = HostKVPool(host_pages=host_cache_pages,
-                                           disk_dir=disk_cache_dir,
-                                           disk_pages=disk_cache_pages)
-            self.demote_watermark = float(demote_watermark)
-            self.demote_batch = max(1, int(demote_batch))
-            self._gather_jit = None
-            self._upload_jit = None
-            self._demote_thread = None
-            self._demote_mutex = threading.Lock()
-            self._tier_hit_tokens = {"hbm": 0, "host": 0, "disk": 0}
-            self._kv_demotions = 0
-            self._kv_promotions = 0
-        elif cache_dtype == "int8":
-            self.caches = [
-                (jnp.zeros((B, H, L, D), jnp.int8),
-                 jnp.zeros((B, H, L, D), jnp.int8),
-                 jnp.zeros((B,), jnp.int32),
-                 jnp.full((B, H, L), 1e-8, jnp.float32),
-                 jnp.full((B, H, L), 1e-8, jnp.float32))
-                for _ in range(nl)]
+        if kinds is None:
+            self.caches = [pools(H, D) for _ in range(nl)]
         else:
+            # one entry a layer, by its kind: page pools at the layer's
+            # own head count and size, zeroed state a SLOT, or nothing
             self.caches = [
-                (jnp.zeros((B, H, L, D), kv_dtype),
-                 jnp.zeros((B, H, L, D), kv_dtype),
-                 jnp.zeros((B,), jnp.int32))
-                for _ in range(nl)]
+                pools(k.kv_heads, k.head_dim) if k.kind == "paged_kv"
+                else tuple(jnp.zeros((B,) + tuple(shape), dt)
+                           for _, shape, dt in k.state)
+                for k in kinds]
+        # host-side allocator: page 0 is the trash page, never handed
+        # out; pop() order is deterministic (highest id first).  Pages
+        # are REFCOUNTED: a page may be held by several slots (shared
+        # prefix) and/or by one prefix-cache node; it returns to the
+        # free list only when the last holder decrefs.
+        self._free_pages = list(range(1, P))
+        self._page_ref = np.zeros(P, np.int32)
+        self._page_cached = np.zeros(P, bool)  # held by a cache node
+        self._slot_pages: list[list[int]] = [[] for _ in range(B)]
+        self._pt_host = np.zeros((B, self.M), np.int32)
+        self.prefill_chunk = max(1, min(
+            int(prefill_chunk) if prefill_chunk is not None else 128,
+            self.L))
+        if prefix_cache is None:
+            prefix_cache = True  # the fleet default: share prefixes
+        self._prefix = None
+        if prefix_cache:
+            from .prefix_cache import PrefixCache
+
+            self._prefix = PrefixCache(self.ps)
+        self._prefix_hit_tokens = 0
+        self._prefix_prompt_tokens = 0
+        # engine-local mirrors of the process-global counters, so
+        # stats() stays per-engine (two engines in one process must not
+        # read each other's forks/evictions)
+        self._cow_copies = 0
+        self._prefix_evictions = 0
+        self._prefix_epoch = 0  # bumped on insert/evict: invalidates
+                                # requests' memoized match results
+        self._cow_jit = None
+        # ---- hierarchical kv tiers (host RAM + disk under the radix
+        # index): demotion stages pages AHEAD of eviction, promotion
+        # re-uploads them at admission — README §Serving
+        self._host_kv = None
+        if host_cache_pages:
+            if self._prefix is None:
+                raise ValueError(
+                    "host_cache_pages requires the prefix cache (the "
+                    "tiers are keyed by its chained block hashes)")
+            from .kv_host_cache import HostKVPool
+
+            self._host_kv = HostKVPool(host_pages=host_cache_pages,
+                                       disk_dir=disk_cache_dir,
+                                       disk_pages=disk_cache_pages)
+        self.demote_watermark = float(demote_watermark)
+        self.demote_batch = max(1, int(demote_batch))
+        self._gather_jit = None
+        self._upload_jit = None
+        self._demote_thread = None
+        self._demote_mutex = threading.Lock()
+        self._tier_hit_tokens = {"hbm": 0, "host": 0, "disk": 0}
+        self._kv_demotions = 0
+        self._kv_promotions = 0
         # expert layers report their pairs by held expert: a running total
         # on the device, [program, expert layer, held experts + touched],
         # that both programs add to and the decode tick brings back with its
@@ -870,20 +847,14 @@ class LLMEngine:
         self._sampler_ticks = dict.fromkeys(_SAMPLER_PATHS, 0)
         self.cache_aware = bool(cache_aware_admission)
         self.admission_age_cap = max(1, int(admission_age_cap))
-        if self.cache_aware and (not self.paged or self._prefix is None):
+        if self.cache_aware and self._prefix is None:
             raise ValueError(
-                "cache_aware_admission requires kv_layout='paged' with the "
-                "prefix cache enabled (the reorder key IS the cached-prefix "
-                "length)")
+                "cache_aware_admission requires the prefix cache (the reorder "
+                "key IS the cached-prefix length)")
         self._adm_reorders = 0
         # -------------------------------------------- multi-tenant serving
         self.adapters = adapters
         if adapters is not None:
-            if not self.paged:
-                raise ValueError(
-                    "adapters= requires kv_layout='paged' (the adapter pool "
-                    "rides the paged serving path; dense slots have no page "
-                    "rows to gather)")
             from ..models.lora import AdapterRegistry
 
             if not isinstance(adapters, AdapterRegistry):
@@ -901,11 +872,10 @@ class LLMEngine:
         # exact no-op through the fused sampler, so unconstrained batches
         # stay bitwise identical to a mask-free program — and the mask arg
         # is ALWAYS present, so turning constraints on never recompiles
-        self._mask_all_true = (jnp.ones((self.n_slots, self._vocab), bool)
-                               if self.paged else None)
+        self._mask_all_true = jnp.ones((self.n_slots, self._vocab), bool)
         self._verify_jit = None
         self._decode_jit = {}  # scan length (effective chunk) -> jitted fn
-        self._prefill_jit = {}
+        self._chunk_jit = None  # the one prefill-chunk program
         # page id -> trace_id of the request whose prefill first indexed
         # it in the prefix cache (the COW-fork provenance stamp; bounded
         # by num_pages since inserts overwrite reused page ids)
@@ -960,7 +930,7 @@ class LLMEngine:
             # snapshot becomes a /varz section
             self.telemetry.register_collect(
                 self._goodput.publish, varz_key="goodput")
-            if self.paged and self._host_kv is not None:
+            if self._host_kv is not None:
                 # per-tier occupancy/hit-ratio on /varz — fleetwatch and
                 # the router read this absent-not-zero (older replicas
                 # simply have no prefix_tiers section)
@@ -1022,10 +992,6 @@ class LLMEngine:
         tables, so repeat traffic pays zero rebuild."""
         if constraint is None:
             return None
-        if not self.paged:
-            raise ValueError(
-                "constraint= requires kv_layout='paged' (the token-mask "
-                "path rides the paged decode program)")
         if self.spec_k:
             raise ValueError(
                 "constraint= does not compose with spec_k (constraint "
@@ -1229,12 +1195,12 @@ class LLMEngine:
         Request/latency series come from the process-global metrics
         registry, so two engines in one process share those counters.
         """
-        pages_total = (self.num_pages - 1) if self.paged else 0
+        pages_total = self.num_pages - 1
         # "in use" counts pages mapped by SLOTS; pages held only by the
         # prefix cache are reclaimable on demand and reported separately
-        pages_used = self._slot_held_pages() if self.paged else 0
+        pages_used = self._slot_held_pages()
         prefix = None
-        if self.paged and self._prefix is not None:
+        if self._prefix is not None:
             prompt_toks = self._prefix_prompt_tokens
             prefix = {
                 "hit_ratio": self._prefix_hit_tokens / prompt_toks
@@ -1278,9 +1244,9 @@ class LLMEngine:
             "queue_depth": self._pending.qsize(),
             "active_slots": sum(r is not None for r in self.slot_req),
             "n_slots": self.n_slots,
-            "kv_layout": self.kv_layout,
+            "kv_layout": "paged",  # fleetwatch and the replica wire show it
             # what the layers keep: {kind: {"layers", "bytes"}} over
-            # paged_kv, recurrent and none (the dense layout reads paged_kv)
+            # paged_kv, recurrent and none
             "cache_kinds": cache_kinds,
             # per-slot state of the recurrent layers; None without any
             "recurrent_state": None if rec is None else {
@@ -1372,7 +1338,7 @@ class LLMEngine:
             self._pump_error = None
             self._thread = threading.Thread(target=self._loop, daemon=True)
             self._thread.start()
-        if self.paged and self._host_kv is not None \
+        if self._host_kv is not None \
                 and (self._demote_thread is None
                      or not self._demote_thread.is_alive()):
             # demotion worker: device->host staging stays OFF the decode
@@ -1408,7 +1374,7 @@ class LLMEngine:
             self._drain_queue(RuntimeError("LLMEngine stopped"))
         else:
             self._fail_pending(RuntimeError("LLMEngine stopped"))
-            if self.paged and self._host_kv is not None \
+            if self._host_kv is not None \
                     and self._demote_thread is not None:
                 # pump terminated => the engine lock is free, so the
                 # worker exits at its next _stop check — join BEFORE the
@@ -1627,7 +1593,7 @@ class LLMEngine:
                 pass  # a failing stream callback must never kill the pump
 
     def _first_token_out(self, req, first):
-        """The admission's final step, both layouts: the first token has
+        """The admission's final step: the first token has
         just been emitted.  Closes the admission span; on the request's
         FIRST admission stamps ``ttft_s`` on the trace (the same instant,
         relative to the trace's start) and observes ``llm_ttft_seconds``."""
@@ -1641,8 +1607,7 @@ class LLMEngine:
 
     def _trace_queue_wait(self, req):
         """First-admission queue-wait: histogram (+trace exemplar), SLO
-        verdict onto the trace, queue_wait span — shared by the dense and
-        paged admission paths so their traces cannot diverge."""
+        verdict onto the trace, queue_wait span."""
         wait = max(0.0, req.admit_ts - req.submit_ts)
         _M_QUEUE_WAIT.observe(wait, exemplar=req.trace.trace_id or None)
         if _slo.track("llm_queue_wait", wait):
@@ -1671,7 +1636,7 @@ class LLMEngine:
                 pass  # a failing ack callback must never kill the pump
 
     def _observe_ttft(self, req):
-        """The admission token IS the first token out (both layouts)."""
+        """The admission token IS the first token out."""
         ttft = max(0.0, self._clock() - req.submit_ts)
         _M_TTFT.observe(ttft, exemplar=req.trace.trace_id or None)
         if _slo.track("llm_ttft", ttft):
@@ -1679,158 +1644,10 @@ class LLMEngine:
 
     # --------------------------------------------------------- internals
 
-    def _bucket(self, n):
-        for b in self.buckets:
-            if n <= b:
-                return b
-        return self.L
-
-    def _prefill_fn(self, Lb):
-        """Compiled prompt prefill at bucket length Lb: returns the last
-        real token's logits and the head-major k/v rows."""
-        model = self.model
-
-        def llm_prefill(params, buffers, ids, last_index):
-            restore = model.bind_functional_state(params, buffers)
-            try:
-                with tape.no_grad():
-                    logits, caches = model.prefill_step(Tensor(ids),
-                                                        last_index)
-            finally:
-                restore()
-            # k/v come out [1, Lb, H, D] -> head-major [1, H, Lb, D]
-            kvs = [(jnp.transpose(k._value, (0, 2, 1, 3)),
-                    jnp.transpose(v._value, (0, 2, 1, 3)))
-                   for (k, v) in caches]
-            return logits._value, kvs
-
-        return jax.jit(llm_prefill)
-
-    def _get_prefill(self, Lb):
-        if Lb not in self._prefill_jit:
-            _profiling.record_compile("prefill")
-            self._prefill_jit[Lb] = self._prefill_fn(Lb)
-        return self._prefill_jit[Lb]
-
-    def _admit(self):
-        free = [i for i, r in enumerate(self.slot_req) if r is None]
-        while free and not self._pending.empty():
-            # _adm_inflight (incremented BEFORE the pop) covers the window
-            # where the request is out of the queue but not yet in a slot
-            # or terminal, so drain()'s _drained() — read from another
-            # thread — can never observe a momentarily-empty engine
-            self._adm_inflight += 1
-            try:
-                try:
-                    req = self._pending.get_nowait()
-                except queue.Empty:
-                    break
-                if req.future.done():
-                    # cancelled by the caller, or failed by a pump-death
-                    # race — don't waste a slot on it
-                    self._end_trace(req, "cancelled")
-                    continue
-                if req.deadline is not None \
-                        and self._clock() > req.deadline:
-                    _M_EXPIRED.labels(where="queued").inc()
-                    _fail_future(req.future, DeadlineExceededError(
-                        "request deadline expired while queued for "
-                        "admission"))
-                    self._end_trace(req, "expired", where="queued")
-                    continue
-                slot = free.pop(0)
-                try:
-                    self._admit_one(req, slot)
-                except Exception as e:
-                    self.slot_req[slot] = None
-                    free.insert(0, slot)
-                    _fail_future(req.future, e)
-                    self._end_trace(req, "error", error=repr(e))
-                    if not self._caches_alive():
-                        # the slot writer donates self.caches (see
-                        # _prefill_tick): a consumed-buffer failure is
-                        # engine-fatal, not a per-request one
-                        raise
-            finally:
-                self._adm_inflight -= 1
-        if not free and not self._pending.empty():
-            self._blocked("no_slot")
-
     def _blocked(self, reason):
         """This tick leaves the queue head waiting: count what held it."""
         self._adm_blocked[reason] += 1
         _ADM_BLOCKED_SERIES[reason].inc()
-
-    def _admit_one(self, req, slot):
-        req.admit_ts = self._clock()
-        if req.submit_ts is not None:
-            self._trace_queue_wait(req)
-        n = req.prompt.size
-        Lb = self._bucket(n)
-        self._open_admission_span(req, slot, bucket=int(Lb))
-        padded = np.full((1, Lb), self.pad, np.int32)
-        padded[0, :n] = req.prompt
-        logits, kvs = self._get_prefill(Lb)(
-            self._params, self._buffers, padded, np.int32(n - 1))
-        # causal attention: positions >= n never influence position n-1,
-        # so the padded prefill's first n k/v rows are exact
-        self._phases.switch("first_token_sync")
-        tok = self._host_select(np.asarray(logits)[0, 0], req)
-        self.caches = self._get_slot_writer(Lb)(
-            self.caches, kvs, np.int32(slot))
-        req.slot = slot
-        self._emit_token(req, tok, time.perf_counter())
-        self.slot_req[slot] = req
-        self.slot_pos[slot] = n
-        self.last_token[slot] = tok
-        # the admission token IS the first token out: useful, like every
-        # decode-tick emission
-        self._goodput.count_tokens("useful", 1)
-        _M_ADMITTED.inc()
-        self._first_token_out(req, first=True)
-        self._phases.switch("admit")
-        if tok == self.eos or req.max_new_tokens <= 1:
-            self._finish(slot)
-
-    def _get_slot_writer(self, Lb):
-        """ONE compiled call writes a prefill's k/v into a slot across all
-        layers (instead of 2-5 host-dispatched updates per layer)."""
-        key = ("w", Lb)
-        if key not in self._prefill_jit:
-            _profiling.record_compile("slot_writer")
-            quant = self.cache_dtype == "int8"
-
-            def write(caches, kvs, slot):
-                out = []
-                for c, (k_hm, v_hm) in zip(caches, kvs):
-                    if quant:
-                        from ..models.kv_cache import _quantize_kv
-
-                        kq, ks = _quantize_kv(k_hm[:, :, :Lb])
-                        vq, vs = _quantize_kv(v_hm[:, :, :Lb])
-                        out.append((
-                            jax.lax.dynamic_update_slice(
-                                c[0], kq, (slot, 0, 0, 0)),
-                            jax.lax.dynamic_update_slice(
-                                c[1], vq, (slot, 0, 0, 0)),
-                            c[2],
-                            jax.lax.dynamic_update_slice(
-                                c[3], ks, (slot, 0, 0)),
-                            jax.lax.dynamic_update_slice(
-                                c[4], vs, (slot, 0, 0))))
-                    else:
-                        out.append((
-                            jax.lax.dynamic_update_slice(
-                                c[0], k_hm[:, :, :Lb].astype(c[0].dtype),
-                                (slot, 0, 0, 0)),
-                            jax.lax.dynamic_update_slice(
-                                c[1], v_hm[:, :, :Lb].astype(c[1].dtype),
-                                (slot, 0, 0, 0)),
-                            c[2]))
-                return out
-
-            self._prefill_jit[key] = jax.jit(write, donate_argnums=(0,))
-        return self._prefill_jit[key]
 
     def _caches_alive(self):
         """False when the kv cache buffers were consumed by a donating
@@ -1844,7 +1661,7 @@ class LLMEngine:
         except Exception:
             return False
 
-    # ---------------------------------------------------- paged internals
+    # ------------------------------------------------- page-pool internals
 
     def _incref(self, page):
         self._page_ref[page] += 1
@@ -1864,7 +1681,7 @@ class LLMEngine:
         """Decref every page a slot holds (finish/expiry/preempt/stop) and
         point its page-table row back at the trash page.  Shared pages
         survive in other slots / the prefix cache; exclusive ones free."""
-        if not self.paged or not self._slot_pages[slot]:
+        if not self._slot_pages[slot]:
             return
         for page in self._slot_pages[slot]:
             self._decref(page)
@@ -2195,7 +2012,7 @@ class LLMEngine:
         """stats()/`/varz` "tiers" block — lock-free single reads, same
         contract as stats(); None when the tiers are off (absent-not-zero
         for pre-tier replicas and configs)."""
-        if not self.paged or self._host_kv is None:
+        if self._host_kv is None:
             return None
         hk = self._host_kv.stats()
         pt = self._prefix_prompt_tokens
@@ -2220,7 +2037,7 @@ class LLMEngine:
         }
 
     def _lora_args(self, pages):
-        """(lora_tree, lora_rows) tail for the paged compiled programs.
+        """(lora_tree, lora_rows) tail for the compiled programs.
         The tree is the pool's live device arrays (a jit ARGUMENT —
         loading/evicting adapters swaps data, never the program) and
         ``pages`` the per-row pool pages (0 = the reserved zero adapter:
@@ -2233,7 +2050,7 @@ class LLMEngine:
 
     def _release_adapter(self, req):
         """Drop a request's adapter-pool reference (idempotent: requests
-        that never acquired — queued, dense, base-model — hold page 0).
+        that never acquired — queued, base-model — hold page 0).
         Called on every terminal/requeue path, mirroring _release_pages;
         a preempted request re-acquires at re-admission."""
         if req is not None and req.adapter_page:
@@ -2323,8 +2140,12 @@ class LLMEngine:
         req.skip_cache = True
         req.requeue_reason = "page_pool_dry"
         req.trace.inc_attr("preempt_requeues")
+        # only the tokens generated since the last requeue: the earlier
+        # ones are in the prompt already (a second preemption that appended
+        # them all again would resume from a context with them doubled)
         req.prompt = np.concatenate(
-            [req.prompt, np.asarray(req.tokens, np.int32)])
+            [req.prompt, np.asarray(req.tokens[req.regrown:], np.int32)])
+        req.regrown = len(req.tokens)
         # every token of the extended prompt (original prompt + generated
         # so far) must be re-prefilled from scratch — preemption's token
         # bill, on the registry counter and the goodput token ledger
@@ -2362,8 +2183,8 @@ class LLMEngine:
 
     def _chunk_prefill_fn(self):
         """ONE compiled program prefills any prompt in fixed-size chunks —
-        ids [1, C] against the paged pools at per-slot offset `off`,
-        killing the per-bucket prefill compile zoo.  On tile-aligned
+        ids [1, C] against the paged pools at per-slot offset `off`, so no
+        prompt length compiles anything.  On tile-aligned
         shapes the chunk's attention is the RAGGED paged Pallas kernel
         (the chunk offset rides the kernel's prefetched lengths;
         llm_attn_kernel_total counts the dispatch).  Returns the logits at
@@ -2404,10 +2225,10 @@ class LLMEngine:
             (2, 10) if self._moe_acc is not None else (2,)))
 
     def _get_chunk_prefill(self):
-        if "chunk" not in self._prefill_jit:
+        if self._chunk_jit is None:
             _profiling.record_compile("chunk_prefill")
-            self._prefill_jit["chunk"] = self._chunk_prefill_fn()
-        return self._prefill_jit["chunk"]
+            self._chunk_jit = self._chunk_prefill_fn()
+        return self._chunk_jit
 
     def _chunk_extra(self, slot):
         """The chunk program's trailing arguments for a model that declares
@@ -2787,21 +2608,19 @@ class LLMEngine:
         if tok == self.eos or len(req.tokens) >= req.max_new_tokens:
             self._finish(slot)
 
-    def warmup(self, buckets=None):
+    def warmup(self):
         """Pre-compile the serving programs so the FIRST request pays no
         compile latency (the TTFT spike visible in llm_ttft_seconds): the
-        decode step at the configured decode_chunk, plus either every
-        prompt-bucket prefill + slot writer (dense layout) or the single
-        prefill-chunk program (paged layout; `buckets` is ignored there —
-        the chunk program serves every prompt length).  Runs the real
-        compiled calls against the engine's own idle cache state: the
-        garbage rows land in the trash page (paged) or in rows admission
-        rewrites wholesale (dense).  The decode, verify and chunk calls get
-        what a tick gives them — host arrays, and the default generator's
-        resident key with a host offset in place of keys — so the warmed
-        programs are the ones the ticks call; the offset is not advanced
-        (warmup draws nothing a request sees).  Returns the wall seconds
-        spent and publishes them on llm_warmup_compile_seconds."""
+        prefill-chunk program (it serves every prompt length), the COW page
+        copy, the decode step at the configured decode_chunk and, with
+        ``spec_k``, the verify step.  Runs the real compiled calls against
+        the engine's own idle cache state: the garbage rows land in the
+        trash page.  The decode, verify and chunk calls get what a tick
+        gives them — host arrays, and the default generator's resident key
+        with a host offset in place of keys — so the warmed programs are
+        the ones the ticks call; the offset is not advanced (warmup draws
+        nothing a request sees).  Returns the wall seconds spent and
+        publishes them on llm_warmup_compile_seconds."""
         t0 = time.perf_counter()
         # every tick's span records into the native host-trace buffer, whose
         # library is BUILT on first use in a fresh checkout (a g++ run):
@@ -2813,59 +2632,39 @@ class LLMEngine:
             if self._prefilling is not None \
                     or any(r is not None for r in self.slot_req):
                 raise RuntimeError("warmup() requires an idle engine")
-            params, buffers = self._params, self._buffers
-            if self.paged:
-                C = self.prefill_chunk
-                # last_index -1: no token of the warm-up chunk is real, so
-                # slot 0's recurrent state and the expert counts stay put
-                self._took(self._get_chunk_prefill()(
-                    params, buffers, self.caches,
-                    np.zeros((1, self.M), np.int32),
-                    np.full((1, C), self.pad, np.int32),
-                    np.zeros((1,), np.int32),
-                    np.int32(0 if self._cache_kinds is None else -1),
-                    *self._lora_args([0]), *self._chunk_extra(0)))
-                if not self._recurrent:
-                    # the COW fork program too: a warm engine's first
-                    # shared-prefix fork must not compile (and must not trip
-                    # recompile_storm).  A trash-page self-copy is harmless.
-                    # (A model with recurrent state shares no page: no fork.)
-                    self.caches = self._get_cow_copy()(
-                        self.caches, np.int32(0), np.int32(0))
-            else:
-                for Lb in (buckets if buckets is not None else self.buckets):
-                    Lb = int(Lb)
-                    ids = np.full((1, Lb), self.pad, np.int32)
-                    _, kvs = self._get_prefill(Lb)(
-                        params, buffers, ids, np.int32(Lb - 1))
-                    self.caches = self._get_slot_writer(Lb)(
-                        self.caches, kvs, np.int32(0))
+            C = self.prefill_chunk
+            # last_index -1: no token of the warm-up chunk is real, so
+            # slot 0's recurrent state and the expert counts stay put
+            self._took(self._get_chunk_prefill()(
+                self._params, self._buffers, self.caches,
+                np.zeros((1, self.M), np.int32),
+                np.full((1, C), self.pad, np.int32),
+                np.zeros((1,), np.int32),
+                np.int32(0 if self._cache_kinds is None else -1),
+                *self._lora_args([0]), *self._chunk_extra(0)))
+            if not self._recurrent:
+                # the COW fork program too: a warm engine's first
+                # shared-prefix fork must not compile (and must not trip
+                # recompile_storm).  A trash-page self-copy is harmless.
+                # (A model with recurrent state shares no page: no fork.)
+                self.caches = self._get_cow_copy()(
+                    self.caches, np.int32(0), np.int32(0))
             eff = max(1, min(self.decode_chunk, self.L - 1))
             B = self.n_slots
             tokens = np.full((B, 1), self.pad, np.int32)
             pos = np.zeros((B,), np.int32)
             knobs = self._sampling_knobs()  # idle engine: all greedy
             rng = (_fr.default_generator().key, np.uint32(0))
-            args = (params, buffers, self.caches)
-            if self.paged:
-                args += (self._pt_host.copy(),)
-            args += (tokens, pos, *knobs)
-            if self.paged:
-                args += (self._mask_all_true, *rng, *self._lora_args([0] * B))
-                if self._moe_acc is not None:
-                    args += (self._moe_acc,)
-            else:
-                args += rng
-            self._took(self._get_decode(eff)(*args))
+            lora = self._lora_args([0] * B)
+            moe = () if self._moe_acc is None else (self._moe_acc,)
+            self._took(self._get_decode(eff)(
+                *self._cache_args(), tokens, pos, *knobs,
+                self._mask_all_true, *rng, *lora, *moe))
             if self.spec_k:
-                vargs = (params, buffers, self.caches)
-                if self.paged:
-                    vargs += (self._pt_host.copy(),)
-                vargs += (tokens, np.zeros((B, self.spec_k), np.int32), pos,
-                          *knobs, *rng)
-                if self.paged:
-                    vargs += self._lora_args([0] * B)
-                _, _, self.caches = self._get_verify()(*vargs)
+                _, _, self.caches = self._get_verify()(
+                    *self._cache_args(), tokens,
+                    np.zeros((B, self.spec_k), np.int32), pos, *knobs, *rng,
+                    *lora)
             if self.adapters is not None:
                 # the pool's donating page writer compiles here too, so a
                 # post-warmup register()/acquire() never counts as a
@@ -2942,6 +2741,18 @@ class LLMEngine:
         self._sampler_ticks[path] += 1
         _SAMPLER_SERIES[path].inc()
 
+    def _cache_args(self):
+        """What the decode and verify programs take first: the weights, the
+        caches, and the page table with INACTIVE slots masked to the trash
+        page — a mid-prefill slot already owns real pages, and the shared
+        step's garbage scatter for it must not clobber the prompt rows the
+        chunked prefill has already written."""
+        pt = self._pt_host.copy()
+        for i, r in enumerate(self.slot_req):
+            if r is None:
+                pt[i, :] = 0
+        return self._params, self._buffers, self.caches, pt
+
     def _get_decode(self, eff):
         jit = self._decode_jit.get(eff)
         if jit is None:
@@ -2959,94 +2770,56 @@ class LLMEngine:
         model = self.model
         pool = self.adapters.pool if self.adapters is not None else None
 
-        if self.paged:
-            # token_mask and the lora tail are ALWAYS in the signature:
-            # constrained rows upload their automaton mask rows, the rest
-            # ride the cached all-True mask (an exact sampler no-op), and
-            # adapter swaps change only the gathered rows — so turning
-            # either feature on after warmup() never recompiles
-            kinds = self._cache_kinds
+        # token_mask and the lora tail are ALWAYS in the signature:
+        # constrained rows upload their automaton mask rows, the rest
+        # ride the cached all-True mask (an exact sampler no-op), and
+        # adapter swaps change only the gathered rows — so turning
+        # either feature on after warmup() never recompiles
+        kinds = self._cache_kinds
 
-            def llm_decode(params, buffers, caches, page_tbl, tokens, pos,
-                           do_sample, temperature, top_k, top_p, token_mask,
-                           base_key, offset, lora_tree, lora_rows, *moe_acc):
-                keys = jax.random.split(
-                    jax.random.fold_in(base_key, offset), eff)
-                # the tick masks the table rows of idle and mid-prefill
-                # slots to the trash page: such a row is computed like the
-                # others but advances no recurrent state and counts nowhere
-                rows = None if kinds is None else _SlotRows(
-                    None, None, (page_tbl[:, 0] != 0).astype(jnp.int32))
-                restore = model.bind_functional_state(params, buffers)
-                try:
-                    with tape.no_grad(), _lora_ctx(pool, lora_tree,
-                                                   lora_rows):
-                        def tick(carry, key):
-                            caches, tok, p = carry[:3]
-                            logits, new_caches = model.generate_step(
-                                Tensor(tok), caches=_to_model_caches(
-                                    kinds, caches, p, page_tbl, rows))
-                            raw, aux = _from_model_caches(kinds, new_caches)
-                            nxt = _select_rows(logits._value[:, -1], key,
-                                               do_sample, temperature,
-                                               top_k, top_p,
-                                               token_mask=token_mask)
-                            acc = tuple(a.at[0].add(jnp.stack(aux))
-                                        for a in carry[3:])
-                            return (raw, nxt[:, None], p + 1) + acc, nxt
-
-                        carry, toks = jax.lax.scan(
-                            tick, (caches, tokens, pos) + moe_acc, keys)
-                finally:
-                    restore()
-                if moe_acc:
-                    # the pair counts ride home with the tokens: one array
-                    return jnp.concatenate(
-                        [toks.T.reshape(-1), carry[3].reshape(-1)]), \
-                        carry[0], carry[3]
-                return toks.T, carry[0]  # [B, chunk]
-
-            return jax.jit(llm_decode, donate_argnums=(
-                (2, 15) if self._moe_acc is not None else (2,)))
-
-        def llm_decode(params, buffers, caches, tokens, pos, do_sample,
-                       temperature, top_k, top_p, base_key, offset):
+        def llm_decode(params, buffers, caches, page_tbl, tokens, pos,
+                       do_sample, temperature, top_k, top_p, token_mask,
+                       base_key, offset, lora_tree, lora_rows, *moe_acc):
             keys = jax.random.split(jax.random.fold_in(base_key, offset), eff)
+            # the tick masks the table rows of idle and mid-prefill
+            # slots to the trash page: such a row is computed like the
+            # others but advances no recurrent state and counts nowhere
+            rows = None if kinds is None else _SlotRows(
+                None, None, (page_tbl[:, 0] != 0).astype(jnp.int32))
             restore = model.bind_functional_state(params, buffers)
             try:
-                with tape.no_grad():
+                with tape.no_grad(), _lora_ctx(pool, lora_tree, lora_rows):
                     def tick(carry, key):
-                        caches, tok, p = carry
-                        # the [B] position vector rides RAW (like the scalar
-                        # pos in generation.py): rope/scatter/mask closures
-                        # consume it with plain jnp ops
-                        t_caches = [
-                            (Tensor(c[0]), Tensor(c[1]), p)
-                            + tuple(Tensor(x) for x in c[3:])
-                            for c in caches]
+                        caches, tok, p = carry[:3]
                         logits, new_caches = model.generate_step(
-                            Tensor(tok), caches=t_caches)
-                        raw = [tuple(x._value if isinstance(x, Tensor) else x
-                                     for x in c) for c in new_caches]
-                        # select ON DEVICE: the host fetches token ids,
-                        # not [B, vocab] logits
-                        nxt = _select_rows(logits._value[:, -1], key,
-                                           do_sample, temperature,
-                                           top_k, top_p)
-                        return (raw, nxt[:, None], p + 1), nxt
+                            Tensor(tok), caches=_to_model_caches(
+                                kinds, caches, p, page_tbl, rows))
+                        raw, aux = _from_model_caches(kinds, new_caches)
+                        nxt = _select_rows(
+                            logits._value[:, -1], key, do_sample, temperature,
+                            top_k, top_p, token_mask=token_mask)
+                        acc = tuple(a.at[0].add(jnp.stack(aux))
+                                    for a in carry[3:])
+                        return (raw, nxt[:, None], p + 1) + acc, nxt
 
-                    (caches, _, _), toks = jax.lax.scan(
-                        tick, (caches, tokens, pos), keys)
+                    carry, toks = jax.lax.scan(
+                        tick, (caches, tokens, pos) + moe_acc, keys)
             finally:
                 restore()
-            return toks.T, caches  # [B, chunk]
+            if moe_acc:
+                # the pair counts ride home with the tokens: one array
+                return jnp.concatenate(
+                    [toks.T.reshape(-1), carry[3].reshape(-1)]), \
+                    carry[0], carry[3]
+            return toks.T, carry[0]  # [B, chunk]
 
-        return jax.jit(llm_decode, donate_argnums=(2,))
+        return jax.jit(llm_decode, donate_argnums=(
+            (2, 15) if self._moe_acc is not None else (2,)))
 
     def _verify_fn(self):
         """ONE compiled speculative verify: score K drafts + one bonus
         position for every slot (S = K+1 through the same cache scatter /
-        attention paths decode uses — on tile-aligned paged shapes that is
+        attention paths decode uses — on tile-aligned shapes that is
         the ragged Pallas kernel walking the page tables, not a gathered
         dense pass) and run the accept/rollback decision on device
         (ops/sampling.spec_accept) — only the [B, K+1] token ladder and
@@ -3055,50 +2828,21 @@ class LLMEngine:
         model = self.model
         pool = self.adapters.pool if self.adapters is not None else None
 
-        if self.paged:
-            def llm_spec_verify(params, buffers, caches, page_tbl, tokens,
-                                drafts, pos, do_sample, temperature, top_k,
-                                top_p, base_key, offset, lora_tree,
-                                lora_rows):
-                key = jax.random.fold_in(base_key, offset)
-                restore = model.bind_functional_state(params, buffers)
-                try:
-                    with tape.no_grad(), _lora_ctx(pool, lora_tree,
-                                                   lora_rows):
-                        ids_in = jnp.concatenate([tokens, drafts], axis=1)
-                        logits, new_caches = model.verify_step(
-                            Tensor(ids_in), caches=_to_model_caches(
-                                self._cache_kinds, caches, pos, page_tbl))
-                        raw, _ = _from_model_caches(self._cache_kinds,
-                                                    new_caches)
-                        out, n_acc = _spec_accept(
-                            logits._value, drafts, key, do_sample,
-                            temperature, top_k, top_p)
-                finally:
-                    restore()
-                return out, n_acc, raw
-
-            return jax.jit(llm_spec_verify, donate_argnums=(2,))
-
-        def llm_spec_verify(params, buffers, caches, tokens, drafts, pos,
-                            do_sample, temperature, top_k, top_p, base_key,
-                            offset):
+        def llm_spec_verify(params, buffers, caches, page_tbl, tokens, drafts,
+                            pos, do_sample, temperature, top_k, top_p,
+                            base_key, offset, lora_tree, lora_rows):
             key = jax.random.fold_in(base_key, offset)
             restore = model.bind_functional_state(params, buffers)
             try:
-                with tape.no_grad():
-                    t_caches = [
-                        (Tensor(c[0]), Tensor(c[1]), pos)
-                        + tuple(Tensor(x) for x in c[3:])
-                        for c in caches]
+                with tape.no_grad(), _lora_ctx(pool, lora_tree, lora_rows):
                     ids_in = jnp.concatenate([tokens, drafts], axis=1)
                     logits, new_caches = model.verify_step(
-                        Tensor(ids_in), caches=t_caches)
-                    raw = [tuple(x._value if isinstance(x, Tensor) else x
-                                 for x in c) for c in new_caches]
+                        Tensor(ids_in), caches=_to_model_caches(
+                            self._cache_kinds, caches, pos, page_tbl))
+                    raw, _ = _from_model_caches(self._cache_kinds, new_caches)
                     out, n_acc = _spec_accept(
-                        logits._value, drafts, key, do_sample,
-                        temperature, top_k, top_p)
+                        logits._value, drafts, key, do_sample, temperature,
+                        top_k, top_p)
             finally:
                 restore()
             return out, n_acc, raw
@@ -3155,13 +2899,9 @@ class LLMEngine:
         self._expire_queued()
         self._expire_slots()
         pc.switch("admit")
-        if self.paged:
-            self._admit_paged()
-            pc.switch("bookkeep")
-            self._update_page_gauges()
-        else:
-            self._admit()
-            pc.switch("bookkeep")
+        self._admit_paged()
+        pc.switch("bookkeep")
+        self._update_page_gauges()
         _M_QUEUE_DEPTH.set(self._pending.qsize())
         active = [i for i, r in enumerate(self.slot_req) if r is not None]
         _M_ACTIVE_SLOTS.set(len(active))
@@ -3179,19 +2919,18 @@ class LLMEngine:
         # a constrained row's automaton state advances per TOKEN, and the
         # uploaded mask is constant across a chunk — so ticks with any
         # constrained row decode one token at a time
-        constrained = self.paged and any(
+        constrained = any(
             r is not None and r.cursor is not None for r in self.slot_req)
         if constrained:
             eff = 1
         t_dec = pc.switch("decode_stage")
-        if self.paged:
-            # grow page tables to cover this tick's writes; slots the pool
-            # cannot cover any longer are preempted (shed, not wedged)
-            active = self._ensure_decode_pages(active, eff)
-            self._update_page_gauges()
-            if not active:
-                self._goodput.carve("decode", pc.switch("bookkeep") - t_dec)
-                return 0
+        # grow page tables to cover this tick's writes; slots the pool
+        # cannot cover any longer are preempted (shed, not wedged)
+        active = self._ensure_decode_pages(active, eff)
+        self._update_page_gauges()
+        if not active:
+            self._goodput.carve("decode", pc.switch("bookkeep") - t_dec)
+            return 0
         jit = self._get_decode(eff)
         # host arrays straight into the compiled call, and (key, offset)
         # for the keys it derives itself: no eager device call here (see
@@ -3203,41 +2942,23 @@ class LLMEngine:
         self._count_sampler_tick(do_s, topk, topp)
         # read each tick, not held: paddle.seed() on a live engine governs
         rng = _fr.default_generator().fork()
-        args = (self._params, self._buffers, self.caches)
-        if self.paged:
-            # decode sees a table with INACTIVE slots masked to the trash
-            # page: a mid-prefill slot already owns real pages, and the
-            # shared step's garbage scatter for it must not clobber the
-            # prompt rows the chunked prefill has already written
-            pt = self._pt_host.copy()
-            for i, r in enumerate(self.slot_req):
-                if r is None:
-                    pt[i, :] = 0
-            args += (pt,)
-        if self.paged:
-            if constrained:
-                # per-row [V] masks from each constrained row's automaton
-                # state; unconstrained rows stay all-True (exact no-op)
-                token_mask = np.ones((self.n_slots, self._vocab), bool)
-                for i, r in enumerate(reqs):
-                    if r is not None and r.cursor is not None:
-                        token_mask[i] = r.cursor.mask()
-            else:
-                token_mask = self._mask_all_true
-            args += (tokens, pos, do_s, temp, topk, topp, token_mask, *rng,
-                     *self._lora_args(
-                         [r.adapter_page if r is not None else 0
-                          for r in reqs]))
+        if constrained:
+            # per-row [V] masks from each constrained row's automaton
+            # state; unconstrained rows stay all-True (exact no-op)
+            token_mask = np.ones((self.n_slots, self._vocab), bool)
+            for i, r in enumerate(reqs):
+                if r is not None and r.cursor is not None:
+                    token_mask[i] = r.cursor.mask()
         else:
-            args += (tokens, pos, do_s, temp, topk, topp, *rng)
+            token_mask = self._mask_all_true
+        args = (*self._cache_args(), tokens, pos, do_s, temp, topk, topp,
+                token_mask, *rng, *self._lora_args(
+                    [r.adapter_page if r is not None else 0 for r in reqs]))
         moe = self._moe_acc is not None
         if moe:
             args += (self._moe_acc,)
             self._moe_dispatched("decode", len(active), eff)
         pc.switch("decode_dispatch")
-        # the returned tuples carry advanced pos at slot [2], but the
-        # engine's [B] slot_pos vector stays authoritative — each tick
-        # rebuilds the per-slot positions (finished slots do not advance)
         nxt_dev = self._took(jit(*args))
         pc.switch("decode_sync")
         nxt = np.asarray(nxt_dev).astype(np.int32)  # [B, eff]
@@ -3282,9 +3003,6 @@ class LLMEngine:
             if req is not None \
                     and len(req.token_ts) - req.dec_i0 >= _DECODE_SPAN_TICKS:
                 self._flush_decode_span(req)  # bound spans per episode
-        # inactive slots scatter garbage k/v at their stale position during
-        # the shared step — harmless: a decode WRITES row `pos` before any
-        # read past it, and admission rewrites rows [0, bucket) wholesale
         return emitted
 
     def _spec_tick(self, active):
@@ -3292,47 +3010,35 @@ class LLMEngine:
         compiled verify pass over S = K+1 positions for the whole pool,
         emit each slot's accepted prefix + correction token, then roll
         back — the slot position simply stops at the accept point, and
-        (paged) pages holding only rejected rows return to the pool."""
+        pages holding only rejected rows return to the pool."""
         K = self.spec_k
         pc = self._phases
         pc.switch("spec_stage")
-        if self.paged:
-            # the verify writes rows pos .. pos+K: grow/COW the page
-            # tables for all K+1 rows up front; a slot the pool cannot
-            # cover mid-verify preempts recompute-style, same as decode
-            active = self._ensure_decode_pages(active, K + 1,
-                                               origin="verify")
-            self._update_page_gauges()
-            if not active:
-                pc.switch("bookkeep")
-                return 0
+        # the verify writes rows pos .. pos+K: grow/COW the page tables
+        # for all K+1 rows up front; a slot the pool cannot cover
+        # mid-verify preempts recompute-style, same as decode
+        active = self._ensure_decode_pages(active, K + 1, origin="verify")
+        self._update_page_gauges()
+        if not active:
+            pc.switch("bookkeep")
+            return 0
         t0 = pc.switch("spec_draft")
         drafts = np.zeros((self.n_slots, K), np.int32)
         for i in active:
             req = self.slot_req[i]
             ctx = np.concatenate(
-                [req.prompt, np.asarray(req.tokens, np.int32)])
+                [req.prompt, np.asarray(req.tokens[req.regrown:], np.int32)])
             drafts[i] = self._drafter.propose(ctx, K)
         draft_s = pc.switch("spec_stage") - t0
         reqs = self.slot_req
         do_s, temp, topk, topp = self._sampling_knobs()
         self._count_sampler_tick(do_s, topk, topp)
-        args = (self._params, self._buffers, self.caches)
-        if self.paged:
-            # same inactive-slot masking as decode: a mid-prefill slot's
-            # garbage scatter must land in the trash page
-            pt = self._pt_host.copy()
-            for i, r in enumerate(self.slot_req):
-                if r is None:
-                    pt[i, :] = 0
-            args += (pt,)
         # host arrays and (key, offset), as the decode tick stages them
-        args += (self.last_token.reshape(-1, 1).copy(), drafts,
-                 self.slot_pos.copy(), do_s, temp, topk, topp,
-                 *_fr.default_generator().fork())
-        if self.paged:
-            args += self._lora_args(
-                [r.adapter_page if r is not None else 0 for r in reqs])
+        args = (*self._cache_args(),
+                self.last_token.reshape(-1, 1).copy(), drafts,
+                self.slot_pos.copy(), do_s, temp, topk, topp,
+                *_fr.default_generator().fork(), *self._lora_args(
+                    [r.adapter_page if r is not None else 0 for r in reqs]))
         jit = self._get_verify()
         # the verify window (the span, the goodput carve, the request's
         # verify_s) is spec_dispatch + spec_sync, boundary for boundary
@@ -3377,7 +3083,7 @@ class LLMEngine:
                 if done:
                     self._finish(i)
                     break
-            if self.paged and self.slot_req[i] is not None:
+            if self.slot_req[i] is not None:
                 rb_pages += self._trim_rollback_pages(i)
         rolled = drafted_tick - accepted_tick
         # goodput ledger: split the draft+verify compute by acceptance —
@@ -3407,8 +3113,7 @@ class LLMEngine:
         if self._spec_drafted:
             _M_SPEC_ACCEPT_RATIO.set(
                 self._spec_accepted / self._spec_drafted)
-        if self.paged:
-            self._update_page_gauges()
+        self._update_page_gauges()
         for i in active:
             req = self.slot_req[i]
             if req is not None \

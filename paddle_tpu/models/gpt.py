@@ -233,18 +233,6 @@ class GPTForCausalLM(nn.Layer):
         hidden, caches = self.gpt(input_ids, caches=caches, use_cache=True)
         return self.lm_head(hidden), caches
 
-    def prefill_step(self, input_ids, last_index):
-        """Bucket-padded prefill for the serving engine (see llama.py)."""
-        import jax
-
-        from ..tensor.tensor import apply_op
-
-        hidden, caches = self.gpt(input_ids, caches=None, use_cache=True)
-        last = apply_op(
-            lambda h: jax.lax.dynamic_slice_in_dim(h, last_index, 1, 1),
-            (hidden,), name="prefill_last")
-        return self.lm_head(last), caches
-
     def prefill_chunk_step(self, input_ids, caches, last_index):
         """One chunk of an incremental paged prefill (see llama.py)."""
         import jax

@@ -440,17 +440,6 @@ class LlamaForCausalLM(nn.Layer):
         hidden, caches = self.llama(input_ids, caches=caches, use_cache=True)
         return self._head(hidden), caches
 
-    def prefill_step(self, input_ids, last_index):
-        """Bucket-padded prefill (serving admission): the prompt is padded
-        PAST `last_index`, so the next-token logits live there, not at -1
-        (causal attention keeps positions <= last_index exact under the
-        padding).  Returns (logits [B, 1, V], caches)."""
-        hidden, caches = self.llama(input_ids, caches=None, use_cache=True)
-        last = apply_op(
-            lambda h: jax.lax.dynamic_slice_in_dim(h, last_index, 1, 1),
-            (hidden,), name="prefill_last")
-        return self._head(last), caches
-
     def prefill_chunk_step(self, input_ids, caches, last_index):
         """One CHUNK of an incremental (paged) prefill: input_ids [B, C] are
         the next C prompt tokens of each row (pad-padded past `last_index`
@@ -458,8 +447,8 @@ class LlamaForCausalLM(nn.Layer):
         pos = tokens already prefilled.  Returns (logits [B, 1, V] at
         `last_index`, caches) — the logits only matter on the final chunk;
         earlier chunks pay one [B, 1, V] head gemv for shape stability
-        (llm_server.py compiles exactly ONE chunk program, killing the
-        per-bucket prefill zoo).  On tile-aligned shapes the chunk's
+        (llm_server.py compiles exactly ONE chunk program for every prompt
+        length).  On tile-aligned shapes the chunk's
         attention is the ragged paged Pallas kernel — the per-slot chunk
         offset rides the kernel's prefetched lengths vector."""
         hidden, caches = self.llama(input_ids, caches=caches, use_cache=True)

@@ -348,7 +348,7 @@ def test_demotion_stays_off_the_tick_path(model):
     assert all(force is False for force in calls)  # worker polls unforced
 
 
-def test_tiers_absent_not_zero_and_require_paged(model):
+def test_tiers_absent_not_zero_and_require_the_prefix_cache(model):
     eng = LLMEngine(model, max_batch_slots=1, max_seq_len=128,
                     kv_layout="paged", page_size=32, prefill_chunk=32)
     assert "tiers" not in eng.stats()["prefix_cache"]  # pre-tier config
@@ -357,9 +357,13 @@ def test_tiers_absent_not_zero_and_require_paged(model):
                      host_cache_pages=4)
     tiers = eng2.stats()["prefix_cache"]["tiers"]
     assert tiers["host"]["capacity"] == 4 and tiers["disk"]["capacity"] == 0
-    with pytest.raises(ValueError):
+    with pytest.raises(ValueError, match="requires the prefix cache"):
         LLMEngine(model, max_batch_slots=1, max_seq_len=128,
-                  host_cache_pages=4)  # dense layout has no page pool
+                  host_cache_pages=4, prefix_cache=False)
+    # the bare default has the pool and the prefix cache the tiers ride on
+    eng3 = LLMEngine(model, max_batch_slots=1, max_seq_len=128,
+                     host_cache_pages=4)
+    assert eng3.stats()["prefix_cache"]["tiers"]["host"]["capacity"] == 4
 
 
 # -------------------------------------------- conservation + fault churn

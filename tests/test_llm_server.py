@@ -1,8 +1,9 @@
 """Continuous-batching LLM engine (inference/llm_server.py).
 
-Oracle: per-request greedy tokens must MATCH model.generate run alone —
-slots at different depths share one compiled decode step, bucketed padded
-prefill is exact for causal attention, and eos frees slots mid-flight."""
+Oracle: per-request greedy tokens must MATCH model.generate run alone (its
+dense static cache is the reference) — slots at different depths share one
+compiled decode step, a padded final prefill chunk is exact for causal
+attention, and eos frees slots mid-flight."""
 import numpy as np
 import pytest
 
@@ -23,10 +24,63 @@ def model():
     return m
 
 
-def _oracle(model, prompt, n):
+def _oracle(model, prompt, n, **kw):
     ids = paddle.to_tensor(np.asarray(prompt, np.int32)[None, :])
-    out = model.generate(ids, max_new_tokens=n)
+    out = model.generate(ids, max_new_tokens=n, **kw)
     return list(np.asarray(out._value)[0])
+
+
+@pytest.fixture(scope="module")
+def gpt_model():
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+
+    paddle.seed(11)
+    gpt = GPTForCausalLM(GPTConfig.tiny(max_position_embeddings=128))
+    gpt.eval()
+    return gpt
+
+
+def test_dense_layout_is_refused_with_the_pointer(model):
+    """There is one engine.  kv_layout="dense" names the layout that was
+    removed and is refused with where its cache lives on; a model without
+    the paged surface is told what it lacks."""
+    with pytest.raises(ValueError, match=r"removed.*generate\(\)"):
+        LLMEngine(model, max_batch_slots=2, max_seq_len=128,
+                  kv_layout="dense")
+    with pytest.raises(ValueError, match="None or 'paged'"):
+        LLMEngine(model, kv_layout="ragged")
+
+    class NoPagedSurface:
+        config = model.config
+        generate_step = model.generate_step
+
+    with pytest.raises(ValueError, match="lacks _supports_paged_cache, "
+                                         "prefill_chunk_step"):
+        LLMEngine(NoPagedSurface())
+
+
+@pytest.mark.parametrize("family", ["llama", "gpt"])
+@pytest.mark.parametrize("kv_layout", [None, "paged"])
+def test_bare_default_is_the_paged_engine(model, gpt_model, kv_layout,
+                                          family):
+    """LLMEngine(model) is what the cells run: generate()'s tokens from the
+    page pool at full reserved-row capacity, chunks of 128, the prefix
+    cache on — and kv_layout="paged" says nothing more."""
+    m = model if family == "llama" else gpt_model
+    rng = np.random.RandomState(40)
+    prompts = [rng.randint(0, m.config.vocab_size, n).astype(np.int32)
+               for n in (5, 33, 17)]
+    kw = {} if kv_layout is None else dict(kv_layout=kv_layout)
+    eng = LLMEngine(m, max_batch_slots=2, max_seq_len=128, **kw)
+    futs = [eng.submit(p, max_new_tokens=5) for p in prompts]
+    eng.run_until_complete()
+    for p, f in zip(prompts, futs):
+        assert f.result(timeout=1) == _oracle(m, p, 5)
+    st = eng.stats()
+    assert st["kv_layout"] == "paged"
+    assert st["kv_pages_total"] == 2 * (128 // 128)  # slots x L / page_size
+    assert eng.prefill_chunk == 128 and st["prefix_cache"] is not None
+    assert st["llm_kv_pages_in_use"] == 0  # the pool drains
 
 
 def test_single_request_matches_generate(model):
@@ -43,8 +97,7 @@ def test_continuous_batching_parity_and_slot_reuse(model):
     rng = np.random.RandomState(1)
     prompts = [rng.randint(0, 1024, n).astype(np.int32)
                for n in (5, 17, 33, 9, 26)]
-    eng = LLMEngine(model, max_batch_slots=2, max_seq_len=128,
-                    prompt_buckets=(8, 16, 32, 64))
+    eng = LLMEngine(model, max_batch_slots=2, max_seq_len=128)
     futs = [eng.submit(p, max_new_tokens=5) for p in prompts]
     eng.run_until_complete()
     for p, f in zip(prompts, futs):
@@ -57,8 +110,7 @@ def test_staggered_admission_mid_decode(model):
     rng = np.random.RandomState(2)
     p1 = rng.randint(0, 1024, 20).astype(np.int32)
     p2 = rng.randint(0, 1024, 7).astype(np.int32)
-    eng = LLMEngine(model, max_batch_slots=2, max_seq_len=128,
-                    prompt_buckets=(8, 32))
+    eng = LLMEngine(model, max_batch_slots=2, max_seq_len=128)
     f1 = eng.submit(p1, max_new_tokens=8)
     eng.step()  # admit p1 + decode 1 token
     eng.step()
@@ -134,19 +186,17 @@ def _sampler_series():
     return {s["labels"]["path"]: s["value"] for s in fam["series"]}
 
 
-@pytest.mark.parametrize("kind", ["dense", "paged"])
-def test_sampler_counter_follows_the_knobs_of_rows_that_draw(model, kind):
+def test_sampler_counter_follows_the_knobs_of_rows_that_draw(model):
     """stats()["sampler"] classifies each tick by the predicate the compiled
     sampler branches on: greedy traffic never reaches the sort (not even
     with a stale top_p on a greedy request), a temperature-only request
     draws without one, and a top_p request sorts on exactly the ticks it
     decodes.  The registry's family counts the same ticks, and none of it
     compiles a program after warmup()."""
-    kw = dict(kv_layout="paged", page_size=32, prefill_chunk=16) \
-        if kind == "paged" else dict(prompt_buckets=(8, 32))
     rng = np.random.RandomState(16)
     p = [rng.randint(0, 1024, n).astype(np.int32) for n in (10, 14, 7)]
-    eng = LLMEngine(model, max_batch_slots=2, max_seq_len=128, **kw)
+    eng = LLMEngine(model, max_batch_slots=2, max_seq_len=128,
+                    page_size=32, prefill_chunk=16)
     eng.warmup()
     s0, r0, quiet = eng.stats()["sampler"], _sampler_series(), _compiles()
     assert s0 == {"ticks": 0, "sampled_ticks": 0, "threshold_ticks": 0}
@@ -217,8 +267,9 @@ def _prefill_chunk_count():
 
 
 def test_paged_engine_parity_mixed_lengths_and_slot_reuse(model):
-    """Paged decode + chunked prefill is numerically the dense path under
-    mixed prompt lengths, more requests than slots (page/slot reuse), and
+    """Paged decode + chunked prefill is numerically generate()'s dense
+    static cache under mixed prompt lengths, more requests than slots
+    (page/slot reuse), and
     chunk boundaries that split prompts."""
     rng = np.random.RandomState(21)
     prompts = [rng.randint(0, 1024, n).astype(np.int32)
@@ -236,9 +287,9 @@ def test_paged_engine_parity_mixed_lengths_and_slot_reuse(model):
 
 
 def test_paged_chunked_prefill_matches_whole_prompt(model):
-    """Chunked prefill emits BITWISE the same greedy tokens as the dense
-    engine's whole-prompt prefill (and the solo-generate oracle), for a
-    prompt spanning several chunks including a ragged final chunk."""
+    """Chunked prefill emits BITWISE the same greedy tokens as the
+    solo-generate oracle's whole-prompt prefill, for a prompt spanning
+    several chunks including a ragged final chunk."""
     rng = np.random.RandomState(22)
     p = rng.randint(0, 1024, 43).astype(np.int32)  # 6 chunks of 8, ragged
     paged = LLMEngine(model, max_batch_slots=1, max_seq_len=128,
@@ -263,15 +314,14 @@ def test_paged_prefill_tail_overflow_near_capacity(model):
 
 
 def test_paged_int8_matches_dense_int8_engine(model):
+    """int8 pages against the dense static int8 cache of generate()."""
     rng = np.random.RandomState(23)
     p = rng.randint(0, 1024, 19).astype(np.int32)
     paged = LLMEngine(model, max_batch_slots=2, max_seq_len=128,
                       kv_layout="paged", page_size=32, prefill_chunk=16,
                       cache_dtype="int8")
-    dense = LLMEngine(model, max_batch_slots=2, max_seq_len=128,
-                      cache_dtype="int8")
     assert paged.generate(p, max_new_tokens=4) == \
-        dense.generate(p, max_new_tokens=4)
+        _oracle(model, p, 4, cache_dtype="int8")
 
 
 def test_paged_decode_chunk_crosses_page_boundaries(model):
@@ -362,21 +412,36 @@ def test_paged_deadline_expiry_reclaims_pages(model):
     assert eng.stats()["llm_kv_pages_in_use"] == 0
 
 
-def test_warmup_precompiles_paged_and_dense(model):
+@pytest.mark.parametrize("cache_dtype", [None, "int8"])
+@pytest.mark.parametrize("spec_k", [0, 2])
+def test_warmup_precompiles_exactly_the_tick_programs(model, spec_k,
+                                                      cache_dtype):
+    """warmup() builds the one chunk program, the COW copy, the decode step
+    and (with spec_k) the verify step — one of each, no program a prompt
+    length and nothing the first request still has to build."""
+    from paddle_tpu.observability import profiling as prof
+
     rng = np.random.RandomState(28)
     p = rng.randint(0, 1024, 12).astype(np.int32)
-    paged = LLMEngine(model, max_batch_slots=2, max_seq_len=128,
-                      kv_layout="paged", page_size=32, prefill_chunk=16)
-    dt = paged.warmup()
-    assert dt > 0.0
-    assert "chunk" in paged._prefill_jit and paged._decode_jit
-    assert paged.generate(p, max_new_tokens=5) == _oracle(model, p, 5)
-
-    dense = LLMEngine(model, max_batch_slots=2, max_seq_len=128,
-                      prompt_buckets=(8, 32))
-    dense.warmup()
-    assert set(dense._prefill_jit) >= {8, 32, ("w", 8), ("w", 32)}
-    assert dense.generate(p, max_new_tokens=5) == _oracle(model, p, 5)
+    want = _oracle(model, p, 5, cache_dtype=cache_dtype)
+    eng = LLMEngine(model, max_batch_slots=2, max_seq_len=128, page_size=32,
+                    prefill_chunk=16, spec_k=spec_k, cache_dtype=cache_dtype)
+    before = _compile_families()
+    try:
+        assert eng.warmup() > 0.0
+        built = {k: v - before.get(k, 0)
+                 for k, v in _compile_families().items() if k != "backend"}
+        want_built = {"chunk_prefill": 1, "cow_copy": 1, "decode": 1}
+        if spec_k:
+            want_built["verify"] = 1
+        # and nothing else: no "prefill" / "slot_writer" a prompt length
+        assert {k: v for k, v in built.items() if v} == want_built
+        assert eng._chunk_jit is not None and list(eng._decode_jit) == [1]
+        quiet = _compiles()
+        assert eng.generate(p, max_new_tokens=5) == want
+        assert _compiles() == quiet
+    finally:
+        prof.mark_warm(False)
 
 
 def test_warmup_requires_idle_engine(model):
@@ -390,21 +455,13 @@ def test_warmup_requires_idle_engine(model):
     eng.run_until_complete()
 
 
-def test_paged_engine_with_gpt_family():
-    from paddle_tpu.models import GPTConfig, GPTForCausalLM
-
-    paddle.seed(11)
-    cfg = GPTConfig.tiny(max_position_embeddings=128)
-    gpt = GPTForCausalLM(cfg)
-    gpt.eval()
+def test_paged_engine_with_gpt_family(gpt_model):
+    gpt = gpt_model
     rng = np.random.RandomState(30)
-    p = rng.randint(0, cfg.vocab_size, 21).astype(np.int32)
+    p = rng.randint(0, gpt.config.vocab_size, 21).astype(np.int32)
     eng = LLMEngine(gpt, max_batch_slots=2, max_seq_len=128,
                     kv_layout="paged", page_size=32, prefill_chunk=16)
-    got = eng.generate(p, max_new_tokens=6)
-    ids = paddle.to_tensor(np.asarray(p, np.int32)[None, :])
-    want = list(np.asarray(gpt.generate(ids, max_new_tokens=6)._value)[0])
-    assert got == want
+    assert eng.generate(p, max_new_tokens=6) == _oracle(gpt, p, 6)
 
 
 # ----------------------------- prefix cache: refcounted shared kv pages
@@ -453,15 +510,11 @@ def test_prefix_cache_parity_int8_paged(model):
     assert outs[0] == outs[1]
 
 
-def test_prefix_cache_parity_gpt_family():
-    from paddle_tpu.models import GPTConfig, GPTForCausalLM
-
-    paddle.seed(11)
-    cfg = GPTConfig.tiny(max_position_embeddings=128)
-    gpt = GPTForCausalLM(cfg)
-    gpt.eval()
+def test_prefix_cache_parity_gpt_family(gpt_model):
+    gpt = gpt_model
     rng = np.random.RandomState(52)
-    prompts = _mk_shared_prompts(rng, 37, (6, 4), vocab=cfg.vocab_size)
+    prompts = _mk_shared_prompts(rng, 37, (6, 4),
+                                 vocab=gpt.config.vocab_size)
     outs = []
     for on in (True, False):
         eng = LLMEngine(gpt, max_batch_slots=2, max_seq_len=128,
@@ -472,9 +525,7 @@ def test_prefix_cache_parity_gpt_family():
         outs.append([f.result(timeout=1) for f in futs])
     assert outs[0] == outs[1]
     for p, got in zip(prompts, outs[0]):
-        ids = paddle.to_tensor(np.asarray(p, np.int32)[None, :])
-        want = list(np.asarray(gpt.generate(ids, max_new_tokens=5)._value)[0])
-        assert got == want
+        assert got == _oracle(gpt, p, 5)
 
 
 def test_prefix_hit_skips_prefill_chunks(model):
@@ -564,12 +615,6 @@ def test_prefix_cache_shared_pages_visible_midflight(model):
     assert f2.result(timeout=1) == _oracle(model, prompts[1], 20)
 
 
-def test_prefix_cache_rejected_on_dense_layout(model):
-    with pytest.raises(ValueError):
-        LLMEngine(model, max_batch_slots=2, max_seq_len=128,
-                  prefix_cache=True)
-
-
 def test_prefix_impossible_total_need_is_shed(model):
     """Admission's impossibility check uses the TOTAL page need, not the
     unique (uncached) need: a cached prefix's pages occupy the same pool,
@@ -596,27 +641,20 @@ def test_prefix_impossible_total_need_is_shed(model):
     assert eng.stats()["llm_kv_pages_in_use"] == 0
 
 
-def test_engine_with_gpt_family():
-    """The engine is model-agnostic over the generate_step/prefill_step
-    contract: the GPT family (learned positions, fused qkv block) serves
-    with the same parity."""
-    from paddle_tpu.models import GPTConfig, GPTForCausalLM
-
-    paddle.seed(11)
-    cfg = GPTConfig.tiny(max_position_embeddings=128)
-    gpt = GPTForCausalLM(cfg)
-    gpt.eval()
+def test_engine_with_gpt_family(gpt_model):
+    """The engine is model-agnostic over the generate_step /
+    prefill_chunk_step contract: the GPT family (learned positions, fused
+    qkv block) serves with the same parity."""
+    gpt = gpt_model
     rng = np.random.RandomState(9)
-    p1 = rng.randint(0, cfg.vocab_size, 9).astype(np.int32)
-    p2 = rng.randint(0, cfg.vocab_size, 21).astype(np.int32)
+    p1 = rng.randint(0, gpt.config.vocab_size, 9).astype(np.int32)
+    p2 = rng.randint(0, gpt.config.vocab_size, 21).astype(np.int32)
     eng = LLMEngine(gpt, max_batch_slots=2, max_seq_len=128, decode_chunk=2)
     f1 = eng.submit(p1, max_new_tokens=6)
     f2 = eng.submit(p2, max_new_tokens=6)
     eng.run_until_complete()
     for p, f in ((p1, f1), (p2, f2)):
-        ids = paddle.to_tensor(np.asarray(p, np.int32)[None, :])
-        want = list(np.asarray(gpt.generate(ids, max_new_tokens=6)._value)[0])
-        assert f.result(timeout=1) == want
+        assert f.result(timeout=1) == _oracle(gpt, p, 6)
 
 
 def test_drain_deadline_fails_remainder_loudly(model):
@@ -655,10 +693,9 @@ def test_drain_deadline_fails_remainder_loudly(model):
 # default generator's resident key and a host offset (Generator.fork()).
 _RNG_ENGINES = {
     "paged": dict(kv_layout="paged", page_size=32, prefill_chunk=16),
-    "dense": dict(prompt_buckets=(8, 32)),
+    "paged-int8": dict(page_size=32, prefill_chunk=16, cache_dtype="int8"),
     "spec-paged": dict(kv_layout="paged", page_size=32, prefill_chunk=16,
                        spec_k=2),
-    "spec-dense": dict(prompt_buckets=(8, 32), spec_k=2),
 }
 _rng_engines = pytest.mark.parametrize("kind", sorted(_RNG_ENGINES))
 
@@ -668,11 +705,18 @@ def _rng_engine(model, kind):
                      **_RNG_ENGINES[kind])
 
 
-def _compiles():
+def _compile_families():
+    """{fn: count} of jit_compiles_total: the engine's program families
+    (record_compile) and "backend", every XLA compile of the process."""
     from paddle_tpu import observability as obs
 
     fam = obs.snapshot().get("jit_compiles_total")
-    return sum(s["value"] for s in fam["series"]) if fam else 0.0
+    return {x["labels"]["fn"]: x["value"] for x in fam["series"]} \
+        if fam else {}
+
+
+def _compiles():
+    return sum(_compile_families().values())
 
 
 @_rng_engines
@@ -708,13 +752,13 @@ def test_tick_issues_no_eager_device_call(model, kind, monkeypatch):
             if f1.done() and f2.done():
                 break
             eng.step()
-    assert f1.result(timeout=1) == _oracle(model, p1, 6)
+    assert f1.result(timeout=1) == _oracle(model, p1, 6,
+                                           cache_dtype=eng.cache_dtype)
     got = f2.result(timeout=1)
     assert len(got) == 6 and all(0 <= t < 1024 for t in got)
     # every decode or verify call took one (key, offset) pair
     assert fr.default_generator()._offset > offset0
-    if eng.paged:
-        assert eng.stats()["prefix_cache"]["cow_copies"] > 0
+    assert eng.stats()["prefix_cache"]["cow_copies"] > 0
 
 
 @_rng_engines
@@ -750,7 +794,8 @@ def test_warmed_engine_first_requests_compile_nothing(model, kind):
     rng = np.random.RandomState(62)
     p = rng.randint(0, 1024, 13).astype(np.int32)
     eng = _rng_engine(model, kind)
-    want = _oracle(model, p, 5)  # the oracle's own compiles come first
+    # the oracle's own compiles come first
+    want = _oracle(model, p, 5, cache_dtype=eng.cache_dtype)
     try:
         eng.warmup()
         quiet = _compiles()

@@ -334,10 +334,10 @@ def test_constraint_validation_rejects_loudly(model, vocab):
     assert _counter("llm_constraint_rejects_total") == r0 + 1
     with pytest.raises(ValueError):  # adapters not configured
         eng.submit(p, adapter_id="a0")
-    dense = LLMEngine(model, max_batch_slots=2, max_seq_len=128,
-                      eos_token_id=EOS)
-    with pytest.raises(ValueError):  # constraint needs the paged mask path
-        dense.submit(p, constraint=r"[0-9]+")
+    bare = LLMEngine(model, max_batch_slots=2, max_seq_len=128,
+                     eos_token_id=EOS)
+    with pytest.raises(ValueError, match="constraint_vocab"):
+        bare.submit(p, constraint=r"[0-9]+")  # a wire form needs the vocab
 
 
 # --------------------------------------------- adapter-pool conservation
@@ -426,7 +426,7 @@ def test_admission_death_releases_adapter(model):
             raise RuntimeError("injected admission fault")
         return real(*args, **kw)
 
-    eng._prefill_jit["chunk"] = poisoned
+    eng._chunk_jit = poisoned
     f1 = eng.submit(rng.randint(0, V, 40).astype(np.int32),
                     max_new_tokens=4, adapter_id="a0")
     eng.step()

@@ -267,14 +267,11 @@ def test_moe_experts_kernel_and_fallback_match_a_loop_over_pairs(T, K, E, held, 
     (dict(prefix_cache=True), "prefix_cache"),
     (dict(host_cache_pages=4), "host_cache_pages"),
     (dict(spec_k=2), "spec_k"),
-    (dict(kv_layout=None), "dense layout"),
+    (dict(kv_layout="dense"), "kv_layout='dense' was removed"),
 ], ids=["prefix_cache", "host_cache_pages", "spec_k", "dense_layout"])
 def test_what_recurrent_state_cannot_have_is_refused(kw, word):
     model = serve_hybrid.build_model(tiny_cfg())
     args = {**ENGINE, "kv_layout": "paged", **kw}
-    if args["kv_layout"] is None:
-        for k in ("page_size", "num_pages", "prefill_chunk"):
-            args.pop(k)
     with pytest.raises(ValueError, match=word):
         LLMEngine(model, **args)
 

@@ -240,7 +240,7 @@ def test_admission_dies_mid_alloc_pool_balances(model):
             raise RuntimeError("injected admission fault")
         return real(*args, **kw)
 
-    eng._prefill_jit["chunk"] = poisoned
+    eng._chunk_jit = poisoned
     f1 = eng.submit(rng.randint(0, 1024, 40).astype(np.int32),
                     max_new_tokens=4)
     eng.step()

@@ -266,8 +266,7 @@ class _Recorded:
         return self.jit(*args)
 
 
-@pytest.mark.parametrize("kind", ["paged", "dense", "verify-paged",
-                                  "verify-dense"])
+@pytest.mark.parametrize("kind", ["paged", "verify-paged"])
 def test_the_engines_programs_sort_the_vocabulary_once_under_a_branch(kind):
     """On the lowered text of a tiny engine's `llm_decode` (and of its
     speculative verify): no sort over the vocabulary runs unconditionally,
@@ -281,8 +280,7 @@ def test_the_engines_programs_sort_the_vocabulary_once_under_a_branch(kind):
                            max_position_embeddings=256)
     model = LlamaForCausalLM(cfg)
     model.eval()
-    kw = dict(kv_layout="paged", page_size=32, prefill_chunk=16) \
-        if kind.endswith("paged") else dict(prompt_buckets=(8, 32))
+    kw = dict(kv_layout="paged", page_size=32, prefill_chunk=16)
     if kind.startswith("verify"):
         eng = LLMEngine(model, max_batch_slots=2, max_seq_len=128, spec_k=2,
                         **kw)

@@ -4,7 +4,8 @@ generate spec path, and the engine's verify-and-rollback tick).
 Oracles, all deterministic on CPU:
 
 - greedy spec-on output must be BITWISE identical to spec-off on every
-  cache layout (dense / paged / int8, Llama and GPT, solo and engine) —
+  cache layout (dense / paged / int8 solo, paged / int8 pages in the
+  engine, Llama and GPT) —
   the verify ladder's argmaxes ARE the single-step tokens;
 - an oracle drafter that feeds the verify pass the true continuation
   pins the acceptance accounting (every draft accepted, fewer verify
@@ -274,7 +275,8 @@ def test_engine_spec_paged_parity_and_stats(model):
     assert eng.stats()["admission_reorders"] == 0
 
 
-def test_engine_spec_dense_and_int8_parity(model):
+def test_engine_spec_default_and_int8_parity(model):
+    """The bare default engine and int8 pages under the spec tick."""
     rng = np.random.RandomState(21)
     p = rng.randint(0, 1024, 11).astype(np.int32)
     want = _oracle(model, p, 5)
@@ -354,7 +356,8 @@ def test_engine_spec_guards(model):
     with pytest.raises(ValueError):
         LLMEngine(model, spec_k=2, decode_chunk=2)
     with pytest.raises(ValueError):
-        LLMEngine(model, cache_aware_admission=True)  # needs paged+prefix
+        LLMEngine(model, cache_aware_admission=True,
+                  prefix_cache=False)  # the reorder key is the cached prefix
 
 
 # ------------------------------------------------- preemption under spec
@@ -362,7 +365,13 @@ def test_engine_spec_guards(model):
 def test_engine_spec_mid_verify_preemption_requeues(model):
     """Two spec slots whose speculative headroom cannot coexist in a tiny
     pool: the loser preempt-requeues mid-verify (recompute path), BOTH
-    finish bitwise-exact, and the pool drains to zero."""
+    finish bitwise-exact, and the pool drains to zero.
+
+    The loser is preempted TWICE here (after its first and its second
+    token).  Until PR 29 each requeue appended every generated token to the
+    prompt, so the second one resumed from prompt + [t0, t0, t1] and the
+    sixth token came out 831, third in the reference's ranking (0.11 under
+    368: a wrong context, not a near-tie)."""
     rng = np.random.RandomState(26)
     pa = rng.randint(0, 1024, 30).astype(np.int32)
     pb = rng.randint(0, 1024, 30).astype(np.int32)
@@ -384,6 +393,51 @@ def test_engine_spec_mid_verify_preemption_requeues(model):
     t = tracer.store.get_trace(pre[0]["trace_id"])
     adm = t.find_spans("admission")
     assert adm[-1].attrs["requeue_reason"] == "page_pool_dry"
+    assert t.root.attrs["preempt_requeues"] == 2
+    # the regrown prompt holds each generated token once
+    assert adm[-1].attrs["prompt_tokens"] == 30 + 2
+
+
+@pytest.mark.faults
+def test_twice_preempted_request_regrows_its_prompt_once(model):
+    """The plain decode tick's side of the same rule: a request preempted,
+    re-admitted and preempted again resumes from its prompt plus each token
+    it generated ONCE, and ends on generate()'s tokens.  B (4 pages in the
+    end, the whole pool) loses its third page to A and, re-admitted, its
+    fourth to C."""
+    rng = np.random.RandomState(29)
+    pb, pa, pc = (rng.randint(0, 1024, n).astype(np.int32)
+                  for n in (14, 20, 3))
+    eng = LLMEngine(model, max_batch_slots=2, max_seq_len=128, page_size=16,
+                    prefill_chunk=32, num_pages=5, prefix_cache=False)
+    seen = []
+    real = eng._preempt_slot
+
+    def spy(slot, origin="decode"):
+        req = eng.slot_req[slot]
+        real(slot, origin=origin)
+        seen.append((req, req.prompt.copy(), list(req.tokens)))
+
+    eng._preempt_slot = spy
+    fb = eng.submit(pb, max_new_tokens=45)
+    fa = fc = None
+    for _ in range(200):
+        if fb.done() and fc is not None and fc.done():
+            break
+        eng.step()
+        b_pos = [int(eng.slot_pos[i]) for i, r in enumerate(eng.slot_req)
+                 if r is not None and r.future is fb]
+        if fa is None and b_pos and b_pos[0] >= 24:
+            fa = eng.submit(pa, max_new_tokens=11)   # holds 2 pages to 31
+        if fc is None and len(seen) == 1 and b_pos and b_pos[0] >= 40:
+            fc = eng.submit(pc, max_new_tokens=20)   # holds B's 4th page
+    assert fb.result(timeout=1) == _oracle(model, pb, 45)
+    assert fa.result(timeout=1) == _oracle(model, pa, 11)
+    assert fc.result(timeout=1) == _oracle(model, pc, 20)
+    assert [r.future is fb for r, _, _ in seen] == [True, True]
+    for _, prompt, toks in seen:
+        assert list(prompt) == list(pb) + toks
+    assert eng.stats()["llm_kv_pages_in_use"] == 0
 
 
 # --------------------------------------------------- cache-aware admission

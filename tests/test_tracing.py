@@ -326,22 +326,6 @@ def test_engine_trace_exact_span_tree_with_preemption_and_tracez(model):
         srv.stop()
 
 
-def test_engine_dense_layout_traces_and_stats(model):
-    rng = np.random.RandomState(5)
-    tracer = _tracer()
-    eng = LLMEngine(model, max_batch_slots=2, max_seq_len=128,
-                    tracer=tracer)
-    assert eng.generate(rng.randint(0, 1024, 10).astype(np.int32),
-                        max_new_tokens=3) is not None
-    t = tracer.store.get_trace(tracer.store.list()[0]["trace_id"])
-    assert t.span_tree() == [["queue_wait", []], ["admission", []],
-                             ["decode", []]]
-    dec = t.find_spans("decode")[0]
-    assert dec.attrs["tokens"] == 2  # first token came from the prefill
-    st = eng.stats()["tracing"]
-    assert st["started"] == 1 and st["stored"] == 1
-
-
 def test_engine_cow_fork_stamped_on_trace(model):
     """A request whose first decode write forks its cache-shared tail
     page (roomy pool: fork, not steal-back) carries the episode in its
@@ -802,7 +786,7 @@ def test_tick_phase_counter_family_follows_the_engines_accumulators(model):
         assert after[p] - before[p] == pytest.approx(v, abs=1e-9)
 
 
-def test_tick_phases_of_a_speculative_and_of_a_dense_engine(model):
+def test_tick_phases_of_a_speculative_and_of_a_default_engine(model):
     eng = _paged_engine(model, spec_k=3, checked=True)
     s0, _ = _tick_hist()
     f = eng.submit(_prompts(3, 20)[0], max_new_tokens=12)
@@ -818,15 +802,18 @@ def test_tick_phases_of_a_speculative_and_of_a_dense_engine(model):
     assert b["verify"] + b["spec_rollback_waste"] == pytest.approx(
         sec["spec_draft"] + sec["spec_dispatch"] + sec["spec_sync"],
         abs=2e-6)
-    dense = LLMEngine(model, max_batch_slots=2, max_seq_len=128,
-                      tracer=_tracer())
-    futs = [dense.submit(p, max_new_tokens=4) for p in _prompts(4, 9, 17)]
-    dense.run_until_complete()
+    # every option at its default: one admission and one chunk a tick, so
+    # the second request's first token comes a tick after the first's
+    bare = LLMEngine(model, max_batch_slots=2, max_seq_len=128,
+                     tracer=_tracer())
+    futs = [bare.submit(p, max_new_tokens=4) for p in _prompts(4, 9, 17)]
+    bare.run_until_complete()
     assert all(len(f.result(timeout=1)) == 4 for f in futs)
-    cnt = dense.stats()["tick_phases"]["count"]
-    assert cnt["first_token_sync"] == 2 and cnt["prefill_dispatch"] == 0
-    assert cnt["decode_sync"] == 3
-    dense._goodput.check()
+    cnt = bare.stats()["tick_phases"]["count"]
+    assert cnt["first_token_sync"] == cnt["prefill_dispatch"] == 2
+    assert cnt["decode_sync"] == 4
+    assert not any(cnt[p] for p in cnt if p.startswith("spec_"))
+    bare._goodput.check()
 
 
 def test_tick_phases_do_not_move_with_metrics_disabled(model):
