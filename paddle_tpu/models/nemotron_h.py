@@ -160,15 +160,19 @@ class NemotronHMamba2(_Mixer):
         self.out_proj = self._w((inner, h))
 
     def state_shapes(self, state_dtype):
+        """What a slot keeps: the SSM state with N on the sublanes and the
+        heads of a group side by side on the lanes ([32, 128, 2 * 64] as
+        published: `ops.ssm_update.state_shape`), and the convolution's."""
         c = self.config
-        return (("ssm", (c.mamba_num_heads, c.mamba_head_dim * c.ssm_state_size),
+        return (("ssm", _ssm.state_shape(c.mamba_num_heads, c.mamba_head_dim,
+                                         c.ssm_state_size, c.n_groups),
                  jnp.dtype(state_dtype)),
                 ("conv", ((c.conv_kernel - 1) * c.conv_channels,),
                  jnp.dtype(c.dtype)))
 
     def forward(self, u, cache):
-        """u [B, S, h] raw; cache (ssm [slots, H, P*N], conv [slots, (K-1)*C],
-        SlotRows).  Returns (out [B, S, h], (ssm, conv))."""
+        """u [B, S, h] raw; cache (ssm [slots, H/k, N, k*P], conv [slots,
+        (K-1)*C], SlotRows).  Returns (out [B, S, h], (ssm, conv))."""
         c = self.config
         ssm_all, conv_all, sr = cache
         B, S, _ = u.shape
@@ -181,7 +185,7 @@ class NemotronHMamba2(_Mixer):
             ssm, conv = (ssm_all, conv_all) if sr.rows is None \
                 else (ssm_all[sr.rows], conv_all[sr.rows])
             if sr.fresh is not None:
-                ssm = jnp.where(sr.fresh[:, None, None], 0, ssm)
+                ssm = jnp.where(sr.fresh[:, None, None, None], 0, ssm)
                 conv = jnp.where(sr.fresh[:, None], 0, conv)
             kw = dict(conv_weight=self.conv_weight._value,
                       conv_bias=self.conv_bias._value, a_log=self.A_log._value,

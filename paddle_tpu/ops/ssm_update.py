@@ -11,11 +11,11 @@ its causal depthwise convolution and, a head, the matrix S [head_dim, N] of
 the convolution's shift, bias and SiLU, softplus(dt), the decay, the outer
 product, the readout and the D skip.  The state pass — 2 MB a slot a layer at
 the published sizes, read and written once — is one Pallas kernel
-(``ssm_update``) that updates the state in place; the few [slots, channels]
-vectors around it are plain XLA.  ``ssd_chunk_scan`` is the same recurrence
-over a chunk of T tokens from an initial state, in the chunked
-(state-space-dual) form: plain ``jnp`` in float32 (named scope
-``ssd_chunk_scan``; not a kernel yet).
+(``ssm_update``) that updates the state in place at the rate the HBM
+streams; the few [slots, channels] vectors around it are plain XLA.
+``ssd_chunk_scan`` is the same recurrence over a chunk of T tokens from an
+initial state, in the chunked (state-space-dual) form: plain ``jnp`` in
+float32 (named scope ``ssd_chunk_scan``; not a kernel yet).
 
 Both take ``n_valid``: the leading tokens of a row that are real.  A row
 with ``n_valid = 0`` (an idle slot of the decode batch, a slot between two
@@ -23,10 +23,20 @@ prefill chunks) is not advanced at all, and a chunk's padded tail neither
 decays nor shifts anything: dt = 0 there, and the convolution's state is
 taken from the last real inputs.
 
-Layouts (chosen so nothing is padded in HBM): the SSM state is float32
-[slots, heads, head_dim * N] (one head's matrix flattened, N on the lanes),
-the convolution's state [slots, (K-1) * channels] in the activations' dtype,
-oldest tap first.
+Layouts (chosen so nothing is padded in HBM and the kernel's inner loop is
+VALU work on whole vregs): the SSM state is float32 [slots, heads / k, N,
+k * head_dim] — N on the SUBLANES, and on the lanes the k heads of one
+group that fill 128 of them side by side (``state_shape``: k = 2 at the
+published head_dim 64, [32, 128, 128] a slot).  S[h, p, n] is
+state[h // k, n, (h % k) * head_dim + p].  So B and C are columns
+broadcast along the lanes once a group, dt x and exp(dt A) are lane vectors
+a row, and the readout y[h, p] = sum_n S[h, p, n] C[n] is a running
+product-sum over a row's N / 8 vregs closed by ONE sublane reduction, which
+leaves y in 128-lane rows.  (With N on the lanes every state vreg pays a
+lane broadcast, a full lane reduction and a masked merge: the cross-lane
+unit, not the HBM, then sets the pace.)  ``ssm_chunk`` transposes a row's
+state to a matrix a head for the scan and back.  The convolution's state is
+[slots, (K-1) * channels] in the activations' dtype, oldest tap first.
 """
 from __future__ import annotations
 
@@ -80,47 +90,79 @@ def conv_chunk(conv_state, xbc, weight, bias, n_valid):
 
 
 # ------------------------------------------------------- the state kernel
-def _state_kernel(s_ref, xdt_ref, da_ref, b_ref, c_ref, o_ref, y_ref, *,
-                  heads, head_dim, n_state):
-    """One slot: every head's S <- dA S + (dt x) (x) B, y = S C.  Heads ride
-    the sublanes eight at a time (one group's B and C serve all eight), a
-    head's matrix is flat on the lanes, and position p of it is the lane
-    block [p*N, (p+1)*N)."""
-    lane = jax.lax.broadcasted_iota(jnp.int32, (_SUB, head_dim), 1)
-
-    def group(g, carry):
-        rows = pl.ds(pl.multiple_of(g * _SUB, _SUB), _SUB)
-        xdt = xdt_ref[0, rows, :]           # [8, P]  dt * x
-        da = da_ref[0, rows, :]             # [8, N]  exp(dt A), lane-replicated
-        bg = b_ref[0, pl.ds(g, 1), :]       # [1, N]
-        cg = c_ref[0, pl.ds(g, 1), :]
-        y = jnp.zeros((_SUB, head_dim), jnp.float32)
-        for p in range(head_dim):
-            cols = slice(p * n_state, (p + 1) * n_state)
-            s = s_ref[0, rows, cols] * da + xdt[:, p:p + 1] * bg
-            o_ref[0, rows, cols] = s
-            yc = jnp.sum(s * cg, axis=1, keepdims=True)  # [8, 1]
-            y = jnp.where(lane == p, yc, y)
-        y_ref[0, rows, :] = y
-        return carry
-
-    jax.lax.fori_loop(0, heads // _SUB, group, 0)
+def state_shape(heads, head_dim, n_state, groups):
+    """A slot's SSM state: [heads / k, N, k * head_dim], where k heads of
+    ONE group lie side by side on a row's lanes: as many as fit 128 lanes
+    and divide the group (2 at the published head_dim 64)."""
+    per_group = heads // groups
+    k = max(1, min(128 // head_dim, per_group))
+    while per_group % k:
+        k -= 1
+    return (heads // k, n_state, k * head_dim)
 
 
+def rows_to_heads(state, heads):
+    """[B, H/k, N, k*P] (as kept) -> [B, H, P, N] (a matrix a head)."""
+    B, R, N, L = state.shape
+    k = heads // R
+    return state.reshape(B, R, N, k, L // k).transpose(0, 1, 3, 4, 2).reshape(
+        B, heads, L // k, N)
+
+
+def heads_to_rows(s, rows):
+    """[B, H, P, N] -> [B, H/k, N, k*P]."""
+    B, H, P, N = s.shape
+    k = H // rows
+    return s.reshape(B, rows, k, P, N).transpose(0, 1, 4, 2, 3).reshape(
+        B, rows, N, k * P)
+
+
+def _state_kernel(s_ref, xdt_ref, da_ref, b_ref, c_ref, o_ref, y_ref, *, groups):
+    """One slot: every head's S <- dA S + (dt x) (x) B, y = S C.  A vreg holds
+    eight values of n on the sublanes and one row's (head, p) on the lanes:
+    B and C are columns broadcast along the lanes once a group, dt x and dA
+    lane vectors a row, and the readout accumulates S * C over the row's N / 8
+    vregs with ONE sublane reduction at the row's end."""
+    _, R, N, L = s_ref.shape
+    per = R // groups
+    for g in range(groups):
+        bb = jnp.broadcast_to(b_ref[0, :, g:g + 1], (N, L))
+        cb = jnp.broadcast_to(c_ref[0, :, g:g + 1], (N, L))
+
+        def row(i, carry):
+            r = g * per + i
+            xdt = xdt_ref[0, pl.ds(r, 1), :]    # [1, L]  dt * x
+            da = da_ref[0, pl.ds(r, 1), :]      # [1, L]  exp(dt A) of the lane's head
+            acc = jnp.zeros((_SUB, L), jnp.float32)
+            for n in range(0, N, _SUB):
+                s = s_ref[0, r, n:n + _SUB, :] * da + xdt * bb[n:n + _SUB]
+                o_ref[0, r, n:n + _SUB, :] = s
+                acc = acc + s * cb[n:n + _SUB]
+            y_ref[0, pl.ds(r, 1), :] = jnp.sum(acc, axis=0, keepdims=True)
+            return carry
+
+        jax.lax.fori_loop(0, per, row, 0)
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
 def _state_pallas(state, xdt, da, bm, cm, interpret):
-    B, H, PN = state.shape
-    P = xdt.shape[-1]
-    N = PN // P
+    """state [B, R, N, L]; xdt, da [B, R, L]; bm, cm [B, G, N].  A jit of
+    its own, so a program that calls it once a layer traces and lowers the
+    kernel once, not once a layer (every process pays that in `warmup()`,
+    compile cache or not)."""
+    B, R, N, L = state.shape
     G = bm.shape[1]
-    kernel = functools.partial(_state_kernel, heads=H, head_dim=P, n_state=N)
-    row = lambda *shape: pl.BlockSpec((1,) + shape, lambda b: (b, 0, 0))  # noqa: E731
+    bm, cm = jnp.swapaxes(bm, 1, 2), jnp.swapaxes(cm, 1, 2)  # n on the sublanes
+    kernel = functools.partial(_state_kernel, groups=G)
+    row = lambda *shape: pl.BlockSpec(  # noqa: E731
+        (1,) + shape, lambda b: (b,) + (0,) * len(shape))
     return pl.pallas_call(
         kernel,
         grid=(B,),
-        in_specs=[row(H, PN), row(H, P), row(H, N), row(G, N), row(G, N)],
-        out_specs=[row(H, PN), row(H, P)],
+        in_specs=[row(R, N, L), row(R, L), row(R, L), row(N, G), row(N, G)],
+        out_specs=[row(R, N, L), row(R, L)],
         out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
-                   jax.ShapeDtypeStruct((B, H, P), jnp.float32)],
+                   jax.ShapeDtypeStruct((B, R, L), jnp.float32)],
         input_output_aliases={0: 0},  # the state is updated in place
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
@@ -130,35 +172,32 @@ def _state_pallas(state, xdt, da, bm, cm, interpret):
 
 
 def _state_dense(state, xdt, da, bm, cm):
-    B, H, PN = state.shape
-    P = xdt.shape[-1]
-    N = PN // P
-    rep = H // bm.shape[1]
-    s = state.reshape(B, H, P, N).astype(jnp.float32)
-    bh = jnp.repeat(bm, rep, axis=1)  # [B, H, N]
-    ch = jnp.repeat(cm, rep, axis=1)
-    s = s * da[:, :, :1, None] + xdt[..., None] * bh[:, :, None, :]
-    y = jnp.sum(s * ch[:, :, None, :], axis=-1)
-    return s.reshape(B, H, PN).astype(state.dtype), y
+    """The same pass in plain jnp, same arguments."""
+    rep = state.shape[1] // bm.shape[1]  # rows a group
+    s = state.astype(jnp.float32) * da[:, :, None, :] \
+        + xdt[:, :, None, :] * jnp.repeat(bm, rep, axis=1)[..., None]
+    y = jnp.sum(s * jnp.repeat(cm, rep, axis=1)[..., None], axis=2)
+    return s.astype(state.dtype), y
 
 
-def kernel_ok(state, head_dim, groups):
-    """The kernel's tiling: eight heads of ONE group a step, N on the lanes."""
-    _, H, PN = state.shape
-    n_state = PN // head_dim
-    return (state.dtype == jnp.float32 and H % _SUB == 0 and n_state % 128 == 0
-            and H // groups == _SUB)
+def kernel_ok(state, groups):
+    """The kernel's tiling: whole vregs (eight n by 128 lanes of one group's
+    heads), float32."""
+    _, R, N, L = state.shape
+    return (state.dtype == jnp.float32 and L % 128 == 0 and N % _SUB == 0
+            and R % groups == 0)
 
 
 def ssm_update(ssm_state, conv_state, xbc, dt, *, conv_weight, conv_bias,
                a_log, dt_bias, d_skip, groups, n_state, valid,
                use_kernel=None, interpret=None):
-    """Advance every slot one token.  ssm_state [B, H, P*N], conv_state
-    [B, (K-1)*C], xbc [B, C] (C = H*P + 2*groups*N), dt [B, H] before the
-    bias, valid bool [B].  Returns (y float32 [B, H*P] with the D skip,
-    new ssm_state, new conv_state)."""
-    B, H, PN = ssm_state.shape
-    P = PN // n_state
+    """Advance every slot one token.  ssm_state [B, H/k, N, k*P] (see
+    `state_shape`), conv_state [B, (K-1)*C], xbc [B, C] (C = H*P +
+    2*groups*N), dt [B, H] before the bias, valid bool [B].  Returns (y
+    float32 [B, H*P] with the D skip, new ssm_state, new conv_state)."""
+    B, R, _, L = ssm_state.shape
+    H = dt.shape[-1]
+    P = L * R // H
     with jax.named_scope("ssm_update"):
         act, conv_new = conv_step(conv_state, xbc, conv_weight, conv_bias, valid)
         x = act[:, :H * P].reshape(B, H, P)
@@ -167,17 +206,18 @@ def ssm_update(ssm_state, conv_state, xbc, dt, *, conv_weight, conv_bias,
         dtv = _softplus(dt.astype(jnp.float32) + dt_bias.astype(jnp.float32))
         dtv = jnp.where(valid[:, None], dtv, 0.0)  # an idle row: S stays S
         a = -jnp.exp(a_log.astype(jnp.float32))
-        da = jnp.broadcast_to(jnp.exp(dtv * a)[:, :, None], (B, H, n_state))
-        xdt = x * dtv[:, :, None]
+        # heads side by side on a row's lanes, as the state keeps them
+        da = jnp.broadcast_to(jnp.exp(dtv * a)[:, :, None], (B, H, P)).reshape(B, R, L)
+        xdt = (x * dtv[:, :, None]).reshape(B, R, L)
         if use_kernel is None:
-            use_kernel = kernel_ok(ssm_state, P, groups)
+            use_kernel = kernel_ok(ssm_state, groups)
         if use_kernel:
             if interpret is None:
                 interpret = _interpret_default()
             s_new, y = _state_pallas(ssm_state, xdt, da, bm, cm, interpret)
         else:
             s_new, y = _state_dense(ssm_state, xdt, da, bm, cm)
-        y = y + d_skip.astype(jnp.float32)[None, :, None] * x
+        y = y.reshape(B, H, P) + d_skip.astype(jnp.float32)[None, :, None] * x
     return y.reshape(B, H * P), s_new, conv_new
 
 
@@ -227,13 +267,14 @@ def ssd_chunk_scan(x, dt, a, bm, cm, init_state, chunk_size=128):
 def ssm_chunk(ssm_state, conv_state, xbc, dt, *, conv_weight, conv_bias, a_log,
               dt_bias, d_skip, groups, n_state, n_valid, chunk_size=128):
     """Advance rows by up to T tokens each (a prefill chunk).  ssm_state
-    [B, H, P*N] and conv_state [B, (K-1)*C] are the rows' own; xbc
+    [B, H/k, N, k*P] and conv_state [B, (K-1)*C] are the rows' own; xbc
     [B, T, C], dt [B, T, H], n_valid int32 [B].  Returns (y float32
-    [B, T, H*P] with the D skip, new ssm_state, new conv_state)."""
-    B, H, PN = ssm_state.shape
-    T = xbc.shape[1]
+    [B, T, H*P] with the D skip, new ssm_state, new conv_state).  The scan
+    works a matrix a head: the state is transposed in and out."""
+    B, R, _, L = ssm_state.shape
+    T, H = xbc.shape[1], dt.shape[-1]
+    P = L * R // H
     act, conv_new = conv_chunk(conv_state, xbc, conv_weight, conv_bias, n_valid)
-    P = PN // n_state
     x = act[..., :H * P].reshape(B, T, H, P)
     bm = act[..., H * P:H * P + groups * n_state].reshape(B, T, groups, n_state)
     cm = act[..., H * P + groups * n_state:].reshape(B, T, groups, n_state)
@@ -241,8 +282,8 @@ def ssm_chunk(ssm_state, conv_state, xbc, dt, *, conv_weight, conv_bias, a_log,
     real = jnp.arange(T)[None, :] < n_valid[:, None]
     dtv = jnp.where(real[..., None], dtv, 0.0)
     a = -jnp.exp(a_log.astype(jnp.float32))
-    y, final = ssd_chunk_scan(x, dtv, a, bm, cm,
-                              ssm_state.reshape(B, H, P, n_state), chunk_size)
+    y, final = ssd_chunk_scan(x, dtv, a, bm, cm, rows_to_heads(ssm_state, H),
+                              chunk_size)
     y = y + d_skip.astype(jnp.float32)[None, None, :, None] * x
-    return (y.reshape(B, T, H * P), final.reshape(B, H, PN).astype(ssm_state.dtype),
-            conv_new)
+    return (y.reshape(B, T, H * P),
+            heads_to_rows(final, R).astype(ssm_state.dtype), conv_new)

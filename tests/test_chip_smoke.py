@@ -271,11 +271,13 @@ def test_aot_hybrid_kernels_at_published_widths(v5e_devices, as_on_tpu):
             ((T, 6), i32), ((T, 6), f32))
         assert _names(calls, "moe_experts"), (T, calls)
     B, H, P, N, G = 64, 64, 64, 128, 8
+    R, _, L = ssm.state_shape(H, P, N, G)
+    assert ssm.kernel_ok(jax.ShapeDtypeStruct((B, R, N, L), f32), G)
     calls = _mosaic_calls(
         lambda s, xdt, dA, bm, cm: ssm._state_pallas(s, xdt, dA, bm, cm, False),
-        one, ((B, H, P * N), f32), ((B, H, P), f32), ((B, H, N), f32),
+        one, ((B, R, N, L), f32), ((B, R, L), f32), ((B, R, L), f32),
         ((B, G, N), f32), ((B, G, N), f32))
-    assert _names(calls, "ssm_update"), calls
+    assert sum("ssm_update" in c for c in calls) == 1, calls
     for S, b, n in ((1, 64, 1), (256, 1, 2)):
         calls = _mosaic_calls(
             lambda q, k, v, off, tbl: da.paged_decode_attention(q, k, v, off, tbl),

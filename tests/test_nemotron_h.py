@@ -176,8 +176,22 @@ def test_a_preempted_and_requeued_request_gives_a_fresh_engines_tokens():
 
 
 # (d) ------------------------------------------------------------------------
-def _ssm_case():
-    B, H, P, N, G, K = 3, 16, 8, 128, 2, 4
+SMALL = dict(B=3, H=16, P=8, N=128, G=2)
+PUBLISHED = dict(B=3, H=64, P=64, N=128, G=8)  # a slot's shape as published
+
+
+def _rows(S, R):
+    """[B, H, P, N] -> the layout a slot keeps: row r holds heads r*k..r*k+k-1
+    side by side on its lanes, n on the sublanes."""
+    B, H, P, N = S.shape
+    k = H // R
+    out = jnp.einsum("brjpn->brnjp", S.reshape(B, R, k, P, N)).reshape(B, R, N, k * P)
+    assert out[B - 1, R - 1, 3, (k - 1) * P + 2] == S[B - 1, H - 1, 2, 3]
+    return out
+
+
+def _ssm_case(B, H, P, N, G):
+    K = 4
     C = H * P + 2 * G * N
     k = jax.random.split(jax.random.PRNGKey(0), 9)
     kw = dict(conv_weight=jax.random.normal(k[4], (K, C)) * 0.3,
@@ -185,38 +199,55 @@ def _ssm_case():
               a_log=jnp.log(jnp.linspace(1, 16, H)),
               dt_bias=jax.random.normal(k[6], (H,)), d_skip=jnp.ones(H),
               groups=G, n_state=N)
-    state = jax.random.normal(k[0], (B, H, P * N))
+    R = ssm_op.state_shape(H, P, N, G)[0]
+    state = _rows(jax.random.normal(k[0], (B, H, P, N)), R)
+    assert state.shape[1:] == ssm_op.state_shape(H, P, N, G)
     conv = jax.random.normal(k[1], (B, (K - 1) * C))
     return (B, H, C), state, conv, k, kw
 
 
+def test_the_published_state_fills_whole_vregs_and_nothing_is_padded():
+    assert ssm_op.state_shape(64, 64, 128, 8) == (32, 128, 128)
+    assert ssm_op.state_shape(4, 8, 16, 2) == (2, 16, 16)      # the tiny models
+    assert ssm_op.state_shape(6, 64, 128, 2) == (6, 128, 64)   # 3 heads a group: alone
+    f32 = jnp.float32
+    assert ssm_op.kernel_ok(jax.ShapeDtypeStruct((64, 32, 128, 128), f32), 8)
+    assert not ssm_op.kernel_ok(jax.ShapeDtypeStruct((3, 2, 16, 16), f32), 2)
+    assert not ssm_op.kernel_ok(jax.ShapeDtypeStruct((64, 32, 128, 128), jnp.bfloat16), 8)
+
+
+@pytest.mark.parametrize("shape", [SMALL, PUBLISHED], ids=["small", "published"])
 @pytest.mark.parametrize("use_kernel", [False, True], ids=["fallback", "interpret"])
-def test_ssm_update_matches_the_token_recurrence(use_kernel):
-    """One token a slot, against S <- exp(dt A) S + dt x (x) B written out;
-    the idle row (valid False) keeps its state to the bit."""
-    (B, H, C), state, conv, k, kw = _ssm_case()
+def test_ssm_update_matches_the_token_recurrence(use_kernel, shape):
+    """One token a slot, against S <- exp(dt A) S + dt x (x) B written out a
+    head; the idle row (valid False) keeps its state to the bit."""
+    (B, H, C), state, conv, k, kw = _ssm_case(**shape)
+    P, N, G = shape["P"], shape["N"], shape["G"]
+    R = state.shape[1]
     xbc, dt = jax.random.normal(k[2], (B, C)), jax.random.normal(k[3], (B, H))
     valid = jnp.array([True, False, True])
     y, s_new, c_new = ssm_op.ssm_update(state, conv, xbc, dt, valid=valid,
                                         use_kernel=use_kernel, interpret=True, **kw)
-    P, N, G = 8, 128, 2
     win = jnp.concatenate([conv, xbc], 1).reshape(B, 4, C)
     act = jax.nn.silu(jnp.einsum("bkc,kc->bc", win, kw["conv_weight"]) + kw["conv_bias"])
     x = act[:, :H * P].reshape(B, H, P)
     bm = jnp.repeat(act[:, H * P:H * P + G * N].reshape(B, G, N), H // G, 1)
     cm = jnp.repeat(act[:, H * P + G * N:].reshape(B, G, N), H // G, 1)
     dtv = jax.nn.softplus(dt + kw["dt_bias"]) * valid[:, None]
-    S = state.reshape(B, H, P, N) * jnp.exp(-dtv * jnp.exp(kw["a_log"]))[..., None, None] \
+    S = ssm_op.rows_to_heads(state, H) \
+        * jnp.exp(-dtv * jnp.exp(kw["a_log"]))[..., None, None] \
         + (dtv[..., None] * x)[..., None] * bm[:, :, None, :]
     want = jnp.sum(S * cm[:, :, None, :], -1) + x
     np.testing.assert_allclose(y.reshape(B, H, P), want, atol=2e-5)
-    np.testing.assert_allclose(s_new, S.reshape(B, H, P * N), atol=2e-5)
+    np.testing.assert_allclose(s_new, _rows(S, R), atol=2e-5)
+    np.testing.assert_array_equal(ssm_op.heads_to_rows(ssm_op.rows_to_heads(state, H), R),
+                                  state)
     assert jnp.array_equal(s_new[1], state[1]) and jnp.array_equal(c_new[1], conv[1])
     np.testing.assert_array_equal(c_new[0], win[0, 1:].reshape(-1))
 
 
 def test_ssd_chunk_scan_with_an_initial_state_and_a_padded_tail_matches_token_steps():
-    (B, H, C), state, conv, k, kw = _ssm_case()
+    (B, H, C), state, conv, k, kw = _ssm_case(**SMALL)
     T = 24
     xbc, dt = jax.random.normal(k[7], (B, T, C)), jax.random.normal(k[8], (B, T, H))
     n_valid = jnp.array([24, 0, 13])
@@ -233,6 +264,46 @@ def test_ssd_chunk_scan_with_an_initial_state_and_a_padded_tail_matches_token_st
     np.testing.assert_allclose(s_new, s, atol=5e-5)
     np.testing.assert_array_equal(c_new, c)
     assert jnp.array_equal(s_new[1], state[1])
+
+
+def test_the_state_a_chunk_hands_over_is_the_state_the_kernel_reads():
+    """A chunk through the scan, then three tokens through the KERNEL, at the
+    published slot shape: the same as every token through the recurrence
+    written out a head (so the layout `ssm_chunk` writes is the kernel's)."""
+    (B, H, C), state, conv, k, kw = _ssm_case(**{**PUBLISHED, "B": 2})
+    P, N, G, T = PUBLISHED["P"], PUBLISHED["N"], PUBLISHED["G"], 16
+    xbc = jax.random.normal(k[7], (B, T + 3, C))
+    dt = jax.random.normal(k[8], (B, T + 3, H))
+    n_valid = jnp.array([T, 11])
+    _, s, c = ssm_op.ssm_chunk(state, conv, xbc[:, :T], dt[:, :T], n_valid=n_valid,
+                               chunk_size=8, **kw)
+    ys = []
+    for t in range(T, T + 3):
+        yt, s, c = ssm_op.ssm_update(s, c, xbc[:, t], dt[:, t], valid=jnp.array([True] * B),
+                                     use_kernel=True, interpret=True, **kw)
+        ys.append(yt.reshape(B, H, P))
+    # the recurrence a head, over each row's own real tokens then the three
+    S = ssm_op.rows_to_heads(state, H)
+    a = -jnp.exp(kw["a_log"])
+    want = []
+    for b in range(B):
+        Sb, win = S[b], conv[b].reshape(3, C)
+        for t in [*range(int(n_valid[b])), *range(T, T + 3)]:
+            win = jnp.concatenate([win, xbc[b, t][None]], 0)
+            act = jax.nn.silu(jnp.einsum("kc,kc->c", win, kw["conv_weight"])
+                              + kw["conv_bias"])
+            win = win[1:]
+            x = act[:H * P].reshape(H, P)
+            bm = jnp.repeat(act[H * P:H * P + G * N].reshape(G, N), H // G, 0)
+            cm = jnp.repeat(act[H * P + G * N:].reshape(G, N), H // G, 0)
+            dtv = jax.nn.softplus(dt[b, t] + kw["dt_bias"])
+            Sb = Sb * jnp.exp(dtv * a)[:, None, None] \
+                + (dtv[:, None] * x)[..., None] * bm[:, None, :]
+            if t >= T:
+                want.append(jnp.sum(Sb * cm[:, None, :], -1) + x)
+        np.testing.assert_allclose(s[b], _rows(Sb[None], s.shape[1])[0], atol=1e-4)
+    want = jnp.stack(want).reshape(B, 3, H, P)
+    np.testing.assert_allclose(jnp.stack(ys, 1), want, atol=1e-4)
 
 
 @pytest.mark.parametrize("T,K,E,held,lo", [(8, 2, 8, 4, 0), (8, 2, 8, 4, 4),
