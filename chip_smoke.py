@@ -6,6 +6,9 @@ cut to N_LAYERS and seeded random weights:
 
   serve  LLMEngine(kv_layout="paged") answers 12 requests (README sequence)
   kernel paged_decode_attention vs its own dense path at the engine's shapes
+  state  LLMEngine with a small MiniCPM-SALA (128-wide heads, so the block-
+         sparse and the lightning kernels are the paths taken): a document,
+         then two questions of it that resume from its state checkpoint
   train  paddle.jit.TrainStep + AdamW, 3 steps at batch 4 x seq 2048
   mesh   ShardedTrainStep(zero_stage=2) on sharding=2 x mp=2 (>= 4 chips)
 
@@ -162,6 +165,68 @@ def serve_phase(n_layers=N_LAYERS, max_seq_len=SEQ, prompt_lens=(100, 1500),
                 page=page, slots=slots, num_pages=eng.num_pages,
                 max_pages=eng.M, chunk=eng.prefill_chunk)
     return geom
+
+
+# ------------------------------------------------------------------- state
+def state_phase(doc_pages=3, new_tokens=8, hidden=256, timeout=600.0):
+    """A model with recurrent state beside paged attention, its prefix cache
+    on: a document that ends on a page boundary leaves a state checkpoint,
+    two questions of it resume there, and the tokens the decode kernels gave
+    lie at the top of the model's own one-pass logits (the chunk path: no
+    decode kernel)."""
+    import jax.numpy as jnp
+
+    import paddle_tpu as paddle
+    from paddle_tpu.inference import LLMEngine
+    from paddle_tpu.models.minicpm_sala import (LIGHTNING, SPARSE,
+                                                MiniCPMSALAConfig,
+                                                MiniCPMSALAForCausalLM)
+    from paddle_tpu.ops.sparse_attention import SparseSpec
+
+    paddle.seed(0)
+    page = 128
+    cfg = MiniCPMSALAConfig(
+        vocab_size=512, hidden_size=hidden, intermediate_size=2 * hidden,
+        num_hidden_layers=2, mixer_types=(SPARSE, LIGHTNING),
+        num_attention_heads=16, num_key_value_heads=2, lightning_nh=2,
+        lightning_nkv=2, residual_depth=32,
+        sparse=SparseSpec(topk=3, window_size=64, dense_len=256))
+    model = MiniCPMSALAForCausalLM(cfg)
+    model.eval()
+    eng = LLMEngine(model, page_size=page, prefill_chunk=128, max_batch_slots=4,
+                    max_seq_len=(doc_pages + 2) * page, prefix_cache=True,
+                    state_checkpoints=2)
+    log(f"state: warmup took {eng.warmup():.1f}s")
+    rng = np.random.default_rng(1)
+    doc = rng.integers(0, cfg.vocab_size, doc_pages * page, dtype=np.int32)
+    asks = [np.concatenate([doc, rng.integers(0, cfg.vocab_size, n, dtype=np.int32)])
+            for n in (40, 90)]
+    eng.start()
+    try:
+        eng.submit(doc, max_new_tokens=2).result(timeout=timeout)
+        outs = [f.result(timeout=timeout) for f in
+                [eng.submit(p, max_new_tokens=new_tokens) for p in asks]]
+    finally:
+        eng.stop()
+    st = eng.stats()
+    ck = st["recurrent_state"]["checkpoints"]
+    check(ck["stored"] >= 1 and ck["restored"] == 2
+          and st["prefix_cache"]["hit_tokens"] == 2 * doc.size,
+          f"two questions resumed from the document's state checkpoint: {ck}")
+    sp = st["sparse_attention"]["decode"]
+    check(sp["layer_calls"] > 0 and sp["selected_blocks"] == 3 * sp["layer_calls"],
+          f"every decode query past dense_len read its 3 selected blocks: {sp}")
+    worst = 0.0
+    for p, o in zip(asks, outs):
+        both = np.concatenate([p, np.asarray(o, np.int32)])
+        lg = np.asarray(model.forward(jnp.asarray(both[None]))._value[0],
+                        np.float32)[len(p) - 1:len(both) - 1]
+        worst = max(worst, float(np.max(lg.max(-1) - lg[np.arange(len(o)), o])))
+    # bfloat16 logits of size ~0.5: a served token may lose to a neighbour
+    # by a rounding step or two, not by more
+    check(worst < 0.05, f"served tokens lie within {worst:.4f} of the "
+          "one-pass logits' best")
+    return worst
 
 
 # ------------------------------------------------------------------ kernel
@@ -338,6 +403,8 @@ def main():
     _free()
     errs = kernel_phase(**geom)
     _free()
+    state_gap = state_phase()
+    _free()
     losses = train_phase()
     _free()
     mesh_losses = mesh_phase(losses[0])
@@ -345,7 +412,7 @@ def main():
     # already on disk was loaded: not built from this checkout's sources
     log(f"native: AVAILABLE={native.AVAILABLE} "
         f"built_this_run={native.BUILT_THIS_RUN}")
-    log(f"summary: layers={N_LAYERS} kernel_err={errs} train={losses} "
+    log(f"summary: layers={N_LAYERS} kernel_err={errs} state_gap={state_gap:.4f} train={losses} "
         f"mesh={mesh_losses} paddle_tpu={paddle_tpu.__version__} "
         f"wall={time.perf_counter() - T0:.0f}s")
     print(json.dumps({"ok": True, "device": dev}))

@@ -92,6 +92,7 @@ from ..ops.sampling import has_threshold as _has_threshold
 from ..ops.sampling import sample_rows as _sample_rows
 from ..ops.sampling import spec_accept as _spec_accept
 from ..models.kv_cache import SlotRows as _SlotRows
+from ..models.kv_cache import SparsePaged as _SparsePaged
 from ..tensor.tensor import Tensor
 from . import constrain as _constrain
 
@@ -232,6 +233,18 @@ _M_MOE_MAX_LOAD = _obs.gauge(
     "llm_moe_max_expert_load_count",
     "Most pairs one held expert got in a layer of the latest decode tick")
 _MOE_PROGRAMS = ("decode", "prefill")  # the planes of the device accumulator
+#: what a block-sparse attention layer reports a call (CacheKind.compressed)
+_SPARSE_FIELDS = ("layer_calls", "context_blocks", "selected_blocks")
+_M_SPARSE_SELECTED = _obs.counter(
+    "llm_sparse_blocks_selected_total",
+    "Key blocks the block-sparse attention layers selected, summed over "
+    "their query calls (one a real row a layer)", labelnames=("program",))
+_M_STATE_CKPT = _obs.counter(
+    "llm_state_checkpoints_total",
+    "Recurrent-state checkpoints at a cached prefix's end: stored after a "
+    "prefill, restored into a slot by a prefix hit, evicted with their node "
+    "or for a newer prefix", labelnames=("event",))
+_CKPT_EVENTS = ("stored", "restored", "evicted")
 
 #: The pump's tick, cut into mutually exclusive phases in tick order (a
 #: speculative tick runs the spec_* phases in place of decode_*).  Every
@@ -443,18 +456,28 @@ def _to_model_caches(kinds, caches, pos, page_tbl, slot_rows=None):
 
     if kinds is None:
         return [paged(c) for c in caches]
-    return [paged(c) if k.kind == "paged_kv"
+    # a state layer that rotates by position reads it from the rows
+    if slot_rows is not None:
+        slot_rows = slot_rows._replace(pos=jnp.broadcast_to(
+            jnp.asarray(pos, jnp.int32), slot_rows.n_valid.shape))
+    return [_SparsePaged(*c, pos, page_tbl, slot_rows) if k.compressed
+            else paged(c) if k.kind == "paged_kv"
             else tuple(c) + (slot_rows,) if k.kind == "recurrent"
             else slot_rows
             for k, c in zip(kinds, caches)]
 
 
 def _from_model_caches(kinds, new_caches):
-    """Back again: (engine-side caches, what the expert layers reported)."""
-    raw, aux = [], []
+    """Back again: (engine-side caches, what the layers reported: the expert
+    layers' counts stacked, then the block-sparse layers' summed — the order
+    of the engine's device accumulators, each present only with its layers)."""
+    raw, moe, sparse = [], [], []
     for i, c in enumerate(new_caches):
         kind = "paged_kv" if kinds is None else kinds[i].kind
-        if kind == "paged_kv":
+        if kind == "paged_kv" and kinds is not None and kinds[i].compressed:
+            raw.append(tuple(c[:3]))
+            sparse.append(c[3])
+        elif kind == "paged_kv":
             vals = tuple(x._value if isinstance(x, Tensor) else x for x in c)
             raw.append((vals[0], vals[1]) + vals[4:])
         elif kind == "recurrent":
@@ -462,8 +485,9 @@ def _from_model_caches(kinds, new_caches):
         else:
             raw.append(())
             if kinds[i].experts_held:
-                aux.append(c)
-    return raw, aux
+                moe.append(c)
+    return raw, ([jnp.stack(moe)] if moe else []) \
+        + ([sum(sparse)] if sparse else [])
 
 
 class LLMEngine:
@@ -478,7 +502,7 @@ class LLMEngine:
                  cache_aware_admission=False, admission_age_cap=4,
                  adapters=None, constraint_vocab=None, host_cache_pages=0,
                  disk_cache_dir=None, disk_cache_pages=0,
-                 demote_watermark=0.25, demote_batch=8):
+                 demote_watermark=0.25, demote_batch=8, state_checkpoints=None):
         """decode_chunk > 1 runs k decode steps per compiled call (a
         lax.scan), amortizing the host round-trip k-fold — the multi-step
         scheduling lever for high-latency hosts.  Slots that finish
@@ -614,11 +638,31 @@ class LLMEngine:
         request's first chunk runs (a preempted and requeued request
         recomputes from nothing), carried from chunk to chunk, untouched by
         a final chunk's padded tail, and not advanced by a decode tick for
-        slots that are idle or between chunks.  A shared page says nothing
-        of the state at its end and a rejected draft cannot be rolled back
-        out of one, so for such a model ``prefix_cache`` defaults to off
-        and ``prefix_cache=True``, ``host_cache_pages > 0`` and ``spec_k >
-        0`` raise ``ValueError``.  Expert layers that
+        slots that are idle or between chunks (``models.minicpm_sala``'s
+        lightning-attention layers keep theirs the same way).  A shared
+        page says nothing of the state at its end, so for such a model
+        ``prefix_cache`` defaults to off; ``prefix_cache=True`` shares
+        prefixes through STATE CHECKPOINTS: a fixed pool of
+        ``state_checkpoints`` entries on the device (default 8; one entry
+        = every recurrent layer's state of one sequence).  A prefill whose
+        prompt ends on a page boundary indexes its pages, stores one after
+        its last chunk and hangs it on the last page's node (any other
+        prompt leaves nothing in the index: no checkpoint could follow, so
+        its pages could never be resumed at); a later prompt matches up to the
+        deepest node that carries one (never inside a page), the entry is
+        copied into its slot where a fresh request's state is zeroed, and
+        prefill starts at the first token past it; an entry goes with its
+        node, or for a newer prefix when the pool is full
+        (``stats()["recurrent_state"]["checkpoints"]``).  Greedy tokens
+        equal those of ``prefix_cache=False``.  The host and disk tiers
+        stage pages, not states, and a rejected draft cannot be rolled
+        back out of a state: ``host_cache_pages > 0`` and ``spec_k > 0``
+        raise ``ValueError`` for such a model.  A ``"paged_kv"`` layer
+        with ``compressed`` keeps a third pool of compressed keys beside K
+        and V and reads only the key blocks it selects
+        (ops/sparse_attention.py); what it read comes back with the tick's
+        tokens as the experts' pairs do (``stats()["sparse_attention"]``,
+        ``llm_sparse_blocks_selected_total``).  Expert layers that
         hold a share of the experts report their (token, expert) pairs:
         both programs add them to one device-resident total that comes
         back with the decode tick's tokens (``llm_moe_*``,
@@ -650,25 +694,32 @@ class LLMEngine:
         self._recurrent = kinds is not None and any(
             k.kind == "recurrent" for k in kinds)
         if self._recurrent:
-            # a shared page says nothing of the recurrent state at its end,
-            # and a state cannot be rolled back past a rejected draft: until
-            # state is checkpointed at block boundaries (ROADMAP R5) these
-            # are off for such a model
+            # a shared page says nothing of the recurrent state at its end:
+            # a prefix is resumed from a state CHECKPOINT (below), which the
+            # tiers do not stage; and a state cannot be rolled back past a
+            # rejected draft (ROADMAP R5)
             why = (f"{type(model).__name__} keeps recurrent state in some "
                    "layers: ")
-            if prefix_cache:
-                raise ValueError(
-                    why + "prefix_cache=True would map shared K/V pages but "
-                    "has no state at the shared prefix's end to resume from")
             if host_cache_pages:
                 raise ValueError(
-                    why + "host_cache_pages stages prefix-cache pages, and the "
-                    "prefix cache is off for this model")
+                    why + "host_cache_pages stages prefix-cache pages in host "
+                    "RAM, but not the state checkpoint a prefix of this model "
+                    "is resumed from")
             if spec_k:
                 raise ValueError(
                     why + "spec_k > 0 would need a rejected draft rolled back "
                     "out of the state, and a state has no past to return to")
-            prefix_cache = False
+            if prefix_cache is None:
+                prefix_cache = False  # on request: it costs a checkpoint pool
+        if state_checkpoints is not None and not (self._recurrent and prefix_cache):
+            raise ValueError(
+                "state_checkpoints sizes the pool of recurrent-state "
+                "checkpoints: it needs a model with recurrent state and "
+                "prefix_cache=True")
+        if cache_dtype == "int8" and any(k.compressed for k in kinds or ()):
+            raise ValueError(
+                f"{type(model).__name__} selects key blocks by compressed keys "
+                "kept beside the pages: cache_dtype='int8' has no such pool")
         self.ps = int(page_size)
         if self.ps < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
@@ -710,8 +761,19 @@ class LLMEngine:
         else:
             # one entry a layer, by its kind: page pools at the layer's
             # own head count and size, zeroed state a SLOT, or nothing
+            def compressed(k):
+                if not k.compressed:
+                    return ()
+                if ps % k.compressed:
+                    raise ValueError(
+                        f"page_size {ps} must hold whole strides of "
+                        f"{k.compressed} tokens (one compressed key each)")
+                return (jnp.zeros((P, k.kv_heads, ps // k.compressed,
+                                   k.head_dim), kv_dtype),)
+
             self.caches = [
-                pools(k.kv_heads, k.head_dim) if k.kind == "paged_kv"
+                pools(k.kv_heads, k.head_dim) + compressed(k)
+                if k.kind == "paged_kv"
                 else tuple(jnp.zeros((B,) + tuple(shape), dt)
                            for _, shape, dt in k.state)
                 for k in kinds]
@@ -734,7 +796,29 @@ class LLMEngine:
         if prefix_cache:
             from .prefix_cache import PrefixCache
 
-            self._prefix = PrefixCache(self.ps)
+            # a model with recurrent state resumes a prefix only where a
+            # state checkpoint hangs, and indexes only prompts one can follow
+            self._prefix = PrefixCache(self.ps, stateful=self._recurrent)
+        # ---- state checkpoints: a fixed pool on the device, one entry =
+        # every recurrent layer's state of one sequence; entries hang on
+        # prefix-cache nodes (inference/prefix_cache.py)
+        self._ckpt = None
+        if self._prefix is not None and self._recurrent:
+            n_ck = 8 if state_checkpoints is None else int(state_checkpoints)
+            if n_ck < 1:
+                raise ValueError(
+                    f"state_checkpoints must be >= 1, got {state_checkpoints}")
+            self._ckpt = [
+                tuple(jnp.zeros((n_ck,) + tuple(shape), dt)
+                      for _, shape, dt in k.state)
+                for k in kinds if k.kind == "recurrent"]
+            self._ckpt_free = list(range(n_ck))
+            self._ckpt_capacity = n_ck
+            self._ckpt_bytes = sum(
+                int(np.prod(x.shape)) * x.dtype.itemsize
+                for c in self._ckpt for x in c)
+            self._ckpt_events = dict.fromkeys(_CKPT_EVENTS, 0)
+            self._ckpt_store_jit = self._ckpt_load_jit = None
         self._prefix_hit_tokens = 0
         self._prefix_prompt_tokens = 0
         # engine-local mirrors of the process-global counters, so
@@ -788,6 +872,15 @@ class LLMEngine:
                 p: {"pairs_held": 0, "pairs_absent": 0, "experts_touched": 0,
                     "layer_calls": 0} for p in _MOE_PROGRAMS}
             self._moe_max_load = 0
+        # block-sparse attention layers report (query calls, context blocks,
+        # selected blocks) the same way: [program, 3] on the device
+        self._sparse_acc = None
+        if any(k.compressed for k in kinds or ()):
+            self._sparse_acc = jnp.zeros(
+                (len(_MOE_PROGRAMS), len(_SPARSE_FIELDS)), jnp.int32)
+            self._sparse_seen = np.zeros(self._sparse_acc.shape, np.uint32)
+            self._sparse_stats = {p: dict.fromkeys(_SPARSE_FIELDS, 0)
+                                  for p in _MOE_PROGRAMS}
         # what the layers keep, for stats(): {kind: {"layers", "bytes"}}
         self._cache_stats = {}
         for i, c in enumerate(self.caches):
@@ -1234,6 +1327,15 @@ class LLMEngine:
             }
         cache_kinds = {k: dict(v) for k, v in self._cache_stats.items()}
         rec = cache_kinds.get("recurrent")
+        ckpt = None
+        if self._ckpt is not None:
+            ckpt = dict(self._ckpt_events, capacity=self._ckpt_capacity,
+                        held=self._ckpt_capacity - len(self._ckpt_free),
+                        bytes=self._ckpt_bytes)
+        sparse = None
+        if self._sparse_acc is not None:
+            sparse = {p: dict(v) for p, v in self._sparse_stats.items()}
+            sparse["layers"] = sum(bool(k.compressed) for k in self._cache_kinds)
         moe = None
         if self._moe_kinds:
             moe = {p: dict(v) for p, v in self._moe_stats.items()}
@@ -1249,9 +1351,14 @@ class LLMEngine:
             # paged_kv, recurrent and none
             "cache_kinds": cache_kinds,
             # per-slot state of the recurrent layers; None without any
+            # (+ the pool of state checkpoints under the prefix cache)
             "recurrent_state": None if rec is None else {
                 "bytes": rec["bytes"], "slots": self.n_slots,
-                "layers": rec["layers"]},
+                "layers": rec["layers"], "checkpoints": ckpt},
+            # block-sparse attention layers: query calls (a real row a
+            # layer), their contexts' blocks and the blocks they selected,
+            # by program; None without any
+            "sparse_attention": sparse,
             # expert layers' routed pairs by program; None without any
             "moe": moe,
             "llm_kv_pages_in_use": pages_used,
@@ -1737,15 +1844,99 @@ class LLMEngine:
             self._prefix_evictions += 1
             self._prefix_epoch += 1
             freed += 1
+            self._ckpt_reclaim()
         return True
 
     def _get_cow_copy(self):
         if self._cow_jit is None:
-            from ..models.kv_cache import cow_copy_pages
+            from ..models.kv_cache import cow_copy_pages as copy_pools
+
+            kinds = self._cache_kinds
+
+            def cow_copy_pages(caches, src, dst):
+                # only page pools fork: a state layer's arrays are a slot's
+                if kinds is None:
+                    return copy_pools(caches, src, dst)
+                paged = [i for i, k in enumerate(kinds) if k.kind == "paged_kv"]
+                out = list(caches)
+                for i, c in zip(paged, copy_pools([caches[i] for i in paged], src, dst)):
+                    out[i] = c
+                return out
 
             _profiling.record_compile("cow_copy")
             self._cow_jit = jax.jit(cow_copy_pages, donate_argnums=(0,))
         return self._cow_jit
+
+    # ---------------------------------------------------- state checkpoints
+
+    def _state_layers(self):
+        return [i for i, k in enumerate(self._cache_kinds)
+                if k.kind == "recurrent"]
+
+    def _get_ckpt_store(self):
+        """(caches, pool, slot, entry) -> pool with the slot's state of
+        every recurrent layer written into the entry (the pool is donated)."""
+        if self._ckpt_store_jit is None:
+            layers = self._state_layers()
+
+            def store(caches, pool, slot, entry):
+                return [tuple(p.at[entry].set(x[slot])
+                              for p, x in zip(pl, caches[i]))
+                        for pl, i in zip(pool, layers)]
+
+            _profiling.record_compile("state_checkpoint_store")
+            self._ckpt_store_jit = jax.jit(store, donate_argnums=(1,))
+        return self._ckpt_store_jit
+
+    def _get_ckpt_load(self):
+        """(caches, pool, slot, entry) -> caches with the entry copied into
+        the slot's state of every recurrent layer (the caches are donated)."""
+        if self._ckpt_load_jit is None:
+            layers = self._state_layers()
+
+            def load(caches, pool, slot, entry):
+                out = list(caches)
+                for pl, i in zip(pool, layers):
+                    out[i] = tuple(x.at[slot].set(p[entry])
+                                   for p, x in zip(pl, caches[i]))
+                return out
+
+            _profiling.record_compile("state_checkpoint_load")
+            self._ckpt_load_jit = jax.jit(load, donate_argnums=(0,))
+        return self._ckpt_load_jit
+
+    def _ckpt_event(self, event, n=1):
+        self._ckpt_events[event] += n
+        _M_STATE_CKPT.labels(event=event).inc(n)
+
+    def _ckpt_reclaim(self):
+        """Entries whose node the index dropped go back to the pool."""
+        if self._ckpt is None or not self._prefix.released:
+            return
+        self._ckpt_event("evicted", len(self._prefix.released))
+        self._ckpt_free.extend(self._prefix.released)
+        self._prefix.released.clear()
+
+    def _ckpt_store(self, slot, req):
+        """After the last chunk of a prompt that ends on a page boundary:
+        save the slot's state and hang it on that page's node, so a later
+        prompt with this prefix resumes here.  A full pool gives up its
+        least recently used entry."""
+        key = self._prefix.checkpoint_node(req.prompt, adapter_id=req.adapter_id)
+        if key is None:
+            return
+        if not self._ckpt_free:
+            entry = self._prefix.steal_checkpoint()
+            if entry is None:
+                return
+            self._ckpt_event("evicted")
+        else:
+            entry = self._ckpt_free.pop()
+        self._ckpt = self._get_ckpt_store()(
+            self.caches, self._ckpt, np.int32(slot), np.int32(entry))
+        self._prefix.attach_checkpoint(key, entry)
+        self._prefix_epoch += 1  # what match() returns has changed
+        self._ckpt_event("stored")
 
     def _cow_page(self, slot, idx):
         """Copy-on-write guard for a slot about to WRITE rows of its
@@ -1795,6 +1986,7 @@ class LLMEngine:
             _M_PREFIX_EVICT.inc()
             self._prefix_evictions += 1
             self._prefix_epoch += 1
+            self._ckpt_reclaim()
             return True
         return False
 
@@ -2216,13 +2408,12 @@ class LLMEngine:
                     raw, aux = _from_model_caches(kinds, new_caches)
             finally:
                 restore()
-            if aux:
-                return logits._value, raw, \
-                    slot_and_acc[1].at[1].add(jnp.stack(aux))
-            return logits._value, raw
+            # the layers' counts go to the accumulators' prefill plane
+            return (logits._value, raw) + tuple(
+                a.at[1].add(x) for a, x in zip(slot_and_acc[1:], aux))
 
-        return jax.jit(llm_prefill_chunk, donate_argnums=(
-            (2, 10) if self._moe_acc is not None else (2,)))
+        return jax.jit(llm_prefill_chunk, donate_argnums=(2,) + tuple(
+            range(10, 10 + len(self._accs()))))
 
     def _get_chunk_prefill(self):
         if self._chunk_jit is None:
@@ -2230,21 +2421,29 @@ class LLMEngine:
             self._chunk_jit = self._chunk_prefill_fn()
         return self._chunk_jit
 
+    def _accs(self):
+        """The device accumulators the programs add to and hand back, in
+        their fixed order: the expert layers' pairs, the block-sparse
+        layers' blocks (each only with such layers)."""
+        return tuple(a for a in (self._moe_acc, self._sparse_acc)
+                     if a is not None)
+
     def _chunk_extra(self, slot):
         """The chunk program's trailing arguments for a model that declares
-        its layers' cache kinds: the slot, and the expert accumulator."""
+        its layers' cache kinds: the slot, and the accumulators."""
         if self._cache_kinds is None:
             return ()
-        if self._moe_acc is None:
-            return (np.int32(slot),)
-        return (np.int32(slot), self._moe_acc)
+        return (np.int32(slot),) + self._accs()
 
     def _took(self, out):
-        """Keep what a decode or chunk program hands back — the caches and,
-        with expert layers, the accumulator; returns its first result."""
+        """Keep what a decode or chunk program hands back — the caches and
+        the accumulators; returns its first result."""
         first, self.caches = out[:2]
+        rest = list(out[2:])
         if self._moe_acc is not None:
-            self._moe_acc = out[2]
+            self._moe_acc = rest.pop(0)
+        if self._sparse_acc is not None:
+            self._sparse_acc = rest.pop(0)
         return first
 
     def _moe_dispatched(self, program, rows, calls):
@@ -2278,6 +2477,17 @@ class LLMEngine:
         self._moe_pending[:] = 0
         self._moe_max_load = int(delta[0, :, :-1].max())
         _M_MOE_MAX_LOAD.set(self._moe_max_load)
+
+    def _publish_sparse(self, total):
+        """The block-sparse layers' accumulator as the decode tick brought
+        it back, flat: publishes what both programs added since."""
+        total = total.astype(np.uint32).reshape(self._sparse_seen.shape)
+        delta = (total - self._sparse_seen).astype(np.int64)  # wraps like int32
+        self._sparse_seen = total
+        for i, prog in enumerate(_MOE_PROGRAMS):
+            for f, d in zip(_SPARSE_FIELDS, delta[i]):
+                self._sparse_stats[prog][f] += int(d)
+            _M_SPARSE_SELECTED.labels(program=prog).inc(int(delta[i, 2]))
 
     def _admit_paged(self):
         """Chunked-prefill admission: at most ONE prompt chunk per tick, so
@@ -2464,6 +2674,14 @@ class LLMEngine:
                 else:
                     self._open_admission_span(req, slot,
                                               cached_tokens=int(matched))
+                if matched and self._ckpt is not None:
+                    # the state at the shared prefix's end, in place of the
+                    # zeroing a fresh request gets (its first chunk runs at
+                    # off = matched > 0, so SlotRows.fresh is false)
+                    self.caches = self._get_ckpt_load()(
+                        self.caches, self._ckpt, np.int32(slot),
+                        np.int32(self._prefix.checkpoint_of(shared[-1])))
+                    self._ckpt_event("restored")
                 # chunked prefill starts at the first UNCACHED token — a
                 # hit skips every chunk the cache already covers
                 self._prefilling = (req, slot, matched)
@@ -2582,9 +2800,12 @@ class LLMEngine:
         # the slot's pages now hold the whole prompt's kv: index the full
         # blocks + partial tail so CONCURRENT same-prefix requests hit
         # (insert precedes the first decode write, whose COW check then
-        # sees the tail page as shared and forks it)
+        # sees the tail page as shared and forks it; a stateful index takes
+        # whole-page prompts alone, which a checkpoint then follows)
         self._cache_insert(slot, req.prompt, trace_id=req.trace.trace_id,
                            adapter_id=req.adapter_id)
+        if self._ckpt is not None:
+            self._ckpt_store(slot, req)
         # the tick's first wait for the device: the chunk (and whatever
         # was queued before it) must finish before its logits can be read
         pc.switch("first_token_sync")
@@ -2646,9 +2867,18 @@ class LLMEngine:
                 # the COW fork program too: a warm engine's first
                 # shared-prefix fork must not compile (and must not trip
                 # recompile_storm).  A trash-page self-copy is harmless.
-                # (A model with recurrent state shares no page: no fork.)
+                # (A model with recurrent state shares whole pages only,
+                # and writes to none of them: no fork.)
                 self.caches = self._get_cow_copy()(
                     self.caches, np.int32(0), np.int32(0))
+            if self._ckpt is not None:
+                # the checkpoint copies, both ways, on entry 0 and slot 0:
+                # the entry is free and the slot idle, so what they move is
+                # never read (a request's first chunk zeroes its state)
+                self._ckpt = self._get_ckpt_store()(
+                    self.caches, self._ckpt, np.int32(0), np.int32(0))
+                self.caches = self._get_ckpt_load()(
+                    self.caches, self._ckpt, np.int32(0), np.int32(0))
             eff = max(1, min(self.decode_chunk, self.L - 1))
             B = self.n_slots
             tokens = np.full((B, 1), self.pad, np.int32)
@@ -2656,10 +2886,9 @@ class LLMEngine:
             knobs = self._sampling_knobs()  # idle engine: all greedy
             rng = (_fr.default_generator().key, np.uint32(0))
             lora = self._lora_args([0] * B)
-            moe = () if self._moe_acc is None else (self._moe_acc,)
             self._took(self._get_decode(eff)(
                 *self._cache_args(), tokens, pos, *knobs,
-                self._mask_all_true, *rng, *lora, *moe))
+                self._mask_all_true, *rng, *lora, *self._accs()))
             if self.spec_k:
                 _, _, self.caches = self._get_verify()(
                     *self._cache_args(), tokens,
@@ -2779,7 +3008,7 @@ class LLMEngine:
 
         def llm_decode(params, buffers, caches, page_tbl, tokens, pos,
                        do_sample, temperature, top_k, top_p, token_mask,
-                       base_key, offset, lora_tree, lora_rows, *moe_acc):
+                       base_key, offset, lora_tree, lora_rows, *accs):
             keys = jax.random.split(jax.random.fold_in(base_key, offset), eff)
             # the tick masks the table rows of idle and mid-prefill
             # slots to the trash page: such a row is computed like the
@@ -2798,23 +3027,23 @@ class LLMEngine:
                         nxt = _select_rows(
                             logits._value[:, -1], key, do_sample, temperature,
                             top_k, top_p, token_mask=token_mask)
-                        acc = tuple(a.at[0].add(jnp.stack(aux))
-                                    for a in carry[3:])
+                        acc = tuple(a.at[0].add(x)
+                                    for a, x in zip(carry[3:], aux))
                         return (raw, nxt[:, None], p + 1) + acc, nxt
 
                     carry, toks = jax.lax.scan(
-                        tick, (caches, tokens, pos) + moe_acc, keys)
+                        tick, (caches, tokens, pos) + accs, keys)
             finally:
                 restore()
-            if moe_acc:
-                # the pair counts ride home with the tokens: one array
-                return jnp.concatenate(
-                    [toks.T.reshape(-1), carry[3].reshape(-1)]), \
-                    carry[0], carry[3]
+            if accs:
+                # the layers' counts ride home with the tokens: one array
+                return (jnp.concatenate(
+                    [toks.T.reshape(-1)] + [a.reshape(-1) for a in carry[3:]]),
+                    carry[0]) + tuple(carry[3:])
             return toks.T, carry[0]  # [B, chunk]
 
-        return jax.jit(llm_decode, donate_argnums=(
-            (2, 15) if self._moe_acc is not None else (2,)))
+        return jax.jit(llm_decode, donate_argnums=(2,) + tuple(
+            range(15, 15 + len(self._accs()))))
 
     def _verify_fn(self):
         """ONE compiled speculative verify: score K drafts + one bonus
@@ -2955,15 +3184,16 @@ class LLMEngine:
                 token_mask, *rng, *self._lora_args(
                     [r.adapter_page if r is not None else 0 for r in reqs]))
         moe = self._moe_acc is not None
+        accs = self._accs()
+        args += accs
         if moe:
-            args += (self._moe_acc,)
             self._moe_dispatched("decode", len(active), eff)
         pc.switch("decode_dispatch")
         nxt_dev = self._took(jit(*args))
         pc.switch("decode_sync")
         nxt = np.asarray(nxt_dev).astype(np.int32)  # [B, eff]
-        if moe:
-            # the expert layers' pair counts came in the same array
+        if accs:
+            # the layers' counts came in the same array, in _accs() order
             counts = nxt[self.n_slots * eff:]
             nxt = nxt[:self.n_slots * eff].reshape(self.n_slots, eff)
         # every token of this tick carries this stamp: the instant the
@@ -2976,7 +3206,9 @@ class LLMEngine:
         self._goodput.carve("decode", t_end - t_dec)
         now_pc = t_end or time.perf_counter()  # the clock is off: read it
         if moe:
-            self._publish_moe(counts)
+            self._publish_moe(counts[:self._moe_seen.size])
+        if self._sparse_acc is not None:
+            self._publish_sparse(counts[-self._sparse_seen.size:])
         emitted = 0
         for j in range(eff):
             for i in list(active):

@@ -25,9 +25,25 @@ This module is the index that finds the shareable pages:
   nodes are never evicted from under a live chain (a matched chain pins its
   pages via slot refcounts, so its nodes never satisfy the predicate).
 
+- **State checkpoints** (``stateful=True``: a model some of whose layers
+  keep recurrent state a sequence, not keys and values a token).  A shared
+  page says nothing of that state at its end, so a prefix can only be
+  resumed at a node that carries a CHECKPOINT: an entry of the engine's
+  fixed pool of saved states, hung on the node of the page the saved
+  sequence ended with (``attach_checkpoint``).  ``match`` is then cut back
+  to the deepest matched node that carries one, and ``insert`` indexes only
+  a prompt that ends on a page boundary: a checkpoint is stored only where a
+  prompt ends, so the pages of any other prompt could never be resumed at
+  and would only hold pool pages until evicted (a state cannot be resumed
+  inside a page either, so partial tails are neither indexed nor matched).
+  An entry goes back to the engine (``released``) when its node is removed
+  or when ``steal_checkpoint`` takes the least recently used one for a newer
+  prefix.
+
 The index owns NO device memory and NO refcounts: it returns/accepts page
-ids and the engine's allocator does the incref/decref — which keeps this
-class a plain deterministic data structure that unit-tests stand alone.
+ids (and checkpoint entries) and the engine's allocator does the
+incref/decref — which keeps this class a plain deterministic data structure
+that unit-tests stand alone.
 """
 from __future__ import annotations
 
@@ -106,7 +122,7 @@ def prefix_key(prompt, page_size, blocks=None, adapter_id=None):
 
 class _Node:
     __slots__ = ("key", "parent", "page", "ntok", "tokens", "nchildren",
-                 "last_used")
+                 "last_used", "ckpt")
 
     def __init__(self, key, parent, page, ntok, tokens):
         self.key = key
@@ -116,16 +132,21 @@ class _Node:
         self.tokens = tokens  # None for full blocks; np.int32 for partials
         self.nchildren = 0
         self.last_used = 0
+        self.ckpt = None  # entry of the engine's state-checkpoint pool
 
 
 class PrefixCache:
     """Trie of cached prompt-prefix pages, keyed by chained block hashes."""
 
-    def __init__(self, page_size):
+    def __init__(self, page_size, stateful=False):
         self.ps = int(page_size)
+        self.stateful = bool(stateful)
         self._nodes: dict[bytes, _Node] = {}
         self._partials: dict[bytes, set[bytes]] = {}  # parent -> partial keys
         self._tick = 0  # LRU clock: bumped on every touch, no wall time
+        self._ckpt_by_page: dict[int, int] = {}  # page -> checkpoint entry
+        #: checkpoint entries whose node went: the engine drains this list
+        self.released: list[int] = []
 
     def __len__(self):
         return len(self._nodes)
@@ -153,11 +174,14 @@ class PrefixCache:
         always be recomputed.  Returns ``(matched_tokens, pages)`` where
         ``pages`` covers page indices ``0 .. len(pages)-1`` of the slot's
         table (the last page is partially valid when ``matched_tokens`` is
-        off the page grid).  Touches every matched node for LRU.
+        off the page grid).  Touches every matched node for LRU.  A
+        stateful index returns the prefix up to the deepest matched node
+        with a state checkpoint (``checkpoint_of(pages[-1])``), or nothing.
         """
         prompt = np.asarray(prompt, np.int32)
         usable = prompt.size - 1
         key, matched, pages = _root_key(adapter_id), 0, []
+        resumable = 0
         while matched + self.ps <= usable:
             k = self._child_key(key, prompt[matched:matched + self.ps]
                                 .tobytes())
@@ -168,6 +192,10 @@ class PrefixCache:
             pages.append(node.page)
             matched += self.ps
             key = k
+            if node.ckpt is not None:
+                resumable = len(pages)
+        if self.stateful:
+            return resumable * self.ps, pages[:resumable]
         best, best_t = None, 0
         # sorted: set order varies with hash randomization, and an
         # equal-overlap tie must pick the same node in every process
@@ -195,10 +223,13 @@ class PrefixCache:
         engine's slot layout.  Blocks already cached are only touched (the
         slot keeps its private duplicate; it frees on finish).  Returns the
         pages NEWLY held by the index — the caller increfs each, which is
-        what keeps them alive after the slot releases.
+        what keeps them alive after the slot releases.  A stateful index
+        takes only a prompt a checkpoint can follow (whole pages).
         """
         prompt = np.asarray(prompt, np.int32)
         n = prompt.size
+        if self.stateful and n % self.ps:
+            return []
         key, new_holds = _root_key(adapter_id), []
         full = n // self.ps
         for i in range(full):
@@ -282,6 +313,46 @@ class PrefixCache:
                 return node.key, node.tokens, node.page, node.ntok
         return None
 
+    # -------------------------------------------------- state checkpoints
+
+    def checkpoint_of(self, page):
+        """The checkpoint entry hung on the node that holds ``page``."""
+        return self._ckpt_by_page.get(int(page))
+
+    def checkpoint_node(self, prompt, adapter_id=None):
+        """Key of the node a checkpoint of the state after ALL of ``prompt``
+        belongs to — the node of its last page — or None: the prompt ends
+        inside a page, the node is not indexed, or it carries one already."""
+        prompt = np.asarray(prompt, np.int32)
+        if prompt.size == 0 or prompt.size % self.ps:
+            return None
+        key = _root_key(adapter_id)
+        for i in range(prompt.size // self.ps):
+            key = self._child_key(key, prompt[i * self.ps:(i + 1) * self.ps]
+                                  .tobytes())
+        node = self._nodes.get(key)
+        return key if node is not None and node.ckpt is None else None
+
+    def attach_checkpoint(self, key, entry):
+        node = self._nodes[key]
+        node.ckpt = int(entry)
+        self._ckpt_by_page[node.page] = node.ckpt
+
+    def steal_checkpoint(self):
+        """Take the checkpoint off the least recently used node that has
+        one (its pages stay indexed, they only stop being resumable there);
+        returns the entry, or None when no node carries one."""
+        best = None
+        for node in self._nodes.values():
+            if node.ckpt is not None \
+                    and (best is None or node.last_used < best.last_used):
+                best = node
+        if best is None:
+            return None
+        entry, best.ckpt = best.ckpt, None
+        del self._ckpt_by_page[best.page]
+        return entry
+
     # ------------------------------------------------- hierarchical tiers
 
     def node_info(self, key):
@@ -324,6 +395,10 @@ class PrefixCache:
 
     def _remove(self, node):
         del self._nodes[node.key]
+        if node.ckpt is not None:
+            self.released.append(node.ckpt)
+            del self._ckpt_by_page[node.page]
+            node.ckpt = None
         if node.tokens is not None:
             siblings = self._partials.get(node.parent)
             if siblings is not None:

@@ -75,7 +75,13 @@ class CacheKind:
     without that method keeps a pair of K/V page pools in every layer.
 
       "paged_kv"   page pools [pages, kv_heads, page_size, head_dim]; a
-                   token's rows are found through its slot's page table
+                   token's rows are found through its slot's page table.
+                   With ``compressed`` = s > 0 the layer keeps a THIRD pool
+                   [pages, kv_heads, page_size / s, head_dim] (the keys a
+                   block selection scores, one every s tokens, each in the
+                   page of its last token: ops/sparse_attention.py), takes a
+                   ``SparsePaged`` and reports, per call, its query calls
+                   and the context's and the selected blocks ([3] int32)
       "recurrent"  fixed-size state a SLOT, not a page: ``state`` lists
                    (name, shape of one slot's, dtype)
       "none"       nothing (a feed-forward or expert layer); with
@@ -89,6 +95,7 @@ class CacheKind:
     state: tuple = ()
     experts_held: int = 0
     top_k: int = 0
+    compressed: int = 0
 
 
 class SlotRows(NamedTuple):
@@ -102,6 +109,20 @@ class SlotRows(NamedTuple):
                   # engine's decode program has no other word for "this row
                   # is idle": it reads 0 where the tick masked the row's page
                   # table to the trash page (page_tbl[:, 0] == 0), 1 elsewhere
+    pos: Any = None  # int32 [b]: the position of each row's first token (a
+                     # state layer that rotates by position has no page
+                     # tuple to read it from)
+
+
+class SparsePaged(NamedTuple):
+    """What a "paged_kv" layer with ``compressed`` is handed: its three
+    pools, where the batch's rows stand, and the rows themselves."""
+    k: Any         # [pages, kv_heads, page_size, head_dim]
+    v: Any
+    ck: Any        # [pages, kv_heads, page_size / compressed, head_dim]
+    pos: Any       # int32 [b]
+    page_tbl: Any  # int32 [b, max_pages]
+    rows: Any      # SlotRows
 
 
 def _quantize_kv(kv):

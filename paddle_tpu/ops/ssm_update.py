@@ -144,12 +144,13 @@ def _state_kernel(s_ref, xdt_ref, da_ref, b_ref, c_ref, o_ref, y_ref, *, groups)
         jax.lax.fori_loop(0, per, row, 0)
 
 
-@functools.partial(jax.jit, static_argnames="interpret")
-def _state_pallas(state, xdt, da, bm, cm, interpret):
+@functools.partial(jax.jit, static_argnames=("interpret", "name"))
+def _state_pallas(state, xdt, da, bm, cm, interpret, name="ssm_update"):
     """state [B, R, N, L]; xdt, da [B, R, L]; bm, cm [B, G, N].  A jit of
     its own, so a program that calls it once a layer traces and lowers the
     kernel once, not once a layer (every process pays that in `warmup()`,
-    compile cache or not)."""
+    compile cache or not).  `name` is the kernel's, hence its device
+    events': another recurrence of this form passes its own."""
     B, R, N, L = state.shape
     G = bm.shape[1]
     bm, cm = jnp.swapaxes(bm, 1, 2), jnp.swapaxes(cm, 1, 2)  # n on the sublanes
@@ -167,7 +168,7 @@ def _state_pallas(state, xdt, da, bm, cm, interpret):
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
-        name="ssm_update",
+        name=name,
     )(state, xdt, da, bm, cm)
 
 
@@ -186,6 +187,20 @@ def kernel_ok(state, groups):
     _, R, N, L = state.shape
     return (state.dtype == jnp.float32 and L % 128 == 0 and N % _SUB == 0
             and R % groups == 0)
+
+
+def state_pass(state, xdt, da, bm, cm, name="ssm_update", use_kernel=None,
+               interpret=None):
+    """S <- da S + xdt (x) B, y = S C over every slot, by the kernel where
+    the shape is its tiling's and in plain jnp elsewhere.  Arguments as
+    `_state_pallas`; returns (new state, y float32 [B, R, L])."""
+    if use_kernel is None:
+        use_kernel = kernel_ok(state, bm.shape[1])
+    if not use_kernel:
+        return _state_dense(state, xdt, da, bm, cm)
+    if interpret is None:
+        interpret = _interpret_default()
+    return _state_pallas(state, xdt, da, bm, cm, interpret, name)
 
 
 def ssm_update(ssm_state, conv_state, xbc, dt, *, conv_weight, conv_bias,
@@ -209,14 +224,8 @@ def ssm_update(ssm_state, conv_state, xbc, dt, *, conv_weight, conv_bias,
         # heads side by side on a row's lanes, as the state keeps them
         da = jnp.broadcast_to(jnp.exp(dtv * a)[:, :, None], (B, H, P)).reshape(B, R, L)
         xdt = (x * dtv[:, :, None]).reshape(B, R, L)
-        if use_kernel is None:
-            use_kernel = kernel_ok(ssm_state, groups)
-        if use_kernel:
-            if interpret is None:
-                interpret = _interpret_default()
-            s_new, y = _state_pallas(ssm_state, xdt, da, bm, cm, interpret)
-        else:
-            s_new, y = _state_dense(ssm_state, xdt, da, bm, cm)
+        s_new, y = state_pass(ssm_state, xdt, da, bm, cm,
+                              use_kernel=use_kernel, interpret=interpret)
         y = y.reshape(B, H, P) + d_skip.astype(jnp.float32)[None, :, None] * x
     return y.reshape(B, H * P), s_new, conv_new
 

@@ -287,6 +287,32 @@ def test_aot_hybrid_kernels_at_published_widths(v5e_devices, as_on_tpu):
 
 
 @pytest.mark.slow
+def test_aot_sparse_and_lightning_kernels_at_published_widths(v5e_devices, as_on_tpu):
+    """MiniCPM-SALA's widths at the cell's sizes: the block-sparse decode pass
+    (64 slots, 2 K/V heads of 16 query heads, lists of 128 blocks, pages of
+    two blocks) and the lightning state pass (32 heads of 128 x 128 float32:
+    PR 30's kernel with one "group" a head, under this layer's name)."""
+    from paddle_tpu.ops import lightning_attention as la
+    from paddle_tpu.ops import sparse_attention as sa
+
+    one = _one_device(v5e_devices)
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    B, Hq, H, D, P, M = 64, 32, 2, 128, 1681, 262
+    spec = sa.SparseSpec()
+    calls = _mosaic_calls(
+        lambda q, k, v, tbl, idx, cnt, n: sa.sparse_paged_attention(
+            q, k, v, tbl, idx, cnt, n, spec),
+        one, ((B, Hq, D), bf), ((P, H, 128, D), bf), ((P, H, 128, D), bf),
+        ((B, M), i32), ((B, H, spec.list_len), i32), ((B,), i32), ((B,), i32))
+    assert sum("sparse_paged_attention" in c for c in calls) == 1, calls
+    calls = _mosaic_calls(
+        lambda s, q, k, v, ok: la.lightning_update(s, q, k, v, la.log_decay(32), ok),
+        one, ((B, 32, D, D), f32), ((B, 32, D), bf), ((B, 32, D), bf),
+        ((B, 32, D), bf), ((B,), jnp.bool_))
+    assert sum("lightning_update" in c for c in calls) == 1, calls
+
+
+@pytest.mark.slow
 def test_aot_sampler_keeps_its_conditionals_at_a_64k_vocabulary(v5e_devices):
     """The v5e's compiler leaves the fused sampler's two conditionals in the
     decode scan, with the ONE sort of `[64, 65536]` inside the inner branch:
@@ -331,6 +357,7 @@ def test_smoke_phases_at_tiny_size_on_cpu(smoke, monkeypatch):
                              max_position_embeddings=512, **tiny)
     assert set(smoke.kernel_phase(**geom)) == {
         "S1_bf16", "S1_int8", "S256_bf16", "S256_int8", "S5_bf16", "S5_int8"}
+    assert smoke.state_phase(hidden=128) < 0.05
     losses = smoke.train_phase(n_layers=2, batch=4, seq=128, **tiny)
     assert smoke.mesh_phase(losses[0], n_layers=2, batch=4, seq=128,
                             **tiny) is not None

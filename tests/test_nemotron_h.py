@@ -86,7 +86,7 @@ def test_engine_prefill_in_three_chunks_then_decode_matches_the_reference(monkey
     assert st["cache_kinds"]["none"] == {"layers": 2, "bytes": 0}
     assert st["recurrent_state"] == {
         "bytes": 2 * 3 * (4 * 8 * 16 * 4 + 3 * (32 + 2 * 2 * 16) * 4),
-        "slots": 3, "layers": 2}
+        "slots": 3, "layers": 2, "checkpoints": None}  # no prefix cache: no pool
     # 40 prompt tokens and 11 decode rows through 2 expert layers, 2 of 8 each
     moe = st["moe"]
     assert moe["prefill"]["layer_calls"] == 3 * 2 and moe["decode"]["layer_calls"] == 11 * 2
@@ -335,14 +335,26 @@ def test_moe_experts_kernel_and_fallback_match_a_loop_over_pairs(T, K, E, held, 
 
 # (e) ------------------------------------------------------------------------
 @pytest.mark.parametrize("kw,word", [
-    (dict(prefix_cache=True), "prefix_cache"),
+    (dict(prefix_cache=True), None),
     (dict(host_cache_pages=4), "host_cache_pages"),
     (dict(spec_k=2), "spec_k"),
     (dict(kv_layout="dense"), "kv_layout='dense' was removed"),
 ], ids=["prefix_cache", "host_cache_pages", "spec_k", "dense_layout"])
 def test_what_recurrent_state_cannot_have_is_refused(kw, word):
+    """`prefix_cache=True` was refused until state could be checkpointed at a
+    prefix's end (PR 31): it now serves, a second prompt with the first's
+    pages resuming from the first's checkpoint (tests/test_minicpm_sala.py
+    holds its tokens to a cold engine's)."""
     model = serve_hybrid.build_model(tiny_cfg())
     args = {**ENGINE, "kv_layout": "paged", **kw}
+    if word is None:
+        eng = LLMEngine(model, **args)
+        eng.generate(prompt(32, 1), max_new_tokens=2)
+        eng.generate(np.concatenate([prompt(32, 1), prompt(5, 2)]), max_new_tokens=2)
+        st = eng.stats()
+        assert st["prefix_cache"]["hit_tokens"] == 32
+        assert st["recurrent_state"]["checkpoints"]["restored"] == 1
+        return
     with pytest.raises(ValueError, match=word):
         LLMEngine(model, **args)
 
