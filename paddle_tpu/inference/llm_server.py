@@ -51,8 +51,18 @@ greedy output.  Rollback rides the existing machinery: the slot position
 stops at the accept point, rejected rows are overwritten before any read,
 and pages past the accept point decref back to the pool each tick.
 
+The pump keeps ONE DECODE PROGRAM IN FLIGHT: a tick dispatches its decode
+program before it reads the previous tick's result, whose tokens feed it on
+the device, and books that result (emit, stamp, finish) while the device
+runs the new one — the host's work hides under the device's.  Where the
+next program needs what the host has not read (a constrained row, a
+drafter, a preemption) the tick reads first and is synchronous
+(`_decode_tick`; `stats()["tick_pipeline"]`).  Tokens are the synchronous
+pump's, token for token.
+
 The engine is deterministic and thread-free by default (`step()` pumps one
-decode tick; `run_until_complete()` drains); `start()` spawns the
+tick, after which a decode result is as a rule still in flight;
+`run_until_complete()` drains, leaving none); `start()` spawns the
 background pump for server use.
 
 Numerics: per-request outputs are exactly the solo `generate()` tokens in
@@ -257,9 +267,12 @@ _TICK_PHASES = (
     "first_token_sync",  # np.asarray(logits) + host select + activation
     "decode_stage",      # page growth, uploads, rng split, knobs, masks
     "decode_dispatch",   # the decode program's call, until it returns
-    "decode_sync",       # np.asarray(nxt_dev): the wait for the device
+    "decode_sync",       # any read of a decode result in flight: as a rule
+                         # the PREVIOUS program's, while this tick's runs
     "bookkeep",          # gauges, token loop, _finish, span flushes
     "spec_draft", "spec_stage", "spec_dispatch", "spec_sync", "spec_accept")
+#: the phases the goodput ledger's productive decode seconds are made of
+_DECODE_PHASES = ("decode_stage", "decode_dispatch", "decode_sync")
 #: phases in which the host only waits for the device; every other phase
 #: is the host's own work (stats()["tick_phases"]["host_s"])
 _SYNC_PHASES = ("first_token_sync", "decode_sync", "spec_sync")
@@ -269,6 +282,16 @@ _M_TICK_PHASE_S = _obs.counter(
     "llm_decode_tick_duration_seconds' sum)", labelnames=("phase",))
 _TICK_PHASE_SERIES = {p: _M_TICK_PHASE_S.labels(phase=p)
                       for p in _TICK_PHASES}
+#: why the pump read a decode result with no later program queued behind it
+#: (_decode_tick): what the next program needs of the host, or nothing to run
+_DRAIN_REASONS = ("constrained", "spec", "preempt", "idle", "stop", "warmup")
+_M_TICK_PIPELINE = _obs.counter(
+    "llm_tick_pipeline_ticks_total",
+    "Decode results the pump read, by whether the next decode program was "
+    "already dispatched (overlapped) or why it was not (the drain's reason)",
+    labelnames=("mode",))
+_PIPELINE_SERIES = {m: _M_TICK_PIPELINE.labels(mode=m)
+                    for m in ("overlapped",) + _DRAIN_REASONS}
 #: why a tick left the queue head waiting: exactly one reason a tick
 _BLOCK_REASONS = ("prefill_busy", "no_slot", "no_pages", "no_adapter_page")
 _M_ADM_BLOCKED = _obs.counter(
@@ -408,6 +431,18 @@ class _Request:
                                     # state SURVIVES preemption requeues
                                     # (the regrown prompt's generated tail
                                     # was already consumed token by token)
+
+
+@dataclass
+class _InFlight:
+    """A dispatched decode program whose result the host has not read."""
+    out: object    # its first result on the device: the tokens [B, eff],
+                   # flat with the layers' counts behind them where
+                   # the engine keeps accumulators
+    eff: int       # tokens a row
+    rows: list     # [(slot, request)] of the rows it carried
+    moe: object    # the (pairs, layer calls) dispatched since the program
+                   # before it, this one included: what its counts cover
 
 
 def _select_rows(logits, key, do_sample, temperature, top_k, top_p,
@@ -894,6 +929,18 @@ class LLMEngine:
         self.slot_pos = np.zeros(B, np.int32)       # valid tokens per slot
         self.slot_req: list[_Request | None] = [None] * B
         self.last_token = np.full(B, self.pad, np.int32)
+        # ---- the pump keeps ONE decode program in flight (_decode_tick):
+        # what it dispatched and has not read, the tokens a slot has in it
+        # (slot_pos and last_token are what the host has BOOKED), and the
+        # last tokens of the newest program, which stay on the device and
+        # feed the next one
+        self._inflight: _InFlight | None = None
+        self._ahead = np.zeros(B, np.int32)
+        self._feed0 = self._feed = jnp.zeros((B,), jnp.int32)
+        self._chunk_out = None  # a final chunk's (request, slot, logits)
+        # until the tick reads them (_first_token)
+        self._pipeline = {"overlapped": 0, "surplus_tokens": 0,
+                          "drained": dict.fromkeys(_DRAIN_REASONS, 0)}
         self.max_queue_len = None if max_queue_len is None \
             else int(max_queue_len)
         self._clock = clock if clock is not None else time.monotonic
@@ -1268,11 +1315,18 @@ class LLMEngine:
         return fut.result()
 
     def run_until_complete(self):
-        """Pump decode ticks until the queue and all slots drain."""
-        while not self._pending.empty() \
-                or any(r is not None for r in self.slot_req) \
-                or self._prefilling is not None:
+        """Pump decode ticks until the queue and all slots drain and no
+        decode result is left in flight."""
+        while self._busy():
             self.step()
+
+    def _busy(self):
+        """Something is queued, mid-prefill, decoding, or dispatched and not
+        read yet (a program whose every row has ended still has to be read:
+        its counts, and the tokens it ran past an EOS)."""
+        return (not self._pending.empty() or self._prefilling is not None
+                or self._inflight is not None
+                or any(r is not None for r in self.slot_req))
 
     @staticmethod
     def _hist_summary(hist):
@@ -1388,6 +1442,12 @@ class LLMEngine:
                 "host_s": sum(v for p, v in self._phases.seconds.items()
                               if p not in _SYNC_PHASES),
             },
+            # the decode pipeline: results read with the next program
+            # already dispatched, results read without (by why), and tokens
+            # a program computed for a row that had ended before they were
+            # read (an EOS one program late, an expiry)
+            "tick_pipeline": dict(
+                self._pipeline, drained=dict(self._pipeline["drained"])),
             "prefill_in_progress": self._prefilling is not None,
             "pump_alive": self._thread.is_alive()
             if self._thread is not None else False,
@@ -1496,10 +1556,8 @@ class LLMEngine:
 
     def _drained(self):
         """True when nothing is queued, in the pump's hands mid-admission,
-        mid-prefill, or decoding."""
-        return (self._adm_inflight == 0 and self._pending.empty()
-                and self._prefilling is None
-                and all(r is None for r in self.slot_req))
+        mid-prefill, decoding, or dispatched and unread."""
+        return self._adm_inflight == 0 and not self._busy()
 
     def drain(self, timeout=None, deadline_s=None):
         """Graceful drain — the zero-loss half of a rolling restart.
@@ -1567,8 +1625,7 @@ class LLMEngine:
         try:
             while not self._stop:
                 self._pump_heartbeat = time.monotonic()
-                if self._pending.empty() and self._prefilling is None \
-                        and all(r is None for r in self.slot_req):
+                if not self._busy():
                     time.sleep(0.002)
                     continue
                 self.step()
@@ -1613,6 +1670,14 @@ class LLMEngine:
         were failed."""
         with self._lock:
             n = self._drain_queue(exc)
+            if self._inflight is not None:
+                # dropped unread (the device may be what failed); its
+                # counts stay in the device's totals and come home with the
+                # next program's
+                self._inflight = None
+                self._count_drain("stop")
+            self._feed = self._feed0
+            self._chunk_out = None
             if self._prefilling is not None:
                 req, slot, _ = self._prefilling
                 self._prefilling = None
@@ -1623,10 +1688,7 @@ class LLMEngine:
                 n += 1
             for i, req in enumerate(self.slot_req):
                 if req is not None:
-                    self.slot_req[i] = None
-                    self.last_token[i] = self.pad
-                    self._release_pages(i)
-                    self._release_adapter(req)
+                    self._vacate(i)
                     _fail_future(req.future, exc)
                     self._end_trace(req, "error", error=repr(exc))
                     n += 1
@@ -1794,6 +1856,23 @@ class LLMEngine:
             self._decref(page)
         self._slot_pages[slot] = []
         self._pt_host[slot, :] = 0
+
+    def _vacate(self, slot):
+        """The slot's request leaves it (finish, expiry, preemption, stop):
+        its pages and its adapter page go back; returns the request.  A
+        decode program already dispatched may still write the row's next
+        token into one of those pages.  That is sound by device order:
+        whoever is handed the page next writes it in a LATER program, and no
+        page that others read is ever written (_cow_page).  The token itself
+        is dropped where the program is read (_book): the slot no longer
+        holds the request."""
+        req = self.slot_req[slot]
+        self.slot_req[slot] = None
+        self.last_token[slot] = self.pad
+        self._ahead[slot] = 0
+        self._release_pages(slot)
+        self._release_adapter(req)
+        return req
 
     def _alloc_pages(self, slot, n):
         """Move n pages from the free list into a slot's table (refcount 1:
@@ -2305,12 +2384,8 @@ class LLMEngine:
         instead of looping forever.  ``origin`` labels the recompute
         counter: ``"verify"`` when the pool ran dry growing the K+1
         verify ladder (mid-verify requeue), ``"decode"`` otherwise."""
-        req = self.slot_req[slot]
-        self.slot_req[slot] = None
-        self.last_token[slot] = self.pad
         held = len(self._slot_pages[slot])
-        self._release_pages(slot)
-        self._release_adapter(req)
+        req = self._vacate(slot)
         _M_PAGE_PREEMPT.inc()
         _flight.record_event("page_preemption", slot=int(slot),
                              pages_held=int(held),
@@ -2354,11 +2429,16 @@ class LLMEngine:
         """Grow each active slot's page table to cover the rows this tick
         will write (pos .. pos+eff-1), COW-forking any of those pages that
         are shared; preempt slots the pool cannot cover.  Returns the
-        surviving active list."""
+        surviving active list — or None, having preempted nothing, where a
+        slot cannot be covered while a decode result is in flight:
+        _preempt_slot regrows the prompt from req.tokens, so the caller
+        reads that result first and asks again (what was grown so far stays
+        with its slot)."""
         out = []
         for i in active:
-            first = int(self.slot_pos[i]) // self.ps
-            last = (int(self.slot_pos[i]) + eff - 1) // self.ps
+            pos = int(self.slot_pos[i]) + int(self._ahead[i])
+            first = pos // self.ps
+            last = (pos + eff - 1) // self.ps
             ok = self._alloc_pages(i, last + 1 - len(self._slot_pages[i]))
             if ok and self._prefix is not None:
                 # only the boundary page can be shared (grown pages are
@@ -2369,6 +2449,8 @@ class LLMEngine:
                         break
             if ok:
                 out.append(i)
+            elif self._inflight is not None:
+                return None
             else:
                 self._preempt_slot(i, origin=origin)
         return out
@@ -2446,21 +2528,30 @@ class LLMEngine:
             self._sparse_acc = rest.pop(0)
         return first
 
+    def _took_decode(self, out):
+        """_took for the decode program, whose last result is the token
+        feed of the next one; returns its tokens, still on the device."""
+        self._feed = out[-1]
+        return self._took(out[:-1])
+
     def _moe_dispatched(self, program, rows, calls):
         """`rows` real rows went through every expert layer `calls` times."""
         n = len(self._moe_kinds)
         self._moe_pending[_MOE_PROGRAMS.index(program)] += (
             rows * calls * self._moe_kinds[0].top_k * n, calls * n)
 
-    def _publish_moe(self, total):
-        """`total`: the device accumulator as the decode tick brought it
-        back, flat.  Publishes what the programs added since the last one:
-        the counters, stats()["moe"] and the largest load of the tick."""
+    def _publish_moe(self, total, pending):
+        """`total`: the device accumulator as a decode program brought it
+        back, flat; `pending`: the (pairs, layer calls) by program that were
+        dispatched between the decode program read before it and this one
+        (_InFlight.moe), i.e. what `total` has gained since.  Publishes
+        that: the counters, stats()["moe"] and the largest load of the
+        tick."""
         total = total.astype(np.uint32).reshape(self._moe_seen.shape)
         delta = (total - self._moe_seen).astype(np.int64)  # wraps like int32
         self._moe_seen = total
         for i, prog in enumerate(_MOE_PROGRAMS):
-            pairs, calls = (int(x) for x in self._moe_pending[i])
+            pairs, calls = (int(x) for x in pending[i])
             if not calls:
                 continue
             held = int(delta[i, :, :-1].sum())
@@ -2474,7 +2565,6 @@ class LLMEngine:
             st["pairs_absent"] += pairs - held
             st["experts_touched"] += touched
             st["layer_calls"] += calls
-        self._moe_pending[:] = 0
         self._moe_max_load = int(delta[0, :, :-1].max())
         _M_MOE_MAX_LOAD.set(self._moe_max_load)
 
@@ -2690,8 +2780,9 @@ class LLMEngine:
                 self._adm_inflight -= 1
 
     def _prefill_tick(self):
-        """Run ONE prefill chunk of the admitting request; on the final
-        chunk emit the first token and activate the slot."""
+        """Dispatch ONE prefill chunk of the admitting request; a final
+        chunk's logits wait in _chunk_out for the tick to read them
+        (_first_token), after it has dispatched its decode program."""
         pc = self._phases
         pc.switch("prefill_stage")
         req, slot, done = self._prefilling
@@ -2757,9 +2848,9 @@ class LLMEngine:
             + self._lora_args([req.adapter_page]) + self._chunk_extra(slot)
         # the call is asynchronous: this phase, like the llm_prefill_chunk
         # span inside it, is the host's time to DISPATCH the chunk.  The
-        # wait for the chunk shows where the host next reads a result:
-        # first_token_sync below on a final chunk, else this tick's
-        # decode_sync (the device runs the programs in order)
+        # wait for the chunk shows where the host next reads a result that
+        # was dispatched after it: first_token_sync on a final chunk, else
+        # the next tick's decode_sync (the device runs the programs in order)
         t_pf = pc.switch("prefill_dispatch")
         try:
             jit = self._get_chunk_prefill()
@@ -2806,9 +2897,23 @@ class LLMEngine:
                            adapter_id=req.adapter_id)
         if self._ckpt is not None:
             self._ckpt_store(slot, req)
-        # the tick's first wait for the device: the chunk (and whatever
-        # was queued before it) must finish before its logits can be read
-        pc.switch("first_token_sync")
+        # _prefilling stays set until the slot is active (_first_token,
+        # later in this tick)
+        self._chunk_out = (req, slot, logits)
+
+    def _first_token(self):
+        """The final chunk's last step, once the tick has dispatched its
+        decode program and booked the one before: wait for the chunk's
+        logits, select the first token on the host and activate the slot.
+        The slot joins the NEXT decode program, with this token from the
+        host (the program just dispatched carries its row masked, like any
+        slot between chunks)."""
+        req, slot, logits = self._chunk_out
+        self._chunk_out = None
+        # the chunk (and whatever was queued before it) must finish before
+        # its logits can be read; the decode program queued behind it runs
+        # meanwhile
+        self._phases.switch("first_token_sync")
         tok = self._host_select(np.asarray(logits)[0, 0], req)
         first = not req.tokens  # re-admission after preemption continues
         req.slot = slot
@@ -2817,7 +2922,7 @@ class LLMEngine:
         # and on a post-preemption re-admission): useful either way
         self._goodput.count_tokens("useful", 1)
         self.slot_req[slot] = req
-        self.slot_pos[slot] = n
+        self.slot_pos[slot] = req.prompt.size
         self.last_token[slot] = tok
         # only now drop the in-flight marker: drain()'s lock-free
         # _drained() must never observe _prefilling cleared while the
@@ -2828,6 +2933,7 @@ class LLMEngine:
         self._first_token_out(req, first)
         if tok == self.eos or len(req.tokens) >= req.max_new_tokens:
             self._finish(slot)
+        self._phases.switch("bookkeep")
 
     def warmup(self):
         """Pre-compile the serving programs so the FIRST request pays no
@@ -2837,11 +2943,14 @@ class LLMEngine:
         ``spec_k``, the verify step.  Runs the real compiled calls against
         the engine's own idle cache state: the garbage rows land in the
         trash page.  The decode, verify and chunk calls get what a tick
-        gives them — host arrays, and the default generator's resident key
-        with a host offset in place of keys — so the warmed programs are
-        the ones the ticks call; the offset is not advanced (warmup draws
-        nothing a request sees).  Returns the wall seconds spent and
-        publishes them on llm_warmup_compile_seconds."""
+        gives them — host arrays, the default generator's resident key
+        with a host offset in place of keys, and for the decode's token
+        feed the resident zeros (one signature with a program's own last
+        tokens: both are uncommitted [B] int32 arrays of the one device) —
+        so the warmed programs are the ones the ticks call; the offset is
+        not advanced (warmup draws nothing a request sees).  Returns the
+        wall seconds spent and publishes them on
+        llm_warmup_compile_seconds."""
         t0 = time.perf_counter()
         # every tick's span records into the native host-trace buffer, whose
         # library is BUILT on first use in a fresh checkout (a g++ run):
@@ -2853,6 +2962,8 @@ class LLMEngine:
             if self._prefilling is not None \
                     or any(r is not None for r in self.slot_req):
                 raise RuntimeError("warmup() requires an idle engine")
+            if self._inflight is not None:
+                self._read_decode("warmup")  # every row of it has ended
             C = self.prefill_chunk
             # last_index -1: no token of the warm-up chunk is real, so
             # slot 0's recurrent state and the expert counts stay put
@@ -2886,9 +2997,9 @@ class LLMEngine:
             knobs = self._sampling_knobs()  # idle engine: all greedy
             rng = (_fr.default_generator().key, np.uint32(0))
             lora = self._lora_args([0] * B)
-            self._took(self._get_decode(eff)(
-                *self._cache_args(), tokens, pos, *knobs,
-                self._mask_all_true, *rng, *lora, *self._accs()))
+            self._took_decode(self._get_decode(eff)(
+                *self._cache_args(), tokens, self._feed, np.ones((B,), bool),
+                pos, *knobs, self._mask_all_true, *rng, *lora, *self._accs()))
             if self.spec_k:
                 _, _, self.caches = self._get_verify()(
                     *self._cache_args(), tokens,
@@ -2934,9 +3045,11 @@ class LLMEngine:
             _constrain.count_masked_token()
         return tok
 
-    def _sampling_knobs(self):
+    def _sampling_knobs(self, rows=None):
         """Per-slot (do_sample, temperature, top_k, top_p) as HOST arrays the
-        compiled step takes as arguments.  numpy on purpose: a
+        compiled step takes as arguments; ``rows``: the slots the program
+        carries (default: every slot with a request), the others are greedy
+        like idle ones.  numpy on purpose: a
         jnp.asarray(list, dtype) converts ON DEVICE, an eager op whose first
         use compiles after warmup() has declared the process warm.
 
@@ -2950,7 +3063,8 @@ class LLMEngine:
         B = self.n_slots
         do_s, temp = np.zeros(B, bool), np.ones(B, np.float32)
         topk, topp = np.zeros(B, np.int32), np.ones(B, np.float32)
-        for i, r in enumerate(self.slot_req):
+        for i in range(B) if rows is None else rows:
+            r = self.slot_req[i]
             if r is not None:
                 do_s[i], temp[i] = r.do_sample, r.temperature
                 topk[i], topp[i] = r.top_k, r.top_p
@@ -2970,16 +3084,19 @@ class LLMEngine:
         self._sampler_ticks[path] += 1
         _SAMPLER_SERIES[path].inc()
 
-    def _cache_args(self):
+    def _cache_args(self, rows=None):
         """What the decode and verify programs take first: the weights, the
-        caches, and the page table with INACTIVE slots masked to the trash
-        page — a mid-prefill slot already owns real pages, and the shared
-        step's garbage scatter for it must not clobber the prompt rows the
-        chunked prefill has already written."""
-        pt = self._pt_host.copy()
-        for i, r in enumerate(self.slot_req):
-            if r is None:
-                pt[i, :] = 0
+        caches, and the page table with every slot the program does not
+        carry masked to the trash page (``rows``: the slots it carries;
+        default: every slot with a request) — a mid-prefill slot already
+        owns real pages, and the shared step's garbage scatter for it must
+        not clobber the prompt rows the chunked prefill has already
+        written; a row that ends with its token in flight must compute
+        nothing more."""
+        if rows is None:
+            rows = [i for i, r in enumerate(self.slot_req) if r is not None]
+        pt = np.zeros_like(self._pt_host)
+        pt[rows] = self._pt_host[rows]
         return self._params, self._buffers, self.caches, pt
 
     def _get_decode(self, eff):
@@ -2995,7 +3112,16 @@ class LLMEngine:
         offset)`` — the default generator's resident key and a host integer
         (``Generator.fork()``) — and the per-token keys are derived HERE, in
         the program: an eager ``jax.random.split`` on the host would be a
-        train of one-op device programs before every tick's dispatch."""
+        train of one-op device programs before every tick's dispatch.
+
+        A row's input token is picked HERE too: ``tokens`` [B, 1] from the
+        host where ``from_host`` says so (a slot activated since the last
+        dispatch, any row after a drain), else ``feed`` [B], the last
+        tokens of the program dispatched before this one, which never left
+        the device — so the pump can dispatch this program before it has
+        read that one's result.  The program hands its own last tokens
+        back as its LAST result, [B] int32 whatever ``eff`` is and whatever
+        rides home beside the tokens: one signature feeds every next one."""
         model = self.model
         pool = self.adapters.pool if self.adapters is not None else None
 
@@ -3006,10 +3132,12 @@ class LLMEngine:
         # either feature on after warmup() never recompiles
         kinds = self._cache_kinds
 
-        def llm_decode(params, buffers, caches, page_tbl, tokens, pos,
-                       do_sample, temperature, top_k, top_p, token_mask,
-                       base_key, offset, lora_tree, lora_rows, *accs):
+        def llm_decode(params, buffers, caches, page_tbl, tokens, feed,
+                       from_host, pos, do_sample, temperature, top_k, top_p,
+                       token_mask, base_key, offset, lora_tree, lora_rows,
+                       *accs):
             keys = jax.random.split(jax.random.fold_in(base_key, offset), eff)
+            tokens = jnp.where(from_host, tokens[:, 0], feed)[:, None]
             # the tick masks the table rows of idle and mid-prefill
             # slots to the trash page: such a row is computed like the
             # others but advances no recurrent state and counts nowhere
@@ -3035,15 +3163,16 @@ class LLMEngine:
                         tick, (caches, tokens, pos) + accs, keys)
             finally:
                 restore()
+            last = toks[-1].astype(jnp.int32)
             if accs:
                 # the layers' counts ride home with the tokens: one array
                 return (jnp.concatenate(
                     [toks.T.reshape(-1)] + [a.reshape(-1) for a in carry[3:]]),
-                    carry[0]) + tuple(carry[3:])
-            return toks.T, carry[0]  # [B, chunk]
+                    carry[0]) + tuple(carry[3:]) + (last,)
+            return toks.T, carry[0], last  # [B, chunk]
 
         return jax.jit(llm_decode, donate_argnums=(2,) + tuple(
-            range(15, 15 + len(self._accs()))))
+            range(17, 17 + len(self._accs()))))
 
     def _verify_fn(self):
         """ONE compiled speculative verify: score K drafts + one bonus
@@ -3085,8 +3214,12 @@ class LLMEngine:
         return self._verify_jit
 
     def step(self):
-        """One engine tick: admit pending prompts, then decode one token
-        for every active slot.  Serialized by the engine lock: the
+        """One engine tick: admit pending prompts (one chunk), dispatch the
+        next decode program for every active slot, then book the tokens of
+        the program dispatched a tick earlier (_decode_tick) — after a
+        hand-driven step() a decode result is as a rule still in flight;
+        run_until_complete() and drain() leave none.  Serialized by the
+        engine lock: the
         background pump and caller-thread pumping (run_until_complete) must
         not race on the DONATED cache buffers or the slot state."""
         with self._lock:
@@ -3107,11 +3240,19 @@ class LLMEngine:
                         emitted = self._step_locked()
                     finally:
                         pc.end()  # no phase stays open across ticks
-            for phase, child in _TICK_PHASE_SERIES.items():
-                d = pc.seconds[phase] - self._phase_pub[phase]
-                if d > 0.0:
-                    child.inc(d)
-                    self._phase_pub[phase] = pc.seconds[phase]
+                dec = 0.0
+                for phase, child in _TICK_PHASE_SERIES.items():
+                    d = pc.seconds[phase] - self._phase_pub[phase]
+                    if d > 0.0:
+                        child.inc(d)
+                        self._phase_pub[phase] = pc.seconds[phase]
+                        if phase in _DECODE_PHASES:
+                            dec += d
+                # goodput ledger: the productive decode seconds ARE the
+                # tick's three decode_* phases (arg staging + compiled call
+                # + the wait for a result), in whichever order it ran them;
+                # bookkeeping stays in the idle/queue_drain residual
+                self._goodput.carve("decode", dec)
             self._first_tick_done = True
             if emitted:
                 self._goodput.count_tokens("useful", emitted)
@@ -3132,42 +3273,95 @@ class LLMEngine:
         pc.switch("bookkeep")
         self._update_page_gauges()
         _M_QUEUE_DEPTH.set(self._pending.qsize())
-        active = [i for i, r in enumerate(self.slot_req) if r is not None]
-        _M_ACTIVE_SLOTS.set(len(active))
-        if not active:
-            return 0
-        # effective chunk: stay inside the cache (slots AT capacity were
-        # finished by the previous tick's done-check, so headroom >= 1)
-        headroom = self.L - 1 - int(self.slot_pos[active].max())
+        emitted = self._decode_tick()
+        if self._chunk_out is not None:
+            self._first_token()
+        return emitted
+
+    def _count_drain(self, why):
+        self._pipeline["drained"][why] += 1
+        _PIPELINE_SERIES[why].inc()
+
+    def _read_decode(self, why):
+        """Read the decode result in flight with no later program queued
+        behind it, and book it; ``why`` names what kept the pump from
+        running ahead (_DRAIN_REASONS).  Returns the tokens emitted."""
+        fl, self._inflight = self._inflight, None
+        self._count_drain(why)
+        return self._book(fl)
+
+    def _decode_tick(self):
+        """The decode half of a tick.  The pump keeps ONE decode program in
+        flight: it dispatches this tick's program BEFORE it reads the
+        previous one's result, whose tokens reach this program on the device
+        (llm_decode's ``feed``), and books that result — emit, stamp,
+        finish, publish the counts — while the device runs this one.  The
+        host does not need the tokens to dispatch: positions advance by one
+        a token, pages grow by count, a row that reaches max_new_tokens or
+        the end of its cache with the token in flight is left out (and
+        finished when that token is read).  Only an EOS is learnt one
+        program late: the row's surplus token is dropped and counted.
+
+        Where the next program DOES need what the host has not read, the
+        pump reads first and the tick is synchronous (dispatch, then read)
+        — decided by what it can observe, tick by tick: a constrained row
+        (its mask follows the token), a speculating engine (the drafter
+        reads the tokens), a slot to preempt (its prompt regrows from all
+        its tokens); with no row to run it reads what is in flight and goes
+        idle.  Returns the tokens booked this tick."""
+        pc = self._phases
+        reqs = self.slot_req
+        emitted = 0
+        # a constrained row's automaton state advances per TOKEN, and the
+        # uploaded mask is constant across a chunk — so ticks with any
+        # constrained row decode one token at a time, each read at once
+        constrained = any(r is not None and r.cursor is not None for r in reqs)
+        sync = "constrained" if constrained else "spec" if self.spec_k \
+            else None
+        if sync and self._inflight is not None:
+            emitted += self._read_decode(sync)
+        _M_ACTIVE_SLOTS.set(sum(r is not None for r in reqs))
+        # rows the program carries: not those that end, by count, with the
+        # token in flight — they compute nothing more
+        rows = [i for i in range(self.n_slots) if self._runs_on(i)]
+        if not rows:
+            if self._inflight is not None:
+                emitted += self._read_decode("idle")
+            return emitted
+        # effective chunk: stay inside the cache (slots AT capacity are
+        # left out above, so headroom >= 1)
+        headroom = self.L - 1 - int(
+            (self.slot_pos[rows] + self._ahead[rows]).max())
         if self.spec_k and headroom >= self.spec_k:
             # speculative tick: verify writes rows pos .. pos+K, so it
             # needs K rows of headroom; the last strides before capacity
             # fall back to plain one-token decode below
-            return self._spec_tick(active)
-        eff = max(1, min(self.decode_chunk, headroom))
-        # a constrained row's automaton state advances per TOKEN, and the
-        # uploaded mask is constant across a chunk — so ticks with any
-        # constrained row decode one token at a time
-        constrained = any(
-            r is not None and r.cursor is not None for r in self.slot_req)
-        if constrained:
-            eff = 1
-        t_dec = pc.switch("decode_stage")
+            return emitted + self._spec_tick(rows)
+        eff = 1 if constrained else max(1, min(self.decode_chunk, headroom))
+        pc.switch("decode_stage")
         # grow page tables to cover this tick's writes; slots the pool
         # cannot cover any longer are preempted (shed, not wedged)
-        active = self._ensure_decode_pages(active, eff)
+        grown = self._ensure_decode_pages(rows, eff)
+        if grown is None:
+            emitted += self._read_decode("preempt")
+            pc.switch("decode_stage")
+            grown = self._ensure_decode_pages(
+                [i for i in rows if self._runs_on(i)], eff)
+        rows = grown
         self._update_page_gauges()
-        if not active:
-            self._goodput.carve("decode", pc.switch("bookkeep") - t_dec)
-            return 0
+        if not rows:
+            pc.switch("bookkeep")
+            return emitted
         jit = self._get_decode(eff)
         # host arrays straight into the compiled call, and (key, offset)
         # for the keys it derives itself: no eager device call here (see
-        # _sampling_knobs).  Copies: bookkeeping writes these in place
+        # _sampling_knobs).  Copies: bookkeeping writes these in place.
+        # A row with tokens in flight takes the newest of them from the
+        # device, at the position behind them
         tokens = self.last_token.reshape(-1, 1).copy()
-        pos = self.slot_pos.copy()
-        reqs = self.slot_req
-        do_s, temp, topk, topp = self._sampling_knobs()
+        from_host = self._ahead == 0
+        pos = self.slot_pos + self._ahead
+        do_s, temp, topk, topp = self._sampling_knobs(rows)
         self._count_sampler_tick(do_s, topk, topp)
         # read each tick, not held: paddle.seed() on a live engine governs
         rng = _fr.default_generator().fork()
@@ -3175,46 +3369,76 @@ class LLMEngine:
             # per-row [V] masks from each constrained row's automaton
             # state; unconstrained rows stay all-True (exact no-op)
             token_mask = np.ones((self.n_slots, self._vocab), bool)
-            for i, r in enumerate(reqs):
-                if r is not None and r.cursor is not None:
-                    token_mask[i] = r.cursor.mask()
+            for i in rows:
+                if reqs[i].cursor is not None:
+                    token_mask[i] = reqs[i].cursor.mask()
         else:
             token_mask = self._mask_all_true
-        args = (*self._cache_args(), tokens, pos, do_s, temp, topk, topp,
-                token_mask, *rng, *self._lora_args(
-                    [r.adapter_page if r is not None else 0 for r in reqs]))
-        moe = self._moe_acc is not None
-        accs = self._accs()
-        args += accs
-        if moe:
-            self._moe_dispatched("decode", len(active), eff)
+        args = (*self._cache_args(rows), tokens, self._feed, from_host, pos,
+                do_s, temp, topk, topp, token_mask, *rng, *self._lora_args(
+                    [r.adapter_page if r is not None else 0 for r in reqs]),
+                *self._accs())
+        moe = None
+        if self._moe_acc is not None:
+            # what this program's counts will cover: every dispatch since
+            # the decode program before it, chunks included
+            self._moe_dispatched("decode", len(rows), eff)
+            moe, self._moe_pending = self._moe_pending, np.zeros_like(
+                self._moe_pending)
         pc.switch("decode_dispatch")
-        nxt_dev = self._took(jit(*args))
+        out = self._took_decode(jit(*args))
+        prev, self._inflight = self._inflight, _InFlight(
+            out, eff, [(i, reqs[i]) for i in rows], moe)
+        self._ahead[rows] += eff
+        if prev is not None:
+            # the device is busy with the program just dispatched
+            self._pipeline["overlapped"] += 1
+            _PIPELINE_SERIES["overlapped"].inc()
+            emitted += self._book(prev)
+        if sync:
+            emitted += self._read_decode(sync)
+        elif prev is None:
+            pc.switch("bookkeep")
+        return emitted
+
+    def _runs_on(self, slot):
+        """Whether the next decode program carries the slot: it holds a
+        request that does not end, by count, with the tokens it has in
+        flight (at max_new_tokens, or at the end of its cache)."""
+        req, ahead = self.slot_req[slot], int(self._ahead[slot])
+        return (req is not None
+                and len(req.tokens) + ahead < req.max_new_tokens
+                and int(self.slot_pos[slot]) + ahead < self.L - 1)
+
+    def _book(self, fl):
+        """Wait for a dispatched decode program and book its tokens: emit,
+        stamp, advance, finish, publish the layers' counts.  A row whose
+        slot no longer holds the request it was dispatched for — it met its
+        EOS in the program before, or expired — has its tokens dropped and
+        counted as surplus."""
+        pc = self._phases
         pc.switch("decode_sync")
-        nxt = np.asarray(nxt_dev).astype(np.int32)  # [B, eff]
-        if accs:
+        nxt = np.asarray(fl.out).astype(np.int32)  # [B, eff]
+        B, eff = self.n_slots, fl.eff
+        if nxt.ndim == 1:
             # the layers' counts came in the same array, in _accs() order
-            counts = nxt[self.n_slots * eff:]
-            nxt = nxt[:self.n_slots * eff].reshape(self.n_slots, eff)
-        # every token of this tick carries this stamp: the instant the
-        # host had them.  It is also the boundary into bookkeeping, and
-        # the end of the goodput ledger's productive decode seconds (arg
-        # staging + compiled call + the host sync = the three decode_*
-        # phases; the bookkeeping below stays in the idle/queue_drain
-        # residual)
-        t_end = pc.switch("bookkeep")
-        self._goodput.carve("decode", t_end - t_dec)
-        now_pc = t_end or time.perf_counter()  # the clock is off: read it
-        if moe:
-            self._publish_moe(counts[:self._moe_seen.size])
+            counts = nxt[B * eff:]
+            nxt = nxt[:B * eff].reshape(B, eff)
+        # every token of this program carries this stamp: the instant the
+        # host had them.  It is also the boundary into bookkeeping
+        now_pc = pc.switch("bookkeep") \
+            or time.perf_counter()  # the clock is off: read it
+        if fl.moe is not None:
+            self._publish_moe(counts[:self._moe_seen.size], fl.moe)
         if self._sparse_acc is not None:
             self._publish_sparse(counts[-self._sparse_seen.size:])
         emitted = 0
-        for j in range(eff):
-            for i in list(active):
-                req = self.slot_req[i]
-                if req is None:
-                    continue  # finished earlier in this chunk: surplus
+        for i, req in fl.rows:
+            if self.slot_req[i] is not req:
+                self._pipeline["surplus_tokens"] += eff
+                continue
+            self._ahead[i] -= eff
+            for j in range(eff):
                 tok = int(nxt[i, j])
                 self._emit_token(req, tok, now_pc)
                 if req.cursor is not None:
@@ -3225,16 +3449,16 @@ class LLMEngine:
                 self.last_token[i] = tok
                 self.slot_pos[i] += 1
                 emitted += 1
-                done = (tok == self.eos
+                if (tok == self.eos
                         or len(req.tokens) >= req.max_new_tokens
-                        or self.slot_pos[i] >= self.L - 1)
-                if done:
+                        or self.slot_pos[i] >= self.L - 1):
+                    # the rest of the chunk is surplus; so is what a later
+                    # program already dispatched computes for this row
                     self._finish(i)
-        for i in active:
-            req = self.slot_req[i]
-            if req is not None \
-                    and len(req.token_ts) - req.dec_i0 >= _DECODE_SPAN_TICKS:
-                self._flush_decode_span(req)  # bound spans per episode
+                    break
+            else:
+                if len(req.token_ts) - req.dec_i0 >= _DECODE_SPAN_TICKS:
+                    self._flush_decode_span(req)  # bound spans per episode
         return emitted
 
     def _spec_tick(self, active):
@@ -3411,10 +3635,7 @@ class LLMEngine:
         for i, req in enumerate(self.slot_req):
             if req is not None and req.deadline is not None \
                     and self._clock() > req.deadline:
-                self.slot_req[i] = None
-                self.last_token[i] = self.pad
-                self._release_pages(i)
-                self._release_adapter(req)
+                self._vacate(i)  # a token in flight for the row is dropped
                 _M_EXPIRED.labels(where="inflight").inc()
                 _flight.record_event("deadline_expiry", where="inflight",
                                      slot=int(i), tokens=len(req.tokens),
@@ -3425,11 +3646,7 @@ class LLMEngine:
                 self._end_trace(req, "expired", where="inflight")
 
     def _finish(self, slot):
-        req = self.slot_req[slot]
-        self.slot_req[slot] = None
-        self.last_token[slot] = self.pad
-        self._release_pages(slot)
-        self._release_adapter(req)
+        req = self._vacate(slot)
         if req is not None:
             _M_COMPLETED.inc()
             if req.submit_ts is not None:

@@ -44,6 +44,23 @@ def test_smoke_refuses_off_chip(smoke, capsys):
     assert '"ok"' not in capsys.readouterr().out
 
 
+def test_smoke_fails_an_engine_that_never_ran_ahead(smoke):
+    """serve_phase and state_phase report stats()["tick_pipeline"] through
+    _ran_ahead, which fails the run where no decode result was read with the
+    next program already dispatched (a pump that quietly drains every tick)."""
+    class Engine:
+        def __init__(self, overlapped):
+            self.pl = {"overlapped": overlapped, "surplus_tokens": 0,
+                       "drained": {"idle": 3}}
+
+        def stats(self):
+            return {"tick_pipeline": self.pl}
+
+    assert smoke._ran_ahead(Engine(7))["overlapped"] == 7
+    with pytest.raises(AssertionError, match="next program dispatched"):
+        smoke._ran_ahead(Engine(0))
+
+
 def _grads(fn, args, n_diff):
     def loss(*a):
         o = fn(*a)

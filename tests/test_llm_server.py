@@ -337,8 +337,11 @@ def test_paged_decode_chunk_crosses_page_boundaries(model):
 
 def test_paged_long_prompt_does_not_stall_decode(model):
     """A long prompt admitted mid-decode prefills one chunk per tick while
-    the running slot emits a token EVERY tick — the head-of-line fix,
-    asserted through the chunked-prefill counter."""
+    the running slot gets a token EVERY tick — the head-of-line fix,
+    asserted through the chunked-prefill counter.  The pump books a tick's
+    token one tick later (one decode program stays in flight), so the
+    running slot's progress is what it has booked plus what it has in
+    flight."""
     rng = np.random.RandomState(24)
     eng = LLMEngine(model, max_batch_slots=2, max_seq_len=128,
                     kv_layout="paged", page_size=32, prefill_chunk=8)
@@ -348,10 +351,13 @@ def test_paged_long_prompt_does_not_stall_decode(model):
     pb = rng.randint(0, 1024, 33).astype(np.int32)  # 5 chunks of 8
     fb = eng.submit(pb, max_new_tokens=4)
     n0 = _prefill_chunk_count()
-    for _ in range(5):  # the whole admission of B
-        before = len(eng.slot_req[0].tokens)
+    progress = lambda: len(eng.slot_req[0].tokens) + int(eng._ahead[0])  # noqa: E731
+    for k in range(5):  # the whole admission of B
+        before, booked = progress(), len(eng.slot_req[0].tokens)
         eng.step()
-        assert len(eng.slot_req[0].tokens) == before + 1  # A never stalls
+        assert progress() == before + 1  # A never stalls
+        # and what a tick dispatched is booked by the next one
+        assert len(eng.slot_req[0].tokens) == booked + (k > 0)
     assert _prefill_chunk_count() - n0 == 5
     eng.run_until_complete()
     assert fb.result(timeout=1) == _oracle(model, pb, 4)

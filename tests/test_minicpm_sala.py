@@ -426,8 +426,8 @@ def test_the_models_scopes_are_in_the_compiled_programs():
         np.zeros((1, C), i32), np.zeros((1,), i32), i32(3), *eng._lora_args([0]),
         *eng._chunk_extra(0)).compile().as_text()
     decode = eng._get_decode(1).lower(
-        *eng._cache_args(), np.zeros((B, 1), i32), np.zeros((B,), i32),
-        *eng._sampling_knobs(), eng._mask_all_true, fr.default_generator().key,
+        *eng._cache_args(), np.zeros((B, 1), i32), eng._feed,
+        np.ones((B,), bool), np.zeros((B,), i32), *eng._sampling_knobs(), eng._mask_all_true, fr.default_generator().key,
         np.uint32(0), *eng._lora_args([0] * B), *eng._accs()).compile().as_text()
     for hlo in (chunk, decode):
         assert "/sparse_select/" in hlo

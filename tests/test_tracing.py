@@ -843,11 +843,15 @@ def test_admission_blocked_names_what_held_the_queue_head(model):
                          "no_adapter_page": 0}
     eng.step()  # tick 2: its second chunk holds the prefill lane
     assert blocked()["prefill_busy"] == 1 and blocked()["no_slot"] == 0
-    eng.step()  # tick 3: the only slot is decoding (and finishes)
+    eng.step()  # tick 3: the only slot is decoding
     assert blocked() == {"prefill_busy": 1, "no_slot": 1, "no_pages": 0,
                          "no_adapter_page": 0}
-    eng.run_until_complete()  # tick 4 admits the short one: no more waits
-    assert blocked() == {"prefill_busy": 1, "no_slot": 1, "no_pages": 0,
+    # the slot joined the decode a tick after its first token and is freed
+    # where its last token is READ, a tick after the program that computed
+    # it was dispatched (one decode program stays in flight): ticks 4 and 5
+    # still find it taken, tick 6 admits the short one, then no more waits
+    eng.run_until_complete()
+    assert blocked() == {"prefill_busy": 1, "no_slot": 3, "no_pages": 0,
                          "no_adapter_page": 0}
     fam = obs.REGISTRY.get("llm_admission_blocked_ticks_total")
     assert {lv[0] for lv, _ in fam.series()} == set(blocked())
@@ -946,8 +950,8 @@ def test_programs_and_scopes_are_named_and_change_no_token(model,
     lora = eng._lora_args([0] * B)
     programs = {
         "llm_decode": (eng._decode_jit[1], head + (
-            np.zeros((B, M), i32), np.zeros((B, 1), i32), np.zeros(B, i32),
-            *knobs, eng._mask_all_true, key, np.uint32(0), *lora)),
+            np.zeros((B, M), i32), np.zeros((B, 1), i32), eng._feed,
+            np.ones(B, bool), np.zeros(B, i32), *knobs, eng._mask_all_true, key, np.uint32(0), *lora)),
         "llm_prefill_chunk": (eng._get_chunk_prefill(), head + (
             np.zeros((1, M), i32), np.zeros((1, C), i32), np.zeros(1, i32),
             i32(0), *eng._lora_args([0]))),
