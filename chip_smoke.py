@@ -9,6 +9,10 @@ cut to N_LAYERS and seeded random weights:
   state  LLMEngine with a small MiniCPM-SALA (128-wide heads, so the block-
          sparse and the lightning kernels are the paths taken): a document,
          then two questions of it that resume from its state checkpoint
+  latent LLMEngine with a small DeepSeek-V3-shaped model (latent 512 + rope
+         64 a token, 8 gated experts of whole-lane width): a document, then
+         two questions of it that map its latent pages (one forks a partial
+         page), decode through the latent kernel
   train  paddle.jit.TrainStep + AdamW, 3 steps at batch 4 x seq 2048
   mesh   ShardedTrainStep(zero_stage=2) on sharding=2 x mp=2 (>= 4 chips)
 
@@ -177,6 +181,24 @@ def _ran_ahead(eng):
     return pl
 
 
+def _one_pass_gap(model, asks, outs):
+    """How far the served tokens lie below the best of the model's own
+    one-pass logits (the chunk path: no decode kernel), at worst."""
+    import jax.numpy as jnp
+
+    worst = 0.0
+    for p, o in zip(asks, outs):
+        both = np.concatenate([p, np.asarray(o, np.int32)])
+        lg = np.asarray(model.forward(jnp.asarray(both[None]))._value[0],
+                        np.float32)[len(p) - 1:len(both) - 1]
+        worst = max(worst, float(np.max(lg.max(-1) - lg[np.arange(len(o)), o])))
+    # bfloat16 logits of size ~0.5: a served token may lose to a neighbour
+    # by a rounding step or two, not by more
+    check(worst < 0.05, f"served tokens lie within {worst:.4f} of the "
+          "one-pass logits' best")
+    return worst
+
+
 # ------------------------------------------------------------------- state
 def state_phase(doc_pages=3, new_tokens=8, hidden=256, timeout=600.0):
     """A model with recurrent state beside paged attention, its prefix cache
@@ -226,16 +248,66 @@ def state_phase(doc_pages=3, new_tokens=8, hidden=256, timeout=600.0):
     sp = st["sparse_attention"]["decode"]
     check(sp["layer_calls"] > 0 and sp["selected_blocks"] == 3 * sp["layer_calls"],
           f"every decode query past dense_len read its 3 selected blocks: {sp}")
-    worst = 0.0
-    for p, o in zip(asks, outs):
-        both = np.concatenate([p, np.asarray(o, np.int32)])
-        lg = np.asarray(model.forward(jnp.asarray(both[None]))._value[0],
-                        np.float32)[len(p) - 1:len(both) - 1]
-        worst = max(worst, float(np.max(lg.max(-1) - lg[np.arange(len(o)), o])))
-    # bfloat16 logits of size ~0.5: a served token may lose to a neighbour
-    # by a rounding step or two, not by more
-    check(worst < 0.05, f"served tokens lie within {worst:.4f} of the "
-          "one-pass logits' best")
+    worst = _one_pass_gap(model, asks, outs)
+    _ran_ahead(eng)
+    return worst
+
+
+# ------------------------------------------------------------------ latent
+def latent_phase(doc_tokens=300, new_tokens=8, timeout=600.0):
+    """Latent attention and gated experts through the engine, the prefix
+    cache on: two questions of one document map its pages (the second page
+    and a half of it whole pages, the rest a partial page forked copy-on-
+    write), decode takes the latent KERNEL (not the gathered pass), and the
+    tokens it gave lie at the top of the model's own one-pass logits (the
+    chunk path: no decode kernel)."""
+    import jax.numpy as jnp
+
+    import paddle_tpu as paddle
+    from paddle_tpu.inference import LLMEngine
+    from paddle_tpu.inference.llm_server import _attn_dispatch_series
+    from paddle_tpu.models.deepseek_v3 import (DeepseekV3Config,
+                                               DeepseekV3ForCausalLM)
+
+    paddle.seed(0)
+    page = 128
+    cfg = DeepseekV3Config(
+        vocab_size=512, hidden_size=256, intermediate_size=512,
+        moe_intermediate_size=128, num_hidden_layers=2, num_attention_heads=8,
+        num_key_value_heads=8, n_routed_experts=8, num_experts_per_tok=2,
+        max_position_embeddings=1024)
+    model = DeepseekV3ForCausalLM(cfg)
+    model.eval()
+    taken = lambda: {labels[0]: n for labels, n in _attn_dispatch_series()  # noqa: E731
+                     if labels[0].startswith("latent_")}
+    before = taken()
+    eng = LLMEngine(model, page_size=page, prefill_chunk=128, max_batch_slots=4,
+                    max_seq_len=5 * page, prefix_cache=True)
+    log(f"latent: warmup took {eng.warmup():.1f}s")
+    rng = np.random.default_rng(1)
+    doc = rng.integers(0, cfg.vocab_size, doc_tokens, dtype=np.int32)
+    asks = [np.concatenate([doc, rng.integers(0, cfg.vocab_size, n, dtype=np.int32)])
+            for n in (40, 90)]
+    eng.start()
+    try:
+        eng.submit(doc, max_new_tokens=2).result(timeout=timeout)
+        outs = [f.result(timeout=timeout) for f in
+                [eng.submit(p, max_new_tokens=new_tokens) for p in asks]]
+    finally:
+        eng.stop()
+    st = eng.stats()
+    after = taken()
+    check(after.get("latent_kernel", 0) > before.get("latent_kernel", 0)
+          and after.get("latent_dense", 0) == before.get("latent_dense", 0),
+          f"decode took the latent kernel, not the gathered pass: {after}")
+    pc = st["prefix_cache"]
+    check(pc["hit_tokens"] >= 2 * (doc_tokens // page) * page and pc["cow_copies"] >= 1,
+          f"two questions mapped the document's latent pages, one forked: {pc}")
+    lat, moe = st["latent_attention"]["decode"], st["moe"]["decode"]
+    check(lat["layer_calls"] > 0
+          and moe["pairs_held"] == lat["layer_calls"] // 2 * 2 and moe["pairs_absent"] == 0,
+          f"decode rows were counted by both kinds of layer: {lat} {moe}")
+    worst = _one_pass_gap(model, asks, outs)
     _ran_ahead(eng)
     return worst
 
@@ -416,6 +488,8 @@ def main():
     _free()
     state_gap = state_phase()
     _free()
+    latent_gap = latent_phase()
+    _free()
     losses = train_phase()
     _free()
     mesh_losses = mesh_phase(losses[0])
@@ -423,7 +497,8 @@ def main():
     # already on disk was loaded: not built from this checkout's sources
     log(f"native: AVAILABLE={native.AVAILABLE} "
         f"built_this_run={native.BUILT_THIS_RUN}")
-    log(f"summary: layers={N_LAYERS} kernel_err={errs} state_gap={state_gap:.4f} train={losses} "
+    log(f"summary: layers={N_LAYERS} kernel_err={errs} state_gap={state_gap:.4f} "
+        f"latent_gap={latent_gap:.4f} train={losses} "
         f"mesh={mesh_losses} paddle_tpu={paddle_tpu.__version__} "
         f"wall={time.perf_counter() - T0:.0f}s")
     print(json.dumps({"ok": True, "device": dev}))

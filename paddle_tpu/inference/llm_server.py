@@ -102,6 +102,7 @@ from ..ops.sampling import has_threshold as _has_threshold
 from ..ops.sampling import sample_rows as _sample_rows
 from ..ops.sampling import spec_accept as _spec_accept
 from ..models.kv_cache import SlotRows as _SlotRows
+from ..models.kv_cache import LatentPaged as _LatentPaged
 from ..models.kv_cache import SparsePaged as _SparsePaged
 from ..tensor.tensor import Tensor
 from . import constrain as _constrain
@@ -249,6 +250,12 @@ _M_SPARSE_SELECTED = _obs.counter(
     "llm_sparse_blocks_selected_total",
     "Key blocks the block-sparse attention layers selected, summed over "
     "their query calls (one a real row a layer)", labelnames=("program",))
+#: what a latent attention layer reports a call ("paged_latent")
+_LATENT_FIELDS = ("layer_calls", "context_tokens")
+_M_LATENT_CONTEXT = _obs.counter(
+    "llm_latent_context_tokens_total",
+    "Context tokens the latent attention layers' queries attended, summed "
+    "over their query calls (one a real row a layer)", labelnames=("program",))
 _M_STATE_CKPT = _obs.counter(
     "llm_state_checkpoints_total",
     "Recurrent-state checkpoints at a cached prefix's end: stored after a "
@@ -496,6 +503,8 @@ def _to_model_caches(kinds, caches, pos, page_tbl, slot_rows=None):
         slot_rows = slot_rows._replace(pos=jnp.broadcast_to(
             jnp.asarray(pos, jnp.int32), slot_rows.n_valid.shape))
     return [_SparsePaged(*c, pos, page_tbl, slot_rows) if k.compressed
+            else _LatentPaged(c[0], pos, page_tbl, slot_rows)
+            if k.kind == "paged_latent"
             else paged(c) if k.kind == "paged_kv"
             else tuple(c) + (slot_rows,) if k.kind == "recurrent"
             else slot_rows
@@ -504,9 +513,10 @@ def _to_model_caches(kinds, caches, pos, page_tbl, slot_rows=None):
 
 def _from_model_caches(kinds, new_caches):
     """Back again: (engine-side caches, what the layers reported: the expert
-    layers' counts stacked, then the block-sparse layers' summed — the order
-    of the engine's device accumulators, each present only with its layers)."""
-    raw, moe, sparse = [], [], []
+    layers' counts stacked, then the block-sparse layers' summed, then the
+    latent layers' summed — the order of the engine's device accumulators,
+    each present only with its layers)."""
+    raw, moe, sparse, latent = [], [], [], []
     for i, c in enumerate(new_caches):
         kind = "paged_kv" if kinds is None else kinds[i].kind
         if kind == "paged_kv" and kinds is not None and kinds[i].compressed:
@@ -515,6 +525,12 @@ def _from_model_caches(kinds, new_caches):
         elif kind == "paged_kv":
             vals = tuple(x._value if isinstance(x, Tensor) else x for x in c)
             raw.append((vals[0], vals[1]) + vals[4:])
+        elif kind == "paged_latent":
+            # (pool, its counts[, the expert feed-forward's])
+            raw.append((c[0],))
+            latent.append(c[1])
+            if kinds[i].experts_held:
+                moe.append(c[2])
         elif kind == "recurrent":
             raw.append(tuple(c))
         else:
@@ -522,7 +538,7 @@ def _from_model_caches(kinds, new_caches):
             if kinds[i].experts_held:
                 moe.append(c)
     return raw, ([jnp.stack(moe)] if moe else []) \
-        + ([sum(sparse)] if sparse else [])
+        + ([sum(sparse)] if sparse else []) + ([sum(latent)] if latent else [])
 
 
 class LLMEngine:
@@ -663,8 +679,9 @@ class LLMEngine:
         WHAT EACH LAYER KEEPS is the model's to say.  A model
         with ``cache_kinds()`` returns one ``models.kv_cache.CacheKind`` a
         layer, and the engine allocates by it: K/V page pools at the
-        layer's own head count and ``head_dim``, fixed-size state a SLOT
-        (``"recurrent"``), or nothing (feed-forward and expert layers);
+        layer's own head count and ``head_dim``, one latent page pool
+        (``"paged_latent"``), fixed-size state a SLOT (``"recurrent"``), or
+        nothing (feed-forward and expert layers);
         ``stats()["cache_kinds"]`` gives layers and bytes by kind.  A
         model without the method (Llama, GPT) pages K/V in every layer.
         ``models.nemotron_h.NemotronHForCausalLM`` keeps RECURRENT STATE in
@@ -697,7 +714,17 @@ class LLMEngine:
         and V and reads only the key blocks it selects
         (ops/sparse_attention.py); what it read comes back with the tick's
         tokens as the experts' pairs do (``stats()["sparse_attention"]``,
-        ``llm_sparse_blocks_selected_total``).  Expert layers that
+        ``llm_sparse_blocks_selected_total``).  A ``"paged_latent"`` layer
+        (multi-head latent attention: ``models.deepseek_v3``) keeps ONE pool
+        ``[pages, page_size, width]`` — a token's compressed latent and the
+        rotary key its heads share, no V — behind the same page table,
+        allocator, refcounts, prefix cache and copy-on-write fork; it is
+        handed a ``LatentPaged`` and what its queries attended comes back
+        the same way (``stats()["latent_attention"]``,
+        ``llm_latent_context_tokens_total``).  ``cache_dtype="int8"``,
+        ``host_cache_pages > 0`` and ``spec_k > 0`` raise ``ValueError`` for
+        it: a latent row has no K/V pair to quantise a head, the tiers and
+        a verify step have not been taken through a latent pool.  Expert layers that
         hold a share of the experts report their (token, expert) pairs:
         both programs add them to one device-resident total that comes
         back with the decode tick's tokens (``llm_moe_*``,
@@ -751,6 +778,23 @@ class LLMEngine:
                 "state_checkpoints sizes the pool of recurrent-state "
                 "checkpoints: it needs a model with recurrent state and "
                 "prefix_cache=True")
+        if any(k.kind == "paged_latent" for k in kinds or ()):
+            # one latent pool a layer: no K/V pair to quantise head by head,
+            # nothing the tiers' page blocks or a verify ladder were tested on
+            why = (f"{type(model).__name__} keeps one latent page pool a "
+                   "layer (multi-head latent attention): ")
+            if cache_dtype == "int8":
+                raise ValueError(
+                    why + "cache_dtype='int8' quantises K and V pages a head, "
+                    "and a latent row has neither")
+            if host_cache_pages:
+                raise ValueError(
+                    why + "host_cache_pages (the KV tiers) has not been "
+                    "taken through a latent pool")
+            if spec_k:
+                raise ValueError(
+                    why + "spec_k > 0 needs a verify step over the latent "
+                    "pages, which the model does not have")
         if cache_dtype == "int8" and any(k.compressed for k in kinds or ()):
             raise ValueError(
                 f"{type(model).__name__} selects key blocks by compressed keys "
@@ -806,9 +850,14 @@ class LLMEngine:
                 return (jnp.zeros((P, k.kv_heads, ps // k.compressed,
                                    k.head_dim), kv_dtype),)
 
+            from ..ops.latent_attention import pool_width
+
             self.caches = [
                 pools(k.kv_heads, k.head_dim) + compressed(k)
                 if k.kind == "paged_kv"
+                else (jnp.zeros((P, ps, pool_width(k.latent_dim, k.rope_dim)),
+                                kv_dtype),)
+                if k.kind == "paged_latent"
                 else tuple(jnp.zeros((B,) + tuple(shape), dt)
                            for _, shape, dt in k.state)
                 for k in kinds]
@@ -915,6 +964,14 @@ class LLMEngine:
                 (len(_MOE_PROGRAMS), len(_SPARSE_FIELDS)), jnp.int32)
             self._sparse_seen = np.zeros(self._sparse_acc.shape, np.uint32)
             self._sparse_stats = {p: dict.fromkeys(_SPARSE_FIELDS, 0)
+                                  for p in _MOE_PROGRAMS}
+        # latent attention layers report (query calls, context tokens)
+        self._latent_acc = None
+        if any(k.kind == "paged_latent" for k in kinds or ()):
+            self._latent_acc = jnp.zeros(
+                (len(_MOE_PROGRAMS), len(_LATENT_FIELDS)), jnp.int32)
+            self._latent_seen = np.zeros(self._latent_acc.shape, np.uint32)
+            self._latent_stats = {p: dict.fromkeys(_LATENT_FIELDS, 0)
                                   for p in _MOE_PROGRAMS}
         # what the layers keep, for stats(): {kind: {"layers", "bytes"}}
         self._cache_stats = {}
@@ -1390,6 +1447,11 @@ class LLMEngine:
         if self._sparse_acc is not None:
             sparse = {p: dict(v) for p, v in self._sparse_stats.items()}
             sparse["layers"] = sum(bool(k.compressed) for k in self._cache_kinds)
+        latent = None
+        if self._latent_acc is not None:
+            latent = {p: dict(v) for p, v in self._latent_stats.items()}
+            latent["layers"] = sum(k.kind == "paged_latent"
+                                   for k in self._cache_kinds)
         moe = None
         if self._moe_kinds:
             moe = {p: dict(v) for p, v in self._moe_stats.items()}
@@ -1402,7 +1464,7 @@ class LLMEngine:
             "n_slots": self.n_slots,
             "kv_layout": "paged",  # fleetwatch and the replica wire show it
             # what the layers keep: {kind: {"layers", "bytes"}} over
-            # paged_kv, recurrent and none
+            # paged_kv, paged_latent, recurrent and none
             "cache_kinds": cache_kinds,
             # per-slot state of the recurrent layers; None without any
             # (+ the pool of state checkpoints under the prefix cache)
@@ -1413,6 +1475,9 @@ class LLMEngine:
             # layer), their contexts' blocks and the blocks they selected,
             # by program; None without any
             "sparse_attention": sparse,
+            # latent attention layers: query calls (a real row a layer) and
+            # the tokens of their contexts, by program; None without any
+            "latent_attention": latent,
             # expert layers' routed pairs by program; None without any
             "moe": moe,
             "llm_kv_pages_in_use": pages_used,
@@ -1936,7 +2001,8 @@ class LLMEngine:
                 # only page pools fork: a state layer's arrays are a slot's
                 if kinds is None:
                     return copy_pools(caches, src, dst)
-                paged = [i for i, k in enumerate(kinds) if k.kind == "paged_kv"]
+                paged = [i for i, k in enumerate(kinds)
+                         if k.kind in ("paged_kv", "paged_latent")]
                 out = list(caches)
                 for i, c in zip(paged, copy_pools([caches[i] for i in paged], src, dst)):
                     out[i] = c
@@ -2506,9 +2572,10 @@ class LLMEngine:
     def _accs(self):
         """The device accumulators the programs add to and hand back, in
         their fixed order: the expert layers' pairs, the block-sparse
-        layers' blocks (each only with such layers)."""
-        return tuple(a for a in (self._moe_acc, self._sparse_acc)
-                     if a is not None)
+        layers' blocks, the latent layers' context tokens (each only with
+        such layers)."""
+        return tuple(a for a in (self._moe_acc, self._sparse_acc,
+                                 self._latent_acc) if a is not None)
 
     def _chunk_extra(self, slot):
         """The chunk program's trailing arguments for a model that declares
@@ -2526,6 +2593,8 @@ class LLMEngine:
             self._moe_acc = rest.pop(0)
         if self._sparse_acc is not None:
             self._sparse_acc = rest.pop(0)
+        if self._latent_acc is not None:
+            self._latent_acc = rest.pop(0)
         return first
 
     def _took_decode(self, out):
@@ -2568,16 +2637,28 @@ class LLMEngine:
         self._moe_max_load = int(delta[0, :, :-1].max())
         _M_MOE_MAX_LOAD.set(self._moe_max_load)
 
-    def _publish_sparse(self, total):
-        """The block-sparse layers' accumulator as the decode tick brought
-        it back, flat: publishes what both programs added since."""
-        total = total.astype(np.uint32).reshape(self._sparse_seen.shape)
-        delta = (total - self._sparse_seen).astype(np.int64)  # wraps like int32
-        self._sparse_seen = total
-        for i, prog in enumerate(_MOE_PROGRAMS):
-            for f, d in zip(_SPARSE_FIELDS, delta[i]):
-                self._sparse_stats[prog][f] += int(d)
-            _M_SPARSE_SELECTED.labels(program=prog).inc(int(delta[i, 2]))
+    def _publish_counts(self, counts):
+        """The attention layers' accumulators as the decode tick brought
+        them back behind the experts', flat and in _accs() order: publishes
+        what both programs added to each since."""
+        groups = []
+        if self._sparse_acc is not None:
+            groups.append((self._sparse_seen, self._sparse_stats,
+                           _SPARSE_FIELDS, _M_SPARSE_SELECTED))
+        if self._latent_acc is not None:
+            groups.append((self._latent_seen, self._latent_stats,
+                           _LATENT_FIELDS, _M_LATENT_CONTEXT))
+        at = 0 if self._moe_acc is None else self._moe_seen.size
+        for seen, stats, fields, series in groups:
+            total = counts[at:at + seen.size].astype(np.uint32).reshape(seen.shape)
+            at += seen.size
+            delta = (total - seen).astype(np.int64)  # wraps like int32
+            seen[...] = total
+            for i, prog in enumerate(_MOE_PROGRAMS):
+                for f, d in zip(fields, delta[i]):
+                    stats[prog][f] += int(d)
+                # the series counts the last field: what the layers read
+                series.labels(program=prog).inc(int(delta[i, -1]))
 
     def _admit_paged(self):
         """Chunked-prefill admission: at most ONE prompt chunk per tick, so
@@ -3430,8 +3511,8 @@ class LLMEngine:
             or time.perf_counter()  # the clock is off: read it
         if fl.moe is not None:
             self._publish_moe(counts[:self._moe_seen.size], fl.moe)
-        if self._sparse_acc is not None:
-            self._publish_sparse(counts[-self._sparse_seen.size:])
+        if self._sparse_acc is not None or self._latent_acc is not None:
+            self._publish_counts(counts)
         emitted = 0
         for i, req in fl.rows:
             if self.slot_req[i] is not req:

@@ -2,7 +2,8 @@
 
 Which layers keep a k/v cache at all is a model's ``cache_kinds()`` (one
 ``CacheKind`` a layer, below; ``SlotRows`` is what the layers with per-slot
-state are handed); a model without it keeps one in every layer.  The k/v
+state are handed, ``SparsePaged`` and ``LatentPaged`` what a block-sparse and
+a latent attention layer are); a model without it keeps one in every layer.  The k/v
 layouts themselves are four, distinguished by tuple length (see
 generation.generate and inference/llm_server.py):
   (k_buf, v_buf, pos)                      — plain static, cache dtype = kv dtype
@@ -84,10 +85,22 @@ class CacheKind:
                    and the context's and the selected blocks ([3] int32)
       "recurrent"  fixed-size state a SLOT, not a page: ``state`` lists
                    (name, shape of one slot's, dtype)
-      "none"       nothing (a feed-forward or expert layer); with
-                   ``experts_held`` > 0 the layer reports, per call, its
-                   pairs by held expert and the experts touched
-                   ([experts_held + 1] int32) in place of a cache
+      "paged_latent"  ONE page pool [pages, page_size, width] of a
+                   multi-head latent attention layer: a token's row holds its
+                   compressed latent (``latent_dim`` values, from which every
+                   head's keys AND values are projections) and the one rotary
+                   key all heads share (``rope_dim``), padded to whole lanes
+                   (ops/latent_attention.py: ``pool_width``).  No V pool.
+                   The layer takes a ``LatentPaged`` and reports, per call,
+                   its real queries and their contexts' tokens ([2] int32);
+                   the same pages, table, refcounts, prefix cache and COW
+                   fork as "paged_kv".  int8 pages, the KV tiers and
+                   speculation are refused for it (LLMEngine)
+      "none"       nothing (a feed-forward or expert layer)
+
+    A layer of any kind whose feed-forward is routed experts sets
+    ``experts_held`` > 0 (and ``top_k``): it also reports, per call, its
+    pairs by held expert and the experts touched ([experts_held + 1] int32).
     """
     kind: str
     kv_heads: int = 0
@@ -96,6 +109,8 @@ class CacheKind:
     experts_held: int = 0
     top_k: int = 0
     compressed: int = 0
+    latent_dim: int = 0
+    rope_dim: int = 0
 
 
 class SlotRows(NamedTuple):
@@ -121,6 +136,15 @@ class SparsePaged(NamedTuple):
     v: Any
     ck: Any        # [pages, kv_heads, page_size / compressed, head_dim]
     pos: Any       # int32 [b]
+    page_tbl: Any  # int32 [b, max_pages]
+    rows: Any      # SlotRows
+
+
+class LatentPaged(NamedTuple):
+    """What a "paged_latent" layer is handed: its one pool, where the batch's
+    rows stand, and the rows themselves."""
+    pool: Any      # [pages, page_size, width]
+    pos: Any       # int32 [b] (or a scalar)
     page_tbl: Any  # int32 [b, max_pages]
     rows: Any      # SlotRows
 
@@ -209,8 +233,8 @@ def cow_copy_pages(caches, src, dst):
     """Copy page ``src``'s rows into page ``dst`` across every layer's
     pools — the device side of a COPY-ON-WRITE fork.  ``caches`` is the
     engine's per-layer list of pool tuples (k/v pools, plus scale pools in
-    the int8 layout — every element is ``[P, ...]`` page-major, so one
-    generic row copy covers both layouts).  The caller then repoints the
+    the int8 layout, or a latent layer's one pool — every element is
+    ``[P, ...]`` page-major, so one generic row copy covers them all).  The caller then repoints the
     writing slot's page-table entry at ``dst``; readers of ``src`` are
     untouched."""
     return [tuple(x.at[dst].set(x[src]) for x in c) for c in caches]

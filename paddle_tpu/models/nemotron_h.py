@@ -43,7 +43,6 @@ from ..ops import ssm_update as _ssm
 from ..tensor.tensor import Tensor
 from .kv_cache import CacheKind, SlotRows, paged_attention_update
 
-HI = jax.lax.Precision.HIGHEST
 
 
 @dataclass
@@ -231,16 +230,10 @@ class NemotronHMoE(_Mixer):
     def route(self, x):
         """x [T, h] -> (expert int32 [T, K], weight float32 [T, K])."""
         c = self.config
-        with jax.named_scope("moe_router"):
-            s = jax.nn.sigmoid(jnp.matmul(
-                x.astype(jnp.float32), self.gate_weight._value.astype(jnp.float32),
-                precision=HI))
-            _, idx = jax.lax.top_k(s + self.e_score_correction_bias._value,
-                                   c.num_experts_per_tok)
-            w = jnp.take_along_axis(s, idx, axis=-1)
-            if c.norm_topk_prob:
-                w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-            return idx.astype(jnp.int32), w * c.routed_scaling_factor
+        return _moe.route(x, self.gate_weight._value,
+                          self.e_score_correction_bias._value,
+                          c.num_experts_per_tok, c.norm_topk_prob,
+                          c.routed_scaling_factor)
 
     def forward(self, u, sr):
         """u [B, S, h] raw; sr SlotRows or None.  Returns (out, counts

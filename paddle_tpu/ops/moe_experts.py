@@ -5,19 +5,25 @@ Every token is routed over all the model's experts; a device that holds the
 experts [lo, hi) computes, for each (token, expert) pair whose expert it
 holds,
 
-    w * W2[e] relu(W1[e] u)^2            (NOT gated: one up-projection)
+    w * W2[e] relu(W1[e] u)^2                    (one up-projection), or
+    w * W2[e] (silu(Wg[e] u) * W1[e] u)          (GATED: `w_gate` given)
 
 and leaves out the pairs of absent experts (on another device they would be
 that device's part).  No token is dropped: there is no capacity.  The pairs
 are sorted by expert and laid out in row tiles of TILE rows that belong to
-one expert each; the Pallas kernel ``moe_experts`` walks the tiles, the
-tile -> expert map rides scalar prefetch, and an expert's two matrices are
-fetched once for its run of tiles and not at all when nobody chose it.  At a
+one expert each; the Pallas kernel ``moe_experts`` (``moe_glu_experts`` in
+the gated form: one op, two names, so a trace tells the two apart) walks the
+tiles, the tile -> expert map rides scalar prefetch, and an expert's two (or
+three) matrices are fetched once for its run of tiles and not at all when
+nobody chose it.  At a
 decode tick's few rows an expert the kernel streams weights: its time is the
 bytes of the experts touched.
 
 ``counts`` ([held experts + 1] int32) comes back for the engine's counters:
 pairs by held expert over the rows marked real, and how many experts had any.
+
+``route`` is the router both expert models call: sigmoid scores over all the
+experts, the best `top_k` by score + correction bias, normalised, scaled.
 """
 from __future__ import annotations
 
@@ -29,8 +35,26 @@ from jax.experimental.pallas import tpu as pltpu
 from ._prng import interpret_default as _interpret_default
 
 #: VMEM for one expert's two matrices, double-buffered (2 x 2 x 10 MB at the
-#: published 2688 x 1856) plus the row tiles
+#: published 2688 x 1856; three of 3.1 MB in the gated form at 2048 x 768)
+#: plus the row tiles
 _VMEM_LIMIT = 56 * 1024 * 1024
+HI = jax.lax.Precision.HIGHEST
+
+
+def route(x, gate_weight, bias, top_k, norm_topk_prob, scaling):
+    """x [T, h] -> (expert int32 [T, K], weight float32 [T, K]): scores
+    sigmoid(W_r x) in float32 over ALL experts, the K best by score + bias
+    (the bias chooses, it does not weigh), weights s / (sum s + 1e-20) x
+    scaling."""
+    with jax.named_scope("moe_router"):
+        s = jax.nn.sigmoid(jnp.matmul(
+            x.astype(jnp.float32), gate_weight.astype(jnp.float32),
+            precision=HI))
+        _, idx = jax.lax.top_k(s + bias, top_k)
+        w = jnp.take_along_axis(s, idx, axis=-1)
+        if norm_topk_prob:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        return idx.astype(jnp.int32), w * scaling
 
 
 def _tile_rows(n_pairs):
@@ -50,7 +74,20 @@ def _kernel(te_ref, nt_ref, x_ref, w1_ref, w2_ref, o_ref):
         o_ref[...] = jnp.dot(h, w2_ref[0], preferred_element_type=jnp.float32)
 
 
-def _grouped_pallas(xs, w1, w2, tile_expert, n_tiles, tm, interpret):
+def _glu_kernel(te_ref, nt_ref, x_ref, wg_ref, w1_ref, w2_ref, o_ref):
+    del te_ref
+
+    @pl.when(pl.program_id(0) < nt_ref[0])
+    def _():
+        up = lambda w: jax.lax.dot_general(  # noqa: E731
+            x_ref[...], w[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        h = (jax.nn.silu(up(wg_ref)) * up(w1_ref)).astype(w2_ref.dtype)
+        o_ref[...] = jnp.dot(h, w2_ref[0], preferred_element_type=jnp.float32)
+
+
+def _grouped_pallas(xs, w1, w2, tile_expert, n_tiles, tm, interpret,
+                    w_gate=None):
     """xs [tiles*tm, H] rows sorted into per-expert tiles -> float32
     [tiles*tm, H].  Tiles at and past `n_tiles` are not computed, and their
     block indices repeat the last real tile's, so nothing is fetched."""
@@ -58,16 +95,14 @@ def _grouped_pallas(xs, w1, w2, tile_expert, n_tiles, tm, interpret):
     F = w1.shape[1]
     last = lambda nt: jnp.maximum(nt[0] - 1, 0)  # noqa: E731
     row_map = lambda t, te, nt: (jnp.minimum(t, last(nt)), 0)  # noqa: E731
+    expert = pl.BlockSpec((1, F, H), lambda t, te, nt: (te[t], 0, 0))
+    mats = (w1, w2) if w_gate is None else (w_gate, w1, w2)
     return pl.pallas_call(
-        _kernel,
+        _kernel if w_gate is None else _glu_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(R // tm,),
-            in_specs=[
-                pl.BlockSpec((tm, H), row_map),
-                pl.BlockSpec((1, F, H), lambda t, te, nt: (te[t], 0, 0)),
-                pl.BlockSpec((1, F, H), lambda t, te, nt: (te[t], 0, 0)),
-            ],
+            in_specs=[pl.BlockSpec((tm, H), row_map)] + [expert] * len(mats),
             out_specs=pl.BlockSpec((tm, H), row_map),
         ),
         out_shape=jax.ShapeDtypeStruct((R, H), jnp.float32),
@@ -75,8 +110,8 @@ def _grouped_pallas(xs, w1, w2, tile_expert, n_tiles, tm, interpret):
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT),
-        name="moe_experts",
-    )(tile_expert, n_tiles, xs, w1, w2)
+        name="moe_experts" if w_gate is None else "moe_glu_experts",
+    )(tile_expert, n_tiles, xs, *mats)
 
 
 def _layout(local, n_held, tm):
@@ -108,7 +143,7 @@ def _layout(local, n_held, tm):
     return dest, tile_expert.astype(jnp.int32), n_tiles.astype(jnp.int32)
 
 
-def _dense(x, w1, w2, local, weight):
+def _dense(x, w1, w2, local, weight, w_gate=None):
     """Fallback: every held expert over every token, masked.  For the CPU
     and for sizes the kernel's tiling does not fit."""
     n_held = w1.shape[0]
@@ -117,7 +152,12 @@ def _dense(x, w1, w2, local, weight):
         jnp.arange(x.shape[0])[:, None], local].add(weight.astype(f32))[:, :n_held]
     # float32 operands: the CPU has no bf16 x bf16 -> f32 product
     h = jnp.einsum("th,efh->tef", x.astype(f32), w1.astype(f32))
-    h = jnp.square(jnp.maximum(h, 0.0)).astype(w2.dtype).astype(f32)
+    if w_gate is None:
+        h = jnp.square(jnp.maximum(h, 0.0))
+    else:
+        h = jax.nn.silu(jnp.einsum("th,efh->tef", x.astype(f32),
+                                   w_gate.astype(f32))) * h
+    h = h.astype(w2.dtype).astype(f32)
     y = jnp.einsum("tef,efh->teh", h, w2.astype(f32))
     return jnp.einsum("teh,te->th", y, cw)
 
@@ -130,16 +170,18 @@ def kernel_ok(x, w1):
 
 
 def moe_experts(x, w1, w2, expert, weight, lo, real=None, use_kernel=None,
-                interpret=None):
+                interpret=None, w_gate=None):
     """x [T, H]; w1 and w2 [held, F, H] (the up-projection as [out, in], the
     down-projection as [in, out]: H, a whole number of lanes, is minor in
     both): the experts [lo, lo + held) of the layer; expert int32 [T, K] and weight [T, K]: each
     token's choices among ALL experts and their normalised scores; real bool
-    [T]: rows that are traffic (padding is computed but not counted).
+    [T]: rows that are traffic (padding is computed but not counted);
+    w_gate [held, F, H]: the gate of the gated form (silu(Wg u) * W1 u in
+    place of relu(W1 u)^2), laid out as w1.
     Returns (float32 [T, H]: the held experts' part, counts [held + 1])."""
     T, K = expert.shape
     n_held = w1.shape[0]
-    with jax.named_scope("moe_experts"):
+    with jax.named_scope("moe_experts" if w_gate is None else "moe_glu_experts"):
         local = expert.astype(jnp.int32) - lo
         local = jnp.where((local >= 0) & (local < n_held), local, n_held)
         if use_kernel is None:
@@ -151,7 +193,7 @@ def moe_experts(x, w1, w2, expert, weight, lo, real=None, use_kernel=None,
         counts = jnp.concatenate([counts, jnp.sum(counts > 0, keepdims=True,
                                                   dtype=jnp.int32)])
         if not use_kernel:
-            return _dense(x, w1, w2, local, weight), counts
+            return _dense(x, w1, w2, local, weight, w_gate), counts
         if interpret is None:
             interpret = _interpret_default()
         tm = _tile_rows(T * K)
@@ -161,7 +203,8 @@ def moe_experts(x, w1, w2, expert, weight, lo, real=None, use_kernel=None,
         token = jnp.full((rows + 1,), T, jnp.int32).at[dest].set(
             jnp.repeat(jnp.arange(T, dtype=jnp.int32), K))[:rows]
         xs = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)])[token]
-        ys = _grouped_pallas(xs, w1, w2, tile_expert, n_tiles, tm, interpret)
+        ys = _grouped_pallas(xs, w1, w2, tile_expert, n_tiles, tm, interpret,
+                             w_gate)
         ys = jnp.concatenate([ys, jnp.zeros((1, ys.shape[1]), ys.dtype)])
         picked = ys[dest].reshape(T, K, -1)   # unserved pairs read the zero row
         out = jnp.einsum("tkh,tk->th", picked, weight.astype(jnp.float32))

@@ -330,6 +330,33 @@ def test_aot_sparse_and_lightning_kernels_at_published_widths(v5e_devices, as_on
 
 
 @pytest.mark.slow
+def test_aot_latent_and_gated_expert_kernels_at_published_widths(v5e_devices, as_on_tpu):
+    """Kanana-2's widths at the cell's sizes: the latent decode pass (64
+    slots, 32 heads on rows of 512 + 64 values in 640 lanes, tables of 134
+    pages) and the gated experts (128 held, three 768 x 2048 matrices each) at
+    a decode tick's rows and at a chunk's."""
+    from paddle_tpu.ops import latent_attention as la
+    from paddle_tpu.ops import moe_experts as moe
+
+    one = _one_device(v5e_devices)
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    B, H, P, M = 64, 32, 1793, 134
+    calls = _mosaic_calls(
+        lambda qa, qr, pool, tbl, n: la.latent_decode_attention(
+            qa, qr, pool, tbl, n, 192 ** -0.5),
+        one, ((B, H, 512), bf), ((B, H, 64), bf), ((P, 128, 640), bf),
+        ((B, M), i32), ((B,), i32))
+    assert sum("latent_attention" in c for c in calls) == 1, calls
+    for T in (64, 256):
+        calls = _mosaic_calls(
+            lambda x, wg, w1, w2, idx, w: moe.moe_experts(x, w1, w2, idx, w, 0,
+                                                          w_gate=wg)[0],
+            one, ((T, 2048), bf), *[((128, 768, 2048), bf)] * 3,
+            ((T, 6), i32), ((T, 6), f32))
+        assert sum("moe_glu_experts" in c for c in calls) == 1, (T, calls)
+
+
+@pytest.mark.slow
 def test_aot_sampler_keeps_its_conditionals_at_a_64k_vocabulary(v5e_devices):
     """The v5e's compiler leaves the fused sampler's two conditionals in the
     decode scan, with the ONE sort of `[64, 65536]` inside the inner branch:
@@ -375,6 +402,7 @@ def test_smoke_phases_at_tiny_size_on_cpu(smoke, monkeypatch):
     assert set(smoke.kernel_phase(**geom)) == {
         "S1_bf16", "S1_int8", "S256_bf16", "S256_int8", "S5_bf16", "S5_int8"}
     assert smoke.state_phase(hidden=128) < 0.05
+    assert smoke.latent_phase(doc_tokens=150) < 0.05
     losses = smoke.train_phase(n_layers=2, batch=4, seq=128, **tiny)
     assert smoke.mesh_phase(losses[0], n_layers=2, batch=4, seq=128,
                             **tiny) is not None
