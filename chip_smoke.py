@@ -327,7 +327,8 @@ def kernel_phase(heads, kv_heads, head_dim, page, slots, num_pages,
                  max_pages, chunk):
     """The ragged paged kernel against the same call forced dense, at the
     engine's own shapes: S=1 decode, one S=chunk prefill chunk at a
-    non-zero offset, and the S=5 speculative-verify ladder."""
+    non-zero offset, and the S=5 speculative-verify ladder; then at the chat
+    cell's decode shape, and timed at 1 page a slot and at 14."""
     import jax
     import jax.numpy as jnp
 
@@ -371,6 +372,38 @@ def kernel_phase(heads, kv_heads, head_dim, page, slots, num_pages,
             check(np.isfinite(got).all() and err < KERNEL_TOL[name],
                   f"paged kernel vs dense, S={S} B={B} {name}: max abs err "
                   f"{err:.2e} < {KERNEL_TOL[name]}")
+
+    # the chat cell's decode tick: 32 slots with tables of 32 entries, 12
+    # live at 5-6 pages, 20 masked to the trash page at a stale position
+    B, M, deep = 32, 32, 14
+    kp, vp = (normal(1 + B * deep, kv_heads, page, head_dim) for _ in range(2))
+    free = rng.permutation(np.arange(1, 1 + B * deep))
+    live = np.arange(B) < 12
+    offs = np.where(live, rng.integers(4 * page, 6 * page, B),
+                    7 * page + 4).astype(np.int32)
+    tbl = np.zeros((B, M), np.int32)
+    tbl[live, :6] = free[:12 * 6].reshape(12, 6)
+    q = normal(B, 1, heads, head_dim)
+    got = attend(None, q, offs, tbl, (kp, vp))
+    want = attend("dense", q, offs, tbl, (kp, vp))
+    errs["chat_bf16"] = err = float(np.max(np.abs(got - want)[live]))
+    check(np.isfinite(got).all() and err < KERNEL_TOL["bf16"]
+          and not got[~live].any(),
+          f"paged kernel vs dense, 12 of {B} slots live in {M}-entry tables: "
+          f"max abs err {err:.2e} < {KERNEL_TOL['bf16']}, masked slots zero")
+    # what a call costs follows what the slots hold, not the table's width
+    call = jax.jit(da.paged_decode_attention)
+    for n in (1, deep):
+        tbl = np.zeros((B, M), np.int32)
+        tbl[:, :n] = free[:B * n].reshape(B, n)
+        args = (q, kp, vp, np.full(B, n * page - 1, np.int32), tbl)
+        call(*args).block_until_ready()
+        t0 = time.perf_counter()
+        for _ in range(20):
+            out = call(*args)
+        out.block_until_ready()
+        log(f"kernel: {B} slots x {n} page(s) in {M}-entry tables: "
+            f"{(time.perf_counter() - t0) / 20 * 1e3:.3f} ms a call")
     return errs
 
 

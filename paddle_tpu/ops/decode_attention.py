@@ -1,10 +1,14 @@
-"""Pallas TPU single-query (decode) attention over a static kv-cache.
+"""Pallas TPU decode attention: one query a row against a static kv-cache,
+and ragged query blocks against a PAGED one.
 
 Reference gap: the snapshot has no decode-path attention at all (its
 AnalysisPredictor era predates kv-cache serving); the XLA-composed decode
 attention this replaces reads the head-minor [B, L, H, D] cache through
 strided gathers and realizes well under half of the chip's streaming
-bandwidth.  This kernel owns the decode hot loop instead:
+bandwidth.  These kernels own the decode hot loops instead.
+
+`decode_attention` (kernel `decode_attention`, the compiled generate()
+loop of models/generation.py):
 
 - the static cache is HEAD-MAJOR [B, H, L, D]: each (batch, head) grid point
   streams its keys/values as one contiguous [L, D] block (minor dims satisfy
@@ -20,8 +24,15 @@ bandwidth.  This kernel owns the decode hot loop instead:
 - the valid-length mask rides a scalar-prefetch argument, replacing the
   [1, 1, S, L] additive-mask tensor the composed path rebuilt every step.
 
-Forward-only by design: decode runs under no_grad inside the compiled
-generate() loop (models/generation.py).
+`paged_decode_attention` (kernel `paged_attention`, every attention call of
+LLMEngine's decode, prefill-chunk and verify programs): a page pool
+[P, Hkv, page_size, D] read through per-slot page tables.  The grid is over
+slots; a slot walks its OWN pages once — a loop whose trip count comes from
+the slot's length, not from the table's width — in groups of whole pages
+fetched by explicit double-buffered copies, each group scored at once; a
+masked slot walks nothing.  See the block above `gather_pages`.
+
+Forward-only by design: decode runs under no_grad.
 """
 from __future__ import annotations
 
@@ -285,15 +296,23 @@ def decode_attention(q, k, v, offset, k_scale=None, v_scale=None, scale=None,
 # offsets, and the S=K+1 speculative-verify ladder — the per-slot (offset,
 # query-length) pair rides the scalar-prefetched `lengths` vector
 # (lengths[b] = offset[b] + S) and drives a per-ROW causal mask inside the
-# online-softmax page loop: query s of slot b attends keys
-# [0, lengths[b] - S + s].  The kernel walks each slot's pages through the
-# scalar-prefetched page table: the BlockSpec index map reads pt_ref[b, ·],
-# so the pipeline DMAs exactly the pages the slot owns.  Slots shorter than
-# max_pages point their unused table entries at the trash page
-# (kv_cache.TRASH_PAGE) — the index map CLAMPS the walk to the slot's last
-# valid page, so the ragged tail repeats a block index the pipeline has
-# already fetched and the trash page is never DMA'd at all (trash-fetch
-# elision; the tail compute is skipped by the valid-length gate).
+# online-softmax loop: query s of slot b attends keys
+# [0, lengths[b] - S + s].  The grid is over slots (and kv-head groups where
+# a query block's state does not fit VMEM at every head); the pools stay in
+# HBM, and a grid step walks ITS slot's pages once: a loop whose trip count
+# is the slot's own ceil(lengths[b] / (pages a step * page_size)), each
+# step fetching a group of whole pages through the scalar-prefetched table
+# by explicit copies (the next group in flight while this one is scored).
+# A table's width costs nothing: entries past the slot's last page are
+# never read, fetched or visited.  A slot whose table OPENS on the trash
+# page (kv_cache.TRASH_PAGE: the engine masks idle and mid-prefill slots
+# so) walks nothing, whatever stale position it is handed, and gets zeros.
+
+TRASH_PAGE = 0  # models.kv_cache.TRASH_PAGE: never allocated
+#: what _pick_walk_paged's bounds add up to (4 MB of page buffers, 6 of query
+#: state, the 8 MB score tile) and the compiler's own copies of that tile
+#: (probabilities, their cast): 16.3 MB at the widest shape compiled
+_PAGED_VMEM_LIMIT = 40 * 1024 * 1024
 
 
 def gather_pages(pool, page_tbl):
@@ -308,128 +327,204 @@ def gather_pages(pool, page_tbl):
     return jnp.transpose(g, (0, 2, 1, 3)).reshape(B, H, M * ps)
 
 
-def _paged_kernel(len_ref, pt_ref, q_ref, k_ref, v_ref, *refs, ps, S, G,
-                  rep, scale, quant):
-    """One (slot, kv-head-group, page) grid step: fold this page's keys and
-    values into the slot's online-softmax state (m/l/acc VMEM scratch that
-    persists across the sequential page axis).  The query block is RAGGED:
-    its rows are laid out [G kv heads, S query positions, rep query heads]
-    (row g*S*rep + s*rep + r is query position s of query head g*rep + r),
-    so one [S*rep, D] x [ps, D]^T dot per kv head scores every query row of
-    that head at once, and a per-row causal threshold
+def _paged_kernel(len_ref, pt_ref, q_ref, k_hbm, v_hbm, *refs, ps, M, S, G,
+                  W, rep, scale, quant):
+    """One (slot, kv-head-group) grid step: walk the slot's pages in groups
+    of W, folding each group's W*ps keys and values into the online-softmax
+    state (m/l/acc VMEM scratch).  The query block is RAGGED: its rows are
+    laid out [G kv heads, S query positions, rep query heads] (row
+    g*S*rep + s*rep + r is query position s of query head g*rep + r), so
+    one [S*rep, D] x [W*ps, D]^T dot per kv head scores every query row of
+    that head against the whole group, and a per-row causal threshold
     lengths[b] - S + s + 1 masks each row to its own prefix — S=1 decode,
     prefill chunks, and the K+1 verify ladder are the SAME kernel at
     different static S.  int8 pages dequantize in VMEM: payload cast once
-    per page, per-(head, token) scales applied to the score/probability
-    rows outside the dots (the static kernel's recipe)."""
-    if quant:  # inputs continue with the scale pages, THEN output + scratch
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = refs
-    else:
-        o_ref, m_ref, l_ref, acc_ref = refs
-        ks_ref = vs_ref = None
-    b = pl.program_id(0)
-    p = pl.program_id(2)
-    M = pl.num_programs(2)
-    valid = len_ref[b]
+    per group, per-(head, token) scales applied to the score/probability
+    rows outside the dots (the static kernel's recipe).
+
+    Two buffers of W pages a pool: while a group is scored the next is in
+    flight, and under a step's LAST group that is the first group of the
+    grid step after it (the grid runs in order; `at_ref` hands on which
+    buffer it went to), so only the call's very first fetch is waited for
+    with nothing to do."""
+    # inputs continue with the scale pools, THEN output + scratch
+    pools = (k_hbm, v_hbm) + (tuple(refs[:2]) if quant else ())
+    o_ref, m_ref, l_ref, acc_ref, sem, at_ref, kbuf, vbuf, *sbufs = \
+        refs[len(pools) - 2:]
+    ksbuf, vsbuf = sbufs or (None, None)  # the scale pages' buffers (int8)
+    j, b = pl.program_id(0), pl.program_id(1)
+    ng, B = pl.num_programs(0), pl.num_programs(1)
+    r = b % q_ref.shape[0]  # this slot's place in the resident q/out block
+    KB = W * ps           # keys per loop step
     sg = S * rep          # query rows per kv head
     rows = G * sg         # query rows per grid step
     D = q_ref.shape[-1]
     Rp = q_ref.shape[-2]  # rows padded to the 8-sublane tile
 
-    @pl.when(p == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def walk(bb):
+        """(keys, pages) slot bb's walk covers: none where its table opens
+        on the trash page, no more pages than the table holds."""
+        n = jnp.where(pt_ref[bb * M] == TRASH_PAGE, 0, len_ref[bb])
+        return n, jnp.minimum((n + ps - 1) // ps, M)
 
-    @pl.when(p * ps < valid)
-    def _page():
-        if quant:
-            kb = k_ref[0].astype(jnp.bfloat16)  # [G, ps, D]
-            vb = v_ref[0].astype(jnp.bfloat16)
-        else:
-            kb, vb = k_ref[0], v_ref[0]
-        rows_s = []
-        for g in range(G):
-            qg = q_ref[0, 0, g * sg:(g + 1) * sg, :]  # [S*rep, D]
-            rows_s.append(jax.lax.dot_general(
-                qg, kb[g], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32))
+    def copies(bb, jj, slot, e, i):
+        """Entry i of slot bb's table, head group jj, every pool -> place e
+        of buffer `slot`."""
+        page, heads = pt_ref[bb * M + i], pl.ds(jj * G, G)
+        keys = pl.ds(pl.multiple_of(e * ps, ps), ps)
+        dst = [buf.at[slot, :, keys] for buf in (kbuf, vbuf)]
+        dst += [buf.at[slot, e] for buf in sbufs]
+        return [pltpu.make_async_copy(hbm.at[page, heads], d, sem.at[slot, e, n])
+                for n, (hbm, d) in enumerate(zip(pools, dst))]
+
+    def start(bb, jj, pages, slot, grp):
+        """Group `grp` of a walk of `pages` pages -> buffer `slot`: the
+        entries the walk has, none where it has none.  (Every condition
+        here is a trip count: a `pl.when` an entry cost seconds to trace.)"""
+        def one(e, _):
+            for c in copies(bb, jj, slot, e, grp * W + e):
+                c.start()
+        jax.lax.fori_loop(0, jnp.clip(pages - grp * W, 0, W), one, None)
+
+    valid, npages = walk(b)
+    ngrp = (npages + W - 1) // W
+    # the grid step after this one, whose first group this one fetches
+    nb = jnp.where(b + 1 == B, 0, b + 1)
+    nj = jnp.where(b + 1 == B, j + 1, j)
+    npages_next = jnp.where(nj < ng, walk(nb)[1], 0)
+
+    first = (b == 0) & (j == 0)  # nobody fetched this step's first group
+    start(b, j, jnp.where(first, npages, 0), 0, 0)
+    at = jnp.where(first, 0, at_ref[0])  # the buffer that group is in
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    # per-row causal end: row g*sg + s*rep + r is query position s, and
+    # query s of a slot whose lengths entry is `valid` = offset + S may
+    # read keys [0, offset + s] — i.e. kpos < valid - S + s + 1.  Row 0
+    # always has offset + 1 >= 1 valid keys, so group 0 (the only group
+    # guaranteed to be walked) leaves no row's running max at NEG_INF.
+    # Nothing past the pages walked is a key, whatever `valid` says.
+    ri = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    qend = jnp.minimum(valid - S + (ri // rep) % S + 1, npages * ps)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, KB), 1)
+    cast = (lambda x: x.astype(jnp.bfloat16)) if quant else (lambda x: x)
+
+    def body(grp, _):
+        slot = (at + grp) % 2
+
+        # under this group the next is fetched: this walk's, or after
+        # its last group the first of the grid step after this one
+        last = grp + 1 == ngrp
+        start(jnp.where(last, nb, b), jnp.where(last, nj, j),
+              jnp.where(last, npages_next, npages), 1 - slot,
+              jnp.where(last, 0, grp + 1))
+        live = jnp.minimum(npages - grp * W, W)
+
+        def wait(e, _):
+            for c in copies(b, j, slot, e, 0):
+                c.wait()
+
+        # an entry past the slot's last page is not fetched: its keys are
+        # masked, but a probability of 0 times whatever the buffer held
+        # (NaN bits, at worst) is not 0, so its values are zeroed
+        def zero(e, _):
+            if quant:
+                vsbuf[slot, e] = jnp.zeros(vsbuf.shape[2:], vsbuf.dtype)
+            else:
+                vbuf[slot, :, pl.ds(pl.multiple_of(e * ps, ps), ps)] = \
+                    jnp.zeros((G, ps, D), vbuf.dtype)
+
+        jax.lax.fori_loop(0, live, wait, None)
+        jax.lax.fori_loop(live, W, zero, None)
+
+        def scales(buf):  # [W, G, ps/128, 128] -> [rows, KB]
+            sc = jnp.concatenate(
+                [buf[slot, e].reshape(G, ps) for e in range(W)], axis=1)
+            return jnp.repeat(sc, sg, axis=0) if sg > 1 else sc
+
+        rows_s = [jax.lax.dot_general(
+            q_ref[r, 0, g * sg:(g + 1) * sg, :], cast(kbuf[slot, g]),
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            for g in range(G)]
         s = (jnp.concatenate(rows_s, axis=0) if G > 1
-             else rows_s[0]) * scale  # [rows, ps]
+             else rows_s[0]) * scale  # [rows, KB]
         if quant:
-            ks = ks_ref[0].reshape(G, ps)
-            s = s * (jnp.repeat(ks, sg, axis=0) if sg > 1 else ks)
-        # per-row causal end: row g*sg + s*rep + r is query position s, and
-        # query s of a slot whose lengths entry is `valid` = offset + S may
-        # read keys [0, offset + s] — i.e. kpos < valid - S + s + 1.  Row 0
-        # always has offset + 1 >= 1 valid keys, so page 0 (the only page
-        # guaranteed to participate) leaves no row's running max at NEG_INF.
-        ri = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
-        qend = valid - S + (ri // rep) % S + 1
-        kpos = p * ps + jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 1)
-        s = jnp.where(kpos < qend, s, NEG_INF)
+            s = s * scales(ksbuf)
+        s = jnp.where(col < qend - grp * KB, s, NEG_INF)
         m_prev = m_ref[:rows, :1]
-        l_prev = l_ref[:rows, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        pexp = jnp.exp(s - m_new)  # [rows, ps] f32
+        pexp = jnp.exp(s - m_new)  # [rows, KB] f32
         corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(pexp, axis=1, keepdims=True)
-        if quant:
-            vs = vs_ref[0].reshape(G, ps)
-            pexp = pexp * (jnp.repeat(vs, sg, axis=0) if sg > 1 else vs)
-        pb = pexp.astype(jnp.bfloat16 if quant else vb.dtype)
-        outs = []
-        for g in range(G):
-            outs.append(jax.lax.dot_general(
-                pb[g * sg:(g + 1) * sg], vb[g], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))
-        pv = jnp.concatenate(outs, axis=0) if G > 1 else outs[0]  # [rows, D]
+        l_ref[:rows, :1] = l_ref[:rows, :1] * corr + jnp.sum(
+            pexp, axis=1, keepdims=True)
         m_ref[:rows, :1] = m_new
-        l_ref[:rows, :1] = l_new
+        if quant:
+            pexp = pexp * scales(vsbuf)
+        pb = pexp.astype(jnp.bfloat16 if quant else vbuf.dtype)
+        outs = [jax.lax.dot_general(
+            pb[g * sg:(g + 1) * sg], cast(vbuf[slot, g]),
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            for g in range(G)]
+        pv = jnp.concatenate(outs, axis=0) if G > 1 else outs[0]  # [rows, D]
         acc_ref[:rows, :] = acc_ref[:rows, :] * corr + pv
 
-    @pl.when(p == M - 1)
-    def _emit():
-        l = l_ref[:rows, :1]
-        out = (acc_ref[:rows, :]
-               / jnp.where(l <= 0.0, 1.0, l)).astype(o_ref.dtype)
-        if Rp != rows:
-            out = jnp.concatenate(
-                [out, jnp.zeros((Rp - rows, D), o_ref.dtype)], axis=0)
-        o_ref[0, 0] = out
+    jax.lax.fori_loop(0, ngrp, body, None)
+
+    # a step that walked nothing still hands the next one its first group
+    start(nb, nj, jnp.where(ngrp == 0, npages_next, 0), at, 0)
+    at_ref[0] = (at + ngrp) % 2
+    l = l_ref[:rows, :1]
+    out = (acc_ref[:rows, :] / jnp.where(l <= 0.0, 1.0, l)).astype(o_ref.dtype)
+    if Rp != rows:
+        out = jnp.concatenate(
+            [out, jnp.zeros((Rp - rows, D), o_ref.dtype)], axis=0)
+    o_ref[r, 0] = out
 
 
 def _paged_state_bytes(rows, D):
     """VMEM bytes of the per-grid-step ragged query state: the q block plus
-    the f32 m/l/acc online-softmax scratch (shared bound between the group
+    the f32 m/l/acc online-softmax scratch (shared bound between the walk
     picker and the dispatcher's S cap)."""
     return rows * (4 * D            # q block (f32 worst case)
                    + 2 * 4 * 128    # m + l scratch rows
                    + 4 * D)         # acc scratch
 
 
-def _pick_group_paged(Hkv, ps, D, quant, S=1, rep=1):
-    """kv heads per grid step: page blocks are small (one page, not the
-    whole sequence), so the bounds are the double-buffered page pair
-    staying comfortably inside VMEM plus — now that query blocks are
-    ragged — the G*S*rep query rows of q/m/l/acc state."""
-    per_head = ps * D * (1 if quant else 2) * 2  # k + v page blocks
-    for g in (16, 8, 4, 2, 1):
-        if (Hkv % g == 0 and g * per_head <= 2 * 1024 * 1024
-                and _paged_state_bytes(g * S * rep, D) <= 6 * 1024 * 1024):
-            return g
-    return 1
+def _pick_walk_paged(Hkv, ps, D, quant, S, rep, M):
+    """(kv heads a grid step, pages a loop step).  Heads first: a page's
+    heads are one contiguous block, so a decode row's copies are whole
+    pages; then the widest step of at most M pages: a wide step amortises
+    the per-row work of the soft-max state (a chunk's 2,048 rows pay it a
+    step, whatever the step holds), and what a row's last step holds past
+    its last page costs no bytes.  The bounds are the two page buffers of
+    every pool, the G*S*rep query rows of q/m/l/acc state, and the
+    [rows, keys] float32 score tile."""
+    mib = 1024 * 1024
+    page = ps * D * (1 if quant else 2) * 2 * 2  # k + v, two buffers, a head
+
+    def fits(g, w):
+        rows = g * S * rep
+        return (Hkv % g == 0 and w <= M and g * w * page <= 4 * mib
+                and _paged_state_bytes(rows, D) <= 6 * mib
+                and rows * w * ps * 4 <= 8 * mib)
+
+    G = next((g for g in (16, 8, 4, 2, 1) if fits(g, 1)), 1)
+    return G, next((w for w in (8, 4, 2) if fits(G, w)), 1)
 
 
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def _paged_pallas(q, k_pages, v_pages, lengths, page_tbl, k_scale, v_scale,
                   scale, interpret):
+    """A jit of its own: a program with a call site a layer lowers the
+    kernel once."""
     B, S, H, D = q.shape
     Hkv, ps = k_pages.shape[1], k_pages.shape[2]
     M = page_tbl.shape[1]
     rep = H // Hkv
     quant = k_scale is not None
-    G = _pick_group_paged(Hkv, ps, D, quant, S, rep)
+    G, W = _pick_walk_paged(Hkv, ps, D, quant, S, rep, M)
     ng = Hkv // G
     rows = G * S * rep
     Rp = max(8, -(-rows // 8) * 8)  # 8-sublane tile floor for q/out blocks
@@ -444,56 +539,49 @@ def _paged_pallas(q, k_pages, v_pages, lengths, page_tbl, k_scale, v_scale,
     if Rp != rows:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, Rp - rows), (0, 0)))
     lengths = jnp.asarray(lengths, jnp.int32).reshape(B)
-    page_tbl = jnp.asarray(page_tbl, jnp.int32)
+    page_tbl = jnp.asarray(page_tbl, jnp.int32).reshape(-1)
 
-    # Index maps receive the prefetched (lengths, page-table) refs last;
-    # the page axis walks the slot's table — THE ragged gather.  Trash-fetch
-    # elision: grid steps past the slot's last valid page CLAMP to that last
-    # page, so the pipeline sees a repeated block index and skips the DMA
-    # entirely (the valid-length gate already skips the compute) — the
-    # ragged tail of a short slot in a long-max-len pool costs zero
-    # bandwidth instead of one trash-page fetch per (slot, head-group).
-    def _pidx(b, p, lens, pt):
-        return pt[b, jnp.minimum(p, jnp.maximum(lens[b] - 1, 0) // ps)]
-
-    in_specs = [
-        pl.BlockSpec((1, 1, Rp, D), lambda b, g, p, _len, _pt: (b, g, 0, 0)),
-        pl.BlockSpec((1, G, ps, D),
-                     lambda b, g, p, lens, pt: (_pidx(b, p, lens, pt), g, 0, 0)),
-        pl.BlockSpec((1, G, ps, D),
-                     lambda b, g, p, lens, pt: (_pidx(b, p, lens, pt), g, 0, 0)),
-    ]
+    # the prefetched (lengths, flat page table) refs reach the index maps
+    # last and the kernel first; the pools are handed over where they lie.
+    # Slots are the grid's inner axis and RB of them share one q/out block:
+    # the pipeline moves a block when its index changes, once in RB steps,
+    # where a block a step costs every step a copy's latency
+    RB = max(d for d in range(1, B + 1) if B % d == 0 and (
+        d == 1 or d * Rp * D * 16 <= 4 * 1024 * 1024))
+    qo_spec = pl.BlockSpec((RB, 1, Rp, D),
+                           lambda g, b, _len, _pt: (b // RB, g, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     args = [qg, k_pages, v_pages]
+    scratch = [pltpu.VMEM((Rp, 128), jnp.float32),
+               pltpu.VMEM((Rp, 128), jnp.float32),
+               pltpu.VMEM((Rp, D), jnp.float32),
+               pltpu.SemaphoreType.DMA((2, W, 4 if quant else 2)),  # a pool
+               pltpu.SMEM((1,), jnp.int32),
+               pltpu.VMEM((2, G, W * ps, D), k_pages.dtype),
+               pltpu.VMEM((2, G, W * ps, D), v_pages.dtype)]
     if quant:
         sb = ps // 128
-        in_specs += [
-            pl.BlockSpec((1, G, sb, 128),
-                         lambda b, g, p, lens, pt: (_pidx(b, p, lens, pt), g, 0, 0)),
-            pl.BlockSpec((1, G, sb, 128),
-                         lambda b, g, p, lens, pt: (_pidx(b, p, lens, pt), g, 0, 0)),
-        ]
         P = k_pages.shape[0]
         args += [k_scale.reshape(P, Hkv, sb, 128),
                  v_scale.reshape(P, Hkv, sb, 128)]
+        scratch += [pltpu.VMEM((2, W, G, sb, 128), jnp.float32)] * 2
 
-    kernel = functools.partial(_paged_kernel, ps=ps, S=S, G=G, rep=rep,
-                               scale=scale, quant=quant)
+    kernel = functools.partial(_paged_kernel, ps=ps, M=M, S=S, G=G, W=W,
+                               rep=rep, scale=scale, quant=quant)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(B, ng, M),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, 1, Rp, D), lambda b, g, p, _len, _pt: (b, g, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((Rp, 128), jnp.float32),
-                            pltpu.VMEM((Rp, 128), jnp.float32),
-                            pltpu.VMEM((Rp, D), jnp.float32)],
+            grid=(ng, B),
+            in_specs=[qo_spec] + [hbm] * (len(args) - 1),
+            out_specs=qo_spec,
+            scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((B, ng, Rp, D), q.dtype),
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_PAGED_VMEM_LIMIT),
         name="paged_attention",
     )(lengths, page_tbl, *args)
     out = out[:, :, :rows, :].reshape(B, ng, G, S, rep, D)

@@ -400,7 +400,8 @@ def test_smoke_phases_at_tiny_size_on_cpu(smoke, monkeypatch):
                              prompt_lens=(40, 440), new_tokens=8,
                              max_position_embeddings=512, **tiny)
     assert set(smoke.kernel_phase(**geom)) == {
-        "S1_bf16", "S1_int8", "S256_bf16", "S256_int8", "S5_bf16", "S5_int8"}
+        "S1_bf16", "S1_int8", "S256_bf16", "S256_int8", "S5_bf16", "S5_int8",
+        "chat_bf16"}
     assert smoke.state_phase(hidden=128) < 0.05
     assert smoke.latent_phase(doc_tokens=150) < 0.05
     losses = smoke.train_phase(n_layers=2, batch=4, seq=128, **tiny)

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.ops.decode_attention import (
     _decode_dense, _decode_pallas, _paged_dense, _paged_pallas,
-    decode_attention, gather_pages, paged_decode_attention)
+    _pick_walk_paged, decode_attention, gather_pages, paged_decode_attention)
 from paddle_tpu.models.kv_cache import _quantize_kv
 
 pytestmark = [pytest.mark.quick]
@@ -260,6 +260,94 @@ def test_paged_ragged_rows_match_single_query_calls():
                              interpret=True)
         np.testing.assert_allclose(np.asarray(got[:, s:s + 1]),
                                    np.asarray(solo), rtol=2e-5, atol=2e-5)
+
+
+# ------------------------------- the walk: a row's own pages, in groups
+#
+# The kernel's grid has no page axis: a row walks ceil(keys / group) groups
+# of `_pick_walk_paged(...)[1]` pages, fetched by explicit copies, and the
+# last group of a grid step starts the first of the next.  These cases sit
+# where that can go wrong: tables far wider than contexts, lengths at a
+# group's edge, rows that walk nothing beside rows that do.
+
+
+def _group_keys(Hkv, S, rep, M, quant=False):
+    """Keys a loop step of the kernel holds at the test's shapes."""
+    return _pick_walk_paged(Hkv, 128, 128, quant, S, rep, M)[1] * 128
+
+
+def _walk_pair(q, kp, vp, off, pt, S=1, ks=None, vs=None, scale=1 / 128 ** 0.5):
+    off = jnp.asarray(off, jnp.int32)
+    got = _paged_pallas(q, kp, vp, off + S, pt, ks, vs, scale=scale,
+                        interpret=True)
+    want = _paged_dense(q, kp, vp, off, pt, ks, vs, scale)
+    assert np.isfinite(np.asarray(got)).all()
+    return np.asarray(got), np.asarray(want)
+
+
+@pytest.mark.parametrize("lens", [(5, 100, 130),     # 1, 1 and 2 pages of 32
+                                  (0, 127, 255)])    # one key; page edges
+def test_paged_walk_table_far_wider_than_the_context(lens):
+    q, kp, vp, pt, lens = _mk_paged(M=32, lens=lens)
+    got, want = _walk_pair(q, kp, vp, lens, pt)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("delta", [-1, 0, 1])
+def test_paged_walk_lengths_around_a_group_boundary(delta):
+    """Contexts of one and of two groups, a key short, exact, a key over:
+    the trip count and the last group's mask both turn here."""
+    M = 32
+    KB = _group_keys(4, 1, 2, M)
+    assert 2 * KB + 1 < M * 128
+    q, kp, vp, pt, lens = _mk_paged(
+        M=M, lens=(KB + delta - 1, 2 * KB + delta - 1, 37))
+    got, want = _walk_pair(q, kp, vp, lens, pt)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("idle", [(1,), (0,), (2,), (0, 1), (0, 1, 2)])
+def test_paged_walk_skips_rows_whose_table_opens_on_trash(idle):
+    """A masked slot (idle, mid-prefill) keeps whatever position its last
+    request left: it walks nothing, gets zeros, and the rows around it
+    (whose first group the step before them fetches) read what they read
+    without it."""
+    q, kp, vp, pt, lens = _mk_paged(M=32, lens=(300, 3000, 511))
+    live = [b for b in range(3) if b not in idle]
+    alone, want = _walk_pair(q, kp, vp, lens, pt)
+    got, _ = _walk_pair(q, kp, vp, lens, pt.at[np.asarray(idle)].set(0))
+    assert (got[list(idle)] == 0).all()
+    np.testing.assert_array_equal(got[live], alone[live])
+    np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-5)
+
+
+def test_paged_walk_int8_across_a_group_boundary():
+    """int8 pages and their scale pages (a fourth and fifth copy a page),
+    a ragged S=3 block whose rows end either side of a group's edge."""
+    S, M = 3, 16
+    KB = _group_keys(4, S, 2, M, quant=True)
+    offs = (KB - 2, KB + 40, 2 * KB - S)
+    q, kp, vp, pt, lens = _mk_paged(M=M, lens=tuple(o + S - 1 for o in offs),
+                                    poison_trash=False)
+    kq, ks = _quantize_kv(kp)
+    vq, vs = _quantize_kv(vp)
+    got, want = _walk_pair(_mk_ragged_q(3, S, 8, seed=12), kq, vq, offs, pt,
+                           S=S, ks=ks, vs=vs)
+    np.testing.assert_allclose(got, want, rtol=4e-4, atol=4e-4)
+
+
+def test_paged_walk_chunk_at_an_offset_inside_a_group():
+    """The chunk shape (S = 256 queries, 4 query heads a K/V head) at an
+    offset that is no multiple of the group: the causal edge crosses the
+    last group, every earlier group is read whole."""
+    S, M = 256, 16
+    KB = _group_keys(2, S, 4, M)
+    off = KB + 300
+    assert off % KB and off + S <= M * 128
+    q, kp, vp, pt, lens = _mk_paged(B=1, Hkv=2, M=M, lens=(off + S - 1,))
+    got, want = _walk_pair(_mk_ragged_q(1, S, 8, seed=13), kp, vp, (off,), pt,
+                           S=S, scale=0.1)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
 def test_paged_dispatcher_ragged_reasons_and_counter():
