@@ -1184,12 +1184,12 @@ def _bench_xplane_parse(on_accel):
 
     parse_s = med(lambda: xplane.parse_xspace(blob), 9)
     space = xplane.parse_xspace(blob)
-    summ_s = med(lambda: xplane.per_op_summary(space), 9)
+    summ_s = med(lambda: xplane.device_seconds(space), 9)
     mb = len(blob) / 1e6
     return {
         "xplane_parse_us_per_mb": round(parse_s * 1e6 / mb, 1),
         "xplane_summary_us_per_mb": round(summ_s * 1e6 / mb, 1),
-        "xplane_bench_ops": len(xplane.per_op_summary(space)),
+        "xplane_bench_ops": len(xplane.to_timeline(space)),
     }
 
 
@@ -1208,7 +1208,7 @@ def _bench_roofline(on_accel):
                           "tests", "data", "golden.xplane.pb")
     with open(golden, "rb") as f:
         blob = f.read() * 64
-    measured = xplane.per_op_summary(xplane.parse_xspace(blob))
+    measured = xplane.to_timeline(xplane.parse_xspace(blob))
     # synthetic census covering every measured op (worst-case: every row
     # matches, nothing early-outs) plus prefixed variants to exercise the
     # containment fallback

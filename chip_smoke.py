@@ -13,6 +13,9 @@ cut to N_LAYERS and seeded random weights:
          64 a token, 8 gated experts of whole-lane width): a document, then
          two questions of it that map its latent pages (one forks a partial
          page), decode through the latent kernel
+  profile a 2-layer engine under load, profiled for 1 s by itself
+         (LLMEngine.profile_device) and reduced by its own census: every
+         device second lands under a program the engine knows
   train  paddle.jit.TrainStep + AdamW, 3 steps at batch 4 x seq 2048
   mesh   ShardedTrainStep(zero_stage=2) on sharding=2 x mp=2 (>= 4 chips)
 
@@ -312,6 +315,59 @@ def latent_phase(doc_tokens=300, new_tokens=8, timeout=600.0):
     return worst
 
 
+# ----------------------------------------------------------------- profile
+def profile_phase(n_layers=2, seconds=1.0, n_requests=16, new_tokens=500,
+                  timeout=600.0, **overrides):
+    """The engine accounts for its device time: a census of its compiled
+    programs (built here, on demand) and a profile of the running pump,
+    reduced to seconds by (program, named scope)."""
+    from paddle_tpu.inference import LLMEngine
+
+    cfg, model = _llama(n_layers, tensor_parallel=False, **overrides)
+    model.eval()
+    eng = LLMEngine(model, kv_layout="paged", page_size=128, prefill_chunk=256,
+                    max_seq_len=1024, max_batch_slots=8)
+    log(f"profile: {n_layers} layers, warmup took {eng.warmup():.1f}s")
+    t = time.perf_counter()
+    census = eng.program_census()
+    log(f"profile: census of {sorted(census)} built in "
+        f"{time.perf_counter() - t:.1f}s "
+        f"({sum(map(len, census.values()))} rows)")
+    rng = np.random.default_rng(2)
+    eng.start()
+    try:
+        futures = [eng.submit(rng.integers(0, cfg.vocab_size, 300, dtype=np.int32),
+                              max_new_tokens=new_tokens)
+                   for _ in range(n_requests)]
+        time.sleep(0.5)  # past the prompts' chunks, into steady decoding
+        red = eng.profile_device(seconds)
+        for f in futures:
+            f.result(timeout=timeout)
+        device_time = eng.stats()["device_time"]
+    finally:
+        eng.stop()
+    check(red is not None, f"profile_device ran: {device_time}")
+    busy = red["busy_s"]
+    # a 2-layer engine is paced by its host: about half the window is work
+    check(busy > 0.2 * seconds,
+          f"the device was busy {busy:.3f}s of the {red['window_s']:.3f}s profiled")
+    check(set(red["programs"]) <= set(census) and "jit_llm_decode" in red["programs"],
+          f"every program of the dump is the census's {sorted(red['programs'])} "
+          f"or listed as other {sorted(red['other_programs'])}")
+    check(not red["other_programs"],
+          "no program but the engine's ran in the window")
+    check(red["unmatched_s"] <= 0.01 * busy,
+          f"{red['unmatched_s'] / busy:.3%} of the busy time has no census row "
+          f"({red['unmatched']})")
+    named = sum(v["seconds"] for p in red["programs"].values()
+                for v in p["scopes"].values())
+    check(abs(named + red["unmatched_s"] - busy) <= 0.005 * busy,
+          f"self time adds up: {named + red['unmatched_s']:.4f}s of {busy:.4f}s busy")
+    log(f"profile: device seconds by program and scope: {device_time['seconds']}; "
+        f"the profiler's seconds: {device_time['profiler_s']}")
+    return device_time["seconds"]
+
+
 # ------------------------------------------------------------------ kernel
 #: max |kernel - dense| allowed, outputs O(1).  Both paths round the
 #: probabilities to bf16 before the PV matmul (relative 2^-9) and emit bf16;
@@ -522,6 +578,8 @@ def main():
     state_gap = state_phase()
     _free()
     latent_gap = latent_phase()
+    _free()
+    profile_phase()
     _free()
     losses = train_phase()
     _free()

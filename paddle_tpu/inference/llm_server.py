@@ -310,6 +310,12 @@ _ADM_BLOCKED_SERIES = {r: _M_ADM_BLOCKED.labels(reason=r)
 #: how much of the fused sampler a tick's knobs make the device run: the
 #: argmax alone, a draw without a sort, or the one sort of the vocabulary
 _SAMPLER_PATHS = ("greedy", "draw", "threshold")
+_M_DEVICE_SECONDS = _obs.gauge(
+    "llm_device_seconds",
+    "Device self seconds of the last profile_device() window by compiled "
+    "program and first named-scope component (unscoped, unmatched: no "
+    "scope, no census row)",
+    labelnames=("program", "scope"))
 _M_SAMPLER_TICKS = _obs.counter(
     "llm_sampler_ticks_total",
     "Decode and verify ticks by the part of the sampler their knobs "
@@ -1073,6 +1079,10 @@ class LLMEngine:
         self._verify_jit = None
         self._decode_jit = {}  # scan length (effective chunk) -> jitted fn
         self._chunk_jit = None  # the one prefill-chunk program
+        # program_census(): built on the first call, never by a tick
+        self._census = None
+        self._census_lock = threading.Lock()
+        self._device_time = None  # the last profile_device() result
         # page id -> trace_id of the request whose prefill first indexed
         # it in the prefix cache (the COW-fork provenance stamp; bounded
         # by num_pages since inserts overwrite reused page ids)
@@ -1555,6 +1565,9 @@ class LLMEngine:
             # memory_stats — CPU); polling here also refreshes the
             # hbm_* gauges
             "device_memory": _profiling.poll_device_memory(),
+            # the last profile_device(): device seconds by program and
+            # first scope component (None until one has run)
+            "device_time": self._device_time,
             "telemetry_url": self.telemetry.url
             if self.telemetry is not None else None,
         }
@@ -2025,9 +2038,10 @@ class LLMEngine:
             layers = self._state_layers()
 
             def store(caches, pool, slot, entry):
-                return [tuple(p.at[entry].set(x[slot])
-                              for p, x in zip(pl, caches[i]))
-                        for pl, i in zip(pool, layers)]
+                with jax.named_scope("state_checkpoint"):
+                    return [tuple(p.at[entry].set(x[slot])
+                                  for p, x in zip(pl, caches[i]))
+                            for pl, i in zip(pool, layers)]
 
             _profiling.record_compile("state_checkpoint_store")
             self._ckpt_store_jit = jax.jit(store, donate_argnums=(1,))
@@ -2041,9 +2055,10 @@ class LLMEngine:
 
             def load(caches, pool, slot, entry):
                 out = list(caches)
-                for pl, i in zip(pool, layers):
-                    out[i] = tuple(x.at[slot].set(p[entry])
-                                   for p, x in zip(pl, caches[i]))
+                with jax.named_scope("state_checkpoint"):
+                    for pl, i in zip(pool, layers):
+                        out[i] = tuple(x.at[slot].set(p[entry])
+                                       for p, x in zip(pl, caches[i]))
                 return out
 
             _profiling.record_compile("state_checkpoint_load")
@@ -3016,6 +3031,169 @@ class LLMEngine:
             self._finish(slot)
         self._phases.switch("bookkeep")
 
+    def program_census(self):
+        """``{module: {instruction: row}}`` of every program the engine
+        runs (``distributed.census.per_op_census`` rows: opcode,
+        computation, named scope, bytes, flops), keyed by the module name a
+        device prints (``jit_llm_decode``): what
+        ``observability.xplane.device_seconds`` joins a profile of this
+        engine against.
+
+        Built on the first call and kept.  Each program of ``_programs()``
+        (and the tier gather, once the demotion worker has compiled it) is
+        lowered for the arguments a tick gives it and compiled to be read
+        — seconds a program (a lowering traces the model and its Pallas
+        kernels again; the compile is keyed WITH its metadata, so the
+        first census of a code version compiles anew), so never from
+        ``__init__``, ``warmup()`` or a tick, and nothing of it goes into
+        ``stats()``.  The arguments are taken under the engine lock as
+        shapes; the lowering runs outside it, with the pump running.  The
+        tier upload's programs (one a batch bucket) are not in it: their
+        module shows under ``other_programs``."""
+        from ..distributed import census as _census
+
+        with self._census_lock:
+            if self._census is not None:
+                return self._census
+
+            def shape(x):
+                if not isinstance(x, jax.Array):
+                    return x
+                return jax.ShapeDtypeStruct(
+                    x.shape, x.dtype, sharding=x.sharding,
+                    weak_type=getattr(x, "weak_type", False))
+
+            with self._lock:
+                programs = [(jit, jax.tree_util.tree_map(shape, args))
+                            for jit, args, _ in self._programs()]
+                if self._gather_jit is not None:
+                    programs.append((self._gather_jit, (
+                        jax.tree_util.tree_map(shape, self.caches),
+                        np.zeros(self.demote_batch, np.int32))))
+            # a persistent compile cache keys programs without their
+            # metadata: a hit would print the scopes of whatever code first
+            # compiled the program.  The pump compiles nothing meanwhile.
+            key = "jax_compilation_cache_include_metadata_in_key"
+            was = getattr(jax.config, key)
+            jax.config.update(key, True)
+            try:
+                with _profiling.census_compiles():
+                    self._census = _census.by_module(*(
+                        _census.per_op_census(jit.lower(*args).compile())
+                        for jit, args in programs))
+            finally:
+                jax.config.update(key, was)
+            return self._census
+
+    def profile_device(self, seconds):
+        """Profile the device for ``seconds`` while the engine runs — from
+        any thread, over the running pump — and reduce the dump by the
+        engine's census to device seconds by (program, named scope).
+
+        Returns the ``xplane.device_seconds`` result (``None`` where the
+        profiler could not run: one is running already, the backend has
+        none — the error is recorded, nothing is raised).  The seconds by
+        (program, first scope component) of the last window are published
+        in ``stats()["device_time"]`` and on ``llm_device_seconds{program,
+        scope}``; the top rows are filed as spans of a ``profile_device``
+        trace.  The census is built before the window opens (the first
+        call pays for it: program_census()); the dump is read after it
+        closes and then deleted."""
+        import shutil
+
+        census = self.program_census()
+        trace = self._tracer.start_trace("profile_device",
+                                         seconds=float(seconds))
+        with _profiling.ProfilingSession(trace=trace, census=census) as sess:
+            time.sleep(seconds)
+        trace.end("ok" if sess.error is None else "error")
+        shutil.rmtree(sess.logdir, ignore_errors=True)
+        red = sess.by_scope
+        if red is None:
+            self._device_time = {"error": sess.error}
+            return None
+        table = {}
+        for module, prog in red["programs"].items():
+            row = table.setdefault(module, {})
+            for scope, v in prog["scopes"].items():
+                first = scope.split("/")[0]
+                row[first] = row.get(first, 0.0) + v["seconds"]
+            if prog["unmatched_s"]:
+                row["unmatched"] = prog["unmatched_s"]
+        for module, row in table.items():
+            for scope, secs in row.items():
+                _M_DEVICE_SECONDS.labels(program=module, scope=scope).set(secs)
+        self._device_time = {
+            "error": None, "window_s": red["window_s"],
+            "busy_s": red["busy_s"], "seconds": table,
+            "unmatched_s": red["unmatched_s"],
+            "other_programs": red["other_programs"],
+            # what the call itself took: the profiler's start and stop,
+            # and reading the dump
+            "profiler_s": {"start": sess.start_s, "stop": sess.stop_s,
+                           "extract": sess.extract_s}}
+        return red
+
+    def _programs(self):
+        """Every program warmup() compiles, in its order, as ``(jit,
+        arguments, keep)``: the arguments are what a tick gives the
+        program — host arrays, the default generator's resident key with a
+        host offset in place of keys, and for the decode's token feed the
+        resident zeros (one signature with a program's own last tokens:
+        both are uncommitted [B] int32 arrays of the one device) — and
+        ``keep`` takes the call's results where a tick puts them.
+        warmup() calls each; program_census() lowers each and calls
+        none, so the program it reads is the one the ticks call.  A
+        generator on purpose: a call donates the caches, and the next
+        program's arguments are read after ``keep`` has replaced them."""
+        def caches(c):
+            self.caches = c
+
+        def ckpt(p):
+            self._ckpt = p
+
+        C, B = self.prefill_chunk, self.n_slots
+        # last_index -1: no token of the warm-up chunk is real, so
+        # slot 0's recurrent state and the expert counts stay put
+        yield self._get_chunk_prefill(), (
+            self._params, self._buffers, self.caches,
+            np.zeros((1, self.M), np.int32),
+            np.full((1, C), self.pad, np.int32),
+            np.zeros((1,), np.int32),
+            np.int32(0 if self._cache_kinds is None else -1),
+            *self._lora_args([0]), *self._chunk_extra(0)), self._took
+        if not self._recurrent:
+            # the COW fork program too: a warm engine's first
+            # shared-prefix fork must not compile (and must not trip
+            # recompile_storm).  A trash-page self-copy is harmless.
+            # (A model with recurrent state shares whole pages only,
+            # and writes to none of them: no fork.)
+            yield self._get_cow_copy(), (
+                self.caches, np.int32(0), np.int32(0)), caches
+        if self._ckpt is not None:
+            # the checkpoint copies, both ways, on entry 0 and slot 0:
+            # the entry is free and the slot idle, so what they move is
+            # never read (a request's first chunk zeroes its state)
+            yield self._get_ckpt_store(), (
+                self.caches, self._ckpt, np.int32(0), np.int32(0)), ckpt
+            yield self._get_ckpt_load(), (
+                self.caches, self._ckpt, np.int32(0), np.int32(0)), caches
+        eff = max(1, min(self.decode_chunk, self.L - 1))
+        tokens = np.full((B, 1), self.pad, np.int32)
+        pos = np.zeros((B,), np.int32)
+        knobs = self._sampling_knobs([])  # every row greedy
+        rng = (_fr.default_generator().key, np.uint32(0))
+        lora = self._lora_args([0] * B)
+        yield self._get_decode(eff), (
+            *self._cache_args(), tokens, self._feed, np.ones((B,), bool),
+            pos, *knobs, self._mask_all_true, *rng, *lora,
+            *self._accs()), self._took_decode
+        if self.spec_k:
+            yield self._get_verify(), (
+                *self._cache_args(), tokens,
+                np.zeros((B, self.spec_k), np.int32), pos, *knobs, *rng,
+                *lora), lambda out: caches(out[2])
+
     def warmup(self):
         """Pre-compile the serving programs so the FIRST request pays no
         compile latency (the TTFT spike visible in llm_ttft_seconds): the
@@ -3023,12 +3201,8 @@ class LLMEngine:
         copy, the decode step at the configured decode_chunk and, with
         ``spec_k``, the verify step.  Runs the real compiled calls against
         the engine's own idle cache state: the garbage rows land in the
-        trash page.  The decode, verify and chunk calls get what a tick
-        gives them — host arrays, the default generator's resident key
-        with a host offset in place of keys, and for the decode's token
-        feed the resident zeros (one signature with a program's own last
-        tokens: both are uncommitted [B] int32 arrays of the one device) —
-        so the warmed programs are the ones the ticks call; the offset is
+        trash page.  The calls get what a tick gives them (_programs), so
+        the warmed programs are the ones the ticks call; the offset is
         not advanced (warmup draws nothing a request sees).  Returns the
         wall seconds spent and publishes them on
         llm_warmup_compile_seconds."""
@@ -3045,47 +3219,8 @@ class LLMEngine:
                 raise RuntimeError("warmup() requires an idle engine")
             if self._inflight is not None:
                 self._read_decode("warmup")  # every row of it has ended
-            C = self.prefill_chunk
-            # last_index -1: no token of the warm-up chunk is real, so
-            # slot 0's recurrent state and the expert counts stay put
-            self._took(self._get_chunk_prefill()(
-                self._params, self._buffers, self.caches,
-                np.zeros((1, self.M), np.int32),
-                np.full((1, C), self.pad, np.int32),
-                np.zeros((1,), np.int32),
-                np.int32(0 if self._cache_kinds is None else -1),
-                *self._lora_args([0]), *self._chunk_extra(0)))
-            if not self._recurrent:
-                # the COW fork program too: a warm engine's first
-                # shared-prefix fork must not compile (and must not trip
-                # recompile_storm).  A trash-page self-copy is harmless.
-                # (A model with recurrent state shares whole pages only,
-                # and writes to none of them: no fork.)
-                self.caches = self._get_cow_copy()(
-                    self.caches, np.int32(0), np.int32(0))
-            if self._ckpt is not None:
-                # the checkpoint copies, both ways, on entry 0 and slot 0:
-                # the entry is free and the slot idle, so what they move is
-                # never read (a request's first chunk zeroes its state)
-                self._ckpt = self._get_ckpt_store()(
-                    self.caches, self._ckpt, np.int32(0), np.int32(0))
-                self.caches = self._get_ckpt_load()(
-                    self.caches, self._ckpt, np.int32(0), np.int32(0))
-            eff = max(1, min(self.decode_chunk, self.L - 1))
-            B = self.n_slots
-            tokens = np.full((B, 1), self.pad, np.int32)
-            pos = np.zeros((B,), np.int32)
-            knobs = self._sampling_knobs()  # idle engine: all greedy
-            rng = (_fr.default_generator().key, np.uint32(0))
-            lora = self._lora_args([0] * B)
-            self._took_decode(self._get_decode(eff)(
-                *self._cache_args(), tokens, self._feed, np.ones((B,), bool),
-                pos, *knobs, self._mask_all_true, *rng, *lora, *self._accs()))
-            if self.spec_k:
-                _, _, self.caches = self._get_verify()(
-                    *self._cache_args(), tokens,
-                    np.zeros((B, self.spec_k), np.int32), pos, *knobs, *rng,
-                    *lora)
+            for jit, args, keep in self._programs():
+                keep(jit(*args))
             if self.adapters is not None:
                 # the pool's donating page writer compiles here too, so a
                 # post-warmup register()/acquire() never counts as a
@@ -3217,8 +3352,11 @@ class LLMEngine:
                        from_host, pos, do_sample, temperature, top_k, top_p,
                        token_mask, base_key, offset, lora_tree, lora_rows,
                        *accs):
-            keys = jax.random.split(jax.random.fold_in(base_key, offset), eff)
-            tokens = jnp.where(from_host, tokens[:, 0], feed)[:, None]
+            with jax.named_scope("sampler"):
+                keys = jax.random.split(
+                    jax.random.fold_in(base_key, offset), eff)
+            with jax.named_scope("token_feed"):
+                tokens = jnp.where(from_host, tokens[:, 0], feed)[:, None]
             # the tick masks the table rows of idle and mid-prefill
             # slots to the trash page: such a row is computed like the
             # others but advances no recurrent state and counts nowhere

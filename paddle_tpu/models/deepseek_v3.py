@@ -270,14 +270,20 @@ class DeepseekV3Block(nn.Layer):
     def forward(self, x, cache: LatentPaged):
         """Returns (x, (pool, latent counts[, expert counts]))."""
         eps = self.input_layernorm._epsilon
-        out, new = self.self_attn(
-            _rms(x, self.input_layernorm.weight._value, eps), cache)
-        x = x + out
-        u = _rms(x, self.post_attention_layernorm.weight._value, eps)
+        # the block's norms and residuals: a scope of their own
+        with jax.named_scope("block_norm"):
+            u = _rms(x, self.input_layernorm.weight._value, eps)
+        out, new = self.self_attn(u, cache)
+        with jax.named_scope("block_norm"):
+            x = x + out
+            u = _rms(x, self.post_attention_layernorm.weight._value, eps)
         if self.is_moe:
             y, counts = self.mlp(u, cache.rows)
-            return x + y, new + (counts,)
-        return x + self.mlp(u), new
+            new = new + (counts,)
+        else:
+            y = self.mlp(u)
+        with jax.named_scope("block_norm"):
+            return x + y, new
 
 
 class DeepseekV3ForCausalLM(nn.Layer):
@@ -324,7 +330,8 @@ class DeepseekV3ForCausalLM(nn.Layer):
 
     # ------------------------------------------------------------- the stack
     def _run(self, ids, caches):
-        x = self.embed_tokens._value[ids]
+        with jax.named_scope("embed"):
+            x = self.embed_tokens._value[ids]
         new = []
         for blk, cache in zip(self.layers, caches):
             x, n = blk(x, cache)

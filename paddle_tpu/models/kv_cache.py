@@ -237,7 +237,8 @@ def cow_copy_pages(caches, src, dst):
     ``[P, ...]`` page-major, so one generic row copy covers them all).  The caller then repoints the
     writing slot's page-table entry at ``dst``; readers of ``src`` are
     untouched."""
-    return [tuple(x.at[dst].set(x[src]) for x in c) for c in caches]
+    with jax.named_scope("cow_copy"):
+        return [tuple(x.at[dst].set(x[src]) for x in c) for c in caches]
 
 
 def gather_pages_to_host(caches, pages):
@@ -250,7 +251,8 @@ def gather_pages_to_host(caches, pages):
     layouts.  Returns per-layer tuples of ``[N, ...]`` blocks; the caller
     fetches them host-side (``np.asarray``) OUTSIDE any engine lock —
     dispatch is async, the transfer is the blocking part."""
-    return [tuple(x[pages] for x in c) for c in caches]
+    with jax.named_scope("kv_tier_gather"):
+        return [tuple(x[pages] for x in c) for c in caches]
 
 
 def upload_host_pages(caches, pages, blocks):
@@ -263,8 +265,9 @@ def upload_host_pages(caches, pages, blocks):
     caller typically donates ``caches`` — after the upload the promoted
     pages are indistinguishable from never-evicted ones (the ragged paged
     kernel just walks page tables)."""
-    return [tuple(x.at[pages].set(b) for x, b in zip(c, blk))
-            for c, blk in zip(caches, blocks)]
+    with jax.named_scope("kv_tier_upload"):
+        return [tuple(x.at[pages].set(b) for x, b in zip(c, blk))
+                for c, blk in zip(caches, blocks)]
 
 
 def _token_pages_rows(pos, page_tbl, S, page_size, max_pages):
@@ -314,8 +317,9 @@ def update_paged_cache(cache, k, v, offset):
     S = k.shape[1]
     upd = lambda pool, kv, tbl: _paged_scatter(  # noqa: E731
         pool, _to_head_major(kv.astype(pool.dtype)), offset, tbl)
-    k_pool = apply_op(upd, (cache[0], k, cache[3]), name="kv_paged_scatter")
-    v_pool = apply_op(upd, (cache[1], v, cache[3]), name="kv_paged_scatter")
+    with jax.named_scope("kv_write"):
+        k_pool = apply_op(upd, (cache[0], k, cache[3]), name="kv_paged_scatter")
+        v_pool = apply_op(upd, (cache[1], v, cache[3]), name="kv_paged_scatter")
     return (k_pool, v_pool, offset + S, cache[3]), k_pool, v_pool
 
 
@@ -329,10 +333,11 @@ def update_paged_quant_cache(cache, k, v, offset):
         return (_paged_scatter(pool, kv_q, offset, tbl),
                 _paged_scatter_scale(spool, scale, offset, tbl))
 
-    k_pool, k_sc = apply_op(upd_q, (cache[0], cache[4], k, cache[3]),
-                            name="kv_paged_scatter_q")
-    v_pool, v_sc = apply_op(upd_q, (cache[1], cache[5], v, cache[3]),
-                            name="kv_paged_scatter_q")
+    with jax.named_scope("kv_write"):
+        k_pool, k_sc = apply_op(upd_q, (cache[0], cache[4], k, cache[3]),
+                                name="kv_paged_scatter_q")
+        v_pool, v_sc = apply_op(upd_q, (cache[1], cache[5], v, cache[3]),
+                                name="kv_paged_scatter_q")
     return ((k_pool, v_pool, offset + S, cache[3], k_sc, v_sc),
             k_pool, v_pool, k_sc, v_sc)
 

@@ -132,9 +132,46 @@ class LlamaAttention(nn.Layer):
             self.o_proj = nn.Linear(self.num_heads * self.head_dim, self.hidden_size, bias_attr=False)
 
     def _o(self, out):
-        y = self.o_proj(out)
-        d = _lora.apply_site("o", out)
-        return y if d is None else y + d
+        with jax.named_scope("o_proj"):
+            y = self.o_proj(out)
+            d = _lora.apply_site("o", out)
+            return y if d is None else y + d
+
+    def _qkv(self, hidden_states, fusable):
+        """The three projections (one fused gemv at a decode step) with
+        their LoRA epilogues, under the ``qkv_proj`` scope."""
+        S = hidden_states.shape[1]
+        nq = self.num_heads * self.head_dim
+        nkv = self.num_kv_heads * self.head_dim
+        with jax.named_scope("qkv_proj"):
+            if S == 1 and fusable:
+                # decode step: ONE fused qkv gemv instead of three — at batch<<128
+                # each projection is weight-streaming-bound and per-op latency
+                # dominates; the concat of the (loop-invariant) weights is hoisted
+                # out of the decode scan by XLA LICM, so the fusion costs nothing
+                def _fused_qkv(h, wq, wk, wv):
+                    w = jnp.concatenate([wq, wk, wv], axis=1)
+                    return h @ w.astype(h.dtype)
+
+                qkv = apply_op(_fused_qkv,
+                               (hidden_states, self.q_proj.weight,
+                                self.k_proj.weight, self.v_proj.weight),
+                               name="fused_qkv")
+                q = qkv[:, :, :nq]
+                k = qkv[:, :, nq:nq + nkv]
+                v = qkv[:, :, nq + nkv:]
+            else:
+                q = self.q_proj(hidden_states)
+                k = self.k_proj(hidden_states)
+                v = self.v_proj(hidden_states)
+            dq = _lora.apply_site("q", hidden_states)
+            if dq is not None:
+                # multi-tenant LoRA epilogue: per-row adapter-page gathers add
+                # the low-rank delta; zero-adapter rows gather page 0 (exact +0)
+                q = q + dq
+                k = k + _lora.apply_site("k", hidden_states)
+                v = v + _lora.apply_site("v", hidden_states)
+        return q, k, v
 
     def forward(self, hidden_states, rope, attn_mask=None, cache=None, use_cache=False):
         """rope: (cos, sin) Tensors shared at LlamaModel level (one copy, not 32).
@@ -146,35 +183,7 @@ class LlamaAttention(nn.Layer):
                    and type(self.v_proj) is nn.Linear  # not wrapped (quant etc.)
                    and all(getattr(p, "bias", None) is None
                            for p in (self.q_proj, self.k_proj, self.v_proj)))
-        nq = self.num_heads * self.head_dim
-        nkv = self.num_kv_heads * self.head_dim
-        if S == 1 and fusable:
-            # decode step: ONE fused qkv gemv instead of three — at batch<<128
-            # each projection is weight-streaming-bound and per-op latency
-            # dominates; the concat of the (loop-invariant) weights is hoisted
-            # out of the decode scan by XLA LICM, so the fusion costs nothing
-            def _fused_qkv(h, wq, wk, wv):
-                w = jnp.concatenate([wq, wk, wv], axis=1)
-                return h @ w.astype(h.dtype)
-
-            qkv = apply_op(_fused_qkv,
-                           (hidden_states, self.q_proj.weight,
-                            self.k_proj.weight, self.v_proj.weight),
-                           name="fused_qkv")
-            q = qkv[:, :, :nq]
-            k = qkv[:, :, nq:nq + nkv]
-            v = qkv[:, :, nq + nkv:]
-        else:
-            q = self.q_proj(hidden_states)
-            k = self.k_proj(hidden_states)
-            v = self.v_proj(hidden_states)
-        dq = _lora.apply_site("q", hidden_states)
-        if dq is not None:
-            # multi-tenant LoRA epilogue: per-row adapter-page gathers add
-            # the low-rank delta; zero-adapter rows gather page 0 (exact +0)
-            q = q + dq
-            k = k + _lora.apply_site("k", hidden_states)
-            v = v + _lora.apply_site("v", hidden_states)
+        q, k, v = self._qkv(hidden_states, fusable)
         q = q.reshape([B, S, self.num_heads, self.head_dim])
         k = k.reshape([B, S, self.num_kv_heads, self.head_dim])
         v = v.reshape([B, S, self.num_kv_heads, self.head_dim])
@@ -371,7 +380,8 @@ class LlamaModel(nn.Layer):
         use_cache = use_cache or caches is not None
         if use_cache and caches is None:
             caches = [None] * len(self.layers)
-        x = self.embed_tokens(input_ids)
+        with jax.named_scope("embed"):
+            x = self.embed_tokens(input_ids)
         rope = (self.rope_cos, self.rope_sin)
         # static-cache decode needs NO mask tensor: the decode-attention
         # kernel masks by the carried valid length (ops/decode_attention.py)

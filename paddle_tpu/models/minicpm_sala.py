@@ -270,10 +270,16 @@ class MiniCPMSALABlock(nn.Layer):
 
     def forward(self, x, cache):
         eps = self.input_norm._epsilon
-        out, new = self.mixer(_rms(x, self.input_norm.weight._value, eps), cache)
-        x = x + (out.astype(jnp.float32) * self.scale).astype(x.dtype)
-        y = self.mlp(_rms(x, self.post_norm.weight._value, eps))
-        return x + (y.astype(jnp.float32) * self.scale).astype(x.dtype), new
+        # the block's norms and scaled residuals: a scope of their own
+        with jax.named_scope("block_norm"):
+            u = _rms(x, self.input_norm.weight._value, eps)
+        out, new = self.mixer(u, cache)
+        with jax.named_scope("block_norm"):
+            x = x + (out.astype(jnp.float32) * self.scale).astype(x.dtype)
+            u = _rms(x, self.post_norm.weight._value, eps)
+        y = self.mlp(u)
+        with jax.named_scope("block_norm"):
+            return x + (y.astype(jnp.float32) * self.scale).astype(x.dtype), new
 
 
 class MiniCPMSALAForCausalLM(nn.Layer):
@@ -320,8 +326,9 @@ class MiniCPMSALAForCausalLM(nn.Layer):
     # ------------------------------------------------------------- the stack
     def _run(self, ids, caches):
         c = self.config
-        x = (self.embed_tokens._value[ids].astype(jnp.float32)
-             * c.scale_emb).astype(self.embed_tokens._value.dtype)
+        with jax.named_scope("embed"):
+            x = (self.embed_tokens._value[ids].astype(jnp.float32)
+                 * c.scale_emb).astype(self.embed_tokens._value.dtype)
         new = []
         for blk, cache in zip(self.layers, caches):
             x, n = blk(x, cache)

@@ -299,9 +299,13 @@ class NemotronHBlock(nn.Layer):
         self.mixer = _MIXERS[kind](config)
 
     def forward(self, x, cache):
-        u = _rms(x, self.norm.weight._value, self.norm._epsilon)
+        # the block's norm and residual: a scope of their own, so the
+        # mixers' scopes hold the mixers alone
+        with jax.named_scope("block_norm"):
+            u = _rms(x, self.norm.weight._value, self.norm._epsilon)
         out, new = self.mixer(u, cache)
-        return x + out, new
+        with jax.named_scope("block_norm"):
+            return x + out, new
 
 
 class NemotronHForCausalLM(nn.Layer):
@@ -354,7 +358,8 @@ class NemotronHForCausalLM(nn.Layer):
 
     # ------------------------------------------------------------- the stack
     def _run(self, ids, caches):
-        x = self.embed_tokens._value[ids]
+        with jax.named_scope("embed"):
+            x = self.embed_tokens._value[ids]
         new = []
         for blk, c in zip(self.layers, caches):
             x, n = blk(x, c)

@@ -50,8 +50,9 @@ And below the host boundary, the profiling plane (ISSUE 14):
 - `observability.xplane` — dependency-free reader for the
   ``.xplane.pb`` dumps ``jax.profiler.trace()`` writes (hand-rolled
   protobuf wire parsing; no tensorflow/protobuf import), decoding
-  per-HLO device events for the census<->timeline join
-  (``tools/trace_report.py --xplane``);
+  per-HLO device events and reducing them by a census of the compiled
+  programs to device seconds by (program, named scope)
+  (``device_seconds``; ``tools/trace_report.py --xplane``);
 - `observability.profiling` — ``ProfilingSession`` (a profiler window
   filed under the owning span), compile telemetry
   (``jit_compiles_total`` / ``jit_recompiles_total`` feeding the
@@ -104,7 +105,7 @@ from .tracing import (  # noqa: F401
     Trace, Tracer, TraceStore, TRACES, TRACER, NULL_TRACE, start_trace,
 )
 from .xplane import (  # noqa: F401
-    parse_xspace, load_xspace, find_dump, per_op_summary,
+    parse_xspace, load_xspace, find_dump, device_seconds, per_op_summary,
 )
 from .profiling import (  # noqa: F401
     ProfilingSession, install_compile_hooks, record_compile, mark_warm,
@@ -139,7 +140,8 @@ __all__ = [
     "JsonlNotifier", "alerts",
     "Trace", "Tracer", "TraceStore", "TRACES", "TRACER", "NULL_TRACE",
     "start_trace", "tracing",
-    "parse_xspace", "load_xspace", "find_dump", "per_op_summary",
+    "parse_xspace", "load_xspace", "find_dump", "device_seconds",
+    "per_op_summary",
     "xplane",
     "ProfilingSession", "install_compile_hooks", "record_compile",
     "mark_warm", "poll_device_memory", "profiling",

@@ -34,6 +34,7 @@ consumer (fleetwatch, /varz) renders a dash instead of a lie.
 """
 from __future__ import annotations
 
+import contextlib
 import os
 import tempfile
 import threading
@@ -46,7 +47,8 @@ from . import xplane as _xplane
 
 __all__ = [
     "install_compile_hooks", "record_compile", "mark_warm", "is_warm",
-    "poll_device_memory", "ProfilingSession", "BACKEND_COMPILE_EVENT",
+    "census_compiles", "poll_device_memory", "ProfilingSession",
+    "BACKEND_COMPILE_EVENT",
 ]
 
 #: The jax.monitoring duration event one XLA backend compile emits.
@@ -94,6 +96,7 @@ _M_PROF_OPS = _metrics.gauge(
 
 _state = {"installed": False, "warm": False}
 _lock = threading.Lock()
+_census_thread = threading.local()
 
 
 # ------------------------------------------------------- compile telemetry
@@ -115,7 +118,26 @@ def record_compile(fn, seconds=None, warm=None):
 
 
 def _on_backend_compile(duration_s):
-    record_compile("backend", seconds=duration_s)
+    if getattr(_census_thread, "on", False):
+        # a census's ahead-of-time compile: counted under its own label,
+        # never a recompile (the listener runs on the compiling thread)
+        record_compile("census", seconds=duration_s, warm=False)
+    else:
+        record_compile("backend", seconds=duration_s)
+
+
+@contextlib.contextmanager
+def census_compiles():
+    """The compiles this thread makes inside are a census's (``Compiled``
+    objects built to be read, ``LLMEngine.program_census()``): they land
+    on ``jit_compiles_total{fn="census"}`` and never on
+    ``jit_recompiles_total``, so an operator's census of a warm engine
+    does not trip ``recompile_storm``."""
+    _census_thread.on = True
+    try:
+        yield
+    finally:
+        _census_thread.on = False
 
 
 def install_compile_hooks():
@@ -186,34 +208,45 @@ def poll_device_memory(devices=None):
 
 # --------------------------------------------------------- ProfilingSession
 class ProfilingSession:
-    """``jax.profiler.trace()`` around a window of work, with the
-    extracted per-HLO summary filed three ways on exit: as child spans
-    of an ``xplane_profile`` span on the owning PR-8 trace, as a flight
-    recorder event, and on the ``profile_*`` gauges.
+    """``jax.profiler.trace()`` around a window of work (Python frames
+    off: they cost ~100k events a second), reduced on exit by the plane's
+    one reducer (``xplane.device_seconds``) and filed three ways: as child
+    spans of an ``xplane_profile`` span on the owning PR-8 trace, as a
+    flight recorder event, and on the ``profile_*`` gauges.
 
     ::
 
         trace = obs.start_trace("train_window")
-        with ProfilingSession(trace=trace) as prof:
+        with ProfilingSession(trace=trace, census=census) as prof:
             for _ in range(n):
                 step(batch)
-        table = prof.summary          # name -> {count, total_us, ...}
+        prof.by_scope         # device seconds by (program, named scope)
+        table = prof.summary  # "module/instruction" -> {count, total_us, ...}
         path  = prof.dump_path        # feed tools/trace_report.py --xplane
 
-    ``logdir=None`` uses a fresh temp dir (kept — the dump is the
-    artifact ``trace_report --xplane`` consumes).  A backend that cannot
-    profile (no profiler plugin) degrades to an empty summary with the
-    failure recorded on the span, never an exception out of ``__exit__``:
-    a profiling window must not kill the workload it observes."""
+    ``census`` is ``{module: {instruction: row}}`` (``census.by_module``,
+    ``LLMEngine.program_census()``); without one ``by_scope`` still tells
+    the programs apart and every event is unmatched.  ``logdir=None`` uses
+    a fresh temp dir (kept — the dump is the artifact ``trace_report
+    --xplane`` consumes).  A backend that cannot profile (no profiler
+    plugin, a profiler already running) degrades to an empty summary with
+    the failure recorded on the span, never an exception out of
+    ``__exit__``: a profiling window must not kill the workload it
+    observes."""
 
-    def __init__(self, logdir=None, trace=None, top_k=12):
+    def __init__(self, logdir=None, trace=None, top_k=12, census=None):
         from . import tracing as _tracing  # local: avoid import cycle
         self.logdir = logdir or tempfile.mkdtemp(prefix="paddle_xprof_")
         self.top_k = int(top_k)
         self.trace = trace if trace is not None else _tracing.NULL_TRACE
+        self.census = census
         self.summary = None
+        self.by_scope = None
         self.dump_path = None
         self.error = None
+        # what the session itself costs: seconds to start the profiler, to
+        # stop it (the dump is written there) and to read the dump
+        self.start_s = self.stop_s = self.extract_s = None
         self._span = None
         self._t0 = None
 
@@ -224,10 +257,13 @@ class ProfilingSession:
                                      logdir=self.logdir).open()
         self._t0 = time.perf_counter()
         try:
-            jax.profiler.start_trace(self.logdir)
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(self.logdir, profiler_options=opts)
         except Exception as e:  # profiler already active / unsupported
             self.error = repr(e)
             self._span.set_attr("error", self.error)
+        self.start_s = time.perf_counter() - self._t0
         return self
 
     def __exit__(self, etype, exc, tb):
@@ -239,22 +275,23 @@ class ProfilingSession:
             except Exception as e:
                 self.error = repr(e)
         t_extract = time.perf_counter()
+        self.stop_s = t_extract - self._t0 - window_s
         self.summary = {}
         if self.error is None:
             try:
                 self.dump_path = _xplane.find_dump(self.logdir)
-                self.summary = _xplane.per_op_summary(
-                    _xplane.load_xspace(self.dump_path))
+                self.by_scope = _xplane.device_seconds(
+                    _xplane.load_xspace(self.dump_path), self.census)
+                self.summary = _xplane.timeline(self.by_scope, self.census)
             except Exception as e:
                 self.error = repr(e)
-        extract_s = time.perf_counter() - t_extract
-        top = sorted(self.summary.items(),
-                     key=lambda kv: -kv[1]["total_us"])[:self.top_k]
-        for name, row in top:
+        self.extract_s = time.perf_counter() - t_extract
+        # the summary is in order of device time
+        for name, row in list(self.summary.items())[:self.top_k]:
             self.trace.add_span(
                 f"hlo:{name}", duration_s=row["total_us"] / 1e6,
-                count=row["count"],
-                hlo_module=row.get("hlo_module"))
+                count=row["count"], hlo_module=row["hlo_module"],
+                scope=row["scope"])
         self._span.set_attr("ops_extracted", len(self.summary))
         self._span.set_attr("device_us", round(sum(
             r["total_us"] for r in self.summary.values()), 3))
@@ -264,7 +301,7 @@ class ProfilingSession:
             self._span.set_attr("error", self.error)
         self._span.close()
         _M_PROF_SESSIONS.inc()
-        _M_PROF_EXTRACT_S.set(extract_s)
+        _M_PROF_EXTRACT_S.set(self.extract_s)
         _M_PROF_OPS.set(len(self.summary))
         _flight.record_event(
             "xplane_profile", window_s=round(window_s, 6),

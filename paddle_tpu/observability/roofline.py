@@ -116,8 +116,9 @@ def census_table(rows):
         prev = out.setdefault(name, {"opcode": str(row.get("opcode", "")),
                                      "flops": 0.0, "bytes": 0.0})
         prev["flops"] += float(row.get("flops", 0) or 0)
-        prev["bytes"] += float(row.get("bytes", 0) or 0) \
-            + float(row.get("bytes_in", 0) or 0) \
+        # per_op_census rows carry all three, `bytes` being the sum
+        prev["bytes"] += float(row["bytes"] or 0) if "bytes" in row \
+            else float(row.get("bytes_in", 0) or 0) \
             + float(row.get("bytes_out", 0) or 0)
     return out
 

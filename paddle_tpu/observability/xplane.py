@@ -46,14 +46,18 @@ the parser must be loadable in a stdlib-only context.
 """
 from __future__ import annotations
 
+import bisect
 import os
+import re
 import struct
 from collections import OrderedDict
 
 __all__ = [
     "XStat", "XEvent", "XLine", "XPlane", "XSpace",
     "parse_xspace", "load_xspace", "find_dump",
-    "iter_events", "per_op_summary", "to_timeline",
+    "iter_events", "device_seconds", "timeline", "to_timeline",
+    "per_op_summary",
+    "short_name", "family",
 ]
 
 _WIRE_VARINT, _WIRE_FIXED64, _WIRE_LEN, _WIRE_FIXED32 = 0, 1, 2, 5
@@ -138,11 +142,12 @@ class XStat:
 
 class XEvent:
     __slots__ = ("name", "offset_ps", "duration_ps", "num_occurrences",
-                 "stats")
+                 "stats", "timed")
 
     def __init__(self):
         self.name = ""
         self.offset_ps = 0
+        self.timed = False  # offset_ps was written: a place on the line
         self.duration_ps = 0
         self.num_occurrences = 0  # aggregated-event form (offset absent)
         self.stats = {}  # stat name -> resolved value
@@ -231,6 +236,7 @@ def _decode_event(buf, span, event_meta, stat_meta):
             ev.name = event_meta.get(v, f"event_{v}")
         elif field == 2 and wire == _WIRE_VARINT:  # offset_ps (oneof)
             ev.offset_ps = _int64(v)
+            ev.timed = True
         elif field == 3 and wire == _WIRE_VARINT:  # duration_ps
             ev.duration_ps = _int64(v)
         elif field == 4 and wire == _WIRE_LEN:     # stats
@@ -365,37 +371,290 @@ def iter_events(space, lines=None):
             yield plane, line, ev
 
 
-def per_op_summary(space) -> "OrderedDict[str, dict]":
-    """Aggregate the op lines into ``name -> {count, total_us,
-    hlo_module, program_id}`` (insertion-ordered by first appearance).
+_DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
+_OPS_LINE, _MODULES_LINE = "XLA Ops", "XLA Modules"
 
-    The keys are XLA HLO instruction names (``dot.3``, ``fusion.12``) —
-    exactly the namespace ``census.per_op_census`` emits, so the
-    ``trace_report`` join needs no fuzzy matching for same-program runs.
-    Events that carry an ``hlo_op`` stat differing from their own name
-    (device planes nest kernels under op metadata) aggregate under the
-    stat."""
-    out: "OrderedDict[str, dict]" = OrderedDict()
-    for _plane, _line, ev in iter_events(space):
-        name = ev.stats.get("hlo_op") or ev.name
-        if not name:
+
+def short_name(name):
+    """A device event is named by its whole HLO instruction
+    ("%paged_attention.32 = bf16[...] custom-call(...)"): keep the
+    instruction's name."""
+    return name.split(" = ", 1)[0].lstrip("%")
+
+
+def family(name):
+    """`fusion.252` -> `fusion`: the instances of one op, summed."""
+    return re.sub(r"(\.\d+)+$", "", name)
+
+
+def _module_of(name):
+    """`jit_llm_decode(123)` -> `jit_llm_decode`."""
+    return re.sub(r"\(\d+\)$", "", name)
+
+
+def _span_ps(line, ev):
+    """(start, end) in whole picoseconds: a float loses the nanoseconds of
+    a Unix-epoch timestamp."""
+    a = line.timestamp_ns * 1000 + ev.offset_ps
+    return a, a + ev.duration_ps
+
+
+def _streams(space):
+    """The dump as ``(devices, program_ids)``: a device is ``(ops,
+    modules, calls)`` — ``ops`` ``[(start_ps, end_ps, module or None,
+    instruction, occurrences, timed)]``, ``modules`` its program
+    executions ``[(start_ps, end_ps, module)]``, ``calls`` ``{module:
+    executions}`` where the dump has no such line — and ``program_ids``
+    is ``{module: program_id}`` where events carry both (CPU dumps).
+
+    A TPU plane: the events of the ``XLA Ops`` line, each given the module
+    of the ``XLA Modules`` event whose interval holds its start (a v5e
+    event carries no ``hlo_module``).  Without such planes, the XLA-client
+    lines of ``/host:CPU`` together as one device: an op event there
+    carries ``hlo_module`` (and ``run_id``, which counts the calls);
+    events without one (runtime bookkeeping) have no module."""
+    def op(line, ev, name, mod):
+        a, b = _span_ps(line, ev)
+        return (a, b, mod, short_name(name),
+                max(1, int(ev.num_occurrences or 1)), ev.timed)
+
+    out = []
+    for plane in space.planes:
+        if not _DEVICE_PLANE.match(plane.name):
             continue
-        row = out.setdefault(str(name), {
-            "count": 0, "total_us": 0.0, "hlo_module": None,
-            "program_id": None})
-        row["count"] += max(1, int(ev.num_occurrences or 1))
-        row["total_us"] += ev.duration_ps / 1e6
-        if row["hlo_module"] is None and "hlo_module" in ev.stats:
-            row["hlo_module"] = str(ev.stats["hlo_module"])
-        if row["program_id"] is None and "program_id" in ev.stats:
-            row["program_id"] = ev.stats["program_id"]
+        mods = sorted(_span_ps(line, ev) + (_module_of(ev.name),)
+                      for line in plane.lines if line.name == _MODULES_LINE
+                      for ev in line.events)
+        starts = [m[0] for m in mods]
+        ops = []
+        for line in plane.lines:
+            if line.name != _OPS_LINE:
+                continue
+            for ev in line.events:
+                mod = ev.stats.get("hlo_module")
+                if mod is None:
+                    a = line.timestamp_ns * 1000 + ev.offset_ps
+                    i = bisect.bisect_right(starts, a) - 1
+                    if i >= 0 and a < mods[i][1]:
+                        mod = mods[i][2]
+                ops.append(op(line, ev, ev.name, mod))
+        if ops:
+            out.append((ops, mods, {}))
+    if out:
+        return out, {}
+    ops, runs, ids = [], {}, {}
+    for _plane, line in _op_lines(space):
+        for ev in line.events:
+            name = ev.stats.get("hlo_op") or ev.name
+            if not name:
+                continue
+            mod = ev.stats.get("hlo_module")
+            if mod is not None:
+                mod = str(mod)
+                runs.setdefault(mod, set()).add(
+                    ev.stats.get("run_id", len(ops)))
+                if "program_id" in ev.stats:
+                    ids.setdefault(mod, ev.stats["program_id"])
+            ops.append(op(line, ev, str(name), mod))
+    calls = {m: len(r) for m, r in runs.items()}
+    return ([(ops, [], calls)] if ops else []), ids
+
+
+def _window_of(space, window):
+    """``window`` in picoseconds: a (start_ns, end_ns) pair, or a string
+    naming a host annotation whose first event is the window."""
+    if window is None:
+        return None
+    if not isinstance(window, str):
+        return int(window[0] * 1000), int(window[1] * 1000)
+    for plane in space.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name == window:
+                        return _span_ps(line, ev)
+    return None
+
+
+def device_seconds(space, census=None, window=None):
+    """The plane's one aggregation: device seconds of a dump by (program,
+    named scope).
+
+    ``census`` is ``{module: {instruction: row}}`` (``census.by_module`` of
+    ``per_op_census`` rows, ``LLMEngine.program_census()``): an event's key
+    is (its module, its instruction's name), and the row gives its scope,
+    bytes and flops.  ``census=None`` reports every module with no scopes
+    (every event unmatched); with a census, a module it does not hold
+    goes to ``other_programs``.  ``window`` clips to (start_ns, end_ns),
+    or to the first host annotation of that name.
+
+    Time is SELF time: an event that contains others on its line (a
+    ``while`` over its body's ops, a ``conditional``) counts its span less
+    what its direct children cover, so the seconds add up to ``busy_s``
+    (the union of the events, computed on its own).  Several devices are
+    averaged.  Result::
+
+        {"window_s", "busy_s", "devices",
+         "programs": {module: {"calls", "seconds", "program_id",
+                               "scopes": {scope: {"seconds", "events",
+                                                  "bytes", "flops"}},
+                               "unscoped_s", "unmatched_s",
+                               "unmatched": {family: seconds},
+                               "ops": {instruction: {"events", "seconds",
+                                                     "span_s"}}}},
+         "other_programs": {module: {"calls", "seconds"}},
+         "unmatched_s", "unmatched": {family: seconds},
+         "no_module": {name: {"events", "seconds", "span_s"}}}
+
+    ``unmatched`` holds what no census row names: events of a known
+    program without a row (by family, also under the program) and events
+    outside every module (also by name under ``no_module``).  Every second
+    of ``busy_s`` is in exactly one of: a program's scopes (``unscoped``
+    among them), ``unmatched``, ``other_programs``.
+    """
+    devices, program_ids = _streams(space)
+    win = _window_of(space, window)
+    known = census or {}
+    n = len(devices)
+    out = {"window_s": 0.0, "busy_s": 0.0, "devices": n, "programs": {},
+           "other_programs": {}, "unmatched_s": 0.0, "unmatched": {},
+           "no_module": {}}
+    if not n:
+        return out
+    lo = min(op[0] for ops, _, _ in devices for op in ops)
+    hi = max(op[1] for ops, _, _ in devices for op in ops)
+    if win is not None:
+        lo, hi = win
+    ns = 1e-12 / n  # picoseconds of one device -> seconds, averaged
+    out["window_s"] = (hi - lo) * 1e-12
+
+    def program(mod):
+        return out["programs"].setdefault(mod, {
+            "calls": 0, "seconds": 0.0,
+            "program_id": program_ids.get(mod), "scopes": {},
+            "unscoped_s": 0.0, "unmatched_s": 0.0, "unmatched": {},
+            "ops": {}})
+
+    def other(mod):
+        return out["other_programs"].setdefault(
+            mod, {"calls": 0, "seconds": 0.0})
+
+    def op_row(table, name):
+        return table.setdefault(name, {"events": 0, "seconds": 0.0,
+                                       "span_s": 0.0})
+
+    for ops, mods, calls in devices:
+        evs = sorted(((max(a, lo), min(b, hi), mod, name, occ, timed)
+                      for a, b, mod, name, occ, timed in ops
+                      if b >= lo and a <= hi),
+                     key=lambda e: (e[0], -e[1]))
+        # nesting: an event's direct children are the events that start
+        # inside it while it is the innermost one open.  An event in the
+        # aggregated form (no offset) has no place on the line: it nests
+        # nowhere and its whole duration is busy.
+        child = [0] * len(evs)
+        stack, busy, reach = [], 0, lo
+        for i, (a, b, _, _, _, timed) in enumerate(evs):
+            if not timed:
+                busy += b - a
+                continue
+            while stack and evs[stack[-1]][1] <= a:
+                stack.pop()
+            if stack:
+                child[stack[-1]] += min(b, evs[stack[-1]][1]) - a
+            stack.append(i)
+            if b > reach:
+                busy += b - max(a, reach)
+                reach = b
+        out["busy_s"] += busy * ns
+        for a, b, m in mods:  # the executions inside the window
+            if b > lo and a < hi:
+                calls[m] = calls.get(m, 0) + 1
+        for m, c in calls.items():
+            (program if census is None or m in census else other)(m)[
+                "calls"] += c / n
+        for (a, b, mod, name, occ, _), inside in zip(evs, child):
+            own, span = max(0, b - a - inside) * ns, (b - a) * ns
+            if mod is None:
+                fam = family(name)
+                out["unmatched"][fam] = out["unmatched"].get(fam, 0.0) + own
+                out["unmatched_s"] += own
+                r = op_row(out["no_module"], name)
+            elif census is not None and mod not in census:
+                other(mod)["seconds"] += own
+                continue
+            else:
+                prog = program(mod)
+                prog["seconds"] += own
+                row = known.get(mod, {}).get(name)
+                if row is None:
+                    fam = family(name)
+                    for table in (prog["unmatched"], out["unmatched"]):
+                        table[fam] = table.get(fam, 0.0) + own
+                    prog["unmatched_s"] += own
+                    out["unmatched_s"] += own
+                else:
+                    sc = prog["scopes"].setdefault(row["scope"], {
+                        "seconds": 0.0, "events": 0, "bytes": 0,
+                        "flops": 0})
+                    sc["seconds"] += own
+                    sc["events"] += occ
+                    sc["bytes"] += occ * row.get("bytes", 0)
+                    sc["flops"] += occ * row.get("flops", 0)
+                    if row["scope"] == "unscoped":  # census.UNSCOPED
+                        prog["unscoped_s"] += own
+                r = op_row(prog["ops"], name)
+            r["events"] += occ
+            r["seconds"] += own
+            r["span_s"] += span
     return out
 
 
-def to_timeline(path_or_space) -> "OrderedDict[str, dict]":
-    """The ``trace_report.load_timeline`` shape (``name -> {count,
-    total_us, ...}``) straight from a dump path / logdir / parsed space —
-    the ``--xplane`` entry point."""
+def timeline(reduced, census=None):
+    """A :func:`device_seconds` result flattened to the
+    ``trace_report.load_timeline`` shape, one row an instruction of a
+    program: ``"module/instruction" -> {count, total_us, module,
+    instruction, scope, hlo_module, program_id}`` (an event outside every
+    module: its bare name), in order of device time.  ``total_us`` is self
+    time."""
+    rows = []
+    for mod, prog in reduced["programs"].items():
+        known = (census or {}).get(mod, {})
+        for name, r in prog["ops"].items():
+            rows.append((f"{mod}/{name}", r, mod, name,
+                         known.get(name, {}).get("scope"),
+                         prog["program_id"]))
+    for name, r in reduced["no_module"].items():
+        rows.append((name, r, None, name, None, None))
+    rows.sort(key=lambda t: (-t[1]["seconds"], t[0]))
+    out: "OrderedDict[str, dict]" = OrderedDict()
+    for key, r, mod, name, scope, pid in rows:
+        out[key] = {"count": r["events"], "total_us": r["seconds"] * 1e6,
+                    "module": mod, "instruction": name, "scope": scope,
+                    "hlo_module": mod, "program_id": pid}
+    return out
+
+
+def to_timeline(path_or_space, census=None, window=None):
+    """:func:`timeline` of a dump path / logdir / parsed space: the
+    ``--xplane`` entry point."""
     space = path_or_space if isinstance(path_or_space, XSpace) \
         else load_xspace(path_or_space)
-    return per_op_summary(space)
+    return timeline(device_seconds(space, census, window), census)
+
+
+def per_op_summary(space) -> "OrderedDict[str, dict]":
+    """The by-name table of before the census knew modules: ``name ->
+    {count, total_us, hlo_module, program_id}``, two programs' ``fusion.3``
+    summed into one row.  Kept for the dumps and reports recorded in that
+    shape; a projection of :func:`device_seconds`, which every caller in
+    the package uses instead (keyed by module and instruction)."""
+    out: "OrderedDict[str, dict]" = OrderedDict()
+    for row in to_timeline(space).values():
+        prev = out.setdefault(row["instruction"], {
+            "count": 0, "total_us": 0.0,
+            "hlo_module": row["hlo_module"],
+            "program_id": row["program_id"]})
+        prev["count"] += row["count"]
+        prev["total_us"] += row["total_us"]
+    return out

@@ -199,7 +199,14 @@ def test_profiling_session_files_hlo_spans_under_owning_trace(tmp_path):
     trace.end("ok")
     assert sess.error is None
     assert sess.summary and os.path.isfile(sess.dump_path)
-    assert any(k.startswith("dot.") for k in sess.summary)
+    # the reduced rows are keyed "module/instruction"; this jax names the
+    # instruction after the primitive (`dot_general.N`, once `dot.N`)
+    dots = [r for k, r in sess.summary.items()
+            if r["module"] and k == f"{r['module']}/{r['instruction']}"
+            and r["instruction"].startswith("dot")]
+    assert dots and all(r["module"].startswith("jit_") and r["count"] == 2
+                        and r["total_us"] > 0 for r in dots)
+    assert sess.by_scope["programs"][dots[0]["module"]]["calls"] == 2
     (span,) = trace.find_spans("xplane_profile")
     assert span.attrs["ops_extracted"] == len(sess.summary)
     assert span.attrs["device_us"] > 0

@@ -61,8 +61,7 @@ def _measure(args) -> int:
     from paddle_tpu.observability import xplane
     sys.path[0:0] = [os.path.join(_REPO, "tools")]
     import trace_report
-    measured = xplane.per_op_summary(xplane.load_xspace(
-        xplane.find_dump(args.xplane)))
+    measured = xplane.to_timeline(xplane.find_dump(args.xplane))
     census = trace_report.load_census(args.census) if args.census else {}
     pf, pbw = args.peak_flops, args.peak_bw
     if pf is None or pbw is None:

@@ -40,10 +40,14 @@ Inputs
                             cost_model lookups, overridable with
                             --peak-flops / --peak-bw
 
-Join rule: exact name match first, else substring containment either way
-(census op ``dot.4`` matches timeline event ``jit_step/dot.4``); census
-rows without a timed event and events without census costs both stay in
-the table (flagged) — unattributed time is a finding, not noise.
+Join rule: a device dump (``--xplane``) joins by (module, instruction),
+exactly: ``fusion.12`` of ``jit_llm_decode`` is not ``fusion.12`` of
+``jit_llm_prefill_chunk`` (a census row without a module joins by its
+instruction's name, exactly).  The span sources join by exact name first,
+else substring containment either way (census op ``dot.4`` matches
+timeline event ``jit_step/dot.4``).  Census rows without a timed event and
+events without census costs both stay in the table (flagged) —
+unattributed time is a finding, not noise.
 
 Exit code: 0 on a usable table; 1 when there is nothing to attribute at
 all; 2 when a census was supplied but NOT ONE timed row joined it — CI
@@ -73,7 +77,9 @@ SCHEMA_VERSION = 2
 # ------------------------------------------------------------------ loading
 def load_timeline(path=None, events=None, flight_path=None,
                   tracez_path=None, xplane_path=None):
-    """-> OrderedDict name -> {"count", "total_us"} aggregated timings."""
+    """-> OrderedDict name -> {"count", "total_us"} aggregated timings (a
+    device dump: "module/instruction" -> the same plus "module" and
+    "instruction", observability.xplane.to_timeline)."""
     if xplane_path is not None:
         return _timeline_from_xplane(xplane_path)
     if tracez_path is not None:
@@ -113,9 +119,10 @@ def load_timeline(path=None, events=None, flight_path=None,
 
 
 def _timeline_from_xplane(path):
-    """Per-HLO device timings of a profiler dump, via the dependency-free
-    observability.xplane reader (imported lazily: the other sources must
-    keep working without the package on sys.path)."""
+    """Device self time by (module, instruction) of a profiler dump, via
+    the plane's one reducer (observability.xplane.device_seconds; imported
+    lazily: the other sources must keep working without the package on
+    sys.path)."""
     import os
     import sys
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -185,12 +192,15 @@ def load_census(path):
     if isinstance(doc, list):  # per_op_census() rows
         for row in doc:
             name = str(row.get("name", "?"))
+            if row.get("module"):  # keyed as a device dump's rows are
+                name = f"{row['module']}/{name}"
             prev = out.setdefault(name, {"opcode": row.get("opcode", ""),
                                          "flops": 0.0, "bytes": 0.0})
             prev["flops"] += float(row.get("flops", 0) or 0)
-            prev["bytes"] += float(row.get("bytes_out", 0) or 0) \
-                + float(row.get("bytes_in", 0) or 0) \
-                + float(row.get("bytes", 0) or 0)
+            # `bytes` is the sum where a row carries all three
+            prev["bytes"] += float(row["bytes"]) if "bytes" in row \
+                else float(row.get("bytes_out", 0) or 0) \
+                + float(row.get("bytes_in", 0) or 0)
         return out
     if isinstance(doc, dict) and "counts" in doc:  # collective_census()
         for key, op in (("bytes_allreduce", "all-reduce"),
@@ -255,13 +265,20 @@ def join(timeline, census):
     attribution is visible."""
     rows, used = [], set()
     for name, t in timeline.items():
-        cname = _match(name, census)
+        if "instruction" in t:
+            # a device dump: (module, instruction) or, for a census with
+            # no module column, the instruction; never a near miss
+            cname = next((k for k in (name, t["instruction"])
+                          if k in census), None)
+            name = t["instruction"]
+        else:
+            cname = _match(name, census)
         c = census.get(cname) if cname else None
         if cname:
             used.add(cname)
         secs = t["total_us"] / 1e6
         rows.append({
-            "name": name, "count": t["count"],
+            "name": name, "module": t.get("module"), "count": t["count"],
             "total_us": round(t["total_us"], 3),
             "opcode": (c or {}).get("opcode", ""),
             "flops": (c or {}).get("flops", 0.0),
@@ -273,7 +290,8 @@ def join(timeline, census):
     for cname, c in census.items():
         if cname in used:
             continue
-        rows.append({"name": cname, "count": 0, "total_us": 0.0,
+        rows.append({"name": cname, "module": None, "count": 0,
+                     "total_us": 0.0,
                      "opcode": c.get("opcode", ""), "flops": c["flops"],
                      "bytes": c["bytes"], "gflops_per_s": 0.0,
                      "matched": False})
