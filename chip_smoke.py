@@ -166,7 +166,26 @@ def serve_phase(n_layers=N_LAYERS, max_seq_len=SEQ, prompt_lens=(100, 1500),
     agree = int(np.cumprod(ref == np.asarray(outs[0])).sum())
     log(f"serve: request 0 agrees with model.generate() for {agree} of "
         f"{new_tokens} leading tokens")
-    _ran_ahead(eng)
+    pl = _ran_ahead(eng)
+    # one program carried a chunk AND decode rows, and no admission made the
+    # pump read before it dispatched: every program but an idle spell's
+    # first went out with the one before still unread, and a first token's
+    # read found the logits there (a transfer, not a program's wait)
+    ph = eng.stats()["tick_phases"]
+    cnt, sec = ph["count"], ph["seconds"]
+    sent = cnt["decode_dispatch"] + cnt["prefill_dispatch"]
+    check(pl["mixed"] > 0, f"chunks rode beside decoding rows: {pl}")
+    check(pl["overlapped"] >= 0.99 * (sent - pl["drained"]["idle"]),
+          f"{pl['overlapped']} of {sent} programs were dispatched with the "
+          f"one before unread ({pl['drained']['idle']} idle spells)")
+    first_ms = 1e3 * sec["first_token_sync"] / cnt["first_token_sync"]
+    sync_ms = 1e3 * sec["decode_sync"] / cnt["decode_sync"]
+    check(first_ms < max(0.5 * sync_ms, 2.0),
+          f"a first token is read in {first_ms:.2f} ms (a program's wait: "
+          f"{sync_ms:.2f} ms)")
+    # the short requests against the model's own one-pass logits
+    short = [i for i, p in enumerate(prompts) if len(p) <= 640]
+    _one_pass_gap(model, [prompts[i] for i in short], [outs[i] for i in short])
     geom = dict(heads=cfg.num_attention_heads,
                 kv_heads=cfg.num_key_value_heads,
                 head_dim=cfg.hidden_size // cfg.num_attention_heads,

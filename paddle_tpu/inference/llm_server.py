@@ -58,7 +58,11 @@ runs the new one — the host's work hides under the device's.  Where the
 next program needs what the host has not read (a constrained row, a
 drafter, a preemption) the tick reads first and is synchronous
 (`_decode_tick`; `stats()["tick_pipeline"]`).  Tokens are the synchronous
-pump's, token for token.
+pump's, token for token.  For a model with ``mixed_step`` a tick that
+carries a prefill chunk dispatches ONE program, ``llm_mixed``: the chunk's
+rows and the decode rows through one pass over the weights, and a final
+chunk's first token is read where that program is booked, a tick later
+(`_mixed_program`, `_first_token`).
 
 The engine is deterministic and thread-free by default (`step()` pumps one
 tick, after which a decode result is as a rule still in flight;
@@ -269,8 +273,10 @@ _CKPT_EVENTS = ("stored", "restored", "evicted")
 _TICK_PHASES = (
     "expire",            # _expire_queued, _expire_slots
     "admit",             # _start_prefill: pop, prefix match, tiers, pages
-    "prefill_stage",     # COW fork, the chunk's host arrays
-    "prefill_dispatch",  # the chunk program's (asynchronous) call
+    "prefill_stage",     # the chunk's checks, COW fork and host arrays
+    "prefill_dispatch",  # the (asynchronous) call of the program that
+                         # carries a chunk: llm_prefill_chunk, or llm_mixed
+                         # with the tick's decode rows beside it
     "first_token_sync",  # np.asarray(logits) + host select + activation
     "decode_stage",      # page growth, uploads, rng split, knobs, masks
     "decode_dispatch",   # the decode program's call, until it returns
@@ -295,10 +301,12 @@ _DRAIN_REASONS = ("constrained", "spec", "preempt", "idle", "stop", "warmup")
 _M_TICK_PIPELINE = _obs.counter(
     "llm_tick_pipeline_ticks_total",
     "Decode results the pump read, by whether the next decode program was "
-    "already dispatched (overlapped) or why it was not (the drain's reason)",
+    "already dispatched (overlapped) or why it was not (the drain's "
+    "reason); mode=mixed: programs that carried a prefill chunk AND decode "
+    "rows through one weight pass",
     labelnames=("mode",))
 _PIPELINE_SERIES = {m: _M_TICK_PIPELINE.labels(mode=m)
-                    for m in ("overlapped",) + _DRAIN_REASONS}
+                    for m in ("overlapped", "mixed") + _DRAIN_REASONS}
 #: why a tick left the queue head waiting: exactly one reason a tick
 _BLOCK_REASONS = ("prefill_busy", "no_slot", "no_pages", "no_adapter_page")
 _M_ADM_BLOCKED = _obs.counter(
@@ -456,6 +464,25 @@ class _InFlight:
     rows: list     # [(slot, request)] of the rows it carried
     moe: object    # the (pairs, layer calls) dispatched since the program
                    # before it, this one included: what its counts cover
+    first: object = None  # (request, slot, logits): the final chunk the
+                   # program carried (llm_mixed); its first token is
+                   # selected where the program is booked
+
+
+@dataclass
+class _Chunk:
+    """The prefill chunk a tick has checked and staged: the next ``m``
+    prompt tokens of ``req`` (``done`` are in its pages already) for
+    ``slot``, and what the program that carries it takes for it."""
+    req: _Request
+    slot: int
+    done: int
+    m: int
+    args: tuple  # (page-table row [1, M], ids [1, C], done [1], m - 1)
+
+    @property
+    def final(self):
+        return self.done + self.m >= self.req.prompt.size
 
 
 def _select_rows(logits, key, do_sample, temperature, top_k, top_p,
@@ -545,6 +572,45 @@ def _from_model_caches(kinds, new_caches):
                 moe.append(c)
     return raw, ([jnp.stack(moe)] if moe else []) \
         + ([sum(sparse)] if sparse else []) + ([sum(latent)] if latent else [])
+
+
+def _mixed_program(model, kinds):
+    """``llm_mixed``: the compiled step of a tick that carries a prefill
+    chunk, for a model with ``mixed_step`` — the chunk's [1, C] rows and
+    every slot's decode row through ONE pass over the weights, where
+    llm_prefill_chunk and llm_decode would each stream them.  Its arguments
+    are llm_decode's at one token a row (the token feed, the keys derived
+    from ``(base_key, offset)`` exactly as there, so a row draws the same
+    token whichever program carries it) and then the chunk's: its slot's
+    page-table row, its ids, the tokens already prefilled, the index of
+    its last real token.  The tick masks the chunk's slot out of
+    ``page_tbl`` like any slot between chunks; with no row decoding every
+    row of it is masked and the program is the chunk's alone.  Results:
+    the rows' tokens [B, 1], the caches, the chunk's logits [1, 1, V] at
+    ``last_index``, and LAST the token feed of the next program."""
+    def llm_mixed(params, buffers, caches, page_tbl, tokens, feed, from_host,
+                  pos, do_sample, temperature, top_k, top_p, token_mask,
+                  base_key, offset, page_row, ids, off, last_index):
+        with jax.named_scope("sampler"):
+            key = jax.random.split(jax.random.fold_in(base_key, offset), 1)[0]
+        with jax.named_scope("token_feed"):
+            tokens = jnp.where(from_host, tokens[:, 0], feed)[:, None]
+        restore = model.bind_functional_state(params, buffers)
+        try:
+            with tape.no_grad():
+                logits, first, new_caches = model.mixed_step(
+                    Tensor(ids), Tensor(tokens),
+                    _to_model_caches(kinds, caches, pos, page_tbl),
+                    (off, page_row), last_index)
+                raw, _ = _from_model_caches(kinds, new_caches)
+                nxt = _select_rows(
+                    logits._value[:, -1], key, do_sample, temperature, top_k,
+                    top_p, token_mask=token_mask)
+        finally:
+            restore()
+        return nxt[:, None], raw, first._value, nxt.astype(jnp.int32)
+
+    return jax.jit(llm_mixed, donate_argnums=(2,))
 
 
 class LLMEngine:
@@ -1002,7 +1068,9 @@ class LLMEngine:
         self._feed0 = self._feed = jnp.zeros((B,), jnp.int32)
         self._chunk_out = None  # a final chunk's (request, slot, logits)
         # until the tick reads them (_first_token)
-        self._pipeline = {"overlapped": 0, "surplus_tokens": 0,
+        self._staged: _Chunk | None = None  # a mixed engine's chunk, from
+        # _prefill_tick to the program that carries it (_decode_tick)
+        self._pipeline = {"overlapped": 0, "mixed": 0, "surplus_tokens": 0,
                           "drained": dict.fromkeys(_DRAIN_REASONS, 0)}
         self.max_queue_len = None if max_queue_len is None \
             else int(max_queue_len)
@@ -1078,7 +1146,16 @@ class LLMEngine:
         self._mask_all_true = jnp.ones((self.n_slots, self._vocab), bool)
         self._verify_jit = None
         self._decode_jit = {}  # scan length (effective chunk) -> jitted fn
-        self._chunk_jit = None  # the one prefill-chunk program
+        self._chunk_jit = None  # the one program that carries a chunk
+        # ONE program for a tick's chunk and its decode rows, where the
+        # model has the step and every row of a tick is one token of one
+        # plain program: llm_mixed then takes llm_prefill_chunk's place.
+        # (A LoRA epilogue gathers by batch row and a verify ladder or a
+        # multi-token scan has no chunk-shaped twin: such engines keep the
+        # two programs.)
+        self._mixed = (callable(getattr(model, "mixed_step", None))
+                       and self.decode_chunk == 1 and not self.spec_k
+                       and adapters is None)
         # program_census(): built on the first call, never by a tick
         self._census = None
         self._census_lock = threading.Lock()
@@ -1755,7 +1832,7 @@ class LLMEngine:
                 self._inflight = None
                 self._count_drain("stop")
             self._feed = self._feed0
-            self._chunk_out = None
+            self._chunk_out = self._staged = None
             if self._prefilling is not None:
                 req, slot, _ = self._prefilling
                 self._prefilling = None
@@ -2579,9 +2656,13 @@ class LLMEngine:
             range(10, 10 + len(self._accs()))))
 
     def _get_chunk_prefill(self):
+        """The one program that carries a chunk: llm_mixed where the
+        engine takes a chunk and the decode rows through one weight pass,
+        else llm_prefill_chunk."""
         if self._chunk_jit is None:
             _profiling.record_compile("chunk_prefill")
-            self._chunk_jit = self._chunk_prefill_fn()
+            self._chunk_jit = _mixed_program(self.model, self._cache_kinds) \
+                if self._mixed else self._chunk_prefill_fn()
         return self._chunk_jit
 
     def _accs(self):
@@ -2617,6 +2698,11 @@ class LLMEngine:
         feed of the next one; returns its tokens, still on the device."""
         self._feed = out[-1]
         return self._took(out[:-1])
+
+    def _took_mixed(self, out):
+        """_took_decode for llm_mixed; returns (its tokens, the chunk's
+        logits), both still on the device."""
+        return self._took_decode(out[:2] + out[3:]), out[2]
 
     def _moe_dispatched(self, program, rows, calls):
         """`rows` real rows went through every expert layer `calls` times."""
@@ -2876,11 +2962,35 @@ class LLMEngine:
                 self._adm_inflight -= 1
 
     def _prefill_tick(self):
-        """Dispatch ONE prefill chunk of the admitting request; a final
-        chunk's logits wait in _chunk_out for the tick to read them
-        (_first_token), after it has dispatched its decode program."""
-        pc = self._phases
-        pc.switch("prefill_stage")
+        """The admitting request's next chunk: check it (_check_chunk),
+        stage it (_stage_chunk), and either dispatch llm_prefill_chunk now
+        — a final chunk's logits then wait in _chunk_out for the end of
+        this tick (_first_token), after its decode program is dispatched —
+        or, where one program takes a chunk and the decode rows through
+        one weight pass, leave it staged for _decode_tick, which dispatches
+        llm_mixed with it."""
+        self._phases.switch("prefill_stage")
+        if not self._check_chunk():
+            return
+        chunk = self._stage_chunk()
+        if self._mixed:
+            self._staged = chunk
+            return
+        args = (self._params, self._buffers, self.caches, *chunk.args,
+                *self._lora_args([chunk.req.adapter_page]),
+                *self._chunk_extra(chunk.slot))
+        logits = self._dispatch_chunk(chunk, args)
+        if logits is not None and chunk.final:
+            # _prefilling stays set until the slot is active (_first_token,
+            # later in this tick)
+            self._chunk_out = (chunk.req, chunk.slot, logits)
+
+    def _check_chunk(self):
+        """Whether the admitting request's next chunk may run: not when the
+        request was cancelled or its deadline has passed (it is failed and
+        its pages go back), nor when the chunk would write into a page
+        other slots still read and no page can be freed for the fork (it is
+        requeued to prefill privately)."""
         req, slot, done = self._prefilling
         if req.future.done() or (req.deadline is not None
                                  and self._clock() > req.deadline):
@@ -2896,69 +3006,84 @@ class LLMEngine:
                                 prefilled_tokens=int(done))
             else:
                 self._end_trace(req, "cancelled")
-            return
-        n = req.prompt.size
-        C = self.prefill_chunk
-        m = min(C, n - done)
-        if self._prefix is not None \
-                and not self._cow_page(slot, done // self.ps):
-            # the chunk would write into a page other slots still read and
-            # no page can be freed for the fork: requeue recompute-style
-            # (fully private next time) instead of wedging or failing
-            self._release_pages(slot)
-            self._release_adapter(req)
-            req.skip_cache = True
-            # the hit credited at admission never materialized: the private
-            # re-prefill recomputes every chunk the cache was covering
-            self._prefix_hit_tokens -= req.hit_tokens
-            req.hit_tokens = 0
-            _M_PAGE_PREEMPT.inc()
-            _flight.record_event("page_preemption", slot=int(slot),
-                                 where="prefill_cow", **_trace_kv(req))
-            if req.adm_span is not None:
-                req.adm_span.close(error="cow_starved")
-                req.adm_span = None
-            req.requeue_reason = "prefill_cow"
-            req.trace.inc_attr("preempt_requeues")
-            # the whole prompt re-prefills privately next episode — the
-            # chunks already written AND the cache-hit tokens just
-            # un-credited are all recomputed
-            recompute = int(req.prompt.size)
-            _M_RECOMPUTE_TOKENS.labels(reason="prefill_cow").inc(recompute)
-            self._recompute_tokens += recompute
-            self._goodput.count_tokens("preempt_recomputed", recompute)
-            with self._pending.mutex:
-                self._pending.queue.appendleft(req)
-            # clear the marker only after the requeue is visible, so
-            # drain()'s lock-free _drained() never sees an empty queue
-            # with the request parked nowhere
-            self._prefilling = None
-            return
-        chunk = np.full((1, C), self.pad, np.int32)
-        chunk[0, :m] = req.prompt[done:done + m]
-        # host arrays straight into the compiled call: slicing or casting
-        # on the device here would be eager ops that compile after warmup()
-        args = (self._params, self._buffers, self.caches,
-                self._pt_host[slot:slot + 1].copy(), chunk,
-                np.full((1,), done, np.int32), np.int32(m - 1)) \
-            + self._lora_args([req.adapter_page]) + self._chunk_extra(slot)
-        # the call is asynchronous: this phase, like the llm_prefill_chunk
-        # span inside it, is the host's time to DISPATCH the chunk.  The
-        # wait for the chunk shows where the host next reads a result that
-        # was dispatched after it: first_token_sync on a final chunk, else
-        # the next tick's decode_sync (the device runs the programs in order)
+            return False
+        if self._prefix is None or self._cow_page(slot, done // self.ps):
+            return True
+        # requeue recompute-style (fully private next time) instead of
+        # wedging or failing
+        self._release_pages(slot)
+        self._release_adapter(req)
+        req.skip_cache = True
+        # the hit credited at admission never materialized: the private
+        # re-prefill recomputes every chunk the cache was covering
+        self._prefix_hit_tokens -= req.hit_tokens
+        req.hit_tokens = 0
+        _M_PAGE_PREEMPT.inc()
+        _flight.record_event("page_preemption", slot=int(slot),
+                             where="prefill_cow", **_trace_kv(req))
+        if req.adm_span is not None:
+            req.adm_span.close(error="cow_starved")
+            req.adm_span = None
+        req.requeue_reason = "prefill_cow"
+        req.trace.inc_attr("preempt_requeues")
+        # the whole prompt re-prefills privately next episode — the
+        # chunks already written AND the cache-hit tokens just
+        # un-credited are all recomputed
+        recompute = int(req.prompt.size)
+        _M_RECOMPUTE_TOKENS.labels(reason="prefill_cow").inc(recompute)
+        self._recompute_tokens += recompute
+        self._goodput.count_tokens("preempt_recomputed", recompute)
+        with self._pending.mutex:
+            self._pending.queue.appendleft(req)
+        # clear the marker only after the requeue is visible, so
+        # drain()'s lock-free _drained() never sees an empty queue
+        # with the request parked nowhere
+        self._prefilling = None
+        return False
+
+    def _stage_chunk(self):
+        """The admitting request's next chunk with what a program takes for
+        it: the slot's page-table row (forked where it had to be, and not
+        touched again before the dispatch), the ids padded to the program's
+        width, the tokens already prefilled, the index of the last real
+        token.  Host arrays straight into the compiled call: slicing or
+        casting on the device here would be eager ops that compile after
+        warmup()."""
+        req, slot, done = self._prefilling
+        m = min(self.prefill_chunk, req.prompt.size - done)
+        ids = np.full((1, self.prefill_chunk), self.pad, np.int32)
+        ids[0, :m] = req.prompt[done:done + m]
+        return _Chunk(req, slot, done, m, (
+            self._pt_host[slot:slot + 1].copy(), ids,
+            np.full((1,), done, np.int32), np.int32(m - 1)))
+
+    def _dispatch_chunk(self, chunk, args):
+        """Call the program that carries ``chunk`` and keep its results
+        (_took, or _took_mixed for llm_mixed).  The call is asynchronous:
+        the prefill_dispatch phase, like the llm_prefill_chunk span inside
+        it, is the host's time to DISPATCH; the wait shows where the host
+        next reads a result dispatched with or after it.  Books the chunk —
+        its count, its goodput seconds, the request's progress; after its
+        last, the prompt's pages go into the prefix index — and returns
+        what _took / _took_mixed returned; ``None`` where the call failed (that
+        request alone fails; a failure that took the donated caches with
+        it is raised, to the pump's watchdog)."""
+        req, slot = chunk.req, chunk.slot
+        pc = self._phases
         t_pf = pc.switch("prefill_dispatch")
         try:
             jit = self._get_chunk_prefill()
             if _obs.enabled():
                 with _span("llm_prefill_chunk", _M_PREFILL_CHUNK_S,
                            trace=req.trace,
-                           attrs={"index": done // C, "tokens": int(m)}):
-                    logits = self._took(jit(*args))
+                           attrs={"index": chunk.done // self.prefill_chunk,
+                                  "tokens": int(chunk.m)}):
+                    out = jit(*args)
             else:
-                logits = self._took(jit(*args))
+                out = jit(*args)
+            out = self._took_mixed(out) if self._mixed else self._took(out)
             if self._moe_acc is not None:
-                self._moe_dispatched("prefill", m, 1)
+                self._moe_dispatched("prefill", chunk.m, 1)
         except Exception as e:
             self._prefilling = None
             self._release_pages(slot)
@@ -2966,12 +3091,12 @@ class LLMEngine:
             _fail_future(req.future, e)
             self._end_trace(req, "error", error=repr(e))
             if not self._caches_alive():
-                # the chunk call DONATES self.caches: an execution-time
+                # the call DONATES self.caches: an execution-time
                 # failure may have consumed the buffers, and serving on
                 # deleted arrays would fail every later request with a
                 # misleading error — escalate to the pump watchdog instead
                 raise
-            return
+            return None
         # goodput ledger: a first-episode chunk is productive prefill; a
         # re-admission (adm_episode > 1: page-pool-dry, mid-verify or
         # COW-starved requeue) recomputes kv it already computed once.
@@ -2980,10 +3105,9 @@ class LLMEngine:
             "preempt_recompute_waste" if req.adm_episode > 1 else "prefill",
             pc.switch("bookkeep") - t_pf)
         _M_PREFILL_CHUNKS.inc()
-        done += m
-        if done < n:
-            self._prefilling = (req, slot, done)
-            return
+        if not chunk.final:
+            self._prefilling = (req, slot, chunk.done + chunk.m)
+            return out
         # the slot's pages now hold the whole prompt's kv: index the full
         # blocks + partial tail so CONCURRENT same-prefix requests hit
         # (insert precedes the first decode write, whose COW check then
@@ -2993,22 +3117,20 @@ class LLMEngine:
                            adapter_id=req.adapter_id)
         if self._ckpt is not None:
             self._ckpt_store(slot, req)
-        # _prefilling stays set until the slot is active (_first_token,
-        # later in this tick)
-        self._chunk_out = (req, slot, logits)
+        return out
 
-    def _first_token(self):
-        """The final chunk's last step, once the tick has dispatched its
-        decode program and booked the one before: wait for the chunk's
-        logits, select the first token on the host and activate the slot.
-        The slot joins the NEXT decode program, with this token from the
-        host (the program just dispatched carries its row masked, like any
-        slot between chunks)."""
-        req, slot, logits = self._chunk_out
-        self._chunk_out = None
-        # the chunk (and whatever was queued before it) must finish before
-        # its logits can be read; the decode program queued behind it runs
-        # meanwhile
+    def _first_token(self, req, slot, logits):
+        """A final chunk's last step: read its logits, select the first
+        token on the host and activate the slot, which joins the NEXT
+        decode program with this token from the host.  WHEN depends on the
+        program that carried the chunk.  llm_prefill_chunk: at the end of
+        the tick that dispatched it, once that tick's decode program is
+        dispatched behind it and the one before is booked — the read waits
+        for the chunk while the decode program queued behind it keeps the
+        device busy.  llm_mixed: where that program is booked (_book), a
+        tick later and after the next program is dispatched — the logits
+        are there with the program's tokens, the read is a transfer, and
+        an admission never makes the pump read before it dispatches."""
         self._phases.switch("first_token_sync")
         tok = self._host_select(np.asarray(logits)[0, 0], req)
         first = not req.tokens  # re-admission after preemption continues
@@ -3020,11 +3142,6 @@ class LLMEngine:
         self.slot_req[slot] = req
         self.slot_pos[slot] = req.prompt.size
         self.last_token[slot] = tok
-        # only now drop the in-flight marker: drain()'s lock-free
-        # _drained() must never observe _prefilling cleared while the
-        # slot is not yet active, or it declares the engine empty with
-        # this request still about to decode
-        self._prefilling = None
         _M_ADMITTED.inc()
         self._first_token_out(req, first)
         if tok == self.eos or len(req.tokens) >= req.max_new_tokens:
@@ -3153,15 +3270,26 @@ class LLMEngine:
             self._ckpt = p
 
         C, B = self.prefill_chunk, self.n_slots
+        tokens = np.full((B, 1), self.pad, np.int32)
+        pos = np.zeros((B,), np.int32)
+        knobs = self._sampling_knobs([])  # every row greedy
+        rng = (_fr.default_generator().key, np.uint32(0))
         # last_index -1: no token of the warm-up chunk is real, so
         # slot 0's recurrent state and the expert counts stay put
-        yield self._get_chunk_prefill(), (
-            self._params, self._buffers, self.caches,
-            np.zeros((1, self.M), np.int32),
-            np.full((1, C), self.pad, np.int32),
-            np.zeros((1,), np.int32),
-            np.int32(0 if self._cache_kinds is None else -1),
-            *self._lora_args([0]), *self._chunk_extra(0)), self._took
+        chunk = (np.zeros((1, self.M), np.int32),
+                 np.full((1, C), self.pad, np.int32),
+                 np.zeros((1,), np.int32),
+                 np.int32(0 if self._cache_kinds is None else -1))
+        if self._mixed:
+            # llm_mixed in llm_prefill_chunk's place: every row masked
+            yield self._get_chunk_prefill(), (
+                *self._cache_args([]), tokens, self._feed,
+                np.ones((B,), bool), pos, *knobs, self._mask_all_true, *rng,
+                *chunk), self._took_mixed
+        else:
+            yield self._get_chunk_prefill(), (
+                self._params, self._buffers, self.caches, *chunk,
+                *self._lora_args([0]), *self._chunk_extra(0)), self._took
         if not self._recurrent:
             # the COW fork program too: a warm engine's first
             # shared-prefix fork must not compile (and must not trip
@@ -3179,10 +3307,6 @@ class LLMEngine:
             yield self._get_ckpt_load(), (
                 self.caches, self._ckpt, np.int32(0), np.int32(0)), caches
         eff = max(1, min(self.decode_chunk, self.L - 1))
-        tokens = np.full((B, 1), self.pad, np.int32)
-        pos = np.zeros((B,), np.int32)
-        knobs = self._sampling_knobs([])  # every row greedy
-        rng = (_fr.default_generator().key, np.uint32(0))
         lora = self._lora_args([0] * B)
         yield self._get_decode(eff), (
             *self._cache_args(), tokens, self._feed, np.ones((B,), bool),
@@ -3197,7 +3321,8 @@ class LLMEngine:
     def warmup(self):
         """Pre-compile the serving programs so the FIRST request pays no
         compile latency (the TTFT spike visible in llm_ttft_seconds): the
-        prefill-chunk program (it serves every prompt length), the COW page
+        program that carries a prefill chunk (it serves every prompt length:
+        llm_prefill_chunk, or llm_mixed in its place), the COW page
         copy, the decode step at the configured decode_chunk and, with
         ``spec_k``, the verify step.  Runs the real compiled calls against
         the engine's own idle cache state: the garbage rows land in the
@@ -3434,9 +3559,15 @@ class LLMEngine:
 
     def step(self):
         """One engine tick: admit pending prompts (one chunk), dispatch the
-        next decode program for every active slot, then book the tokens of
-        the program dispatched a tick earlier (_decode_tick) — after a
-        hand-driven step() a decode result is as a rule still in flight;
+        next program for every active slot — with the chunk aboard where the
+        model takes both kinds of row through one weight pass (llm_mixed),
+        else behind the chunk's own program — then book the tokens of the
+        program dispatched a tick earlier (_decode_tick); a first token is
+        read at the end of the tick that dispatched llm_prefill_chunk, or
+        with the booking of the llm_mixed that carried the final chunk, a
+        tick after its dispatch (_first_token), and its slot joins the next
+        program dispatched.  After a hand-driven step() a decode result is
+        as a rule still in flight;
         run_until_complete() and drain() leave none.  Serialized by the
         engine lock: the
         background pump and caller-thread pumping (run_until_complete) must
@@ -3494,7 +3625,13 @@ class LLMEngine:
         _M_QUEUE_DEPTH.set(self._pending.qsize())
         emitted = self._decode_tick()
         if self._chunk_out is not None:
-            self._first_token()
+            out, self._chunk_out = self._chunk_out, None
+            self._first_token(*out)
+            # only now drop the in-flight marker: drain()'s lock-free
+            # _drained() must never observe _prefilling cleared while the
+            # slot is not yet active, or it declares the engine empty with
+            # this request still about to decode
+            self._prefilling = None
         return emitted
 
     def _count_drain(self, why):
@@ -3514,7 +3651,13 @@ class LLMEngine:
         flight: it dispatches this tick's program BEFORE it reads the
         previous one's result, whose tokens reach this program on the device
         (llm_decode's ``feed``), and books that result — emit, stamp,
-        finish, publish the counts — while the device runs this one.  The
+        finish, publish the counts — while the device runs this one.  A
+        chunk _prefill_tick left staged rides the SAME program (llm_mixed in
+        llm_decode's place: one pass over the weights for the chunk's rows
+        and the decode rows; with no row decoding, the chunk alone); a final
+        chunk hands its slot over here, with its first token in flight like
+        any row's next one, selected where the program is booked (_book):
+        the slot joins the program dispatched after that.  The
         host does not need the tokens to dispatch: positions advance by one
         a token, pages grow by count, a row that reaches max_new_tokens or
         the end of its cache with the token in flight is left out (and
@@ -3531,6 +3674,7 @@ class LLMEngine:
         pc = self._phases
         reqs = self.slot_req
         emitted = 0
+        chunk, self._staged = self._staged, None
         # a constrained row's automaton state advances per TOKEN, and the
         # uploaded mask is constant across a chunk — so ticks with any
         # constrained row decode one token at a time, each read at once
@@ -3543,14 +3687,14 @@ class LLMEngine:
         # rows the program carries: not those that end, by count, with the
         # token in flight — they compute nothing more
         rows = [i for i in range(self.n_slots) if self._runs_on(i)]
-        if not rows:
+        if not rows and chunk is None:
             if self._inflight is not None:
                 emitted += self._read_decode("idle")
             return emitted
         # effective chunk: stay inside the cache (slots AT capacity are
         # left out above, so headroom >= 1)
         headroom = self.L - 1 - int(
-            (self.slot_pos[rows] + self._ahead[rows]).max())
+            (self.slot_pos[rows] + self._ahead[rows]).max(initial=0))
         if self.spec_k and headroom >= self.spec_k:
             # speculative tick: verify writes rows pos .. pos+K, so it
             # needs K rows of headroom; the last strides before capacity
@@ -3568,10 +3712,9 @@ class LLMEngine:
                 [i for i in rows if self._runs_on(i)], eff)
         rows = grown
         self._update_page_gauges()
-        if not rows:
+        if not rows and chunk is None:
             pc.switch("bookkeep")
             return emitted
-        jit = self._get_decode(eff)
         # host arrays straight into the compiled call, and (key, offset)
         # for the keys it derives itself: no eager device call here (see
         # _sampling_knobs).  Copies: bookkeeping writes these in place.
@@ -3581,7 +3724,8 @@ class LLMEngine:
         from_host = self._ahead == 0
         pos = self.slot_pos + self._ahead
         do_s, temp, topk, topp = self._sampling_knobs(rows)
-        self._count_sampler_tick(do_s, topk, topp)
+        if rows:  # a chunk alone selects no row's token
+            self._count_sampler_tick(do_s, topk, topp)
         # read each tick, not held: paddle.seed() on a live engine governs
         rng = _fr.default_generator().fork()
         if constrained:
@@ -3594,9 +3738,7 @@ class LLMEngine:
         else:
             token_mask = self._mask_all_true
         args = (*self._cache_args(rows), tokens, self._feed, from_host, pos,
-                do_s, temp, topk, topp, token_mask, *rng, *self._lora_args(
-                    [r.adapter_page if r is not None else 0 for r in reqs]),
-                *self._accs())
+                do_s, temp, topk, topp, token_mask, *rng)
         moe = None
         if self._moe_acc is not None:
             # what this program's counts will cover: every dispatch since
@@ -3604,11 +3746,36 @@ class LLMEngine:
             self._moe_dispatched("decode", len(rows), eff)
             moe, self._moe_pending = self._moe_pending, np.zeros_like(
                 self._moe_pending)
-        pc.switch("decode_dispatch")
-        out = self._took_decode(jit(*args))
+        first = None
+        if chunk is None:
+            pc.switch("decode_dispatch")
+            out = self._took_decode(self._get_decode(eff)(
+                *args, *self._lora_args(
+                    [r.adapter_page if r is not None else 0 for r in reqs]),
+                *self._accs()))
+        else:
+            both = self._dispatch_chunk(chunk, args + chunk.args)
+            if both is None:
+                return emitted  # its request failed: the rows run next tick
+            out, logits = both
+            if rows:
+                self._pipeline["mixed"] += 1
+                _PIPELINE_SERIES["mixed"].inc()
+            if chunk.final:
+                first = (chunk.req, chunk.slot, logits)
         prev, self._inflight = self._inflight, _InFlight(
-            out, eff, [(i, reqs[i]) for i in rows], moe)
+            out, eff, [(i, reqs[i]) for i in rows], moe, first)
         self._ahead[rows] += eff
+        if first is not None:
+            # the slot is the request's from here on, though no program
+            # carries it until its first token is booked; only now does the
+            # prefill marker go (drain()'s lock-free _drained() must find
+            # the request somewhere at every instant)
+            req, slot, _ = first
+            req.slot = slot
+            reqs[slot] = req
+            self.slot_pos[slot] = req.prompt.size
+            self._prefilling = None
         if prev is not None:
             # the device is busy with the program just dispatched
             self._pipeline["overlapped"] += 1
@@ -3620,18 +3787,28 @@ class LLMEngine:
             pc.switch("bookkeep")
         return emitted
 
+    def _awaits_first(self, slot):
+        """Whether the slot's request still waits for its first token: the
+        program in flight carried its final chunk (llm_mixed), and no
+        program carries its row until that one is booked."""
+        fl = self._inflight
+        return fl is not None and fl.first is not None and fl.first[1] == slot
+
     def _runs_on(self, slot):
         """Whether the next decode program carries the slot: it holds a
-        request that does not end, by count, with the tokens it has in
-        flight (at max_new_tokens, or at the end of its cache)."""
+        request that has its first token and does not end, by count, with
+        the tokens it has in flight (at max_new_tokens, or at the end of
+        its cache)."""
         req, ahead = self.slot_req[slot], int(self._ahead[slot])
-        return (req is not None
+        return (req is not None and not self._awaits_first(slot)
                 and len(req.tokens) + ahead < req.max_new_tokens
                 and int(self.slot_pos[slot]) + ahead < self.L - 1)
 
     def _book(self, fl):
         """Wait for a dispatched decode program and book its tokens: emit,
-        stamp, advance, finish, publish the layers' counts.  A row whose
+        stamp, advance, finish, publish the layers' counts; then the first
+        token of the request whose final chunk it carried (_first_token:
+        that slot joins the next program dispatched).  A row whose
         slot no longer holds the request it was dispatched for — it met its
         EOS in the program before, or expired — has its tokens dropped and
         counted as surplus."""
@@ -3678,6 +3855,11 @@ class LLMEngine:
             else:
                 if len(req.token_ts) - req.dec_i0 >= _DECODE_SPAN_TICKS:
                     self._flush_decode_span(req)  # bound spans per episode
+        if fl.first is not None and self.slot_req[fl.first[1]] is fl.first[0]:
+            # the final chunk this program carried: the logits came with the
+            # tokens.  (Its request gone from the slot — expired, stopped —
+            # they are dropped like a row's token.)
+            self._first_token(*fl.first)
         return emitted
 
     def _spec_tick(self, active):

@@ -287,27 +287,28 @@ def _token_pages_rows(pos, page_tbl, S, page_size, max_pages):
     return page, tpos % page_size
 
 
+def _scatter_rows(pool, vals, page, row):
+    """Write vals [B, S, H, ...] into pool [P, H, page_size, ...] at
+    (page, row) [B, S]: one token's heads go to one row of one page."""
+    hi = jnp.arange(pool.shape[1])[None, None, :]
+    return pool.at[page[..., None], hi, row[..., None]].set(vals)
+
+
 def _paged_scatter(pool, hm, pos, page_tbl):
     """Write head-major new kv [B, H, S, D] into the page pool
     [P, H, page_size, D] at absolute positions pos..pos+S-1 of each slot,
     routed through that slot's page-table row."""
-    H, ps = pool.shape[1], pool.shape[2]
-    S = hm.shape[2]
-    page, row = _token_pages_rows(pos, page_tbl, S, ps, page_tbl.shape[1])
-    hi = jnp.arange(H)[None, None, :]
-    vals = jnp.transpose(hm, (0, 2, 1, 3))  # [B, S, H, D]
-    return pool.at[page[..., None], hi, row[..., None]].set(vals)
+    page, row = _token_pages_rows(pos, page_tbl, hm.shape[2], pool.shape[2],
+                                  page_tbl.shape[1])
+    return _scatter_rows(pool, jnp.transpose(hm, (0, 2, 1, 3)), page, row)
 
 
 def _paged_scatter_scale(spool, scale, pos, page_tbl):
     """Same routing for the f32 scale pool [P, H, page_size]; scale arrives
     head-major [B, H, S]."""
-    H, ps = spool.shape[1], spool.shape[2]
-    S = scale.shape[2]
-    page, row = _token_pages_rows(pos, page_tbl, S, ps, page_tbl.shape[1])
-    hi = jnp.arange(H)[None, None, :]
-    vals = jnp.transpose(scale, (0, 2, 1))  # [B, S, H]
-    return spool.at[page[..., None], hi, row[..., None]].set(vals)
+    page, row = _token_pages_rows(pos, page_tbl, scale.shape[2],
+                                  spool.shape[2], page_tbl.shape[1])
+    return _scatter_rows(spool, jnp.transpose(scale, (0, 2, 1)), page, row)
 
 
 def update_paged_cache(cache, k, v, offset):
@@ -367,3 +368,60 @@ def paged_attention_update(cache, q, k, v, offset):
                 qq, kk, vv, offset, pt),
             (q, k_p, v_p, cache[3]), name="paged_decode_attention")
     return new_cache, out
+
+
+def paged_mixed_update(cache, chunk, q, k, v):
+    """A tick's two kinds of row through ONE layer's pools: q, k, v
+    [1, C + B, H, D] hold a prefill chunk's C rows — one slot's, at
+    ``chunk`` = (off [1], page_row [1, M]) — and behind them B decode rows,
+    one a slot, at ``cache``'s own pos [B] and page table [B, M].  ONE
+    scatter a pool writes every row's k/v through its own table; each kind
+    then attends through its own table, the same kernel called twice.  The
+    chunk's slot decodes nothing in the same program (the engine masks its
+    table row to the trash page), no page that others read is ever written,
+    so the two kinds' writes never meet and neither read sees the other's.
+    Returns (new_cache in ``cache``'s form, out [1, C + B, Hq, D])."""
+    from ..ops.decode_attention import paged_decode_attention
+
+    off, page_row = chunk
+    pos = cache[2]
+    C = q.shape[1] - pos.shape[0]
+
+    def at(pool, tbl):
+        # (page, row) of every new token, the chunk's first
+        M = tbl.shape[1]
+        pc, rc = _token_pages_rows(off, page_row, C, pool.shape[2], M)
+        pd, rd = _token_pages_rows(pos, tbl, 1, pool.shape[2], M)
+        return (jnp.concatenate([pc, pd.T], axis=1),
+                jnp.concatenate([rc, rd.T], axis=1))
+
+    def attend(qq, kk, vv, tbl, *scales):
+        oc = paged_decode_attention(qq[:, :C], kk, vv, off, page_row, *scales)
+        od = paged_decode_attention(qq[0, C:, None], kk, vv, pos, tbl, *scales)
+        return jnp.concatenate([oc, od[None, :, 0]], axis=1)
+
+    if len(cache) == 6:
+        def upd_q(pool, spool, kv, tbl):
+            kv_q, scale = _quantize_kv(_to_head_major(kv))
+            page, row = at(pool, tbl)
+            return (_scatter_rows(pool, jnp.transpose(kv_q, (0, 2, 1, 3)),
+                                  page, row),
+                    _scatter_rows(spool, jnp.transpose(scale, (0, 2, 1)),
+                                  page, row))
+
+        with jax.named_scope("kv_write"):
+            k_pool, k_sc = apply_op(upd_q, (cache[0], cache[4], k, cache[3]),
+                                    name="kv_paged_scatter_q")
+            v_pool, v_sc = apply_op(upd_q, (cache[1], cache[5], v, cache[3]),
+                                    name="kv_paged_scatter_q")
+        out = apply_op(attend, (q, k_pool, v_pool, cache[3], k_sc, v_sc),
+                       name="paged_decode_attention")
+        return (k_pool, v_pool, pos + 1, cache[3], k_sc, v_sc), out
+    upd = lambda pool, kv, tbl: _scatter_rows(  # noqa: E731
+        pool, kv.astype(pool.dtype), *at(pool, tbl))
+    with jax.named_scope("kv_write"):
+        k_pool = apply_op(upd, (cache[0], k, cache[3]), name="kv_paged_scatter")
+        v_pool = apply_op(upd, (cache[1], v, cache[3]), name="kv_paged_scatter")
+    out = apply_op(attend, (q, k_pool, v_pool, cache[3]),
+                   name="paged_decode_attention")
+    return (k_pool, v_pool, pos + 1, cache[3]), out

@@ -68,6 +68,7 @@ def _rope_cache(head_dim, max_pos, theta):
 from .kv_cache import (  # noqa: E402  (shared cache layouts; re-exported
     _quantize_kv,         # for backward compat — tests import from here)
     paged_attention_update,
+    paged_mixed_update,
     update_plain_cache,
     update_quant_cache,
 )
@@ -88,19 +89,24 @@ def apply_rope(x, cos, sin, position_offset=0):
     decode scan body (each one a serial kernel dispatch); the half-split
     form fuses clean.  Attention scores are identical under either pairing
     since q and k share the permutation.
-    position_offset may be a traced scalar (static-cache decode) or a
-    PER-BATCH [B] vector (continuous-batching slots at different depths)."""
+    position_offset may be a traced scalar (static-cache decode), a
+    PER-BATCH [B] vector (continuous-batching slots at different depths) or
+    every token's own position [B, S]."""
     S, D = x.shape[1], x.shape[-1]
     if isinstance(position_offset, (int, np.integer)):
         c = cos[position_offset:position_offset + S]
         s = sin[position_offset:position_offset + S]
-    elif getattr(position_offset, "ndim", 0) >= 1:
-        # per-slot offsets: gather [B, S, D/2] position rows
-        pos = position_offset[:, None] + jnp.arange(S)[None, :]
-        c = cos[pos][:, :, None, :]  # [B,S,1,D/2]
-        s = sin[pos][:, :, None, :]
+    elif getattr(position_offset, "ndim", 0) == 2:
+        # a position a token [B, S] (a tick's chunk rows and decode rows
+        # side by side): gather [B, S, D/2] position rows
+        c = cos[position_offset][:, :, None, :]  # [B,S,1,D/2]
+        s = sin[position_offset][:, :, None, :]
         x1, x2 = x[..., :D // 2], x[..., D // 2:]
         return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
+    elif getattr(position_offset, "ndim", 0) == 1:
+        # per-slot offsets
+        return apply_rope(x, cos, sin,
+                          position_offset[:, None] + jnp.arange(S)[None, :])
     else:
         c = jax.lax.dynamic_slice_in_dim(cos, position_offset, S, 0)
         s = jax.lax.dynamic_slice_in_dim(sin, position_offset, S, 0)
@@ -173,10 +179,14 @@ class LlamaAttention(nn.Layer):
                 v = v + _lora.apply_site("v", hidden_states)
         return q, k, v
 
-    def forward(self, hidden_states, rope, attn_mask=None, cache=None, use_cache=False):
+    def forward(self, hidden_states, rope, attn_mask=None, cache=None,
+                use_cache=False, chunk=None):
         """rope: (cos, sin) Tensors shared at LlamaModel level (one copy, not 32).
         cache=None with use_cache=True is the prefill step: the returned cache is
-        this call's own k/v."""
+        this call's own k/v.  ``chunk`` = (off, page_row): hidden_states
+        [1, C + B] lays a prefill chunk's rows and a paged cache's B decode
+        rows side by side (mixed_step) — one pass through the projections,
+        each kind's own positions, pages and attention between them."""
         rope_cos, rope_sin = rope
         B, S = hidden_states.shape[0], hidden_states.shape[1]
         fusable = (type(self.q_proj) is nn.Linear and type(self.k_proj) is nn.Linear
@@ -201,7 +211,13 @@ class LlamaAttention(nn.Layer):
         static_cache = cache is not None and len(cache) in (3, 5)
         quant_cache = cache is not None and len(cache) == 5
         paged_cache = cache is not None and len(cache) in (4, 6)
-        if static_cache or paged_cache:
+        if chunk is not None:
+            # every token's own position: the chunk's run from its offset,
+            # each decode row's from its slot's
+            offset = jnp.concatenate([
+                chunk[0] + jnp.arange(S - cache[2].shape[0], dtype=jnp.int32),
+                cache[2]])[None, :]
+        elif static_cache or paged_cache:
             offset = cache[2]
         else:
             offset = cache[0].shape[1] if cache is not None else 0
@@ -215,7 +231,10 @@ class LlamaAttention(nn.Layer):
             # (S=1 decode, prefill chunks, the K+1 verify ladder); gathered
             # dense math only for CPU-odd shapes
             # (llm_attn_kernel_total{path,reason} counts the dispatch)
-            new_cache, out = paged_attention_update(cache, q, k, v, offset)
+            if chunk is not None:
+                new_cache, out = paged_mixed_update(cache, chunk, q, k, v)
+            else:
+                new_cache, out = paged_attention_update(cache, q, k, v, offset)
             out = out.reshape([B, S, self.num_heads * self.head_dim])
             out = self._o(out)
             if use_cache:
@@ -342,13 +361,15 @@ class LlamaDecoderLayer(nn.Layer):
         self.input_layernorm = nn.RMSNorm(config.hidden_size, config.rms_norm_eps)
         self.post_attention_layernorm = nn.RMSNorm(config.hidden_size, config.rms_norm_eps)
 
-    def forward(self, x, rope, attn_mask=None, cache=None, use_cache=False):
+    def forward(self, x, rope, attn_mask=None, cache=None, use_cache=False,
+                chunk=None):
         # named scopes label the ops for the profiler (op_name
         # ".../attention/...", ".../mlp/..."); they change no computation
         with jax.named_scope("attention"):
             h = self.input_layernorm(x)
             if use_cache:
-                attn_out, new_cache = self.self_attn(h, rope, attn_mask, cache, use_cache=True)
+                attn_out, new_cache = self.self_attn(
+                    h, rope, attn_mask, cache, use_cache=True, chunk=chunk)
             else:
                 attn_out = self.self_attn(h, rope, attn_mask)
             x = x + attn_out
@@ -374,9 +395,11 @@ class LlamaModel(nn.Layer):
         self.register_buffer("rope_cos", Tensor(cos), persistable=False)
         self.register_buffer("rope_sin", Tensor(sin), persistable=False)
 
-    def forward(self, input_ids, attn_mask=None, caches=None, use_cache=False):
+    def forward(self, input_ids, attn_mask=None, caches=None, use_cache=False,
+                chunk=None):
         """caches=[None]*num_layers (or caches=None with use_cache=True) is the
-        prefill bootstrap; each entry is then a (k, v) pair for the decode steps."""
+        prefill bootstrap; each entry is then a (k, v) pair for the decode steps.
+        ``chunk``: see LlamaAttention.forward (paged caches only)."""
         use_cache = use_cache or caches is not None
         if use_cache and caches is None:
             caches = [None] * len(self.layers)
@@ -388,7 +411,8 @@ class LlamaModel(nn.Layer):
         new_caches = [] if use_cache else None
         for i, layer in enumerate(self.layers):
             if use_cache:
-                x, c = layer(x, rope, attn_mask, caches[i], use_cache=True)
+                x, c = layer(x, rope, attn_mask, caches[i], use_cache=True,
+                             chunk=chunk)
                 new_caches.append(c)
             else:
                 x = layer(x, rope, attn_mask)
@@ -466,6 +490,31 @@ class LlamaForCausalLM(nn.Layer):
             lambda h: jax.lax.dynamic_slice_in_dim(h, last_index, 1, 1),
             (hidden,), name="prefill_chunk_last")
         return self._head(last), caches
+
+    def mixed_step(self, chunk_ids, input_ids, caches, chunk, last_index):
+        """A serving tick's two kinds of row through ONE weight pass:
+        ``chunk_ids`` [1, C], the next chunk of one slot's prompt (as
+        prefill_chunk_step takes it: ``chunk`` = (off [1], page_row [1, M]),
+        the tokens already prefilled and the slot's page-table row), and
+        ``input_ids`` [B, 1], every slot's decode token (as generate_step
+        takes it: ``caches`` carry pos [B] and the page table [B, M]).  The
+        C + B rows are laid side by side through every norm, projection and
+        MLP; only rope, the page writes and the attention tell them apart.
+        The chunk's slot must not decode in the same call (its row of the
+        table masked to the trash page, as for any slot between chunks).
+        Returns (decode logits [B, 1, V], the chunk's logits [1, 1, V] at
+        ``last_index``, caches)."""
+        C = chunk_ids.shape[1]
+        ids = M.concat([chunk_ids, input_ids.reshape([1, -1])], axis=1)
+        hidden, caches = self.llama(ids, caches=caches, use_cache=True,
+                                    chunk=chunk)
+        rows = apply_op(
+            lambda h: jnp.concatenate([
+                jax.lax.dynamic_slice_in_dim(h[0], last_index, 1, 0),
+                h[0, C:]])[:, None],
+            (hidden,), name="mixed_head_rows")
+        logits = self._head(rows)  # [1 + B, 1, V]: one pass over the head
+        return logits[1:], logits[:1], caches
 
     def generate(self, input_ids, max_new_tokens=32, do_sample=False,
                  temperature=1.0, top_k=0, top_p=1.0, eos_token_id=None,

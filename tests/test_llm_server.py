@@ -347,7 +347,8 @@ def test_paged_long_prompt_does_not_stall_decode(model):
                     kv_layout="paged", page_size=32, prefill_chunk=8)
     fa = eng.submit(rng.randint(0, 1024, 6).astype(np.int32),
                     max_new_tokens=40)
-    eng.step()  # admit A
+    eng.step()  # admit A: its chunk's program goes out
+    eng.step()  # ... and is read: A has its first token
     pb = rng.randint(0, 1024, 33).astype(np.int32)  # 5 chunks of 8
     fb = eng.submit(pb, max_new_tokens=4)
     n0 = _prefill_chunk_count()

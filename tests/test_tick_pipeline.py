@@ -222,10 +222,13 @@ def test_greedy_tokens_are_the_oracles_with_requests_joining_mid_flight(
 
 # -------------------------------------------------------- the order of a tick
 def test_a_tick_dispatches_before_it_reads(model):
-    """Steady state: decode_stage, decode_dispatch (tick k), then decode_sync
-    and bookkeep (tick k-1's result).  The first decode tick has nothing to
-    read, the last only reads; a final chunk's first_token_sync comes after
-    both.  Every program dispatched is read exactly once."""
+    """Steady state: decode_stage, the tick's ONE dispatch (tick k:
+    decode_dispatch, or prefill_dispatch where a chunk rides the program),
+    then decode_sync and bookkeep (tick k-1's result).  The first program
+    has nothing to read, the last tick only reads; a first token is read
+    with the program that carried its final chunk — a tick after that was
+    dispatched, behind this tick's dispatch and that program's own tokens.
+    Every program dispatched is read exactly once."""
     eng = _logged(_engine(model, slots=2))
     long_, short = _prompts(11, 9, 20)
     f1 = eng.submit(long_, max_new_tokens=7)
@@ -235,28 +238,36 @@ def test_a_tick_dispatches_before_it_reads(model):
     assert f1.result(timeout=1) == _oracle(model, long_, 7)
     assert f2.result(timeout=1) == _oracle(model, short, 3)
     ticks = _ticks(eng._phases.order)
-    dec = [[p for p in t if p.startswith("decode_")] for t in ticks]
+    dec = [[p for p in t if p.startswith("decode_") or p == "prefill_dispatch"]
+           for t in ticks]
     dec = [d for d in dec if d]
-    assert dec[0] == ["decode_stage", "decode_dispatch"]  # nothing in flight
+    assert dec[0] == ["decode_stage", "prefill_dispatch"]  # nothing in flight
     assert dec[-1] == ["decode_sync"]                     # nothing left to run
-    assert all(d == ["decode_stage", "decode_dispatch", "decode_sync"]
-               for d in dec[1:-1]) and len(dec) == 7
-    for t in ticks:
-        if "first_token_sync" in t:
-            # the chunk was dispatched before the decode program, its logits
-            # are read after the previous result is booked
-            i = t.index("first_token_sync")
-            assert "prefill_dispatch" in t[:i] and t[i:] == [
-                "first_token_sync", "bookkeep"]
-            assert not any(p.startswith("decode_") for p in t[i:])
+    assert all(d[0] == "decode_stage" and d[2:] == ["decode_sync"]
+               for d in dec[1:-1]) and len(dec) == 9
+    # three programs carried a chunk (the second prompt's first chunk rode
+    # alone: the first request had no token yet), five decoded only
+    assert [d[1] for d in dec[:-1]] == ["prefill_dispatch"] * 3 \
+        + ["decode_dispatch"] * 5
+    firsts = [t for t in ticks if "first_token_sync" in t]
+    assert len(firsts) == 2
+    for t in firsts:
+        # this tick's program is out, the program that carried the final
+        # chunk is read: its rows' tokens are booked, then its first token
+        i = t.index("first_token_sync")
+        assert t[i - 2:] == ["decode_sync", "bookkeep",
+                             "first_token_sync", "bookkeep"]
+        assert t.index("decode_stage") < i - 2 and t[i - 3] in (
+            "decode_dispatch", "bookkeep")  # bookkeep: closes prefill_dispatch
     cnt = eng.stats()["tick_phases"]["count"]
-    assert cnt["decode_stage"] == cnt["decode_dispatch"] \
-        == cnt["decode_sync"] == 6
+    assert cnt["decode_stage"] == cnt["decode_sync"] == 8 \
+        == cnt["decode_dispatch"] + cnt["prefill_dispatch"]
     pl = _pipeline(eng)
-    assert pl["overlapped"] == 5 and pl["drained"]["idle"] == 1
+    assert pl["overlapped"] == 7 and pl["drained"]["idle"] == 1
+    assert pl["mixed"] == 1  # the final chunk that met a decoding row
     fam = obs.REGISTRY.get("llm_tick_pipeline_ticks_total")
     assert {lv[0] for lv, _ in fam.series()} \
-        == {"overlapped", *llm_server._DRAIN_REASONS}
+        == {"overlapped", "mixed", *llm_server._DRAIN_REASONS}
 
 
 def test_only_a_drained_engine_is_idle(model):
@@ -265,8 +276,11 @@ def test_only_a_drained_engine_is_idle(model):
     eng = _engine(model, slots=1)
     (p,) = _prompts(12, 10)
     f = eng.submit(p, max_new_tokens=5)
-    eng.step()
-    eng.step()
+    eng.step()  # the chunk's program goes out
+    assert eng._inflight is not None and eng._awaits_first(0)
+    eng.step()  # ... is read: the first token
+    assert eng._inflight is None and eng._busy() and not eng._drained()
+    eng.step()  # the first decode program
     assert eng._inflight is not None and eng._busy() and not eng._drained()
     assert int(eng._ahead[0]) == 1 and len(eng.slot_req[0].tokens) == 1
     eng.run_until_complete()
@@ -464,16 +478,23 @@ def test_stop_drain_and_pump_death_leave_no_future_pending(model, how):
 
 # ------------------------------------------------------------- surplus tokens
 def _carried(eng):
-    """Wraps the engine's decode program: the real rows of each call (the
-    page-table rows it did not mask to the trash page)."""
+    """Wraps the engine's programs: the real rows of each call that decodes
+    (the page-table rows it did not mask to the trash page) — every
+    llm_decode, and an llm_mixed where rows rode beside the chunk."""
     eff = max(1, eng.decode_chunk)
-    real, rows = eng._get_decode(eff), []
+    rows = []
 
-    def counting(*a, **k):
-        rows.append(int((np.asarray(a[3])[:, 0] != 0).sum()))
-        return real(*a, **k)
+    def counting(real, always):
+        def call(*a, **k):
+            n = int((np.asarray(a[3])[:, 0] != 0).sum())
+            if n or always:
+                rows.append(n)
+            return real(*a, **k)
+        return call
 
-    eng._decode_jit[eff] = counting
+    eng._decode_jit[eff] = counting(eng._get_decode(eff), True)
+    if eng._mixed:
+        eng._chunk_jit = counting(eng._get_chunk_prefill(), False)
     return rows
 
 
@@ -488,7 +509,8 @@ def test_an_eos_one_program_late_drops_exactly_one_surplus_token(model):
     assert rows == [1, 1, 1, 1]
     pl = _pipeline(eng)
     assert pl["surplus_tokens"] == 1 and pl["overlapped"] == 3
-    assert pl["drained"]["idle"] == 1
+    # the chunk's program, read with nothing else to run, and the last one
+    assert pl["drained"]["idle"] == 2
     _pool_balanced(eng)
 
 

@@ -745,10 +745,13 @@ def test_tick_phases_exhaust_the_tick_and_feed_the_goodput_carves(model):
     assert cnt["first_token_sync"] == len(futs)  # one an admission
     n_chunks = obs.REGISTRY.get("llm_prefill_chunks_total").value - chunks0
     assert cnt["prefill_dispatch"] == cnt["prefill_stage"] == n_chunks == 10
-    # one token a decode tick and a slot: the decode ticks are the ticks
-    # in which some request was past its first token
-    assert cnt["decode_stage"] == cnt["decode_dispatch"] == cnt["decode_sync"]
-    assert 0 < cnt["decode_sync"] <= ticks
+    # one token a decode tick and a slot.  A tick stages one program — the
+    # chunk rides it (llm_mixed, under prefill_dispatch) beside whatever
+    # rows decode — and every program dispatched is read once
+    assert cnt["decode_stage"] == cnt["decode_sync"] \
+        == cnt["decode_dispatch"] + cnt["prefill_dispatch"]
+    assert 0 < cnt["decode_dispatch"] and cnt["decode_sync"] <= ticks
+    assert eng.stats()["tick_pipeline"]["mixed"] > 0
     assert all(cnt[p] == 0 for p in cnt if p.startswith("spec_"))
     assert ph["host_s"] == pytest.approx(
         total - ph["seconds"]["decode_sync"]
@@ -811,7 +814,10 @@ def test_tick_phases_of_a_speculative_and_of_a_default_engine(model):
     assert all(len(f.result(timeout=1)) == 4 for f in futs)
     cnt = bare.stats()["tick_phases"]["count"]
     assert cnt["first_token_sync"] == cnt["prefill_dispatch"] == 2
-    assert cnt["decode_sync"] == 4
+    # the two programs that carried a chunk, and four that decoded: a first
+    # token is read with the program that carried its chunk, and the second
+    # request's chunk went out before the first's token was read
+    assert cnt["decode_sync"] == 6 and cnt["decode_dispatch"] == 4
     assert not any(cnt[p] for p in cnt if p.startswith("spec_"))
     bare._goodput.check()
 
@@ -846,12 +852,14 @@ def test_admission_blocked_names_what_held_the_queue_head(model):
     eng.step()  # tick 3: the only slot is decoding
     assert blocked() == {"prefill_busy": 1, "no_slot": 1, "no_pages": 0,
                          "no_adapter_page": 0}
-    # the slot joined the decode a tick after its first token and is freed
-    # where its last token is READ, a tick after the program that computed
-    # it was dispatched (one decode program stays in flight): ticks 4 and 5
-    # still find it taken, tick 6 admits the short one, then no more waits
+    # its first token is read a tick after the program that carried its
+    # final chunk was dispatched, the slot joined the decode a tick after
+    # that and is freed where its last token is READ, a tick after the
+    # program that computed it was dispatched (one program stays in
+    # flight): ticks 4, 5 and 6 still find it taken, tick 7 admits the
+    # short one, then no more waits
     eng.run_until_complete()
-    assert blocked() == {"prefill_busy": 1, "no_slot": 3, "no_pages": 0,
+    assert blocked() == {"prefill_busy": 1, "no_slot": 4, "no_pages": 0,
                          "no_adapter_page": 0}
     fam = obs.REGISTRY.get("llm_admission_blocked_ticks_total")
     assert {lv[0] for lv, _ in fam.series()} == set(blocked())
@@ -1022,4 +1030,6 @@ def test_profiler_trace_holds_phases_nested_in_the_tick_on_one_line(
                     "llm_tick.first_token_sync", "llm_tick.decode_stage",
                     "llm_tick.bookkeep"} <= names
             found += len(syncs)
-    assert found == 5  # 6 tokens: one from the prefill, five decode ticks
+    # 6 tokens: the two programs that carried the prompt's chunks (the
+    # second brings the first token), then five decode programs
+    assert found == 7
