@@ -13,6 +13,9 @@ cut to N_LAYERS and seeded random weights:
          64 a token, 8 gated experts of whole-lane width): a document, then
          two questions of it that map its latent pages (one forks a partial
          page), decode through the latent kernel
+  granite LLMEngine with Granite-4.0-H at its published widths, one period
+         deep (9 Mamba-2 layers, 1 attention layer of 64-wide heads), seeded:
+         the paged kernel serves the 64-wide heads, the head is the embedding
   profile a 2-layer engine under load, profiled for 1 s by itself
          (LLMEngine.profile_device) and reduced by its own census: every
          device second lands under a program the engine knows
@@ -203,7 +206,7 @@ def _ran_ahead(eng):
     return pl
 
 
-def _one_pass_gap(model, asks, outs):
+def _one_pass_gap(model, asks, outs, tol=0.05):
     """How far the served tokens lie below the best of the model's own
     one-pass logits (the chunk path: no decode kernel), at worst."""
     import jax.numpy as jnp
@@ -216,7 +219,7 @@ def _one_pass_gap(model, asks, outs):
         worst = max(worst, float(np.max(lg.max(-1) - lg[np.arange(len(o)), o])))
     # bfloat16 logits of size ~0.5: a served token may lose to a neighbour
     # by a rounding step or two, not by more
-    check(worst < 0.05, f"served tokens lie within {worst:.4f} of the "
+    check(worst < tol, f"served tokens lie within {worst:.4f} (< {tol}) of the "
           "one-pass logits' best")
     return worst
 
@@ -330,6 +333,58 @@ def latent_phase(doc_tokens=300, new_tokens=8, timeout=600.0):
           and moe["pairs_held"] == lat["layer_calls"] // 2 * 2 and moe["pairs_absent"] == 0,
           f"decode rows were counted by both kinds of layer: {lat} {moe}")
     worst = _one_pass_gap(model, asks, outs)
+    _ran_ahead(eng)
+    return worst
+
+
+# ----------------------------------------------------------------- granite
+def granite_phase(n_layers=10, new_tokens=8, timeout=600.0, **widths):
+    """Granite-4.0-H at its published widths, one period deep (9 Mamba-2
+    layers and one attention layer), with the benchmark's seeded weights:
+    64-wide heads ride the paged KERNEL two a 128-lane row (not the dense
+    pass), the head is the embedding, and the served tokens (decode kernels,
+    a prompt of two chunks carrying its state) lie at the top of the model's
+    own one-pass logits.  The logits' spread is ~0.013 (the tied head reads
+    an embedding drawn at std 0.00225, over logits_scaling 8): a wrong
+    kernel serves a token ~0.05 below the best, rounding ~0.001."""
+    import json
+    import os
+
+    from benchmark import serve_granite
+    from paddle_tpu.inference import LLMEngine
+    from paddle_tpu.inference.llm_server import _attn_dispatch_series
+
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)), "benchmark",
+                           "configs", "granite-4.0-h-micro.json")) as f:
+        cfg = json.load(f)
+    cfg.update(num_hidden_layers=n_layers,
+               layer_types=cfg["layer_types"][:n_layers], **widths)
+    model = serve_granite.build_model(cfg, seed=1)
+    names = [n for n, _ in model.named_parameters()]
+    check("lm_head" not in names and names.count("embed_tokens") == 1,
+          "the head is the embedding: one [vocab, hidden] leaf")
+    taken = lambda: {labels[0]: n for labels, n in _attn_dispatch_series()  # noqa: E731
+                     if labels[0].startswith("paged_")}
+    before = taken()
+    eng = LLMEngine(model, page_size=128, prefill_chunk=128, max_batch_slots=4,
+                    max_seq_len=256)
+    log(f"granite: warmup took {eng.warmup():.1f}s")
+    rng = np.random.default_rng(2)
+    asks = [rng.integers(0, cfg["vocab_size"], n, dtype=np.int32) for n in (100, 200)]
+    eng.start()
+    try:
+        outs = [f.result(timeout=timeout) for f in
+                [eng.submit(p, max_new_tokens=new_tokens) for p in asks]]
+    finally:
+        eng.stop()
+    after = taken()
+    check(after.get("paged_kernel", 0) > before.get("paged_kernel", 0)
+          and after.get("paged_dense", 0) == before.get("paged_dense", 0),
+          f"64-wide heads took the paged kernel, not the dense pass: {after}")
+    rs = eng.stats()["recurrent_state"]
+    check(rs["carried_chunks"] == 1, f"the 200-token prompt carried its state "
+          f"into its second chunk: {rs['carried_chunks']}")
+    worst = _one_pass_gap(model, asks, outs, tol=0.01)
     _ran_ahead(eng)
     return worst
 
@@ -598,6 +653,8 @@ def main():
     _free()
     latent_gap = latent_phase()
     _free()
+    granite_gap = granite_phase()
+    _free()
     profile_phase()
     _free()
     losses = train_phase()
@@ -608,7 +665,7 @@ def main():
     log(f"native: AVAILABLE={native.AVAILABLE} "
         f"built_this_run={native.BUILT_THIS_RUN}")
     log(f"summary: layers={N_LAYERS} kernel_err={errs} state_gap={state_gap:.4f} "
-        f"latent_gap={latent_gap:.4f} train={losses} "
+        f"latent_gap={latent_gap:.4f} granite_gap={granite_gap:.4f} train={losses} "
         f"mesh={mesh_losses} paddle_tpu={paddle_tpu.__version__} "
         f"wall={time.perf_counter() - T0:.0f}s")
     print(json.dumps({"ok": True, "device": dev}))

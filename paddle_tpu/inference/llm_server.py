@@ -266,6 +266,11 @@ _M_STATE_CKPT = _obs.counter(
     "prefill, restored into a slot by a prefix hit, evicted with their node "
     "or for a newer prefix", labelnames=("event",))
 _CKPT_EVENTS = ("stored", "restored", "evicted")
+_M_STATE_CARRIED = _obs.counter(
+    "llm_state_carried_chunks_total",
+    "Prefill chunks of a model with recurrent state that start from the "
+    "state the same prompt's previous chunk left, not from zeros or a "
+    "checkpoint (counted where the chunk is staged)")
 
 #: The pump's tick, cut into mutually exclusive phases in tick order (a
 #: speculative tick runs the spec_* phases in place of decode_*).  Every
@@ -415,6 +420,8 @@ class _Request:
                               # the prefill those tokens were skipping
     tier_hit_tokens: int = 0  # of those, tokens PROMOTED from the host or
                               # disk tier (hbm attribution = hit - these)
+    prefill_from: int = 0     # the prompt token this admission's prefill
+                              # starts at (a prefix hit's end, else 0)
     tokens: list = field(default_factory=list)
     submit_ts: float | None = None  # engine-clock stamps for the latency
     admit_ts: float | None = None   # histograms (queue wait / TTFT / e2e)
@@ -977,6 +984,7 @@ class LLMEngine:
             self._ckpt_store_jit = self._ckpt_load_jit = None
         self._prefix_hit_tokens = 0
         self._prefix_prompt_tokens = 0
+        self._carried_chunks = 0  # prefill chunks that resumed their state
         # engine-local mirrors of the process-global counters, so
         # stats() stays per-engine (two engines in one process must not
         # read each other's forks/evictions)
@@ -1557,7 +1565,8 @@ class LLMEngine:
             # (+ the pool of state checkpoints under the prefix cache)
             "recurrent_state": None if rec is None else {
                 "bytes": rec["bytes"], "slots": self.n_slots,
-                "layers": rec["layers"], "checkpoints": ckpt},
+                "layers": rec["layers"], "checkpoints": ckpt,
+                "carried_chunks": self._carried_chunks},
             # block-sparse attention layers: query calls (a real row a
             # layer), their contexts' blocks and the blocks they selected,
             # by program; None without any
@@ -2956,6 +2965,7 @@ class LLMEngine:
                     self._ckpt_event("restored")
                 # chunked prefill starts at the first UNCACHED token — a
                 # hit skips every chunk the cache already covers
+                req.prefill_from = int(matched)
                 self._prefilling = (req, slot, matched)
                 return
             finally:
@@ -3050,6 +3060,10 @@ class LLMEngine:
         casting on the device here would be eager ops that compile after
         warmup()."""
         req, slot, done = self._prefilling
+        if self._recurrent and done > req.prefill_from:
+            # the chunk resumes the state this prompt's last chunk left
+            self._carried_chunks += 1
+            _M_STATE_CARRIED.inc()
         m = min(self.prefill_chunk, req.prompt.size - done)
         ids = np.full((1, self.prefill_chunk), self.pad, np.int32)
         ids[0, :m] = req.prompt[done:done + m]

@@ -311,13 +311,24 @@ def _paged_scatter_scale(spool, scale, pos, page_tbl):
     return _scatter_rows(spool, jnp.transpose(scale, (0, 2, 1)), page, row)
 
 
+def _pool_rows(kv, pool):
+    """New kv [..., H, D] as the pool's rows: a pool whose rows are wider
+    than a head holds W / D heads side by side on each
+    (ops/decode_attention.py: ``pool_layout``); otherwise kv as it is."""
+    W = pool.shape[-1]
+    if kv.shape[-1] == W:
+        return kv
+    return kv.reshape(kv.shape[:-2] + (kv.shape[-2] * kv.shape[-1] // W, W))
+
+
 def update_paged_cache(cache, k, v, offset):
     """Scatter new k/v [B, S, H, D] into the paged 4-tuple layout.  Returns
     (new_cache, k_pages, v_pages) — the pools plus the (unchanged) page
     table go straight to paged_decode_attention."""
     S = k.shape[1]
     upd = lambda pool, kv, tbl: _paged_scatter(  # noqa: E731
-        pool, _to_head_major(kv.astype(pool.dtype)), offset, tbl)
+        pool, _to_head_major(_pool_rows(kv, pool).astype(pool.dtype)),
+        offset, tbl)
     with jax.named_scope("kv_write"):
         k_pool = apply_op(upd, (cache[0], k, cache[3]), name="kv_paged_scatter")
         v_pool = apply_op(upd, (cache[1], v, cache[3]), name="kv_paged_scatter")
@@ -343,34 +354,39 @@ def update_paged_quant_cache(cache, k, v, offset):
             k_pool, v_pool, k_sc, v_sc)
 
 
-def paged_attention_update(cache, q, k, v, offset):
+def paged_attention_update(cache, q, k, v, offset, scale=None):
     """Scatter new k/v [B, S, H, D] into the paged cache, then attend q
     through the page table (the ragged paged Pallas kernel for ANY S >= 1
     on tile-aligned shapes — decode, prefill chunks, the K+1 spec-verify
     ladder; gathered dense math only for CPU-odd shapes) — the ONE paged
     decode / chunked-prefill / verify hot path shared by every attention
-    family that understands the paged 4/6-tuples.  Returns
-    (new_cache, out [B, S, Hq, D])."""
+    family that understands the paged 4/6-tuples.  ``scale`` is the
+    softmax's (1/sqrt(D) when None).  Returns (new_cache, out [B, S, Hq, D])."""
     from ..ops.decode_attention import paged_decode_attention
 
     if len(cache) == 6:
+        if k.shape[-1] != cache[0].shape[-1]:
+            raise ValueError(
+                "cache_dtype='int8' keeps a scale a head and token, and a "
+                f"pool of {cache[0].shape[-1]}-wide rows holds several "
+                f"{k.shape[-1]}-wide heads a row")
         new_cache, k_q, v_q, k_sc, v_sc = update_paged_quant_cache(
             cache, k, v, offset)
         out = apply_op(
             lambda qq, kk, vv, pt, ks, vs: paged_decode_attention(
-                qq, kk, vv, offset, pt, ks, vs),
+                qq, kk, vv, offset, pt, ks, vs, scale=scale),
             (q, k_q, v_q, cache[3], k_sc, v_sc),
             name="paged_decode_attention")
     else:
         new_cache, k_p, v_p = update_paged_cache(cache, k, v, offset)
         out = apply_op(
             lambda qq, kk, vv, pt: paged_decode_attention(
-                qq, kk, vv, offset, pt),
+                qq, kk, vv, offset, pt, scale=scale),
             (q, k_p, v_p, cache[3]), name="paged_decode_attention")
     return new_cache, out
 
 
-def paged_mixed_update(cache, chunk, q, k, v):
+def paged_mixed_update(cache, chunk, q, k, v, scale=None):
     """A tick's two kinds of row through ONE layer's pools: q, k, v
     [1, C + B, H, D] hold a prefill chunk's C rows — one slot's, at
     ``chunk`` = (off [1], page_row [1, M]) — and behind them B decode rows,
@@ -380,7 +396,8 @@ def paged_mixed_update(cache, chunk, q, k, v):
     chunk's slot decodes nothing in the same program (the engine masks its
     table row to the trash page), no page that others read is ever written,
     so the two kinds' writes never meet and neither read sees the other's.
-    Returns (new_cache in ``cache``'s form, out [1, C + B, Hq, D])."""
+    ``scale`` as in ``paged_attention_update``.  Returns (new_cache in
+    ``cache``'s form, out [1, C + B, Hq, D])."""
     from ..ops.decode_attention import paged_decode_attention
 
     off, page_row = chunk
@@ -396,8 +413,10 @@ def paged_mixed_update(cache, chunk, q, k, v):
                 jnp.concatenate([rc, rd.T], axis=1))
 
     def attend(qq, kk, vv, tbl, *scales):
-        oc = paged_decode_attention(qq[:, :C], kk, vv, off, page_row, *scales)
-        od = paged_decode_attention(qq[0, C:, None], kk, vv, pos, tbl, *scales)
+        oc = paged_decode_attention(qq[:, :C], kk, vv, off, page_row, *scales,
+                                    scale=scale)
+        od = paged_decode_attention(qq[0, C:, None], kk, vv, pos, tbl, *scales,
+                                    scale=scale)
         return jnp.concatenate([oc, od[None, :, 0]], axis=1)
 
     if len(cache) == 6:
@@ -418,7 +437,7 @@ def paged_mixed_update(cache, chunk, q, k, v):
                        name="paged_decode_attention")
         return (k_pool, v_pool, pos + 1, cache[3], k_sc, v_sc), out
     upd = lambda pool, kv, tbl: _scatter_rows(  # noqa: E731
-        pool, kv.astype(pool.dtype), *at(pool, tbl))
+        pool, _pool_rows(kv, pool).astype(pool.dtype), *at(pool, tbl))
     with jax.named_scope("kv_write"):
         k_pool = apply_op(upd, (cache[0], k, cache[3]), name="kv_paged_scatter")
         v_pool = apply_op(upd, (cache[1], v, cache[3]), name="kv_paged_scatter")

@@ -4,7 +4,8 @@ layers (``model_type`` ``nemotron_h``; NVIDIA-Nemotron-3-Nano-30B-A3B).
 Every layer is ``x <- x + mixer(RMSNorm(x))`` with ONE mixer, chosen by a
 character of ``hybrid_override_pattern``:
 
-  ``M``  Mamba-2: ``[z, xBC, dt] = W_in u``; ``xBC <- silu(conv(xBC) + b)``
+  ``M``  Mamba-2 (models/mamba2.py, shared with Granite-4.0-H):
+         ``[z, xBC, dt] = W_in u``; ``xBC <- silu(conv(xBC) + b)``
          (causal, depthwise, ``conv_kernel`` taps) ``-> x, B, C``;
          ``dt <- softplus(dt + dt_bias)``; ``S_t = exp(dt A) S_{t-1} +
          dt x (x) B``; ``y = S C + D x``; ``y <- RMSNorm over groups of
@@ -37,12 +38,10 @@ import jax
 import jax.numpy as jnp
 
 from .. import nn
-from ..framework import random as _random
 from ..ops import moe_experts as _moe
-from ..ops import ssm_update as _ssm
 from ..tensor.tensor import Tensor
 from .kv_cache import CacheKind, SlotRows, paged_attention_update
-
+from .mamba2 import Mamba2Mixer, Mamba2Sizes, _Drawn, _Mixer
 
 
 @dataclass
@@ -87,14 +86,6 @@ class NemotronHConfig:
                              f"inside [0, {self.n_routed_experts})")
         self.experts_held = (int(lo), int(hi))
 
-    @property
-    def mamba_inner(self):
-        return self.mamba_num_heads * self.mamba_head_dim
-
-    @property
-    def conv_channels(self):
-        return self.mamba_inner + 2 * self.n_groups * self.ssm_state_size
-
     @staticmethod
     def tiny(**kw):
         base = dict(vocab_size=256, hidden_size=64, num_hidden_layers=5,
@@ -108,107 +99,22 @@ class NemotronHConfig:
         return NemotronHConfig(**base)
 
 
-class _Drawn:
-    """Initializer that draws IN the parameter's dtype (the stock ones draw
-    float32 and cast: a transient float32 copy of each leaf)."""
-
-    def __init__(self, std=None, const=None):
-        self.std, self.const = std, const
-
-    def __call__(self, param, block=None):
-        v = param._value
-        if self.const is not None:
-            param.set_value(jnp.full(v.shape, self.const, v.dtype))
-        else:
-            param.set_value(jax.random.normal(
-                _random.get_rng_key(), v.shape, v.dtype) * jnp.asarray(self.std, v.dtype))
-        return param
-
-
-class _Mixer(nn.Layer):
-    def __init__(self, config):
-        super().__init__()
-        self.config = config
-
-    def _w(self, shape, dtype=None, const=None):
-        init = _Drawn(const=const) if const is not None \
-            else _Drawn(std=self.config.initializer_range)
-        return self.create_parameter(list(shape), dtype=dtype or self.config.dtype,
-                                     default_initializer=init)
-
-
 def _rms(x, weight, eps):
     x32 = x.astype(jnp.float32)
     y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
     return (y * weight.astype(jnp.float32)).astype(x.dtype)
 
 
-class NemotronHMamba2(_Mixer):
+class NemotronHMamba2(Mamba2Mixer):
+    """The shared Mamba-2 mixer (models/mamba2.py) at a config's sizes."""
+
     def __init__(self, config):
-        super().__init__(config)
-        c = config
-        h, inner, H = c.hidden_size, c.mamba_inner, c.mamba_num_heads
-        self.in_proj = self._w((h, 2 * inner + 2 * c.n_groups * c.ssm_state_size + H))
-        self.conv_weight = self._w((c.conv_kernel, c.conv_channels))  # taps first
-        self.conv_bias = self._w((c.conv_channels,), const=0.0)
-        # per-head scalars stay float32 whatever the weights' dtype
-        self.dt_bias = self._w((H,), "float32", const=0.0)
-        self.A_log = self._w((H,), "float32", const=0.0)
-        self.D = self._w((H,), "float32", const=1.0)
-        self.norm_weight = self._w((inner,), const=1.0)
-        self.out_proj = self._w((inner, h))
-
-    def state_shapes(self, state_dtype):
-        """What a slot keeps: the SSM state with N on the sublanes and the
-        heads of a group side by side on the lanes ([32, 128, 2 * 64] as
-        published: `ops.ssm_update.state_shape`), and the convolution's."""
-        c = self.config
-        return (("ssm", _ssm.state_shape(c.mamba_num_heads, c.mamba_head_dim,
-                                         c.ssm_state_size, c.n_groups),
-                 jnp.dtype(state_dtype)),
-                ("conv", ((c.conv_kernel - 1) * c.conv_channels,),
-                 jnp.dtype(c.dtype)))
-
-    def forward(self, u, cache):
-        """u [B, S, h] raw; cache (ssm [slots, H/k, N, k*P], conv [slots,
-        (K-1)*C], SlotRows).  Returns (out [B, S, h], (ssm, conv))."""
-        c = self.config
-        ssm_all, conv_all, sr = cache
-        B, S, _ = u.shape
-        inner, H = c.mamba_inner, c.mamba_num_heads
-        with jax.named_scope("ssm_mixer"):
-            proj = u @ self.in_proj._value
-            z = proj[..., :inner]
-            xbc = proj[..., inner:inner + c.conv_channels]
-            dt = proj[..., inner + c.conv_channels:]
-            ssm, conv = (ssm_all, conv_all) if sr.rows is None \
-                else (ssm_all[sr.rows], conv_all[sr.rows])
-            if sr.fresh is not None:
-                ssm = jnp.where(sr.fresh[:, None, None, None], 0, ssm)
-                conv = jnp.where(sr.fresh[:, None], 0, conv)
-            kw = dict(conv_weight=self.conv_weight._value,
-                      conv_bias=self.conv_bias._value, a_log=self.A_log._value,
-                      dt_bias=self.dt_bias._value, d_skip=self.D._value,
-                      groups=c.n_groups, n_state=c.ssm_state_size)
-            if S == 1:
-                y, ssm, conv = _ssm.ssm_update(
-                    ssm, conv, xbc[:, 0], dt[:, 0], valid=sr.n_valid > 0, **kw)
-                y = y[:, None]
-            else:
-                y, ssm, conv = _ssm.ssm_chunk(
-                    ssm, conv, xbc, dt, n_valid=sr.n_valid,
-                    chunk_size=c.chunk_size, **kw)
-            # gated RMSNorm over groups of inner / n_groups channels
-            y = y * jax.nn.silu(z.astype(jnp.float32))
-            yg = y.reshape(B, S, c.n_groups, inner // c.n_groups)
-            yg = yg * jax.lax.rsqrt(jnp.mean(yg * yg, axis=-1, keepdims=True)
-                                    + c.layer_norm_epsilon)
-            y = yg.reshape(B, S, inner) * self.norm_weight._value.astype(jnp.float32)
-            out = y.astype(u.dtype) @ self.out_proj._value
-            if sr.rows is not None:
-                ssm = ssm_all.at[sr.rows].set(ssm)
-                conv = conv_all.at[sr.rows].set(conv)
-        return out, (ssm, conv)
+        super().__init__(Mamba2Sizes(
+            hidden_size=config.hidden_size, heads=config.mamba_num_heads,
+            head_dim=config.mamba_head_dim, groups=config.n_groups,
+            state=config.ssm_state_size, conv_kernel=config.conv_kernel,
+            chunk_size=config.chunk_size, eps=config.layer_norm_epsilon,
+            dtype=config.dtype, initializer_range=config.initializer_range))
 
 
 class NemotronHMoE(_Mixer):
@@ -254,8 +160,12 @@ class NemotronHMoE(_Mixer):
 
 
 class NemotronHAttention(_Mixer):
-    def __init__(self, config):
+    """Causal GQA with the config's own ``head_dim`` and no positional
+    encoding; softmax scale ``scale``, 1/sqrt(head_dim) when None."""
+
+    def __init__(self, config, scale=None):
         super().__init__(config)
+        self.scale = scale
         c = config
         h, D = c.hidden_size, c.head_dim
         self.q_proj = self._w((h, c.num_attention_heads * D))
@@ -276,14 +186,16 @@ class NemotronHAttention(_Mixer):
             if cache is None:
                 kk, vv = (jnp.repeat(a, Hq // Hkv, axis=2) for a in (k, v))
                 s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                               kk.astype(jnp.float32)) / D ** 0.5
+                               kk.astype(jnp.float32))
+                s = s / D ** 0.5 if self.scale is None else s * self.scale
                 s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, -jnp.inf)
                 out = jnp.einsum("bhqk,bkhd->bqhd",
                                  jax.nn.softmax(s, axis=-1).astype(u.dtype), vv)
                 new_cache = None
             else:
                 new_cache, out = paged_attention_update(
-                    cache, Tensor(q), Tensor(k), Tensor(v), cache[2])
+                    cache, Tensor(q), Tensor(k), Tensor(v), cache[2],
+                    scale=self.scale)
                 out = out._value
             return out.reshape(B, S, Hq * D) @ self.o_proj._value, new_cache
 

@@ -40,6 +40,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -602,8 +603,6 @@ def _paged_dense(q, k_pages, v_pages, offset, page_tbl, k_scale, v_scale,
     full-table gather: the cap must be static to change the gather shape."""
     S, M, ps = q.shape[1], page_tbl.shape[1], k_pages.shape[2]
     if not isinstance(jnp.asarray(offset), jax.core.Tracer):
-        import numpy as np
-
         used = min(M, -(-(int(np.max(np.asarray(offset))) + S) // ps))
         page_tbl = page_tbl[:, :max(used, 1)]
     k = gather_pages(k_pages, page_tbl)
@@ -617,6 +616,41 @@ def _paged_dense(q, k_pages, v_pages, offset, page_tbl, k_scale, v_scale,
     return _decode_dense(q, k, v, offset, None, None, scale)
 
 
+def pool_layout(kv_heads, head_dim, lanes=128):
+    """(rows a token, row width) of a page pool for `kv_heads` heads of
+    `head_dim`: heads narrower than a 128-lane row lie side by side on one,
+    as many as fill it (two at head_dim 64), so the pool keeps the heads'
+    own bytes and the kernel sees whole-lane rows; other heads a row each."""
+    f = lanes // head_dim if head_dim < lanes and lanes % head_dim == 0 else 1
+    if kv_heads % f:
+        f = 1
+    return kv_heads // f, head_dim * f
+
+
+def _paged_packed(q, k_pages, v_pages, offset, page_tbl, k_scale, scale,
+                  interpret):
+    """Attention against a pool whose rows hold f = W / D heads side by side
+    (`pool_layout`: kv head f*j + e in lanes [e*D, (e+1)*D) of row j).  Each
+    query goes zero-padded into its kv head's lanes, so one W-wide product
+    scores exactly q_h . k_h, and the paged path runs as for W-wide heads
+    with f times as many queries a row; a query's output is its own lanes
+    of the W-wide result (the others hold its row-mate head's values)."""
+    B, S, H, D = q.shape
+    rows, W = k_pages.shape[1], k_pages.shape[-1]
+    f = W // D
+    if W != f * D or k_scale is not None or H % (rows * f):
+        raise ValueError(
+            f"a pool of {W}-wide rows holds {D}-wide heads only {f} a row, "
+            f"{H} query heads on {rows * f} kv heads and not int8 (a scale a "
+            f"row would be two heads'); got q {q.shape}, pool {k_pages.shape}")
+    lane = (np.arange(H) // (H // (rows * f))) % f  # a query head's place
+    onehot = jnp.asarray(np.eye(f, dtype=np.float32)[lane], q.dtype)
+    qp = (q[:, :, :, None, :] * onehot[:, :, None]).reshape(B, S, H, W)
+    out = paged_decode_attention(qp, k_pages, v_pages, offset, page_tbl,
+                                 scale=scale, interpret=interpret)
+    return out.reshape(B, S, H, f, D)[:, :, np.arange(H), lane]
+
+
 def paged_decode_attention(q, k_pages, v_pages, offset, page_tbl,
                            k_scale=None, v_scale=None, scale=None,
                            interpret=None):
@@ -628,12 +662,17 @@ def paged_decode_attention(q, k_pages, v_pages, offset, page_tbl,
     Pallas kernel on tile-aligned shapes — S=1 decode, prefill chunks,
     and the K+1 spec-verify ladder; the gathered dense path survives only
     for CPU-odd shapes (D/page off the 128 tile, mismatched head counts);
-    a query block too large for VMEM goes through it as sub-blocks.  Returns [B, S, H, D] in q's
-    dtype."""
+    a query block too large for VMEM goes through it as sub-blocks.  A
+    pool whose rows are wider than D holds several heads a row
+    (`pool_layout`, `_paged_packed`).  `scale` defaults to 1/sqrt(D).
+    Returns [B, S, H, D] in q's dtype."""
     B, S, H, D = q.shape
     Hkv, ps = k_pages.shape[1], k_pages.shape[2]
     if scale is None:
         scale = 1.0 / (D ** 0.5)
+    if k_pages.shape[-1] != D:
+        return _paged_packed(q, k_pages, v_pages, offset, page_tbl, k_scale,
+                             scale, interpret)
     if interpret is None:
         interpret = _interpret_default()
     lengths = jnp.broadcast_to(

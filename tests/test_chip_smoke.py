@@ -304,6 +304,36 @@ def test_aot_hybrid_kernels_at_published_widths(v5e_devices, as_on_tpu):
 
 
 @pytest.mark.slow
+def test_aot_granite_kernels_at_published_widths(v5e_devices, as_on_tpu):
+    """Granite-4.0-H-Micro's widths: the paged kernel at 32 query heads of 64
+    on 8 K/V heads kept two a 128-lane row (the cell's 833 pages), for a
+    decode tick of 64 rows and a chunk of 256; the Mamba-2 state update over
+    64 slots at one group."""
+    from paddle_tpu.ops import decode_attention as da
+    from paddle_tpu.ops import ssm_update as ssm
+
+    one = _one_device(v5e_devices)
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    rows, width = da.pool_layout(8, 64)
+    assert (rows, width) == (4, 128)
+    for S, b in ((1, 64), (256, 1)):
+        calls = _mosaic_calls(
+            lambda q, k, v, off, tbl: da.paged_decode_attention(
+                q, k, v, off, tbl, scale=0.015625),
+            one, ((b, S, 32, 64), bf), ((833, rows, 128, width), bf),
+            ((833, rows, 128, width), bf), ((b,), i32), ((b, 13), i32))
+        assert sum("paged_attention" in c for c in calls) == 1, (S, calls)
+    B, H, P, N, G = 64, 64, 64, 128, 1
+    R, _, L = ssm.state_shape(H, P, N, G)
+    assert (R, L) == (32, 128)
+    calls = _mosaic_calls(
+        lambda s, xdt, dA, bm, cm: ssm._state_pallas(s, xdt, dA, bm, cm, False),
+        one, ((B, R, N, L), f32), ((B, R, L), f32), ((B, R, L), f32),
+        ((B, G, N), f32), ((B, G, N), f32))
+    assert sum("ssm_update" in c for c in calls) == 1, calls
+
+
+@pytest.mark.slow
 def test_aot_sparse_and_lightning_kernels_at_published_widths(v5e_devices, as_on_tpu):
     """MiniCPM-SALA's widths at the cell's sizes: the block-sparse decode pass
     (64 slots, 2 K/V heads of 16 query heads, lists of 128 blocks, pages of
@@ -404,6 +434,11 @@ def test_smoke_phases_at_tiny_size_on_cpu(smoke, monkeypatch):
         "chat_bf16"}
     assert smoke.state_phase(hidden=128) < 0.05
     assert smoke.latent_phase(doc_tokens=150) < 0.05
+    # 6 layers (the period's first attention layer is its 6th), 64-wide heads
+    assert smoke.granite_phase(
+        n_layers=6, hidden_size=256, num_attention_heads=4, num_key_value_heads=2,
+        mamba_n_heads=16, mamba_d_head=32, mamba_d_state=16,
+        shared_intermediate_size=1024, vocab_size=512) < 0.01
     losses = smoke.train_phase(n_layers=2, batch=4, seq=128, **tiny)
     assert smoke.mesh_phase(losses[0], n_layers=2, batch=4, seq=128,
                             **tiny) is not None

@@ -86,7 +86,8 @@ def test_engine_prefill_in_three_chunks_then_decode_matches_the_reference(monkey
     assert st["cache_kinds"]["none"] == {"layers": 2, "bytes": 0}
     assert st["recurrent_state"] == {
         "bytes": 2 * 3 * (4 * 8 * 16 * 4 + 3 * (32 + 2 * 2 * 16) * 4),
-        "slots": 3, "layers": 2, "checkpoints": None}  # no prefix cache: no pool
+        "slots": 3, "layers": 2, "checkpoints": None,  # no prefix cache: no pool
+        "carried_chunks": 2}  # the 2nd and 3rd chunk resumed the 1st's state
     # 40 prompt tokens and 11 decode rows through 2 expert layers, 2 of 8 each
     moe = st["moe"]
     assert moe["prefill"]["layer_calls"] == 3 * 2 and moe["decode"]["layer_calls"] == 11 * 2
