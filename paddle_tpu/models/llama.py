@@ -19,6 +19,7 @@ import numpy as np
 from .. import nn
 from ..nn import functional as F
 from ..ops import lora as _lora
+from ..ops.decode_attention import _note
 from ..tensor.tensor import Tensor, apply_op
 from ..tensor import manipulation as M
 from ..distributed.meta_parallel.mp_layers import (
@@ -72,6 +73,16 @@ from .kv_cache import (  # noqa: E402  (shared cache layouts; re-exported
     update_plain_cache,
     update_quant_cache,
 )
+
+
+def _note_qkv(one_product, fusable):
+    """Count the projection path at trace time, once an attention call site
+    a compiled program, where the attention dispatch is counted
+    (``llm_attn_kernel_total``, ``stats()["attn_dispatch"]``)."""
+    if one_product:
+        _note("qkv_one_product", "plain_linear")
+    else:
+        _note("qkv_three_products", "static_rows" if fusable else "wrapped")
 
 
 def _static_decode_mask(offset, S, L):
@@ -143,18 +154,19 @@ class LlamaAttention(nn.Layer):
             d = _lora.apply_site("o", out)
             return y if d is None else y + d
 
-    def _qkv(self, hidden_states, fusable):
-        """The three projections (one fused gemv at a decode step) with
-        their LoRA epilogues, under the ``qkv_proj`` scope."""
-        S = hidden_states.shape[1]
+    def _qkv(self, hidden_states, one_product):
+        """The three projections with their LoRA epilogues, under the
+        ``qkv_proj`` scope: ONE product over the three weights where
+        ``one_product`` (see forward), else three."""
         nq = self.num_heads * self.head_dim
         nkv = self.num_kv_heads * self.head_dim
         with jax.named_scope("qkv_proj"):
-            if S == 1 and fusable:
-                # decode step: ONE fused qkv gemv instead of three — at batch<<128
-                # each projection is weight-streaming-bound and per-op latency
-                # dominates; the concat of the (loop-invariant) weights is hoisted
-                # out of the decode scan by XLA LICM, so the fusion costs nothing
+            if one_product:
+                # the TPU compiler fuses the weights' concat into the product,
+                # which reads each weight byte once where it lies.  Three
+                # products of many rows whose [rows, heads, D] reshape folds
+                # into them become convolutions over the heads that
+                # transpose every weight first, a copy per call
                 def _fused_qkv(h, wq, wk, wv):
                     w = jnp.concatenate([wq, wk, wv], axis=1)
                     return h @ w.astype(h.dtype)
@@ -179,6 +191,20 @@ class LlamaAttention(nn.Layer):
                 v = v + _lora.apply_site("v", hidden_states)
         return q, k, v
 
+    def _qkv_rope(self, hidden_states, rope, offset, one_product):
+        """The normed rows [B, S, hidden] to the rotated q and k and the v,
+        [B, S, heads, D]: the products, the split into heads, and rope at
+        ``offset`` (any form apply_rope takes), all on the rows."""
+        B, S = hidden_states.shape[0], hidden_states.shape[1]
+        q, k, v = self._qkv(hidden_states, one_product)
+        q = q.reshape([B, S, self.num_heads, self.head_dim])
+        k = k.reshape([B, S, self.num_kv_heads, self.head_dim])
+        v = v.reshape([B, S, self.num_kv_heads, self.head_dim])
+        rot = lambda a, c, s: apply_rope(a, c, s, offset)  # noqa: E731
+        q = apply_op(rot, (q, *rope), name="rope")
+        k = apply_op(rot, (k, *rope), name="rope")
+        return q, k, v
+
     def forward(self, hidden_states, rope, attn_mask=None, cache=None,
                 use_cache=False, chunk=None):
         """rope: (cos, sin) Tensors shared at LlamaModel level (one copy, not 32).
@@ -187,17 +213,7 @@ class LlamaAttention(nn.Layer):
         [1, C + B] lays a prefill chunk's rows and a paged cache's B decode
         rows side by side (mixed_step) — one pass through the projections,
         each kind's own positions, pages and attention between them."""
-        rope_cos, rope_sin = rope
         B, S = hidden_states.shape[0], hidden_states.shape[1]
-        fusable = (type(self.q_proj) is nn.Linear and type(self.k_proj) is nn.Linear
-                   and type(self.v_proj) is nn.Linear  # not wrapped (quant etc.)
-                   and all(getattr(p, "bias", None) is None
-                           for p in (self.q_proj, self.k_proj, self.v_proj)))
-        q, k, v = self._qkv(hidden_states, fusable)
-        q = q.reshape([B, S, self.num_heads, self.head_dim])
-        k = k.reshape([B, S, self.num_kv_heads, self.head_dim])
-        v = v.reshape([B, S, self.num_kv_heads, self.head_dim])
-
         # a 3-tuple cache (k_buf, v_buf, pos) is the STATIC layout used by the
         # compiled generate() loop: fixed-size HEAD-MAJOR [B, H, L, D] buffers
         # + in-place scatter, so every decode step has identical shapes and
@@ -211,6 +227,14 @@ class LlamaAttention(nn.Layer):
         static_cache = cache is not None and len(cache) in (3, 5)
         quant_cache = cache is not None and len(cache) == 5
         paged_cache = cache is not None and len(cache) in (4, 6)
+        fusable = (type(self.q_proj) is nn.Linear and type(self.k_proj) is nn.Linear
+                   and type(self.v_proj) is nn.Linear  # not wrapped (quant etc.)
+                   and all(getattr(p, "bias", None) is None
+                           for p in (self.q_proj, self.k_proj, self.v_proj)))
+        # one product for a decode step and for every paged call (decode,
+        # chunk, mixed, verify); the training forward and a static cache's
+        # prefill keep three
+        one_product = fusable and (S == 1 or paged_cache)
         if chunk is not None:
             # every token's own position: the chunk's run from its offset,
             # each decode row's from its slot's
@@ -221,8 +245,9 @@ class LlamaAttention(nn.Layer):
             offset = cache[2]
         else:
             offset = cache[0].shape[1] if cache is not None else 0
-        q = apply_op(lambda a, c, s: apply_rope(a, c, s, offset), (q, rope_cos, rope_sin), name="rope")
-        k = apply_op(lambda a, c, s: apply_rope(a, c, s, offset), (k, rope_cos, rope_sin), name="rope")
+        if use_cache:
+            _note_qkv(one_product, fusable)
+        q, k, v = self._qkv_rope(hidden_states, rope, offset, one_product)
 
         if paged_cache and attn_mask is None:
             # paged decode / chunked-prefill / spec-verify path: scatter

@@ -54,7 +54,8 @@ NEG_INF = -1e30
 #: compiled program — a fallback regression shows up as `path="paged_dense"`
 #: increments on /metrics the moment the offending program compiles, not as
 #: a silent latency cliff.  reason ∈ {tile_aligned, query_blocks, off_tile,
-#: grid_too_large, forced}.
+#: grid_too_large, forced}.  models/llama.py counts its Q/K/V projection
+#: path here too: path ∈ {qkv_one_product, qkv_three_products}.
 _M_ATTN_DISPATCH = _obs.counter(
     "llm_attn_kernel_total",
     "Attention dispatch decisions at trace time: which path (Pallas kernel "

@@ -416,6 +416,57 @@ def test_aot_sampler_keeps_its_conditionals_at_a_64k_vocabulary(v5e_devices):
     assert "sampler/cond/branch_1_fun/cond/branch_1_fun" in sorts[0]
 
 
+@pytest.mark.slow
+def test_aot_llama_mixed_rows_read_each_qkv_weight_once(v5e_devices, as_on_tpu):
+    """Mistral-7B's attention layer over a mixed tick's 256 chunk rows and
+    32 decode rows: ONE product reads the Q, K and V weights as stored.
+    Three products of those rows compile, on the v5e, to convolutions over
+    the heads that copy every weight into a transposed layout each call."""
+    from paddle_tpu.distributed import census
+    from paddle_tpu.models import LlamaConfig
+    from paddle_tpu.models.llama import LlamaAttention, _rope_cache
+    from paddle_tpu.autograd import tape
+    from paddle_tpu.tensor.tensor import Tensor
+
+    hidden, H, KV, D, C, B, pages, M = 4096, 32, 8, 128, 256, 32, 641, 32
+    attn = LlamaAttention(LlamaConfig(
+        hidden_size=hidden, num_attention_heads=H, num_key_value_heads=KV,
+        num_hidden_layers=1, tensor_parallel=False)).bfloat16()
+    names = [n for n, _ in attn.named_parameters()]
+
+    def layer(params, h, k_pool, v_pool, pos, tbl, off, row, cos, sin):
+        restore = attn.bind_functional_state(dict(zip(names, params)), {})
+        try:
+            with tape.no_grad():
+                out, cache = attn(Tensor(h), (Tensor(cos), Tensor(sin)),
+                                  cache=(Tensor(k_pool), Tensor(v_pool), pos,
+                                         Tensor(tbl)),
+                                  use_cache=True, chunk=(off, row))
+        finally:
+            restore()
+        return out._value, cache[0]._value, cache[1]._value
+
+    one = _one_device(v5e_devices)
+    bf, i32 = jnp.bfloat16, jnp.int32
+    spec = lambda s, d=bf: jax.ShapeDtypeStruct(s, d, sharding=one)  # noqa: E731
+    params = [spec(p.shape) for _, p in attn.named_parameters()]
+    cos, _ = _rope_cache(D, 4096, 1e6)
+    hlo = jax.jit(layer).lower(
+        params, spec((1, C + B, hidden)), spec((pages, KV, 128, D)),
+        spec((pages, KV, 128, D)), spec((B,), i32), spec((B, M), i32),
+        spec((1,), i32), spec((1, M), i32), spec(cos.shape), spec(cos.shape),
+    ).compile().as_text()
+    assert "rhs_reversal" not in hlo
+    rows = [r for r in census.per_op_census(hlo)
+            if r["scope"] == "attention/qkv_proj" or r["scope"] == "qkv_proj"]
+    products = [r for r in rows if r["flops"] > 0]
+    assert [r["flops"] for r in products] == [
+        2 * (C + B) * hidden * (H + 2 * KV) * D], products
+    w_k = hidden * KV * D * 2
+    assert not [r for r in rows if r["opcode"] == "copy"
+                and r["bytes_out"] >= w_k], rows
+
+
 # --------------------------------------------- slow: the smoke's control flow
 @pytest.mark.slow
 def test_smoke_phases_at_tiny_size_on_cpu(smoke, monkeypatch):

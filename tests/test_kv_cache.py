@@ -176,3 +176,34 @@ def test_pages_for():
     assert pages_for(1, 8) == 1
     assert pages_for(8, 8) == 1
     assert pages_for(9, 8) == 2
+
+
+@pytest.mark.parametrize("off,C,ps,M", [
+    (0, 32, 16, 6),     # page-aligned: two whole pages, the third untouched
+    (5, 32, 16, 6),     # a partial first page, a whole one, a partial last
+    (16, 16, 16, 6),    # one whole page
+    (3, 8, 16, 6),      # inside one page
+    (70, 32, 16, 6),    # runs past the table's coverage: the tail to trash
+    (96, 32, 16, 6),    # wholly past it
+])
+def test_chunk_page_write_equals_the_row_scatter(off, C, ps, M):
+    """A slot's run of C tokens written a page block at a time leaves every
+    page the row scatter leaves (the trash page aside: what lands there is
+    never read), in a k/v pool and in a scale pool."""
+    from paddle_tpu.models.kv_cache import (
+        _chunk_pages, _scatter_rows, _token_pages_rows, _write_chunk_pages)
+
+    rng = np.random.RandomState(off + C)
+    P, H, D = 9, 2, 8
+    page_row = jnp.asarray([[3, 7, 1, 5, TRASH_PAGE, TRASH_PAGE]], jnp.int32)[:, :M]
+    for pool, vals in (
+            (rng.randn(P, H, ps, D), rng.randn(C, H, D)),   # k/v rows
+            (rng.rand(P, H, ps), rng.rand(C, H))):          # scales
+        pool = jnp.asarray(pool, jnp.float32)
+        vals = jnp.asarray(vals, jnp.float32)
+        page, row = _token_pages_rows(jnp.asarray([off]), page_row, C, ps, M)
+        want = np.asarray(_scatter_rows(pool, vals[None], page, row))
+        got = np.asarray(_write_chunk_pages(
+            pool, vals, _chunk_pages(jnp.asarray([off]), page_row, C, ps)))
+        live = np.arange(P) != TRASH_PAGE
+        assert np.array_equal(got[live], want[live])
