@@ -16,6 +16,11 @@ cut to N_LAYERS and seeded random weights:
   granite LLMEngine with Granite-4.0-H at its published widths, one period
          deep (9 Mamba-2 layers, 1 attention layer of 64-wide heads), seeded:
          the paged kernel serves the 64-wide heads, the head is the embedding
+  granite_moe  LLMEngine with Granite-4.0-H-Small's layer at its published
+         widths (128 Mamba-2 heads, 72 router experts of which 8 are held,
+         top 10, a shared expert), two layers (mamba, attention): the state
+         kernel at 128 heads, the gated expert kernel at 4096 x 768, served
+         tokens against the float32 reference
   profile a 2-layer engine under load, profiled for 1 s by itself
          (LLMEngine.profile_device) and reduced by its own census: every
          device second lands under a program the engine knows
@@ -389,6 +394,53 @@ def granite_phase(n_layers=10, new_tokens=8, timeout=600.0, **widths):
     return worst
 
 
+# ------------------------------------------------------------- granite_moe
+def granite_moe_phase(held=8, new_tokens=8, timeout=600.0, **widths):
+    """Granite-4.0-H-Small's layer at its published widths, two layers
+    (mamba, attention) each with `held` of the router's 72 experts, with the
+    benchmark's seeded weights: the served tokens (decode kernels, a prompt
+    of two chunks carrying its state) lie at the top of the float32
+    reference's logits, and the expert counters count pairs through the
+    recurrent AND the paged layer.  Returns the widest gap."""
+    import json
+    import os
+
+    from benchmark import serve_granite_moe
+    from benchmark.reference import granite_moe_ref
+    from paddle_tpu.inference import LLMEngine
+
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)), "benchmark",
+                           "configs", "granite-4.0-h-small-l10-ep2.json")) as f:
+        cfg = json.load(f)
+    cfg.update(num_hidden_layers=2, layer_types=["mamba", "attention"],
+               num_local_experts=held,
+               share=dict(cfg["share"], experts_held=[0, held]), **widths)
+    model = serve_granite_moe.build_model(cfg, seed=1)
+    eng = LLMEngine(model, page_size=128, prefill_chunk=128, max_batch_slots=4,
+                    max_seq_len=256)
+    log(f"granite_moe: warmup took {eng.warmup():.1f}s")
+    rng = np.random.default_rng(3)
+    asks = [rng.integers(0, cfg["vocab_size"], n, dtype=np.int32) for n in (100, 200)]
+    eng.start()
+    try:
+        outs = [f.result(timeout=timeout) for f in
+                [eng.submit(p, max_new_tokens=new_tokens) for p in asks]]
+    finally:
+        eng.stop()
+    moe = eng.stats()["moe"]
+    check(moe["decode"]["layer_calls"] % 2 == 0 and moe["decode"]["pairs_held"] > 0
+          and moe["prefill"]["layer_calls"] == 3 * 2,
+          f"both layers counted their pairs (stats()['moe']): {moe}")
+    del eng, model
+    _free()
+    gaps, _ = granite_moe_ref.served_gap(
+        cfg, 1, [(p, np.asarray(o, np.int32)) for p, o in zip(asks, outs)], 256)
+    worst = float(np.max(gaps))
+    check(worst < 0.01, f"served tokens lie within {worst:.4f} (< 0.01) of the "
+          "float32 reference's best")
+    return worst
+
+
 # ----------------------------------------------------------------- profile
 def profile_phase(n_layers=2, seconds=1.0, n_requests=16, new_tokens=500,
                   timeout=600.0, **overrides):
@@ -655,6 +707,8 @@ def main():
     _free()
     granite_gap = granite_phase()
     _free()
+    granite_moe_gap = granite_moe_phase()
+    _free()
     profile_phase()
     _free()
     losses = train_phase()
@@ -665,7 +719,8 @@ def main():
     log(f"native: AVAILABLE={native.AVAILABLE} "
         f"built_this_run={native.BUILT_THIS_RUN}")
     log(f"summary: layers={N_LAYERS} kernel_err={errs} state_gap={state_gap:.4f} "
-        f"latent_gap={latent_gap:.4f} granite_gap={granite_gap:.4f} train={losses} "
+        f"latent_gap={latent_gap:.4f} granite_gap={granite_gap:.4f} "
+        f"granite_moe_gap={granite_moe_gap:.4f} train={losses} "
         f"mesh={mesh_losses} paddle_tpu={paddle_tpu.__version__} "
         f"wall={time.perf_counter() - T0:.0f}s")
     print(json.dumps({"ok": True, "device": dev}))

@@ -555,10 +555,15 @@ def _from_model_caches(kinds, new_caches):
     """Back again: (engine-side caches, what the layers reported: the expert
     layers' counts stacked, then the block-sparse layers' summed, then the
     latent layers' summed — the order of the engine's device accumulators,
-    each present only with its layers)."""
+    each present only with its layers).  A paged_kv or recurrent layer with
+    experts hands its expert counts LAST, after what it keeps."""
     raw, moe, sparse, latent = [], [], [], []
     for i, c in enumerate(new_caches):
         kind = "paged_kv" if kinds is None else kinds[i].kind
+        if kind in ("paged_kv", "recurrent") and kinds is not None \
+                and kinds[i].experts_held:
+            moe.append(c[-1])
+            c = tuple(c[:-1])
         if kind == "paged_kv" and kinds is not None and kinds[i].compressed:
             raw.append(tuple(c[:3]))
             sparse.append(c[3])

@@ -1,12 +1,21 @@
 """Granite-4.0-H: a hybrid decoder of Mamba-2 and attention layers, each
-followed by a dense gated MLP, under muP-style multipliers (``model_type``
-``granitemoehybrid`` with ``num_local_experts`` 0; IBM Granite-4.0-H-Micro).
+followed by a gated MLP, under muP-style multipliers (``model_type``
+``granitemoehybrid``; IBM Granite-4.0-H-Micro, -Small).
 
   ``x = embedding_multiplier * E[ids]``
   every layer: ``x <- x + residual_multiplier * mixer(RMSNorm(x))``, then
-               ``x <- x + residual_multiplier * W_out (silu(g) * u)`` with
-               ``[g, u] = W_in RMSNorm(x)`` (the first half is the gate)
+               ``x <- x + residual_multiplier * (moe(v) + W_out (silu(g) * u))``
+               with ``v = RMSNorm(x)``, ``[g, u] = W_in v`` (the first half is
+               the gate: the shared MLP)
   ``logits = RMSNorm(x) E^T / logits_scaling``: the head IS the embedding
+
+``moe`` is there only with ``num_local_experts`` > 0 (Small; Micro has the
+shared MLP alone): the router's logits ``W_r v`` in float32 over all
+``num_local_experts``, the ``num_experts_per_tok`` largest, their weights a
+softmax over those alone; expert e adds ``g_e W2_e (silu(Wg_e v) * W1_e v)``.
+The layer is told which experts it holds (``experts_held``, a range, as in
+Nemotron-H): it routes over all of them and computes the part its own give;
+what an absent expert would add is left out (ops/moe_experts.py).
 
 The mixer is a character of ``layer_types``: ``mamba``, the Mamba-2 mixer of
 models/mamba2.py (shared with Nemotron-H; at one group its gated RMSNorm
@@ -29,6 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import nn
+from ..ops import moe_experts as _moe
 from ..ops.decode_attention import pool_layout
 from ..tensor.tensor import Tensor
 from .kv_cache import CacheKind, SlotRows
@@ -41,7 +51,10 @@ MAMBA, ATTENTION = "mamba", "attention"
 
 @dataclass
 class GraniteHybridConfig:
-    """The published keys of ``config.json`` under their own names."""
+    """The published keys of ``config.json`` under their own names, plus
+    ``experts_held``: the experts [lo, hi) this device holds, all by default.
+    ``num_local_experts`` stays the ROUTER's width; ``intermediate_size`` is
+    one routed expert's."""
     vocab_size: int = 100352
     hidden_size: int = 2048
     num_hidden_layers: int = 40
@@ -62,6 +75,9 @@ class GraniteHybridConfig:
     logits_scaling: float = 8.0
     position_embedding_type: str = "nope"
     num_local_experts: int = 0
+    num_experts_per_tok: int = 0
+    intermediate_size: int = 0
+    experts_held: tuple | None = None
     tie_word_embeddings: bool = True
     hidden_act: str = "silu"
     mamba_conv_bias: bool = True
@@ -72,11 +88,20 @@ class GraniteHybridConfig:
 
     def __post_init__(self):
         self.layer_types = tuple(self.layer_types)
-        if self.num_local_experts > 0:
-            raise ValueError(
-                f"num_local_experts={self.num_local_experts}: the routed "
-                "experts of the Granite-4.0-H MoE variants (Tiny, Small) are "
-                "not implemented; only the dense MLP of Micro is")
+        E = self.num_local_experts
+        if E > 0:
+            if not 0 < self.num_experts_per_tok <= E or self.intermediate_size <= 0:
+                raise ValueError(
+                    f"num_local_experts={E} needs num_experts_per_tok in "
+                    f"1..{E} and an intermediate_size, got "
+                    f"{self.num_experts_per_tok} and {self.intermediate_size}")
+            lo, hi = self.experts_held or (0, E)
+            if not 0 <= lo < hi <= E:
+                raise ValueError(f"experts_held {self.experts_held} is not a "
+                                 f"range inside [0, {E})")
+            self.experts_held = (int(lo), int(hi))
+        elif self.experts_held is not None:
+            raise ValueError("experts_held needs num_local_experts > 0")
         if self.position_embedding_type != "nope":
             raise ValueError(
                 f"position_embedding_type={self.position_embedding_type!r}: "
@@ -136,6 +161,36 @@ class GraniteHybridMLP(_Mixer):
             return (jax.nn.silu(gu[..., :F]) * gu[..., F:]) @ self.output_linear._value
 
 
+class GraniteHybridMoE(_Mixer):
+    """The routed experts this device holds, [held, F, h] each matrix (the
+    hidden size, whole lanes, minor in all three: ops/moe_experts.py)."""
+
+    def __init__(self, config):
+        super().__init__(config)
+        c = config
+        h, F = c.hidden_size, c.intermediate_size
+        lo, hi = c.experts_held
+        self.gate_weight = self._w((h, c.num_local_experts))   # the router
+        self.experts_gate = self._w((hi - lo, F, h))    # [out, in] an expert
+        self.experts_up = self._w((hi - lo, F, h))      # [out, in]
+        self.experts_down = self._w((hi - lo, F, h))    # [in, out]
+
+    def forward(self, u, sr):
+        """u [B, S, h]; sr SlotRows or None.  Returns (float32 [B, S, h]: the
+        held experts' part, counts [held + 1] int32 over the real rows)."""
+        B, S, h = u.shape
+        x = u.reshape(B * S, h)
+        idx, w = _moe.route_softmax(x, self.gate_weight._value,
+                                    self.config.num_experts_per_tok)
+        real = None if sr is None else \
+            (jnp.arange(S)[None, :] < sr.n_valid[:, None]).reshape(B * S)
+        out, counts = _moe.moe_experts(
+            x, self.experts_up._value, self.experts_down._value, idx, w,
+            self.config.experts_held[0], real=real,
+            w_gate=self.experts_gate._value)
+        return out.reshape(B, S, h), counts
+
+
 class GraniteHybridBlock(nn.Layer):
     def __init__(self, config, kind):
         super().__init__()
@@ -146,9 +201,13 @@ class GraniteHybridBlock(nn.Layer):
         self.mixer = Mamba2Mixer(config.mamba_sizes()) if kind == MAMBA \
             else NemotronHAttention(config, scale=config.attention_multiplier)
         self.post_attention_layernorm = nn.RMSNorm(h, eps)
+        self.block_sparse_moe = GraniteHybridMoE(config) \
+            if config.num_local_experts else None
         self.shared_mlp = GraniteHybridMLP(config)
 
-    def forward(self, x, cache):
+    def forward(self, x, cache, sr=None):
+        """With experts, `sr` marks the real rows for their counts, and the
+        counts come back LAST after what the mixer keeps."""
         eps = self.input_layernorm._epsilon
         # the block's norms and scaled residuals: a scope of their own
         with jax.named_scope("block_norm"):
@@ -158,6 +217,10 @@ class GraniteHybridBlock(nn.Layer):
             x = x + (out.astype(jnp.float32) * self.scale).astype(x.dtype)
             u = _rms(x, self.post_attention_layernorm.weight._value, eps)
         y = self.shared_mlp(u)
+        if self.block_sparse_moe is not None:
+            routed, counts = self.block_sparse_moe(u, sr)
+            y = routed + y.astype(jnp.float32)
+            new = (() if new is None else tuple(new)) + (counts,)
         with jax.named_scope("block_norm"):
             return x + (y.astype(jnp.float32) * self.scale).astype(x.dtype), new
 
@@ -187,14 +250,18 @@ class GraniteHybridForCausalLM(nn.Layer):
 
     # ------------------------------------------------- what each layer keeps
     def cache_kinds(self):
-        """Page pools for attention (two 64-wide heads a 128-lane row at the
-        published sizes: `pool_layout`), per-slot state for Mamba-2."""
+        """Page pools for attention (two 64-wide heads a 128-lane row at
+        Micro's sizes: `pool_layout`), per-slot state for Mamba-2; with
+        routed experts every layer also reports its pairs."""
         c = self.config
         rows, width = pool_layout(c.num_key_value_heads, c.head_dim)
-        return [CacheKind("paged_kv", kv_heads=rows, head_dim=width)
+        moe = {} if not c.num_local_experts else dict(
+            experts_held=c.experts_held[1] - c.experts_held[0],
+            top_k=c.num_experts_per_tok)
+        return [CacheKind("paged_kv", kv_heads=rows, head_dim=width, **moe)
                 if blk.kind == ATTENTION else
                 CacheKind("recurrent",
-                          state=blk.mixer.state_shapes(self.ssm_state_dtype))
+                          state=blk.mixer.state_shapes(self.ssm_state_dtype), **moe)
                 for blk in self.layers]
 
     @property
@@ -209,9 +276,13 @@ class GraniteHybridForCausalLM(nn.Layer):
         with jax.named_scope("embed"):
             x = (self.embed_tokens._value[ids].astype(jnp.float32)
                  * c.embedding_multiplier).astype(self.embed_tokens._value.dtype)
+        # the batch's rows, as the recurrent layers are handed them: an
+        # expert layer counts its pairs over the real ones
+        sr = next((c[-1] for c in caches if isinstance(c, tuple) and c
+                   and isinstance(c[-1], SlotRows)), None)
         new = []
         for blk, cache in zip(self.layers, caches):
-            x, n = blk(x, cache)
+            x, n = blk(x, cache, sr)
             new.append(n)
         with jax.named_scope("final_norm"):
             x = _rms(x, self.norm.weight._value, self.norm._epsilon)

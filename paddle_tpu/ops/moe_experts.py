@@ -22,8 +22,10 @@ bytes of the experts touched.
 ``counts`` ([held experts + 1] int32) comes back for the engine's counters:
 pairs by held expert over the rows marked real, and how many experts had any.
 
-``route`` is the router both expert models call: sigmoid scores over all the
-experts, the best `top_k` by score + correction bias, normalised, scaled.
+``route`` is the router Nemotron-H and DeepSeek-V3 call: sigmoid scores over
+all the experts, the best `top_k` by score + correction bias, normalised,
+scaled.  ``route_softmax`` is Granite-4.0-H's: the `top_k` largest logits,
+then a softmax over those alone.
 """
 from __future__ import annotations
 
@@ -35,8 +37,8 @@ from jax.experimental.pallas import tpu as pltpu
 from ._prng import interpret_default as _interpret_default
 
 #: VMEM for one expert's two matrices, double-buffered (2 x 2 x 10 MB at the
-#: published 2688 x 1856; three of 3.1 MB in the gated form at 2048 x 768)
-#: plus the row tiles
+#: published 2688 x 1856; in the gated form three of 3.1 MB at 2048 x 768, and
+#: three of 6.3 MB = 37.7 MB double-buffered at 4096 x 768) plus the row tiles
 _VMEM_LIMIT = 56 * 1024 * 1024
 HI = jax.lax.Precision.HIGHEST
 
@@ -55,6 +57,17 @@ def route(x, gate_weight, bias, top_k, norm_topk_prob, scaling):
         if norm_topk_prob:
             w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
         return idx.astype(jnp.int32), w * scaling
+
+
+def route_softmax(x, gate_weight, top_k):
+    """x [T, h] -> (expert int32 [T, K], weight float32 [T, K]): logits
+    W_r x in float32 over ALL experts, the K largest, and their weights a
+    softmax over those K alone (no bias, no scaling)."""
+    with jax.named_scope("moe_router"):
+        logits = jnp.matmul(x.astype(jnp.float32),
+                            gate_weight.astype(jnp.float32), precision=HI)
+        top, idx = jax.lax.top_k(logits, top_k)
+        return idx.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
 
 
 def _tile_rows(n_pairs):

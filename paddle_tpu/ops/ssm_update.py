@@ -10,9 +10,11 @@ its causal depthwise convolution and, a head, the matrix S [head_dim, N] of
 ``ssm_update`` advances every serving slot by ONE token (the decode tick):
 the convolution's shift, bias and SiLU, softplus(dt), the decay, the outer
 product, the readout and the D skip.  The state pass — 2 MB a slot a layer at
-the published sizes, read and written once — is one Pallas kernel
+64 heads of 64, 4 MB at 128, read and written once — is one Pallas kernel
 (``ssm_update``) that updates the state in place at the rate the HBM
-streams; the few [slots, channels] vectors around it are plain XLA.
+streams, a slot a grid step, or a tile of its rows where the slot is larger
+than a block may be (`_row_tiles`); the few [slots, channels] vectors around
+it are plain XLA.
 ``ssd_chunk_scan`` is the same recurrence over a chunk of T tokens from an
 initial state, in the chunked (state-space-dual) form: plain ``jnp`` in
 float32 (named scope ``ssd_chunk_scan``; not a kernel yet).
@@ -144,6 +146,23 @@ def _state_kernel(s_ref, xdt_ref, da_ref, b_ref, c_ref, o_ref, y_ref, *, groups)
         jax.lax.fori_loop(0, per, row, 0)
 
 
+#: a slot's state block [R, N, L] float32 up to here is one grid step (2 MiB
+#: at 64 heads of 64); in and out, double-buffered, a larger one would pass
+#: the 16 MiB of scoped VMEM the v5e gives a kernel by default
+_BLOCK_BYTES = 2 * 1024 * 1024
+
+
+def _row_tiles(R, N, L, groups):
+    """Tiles a slot's R rows are cut into: 1 where [R, N, L] float32 fits
+    _BLOCK_BYTES, else halvings until it does (at ONE group, so every tile
+    reads the same B and C; tiles of whole sublanes).  2 at 128 heads of 64."""
+    t = 1
+    while groups == 1 and (R // t) * N * L * 4 > _BLOCK_BYTES \
+            and (R // t) % 16 == 0:
+        t *= 2
+    return t
+
+
 @functools.partial(jax.jit, static_argnames=("interpret", "name"))
 def _state_pallas(state, xdt, da, bm, cm, interpret, name="ssm_update"):
     """state [B, R, N, L]; xdt, da [B, R, L]; bm, cm [B, G, N].  A jit of
@@ -155,19 +174,29 @@ def _state_pallas(state, xdt, da, bm, cm, interpret, name="ssm_update"):
     G = bm.shape[1]
     bm, cm = jnp.swapaxes(bm, 1, 2), jnp.swapaxes(cm, 1, 2)  # n on the sublanes
     kernel = functools.partial(_state_kernel, groups=G)
-    row = lambda *shape: pl.BlockSpec(  # noqa: E731
-        (1,) + shape, lambda b: (b,) + (0,) * len(shape))
+    tiles = _row_tiles(R, N, L, G)
+    if tiles == 1:  # a grid step a slot
+        grid = (B,)
+        row = lambda *shape: pl.BlockSpec(  # noqa: E731
+            (1,) + shape, lambda b: (b,) + (0,) * len(shape))
+        whole = row
+    else:  # a grid step a tile of a slot's rows, all of one group
+        grid = (B, tiles)
+        row = lambda r, *shape: pl.BlockSpec(  # noqa: E731
+            (1, r // tiles) + shape, lambda b, t: (b, t) + (0,) * len(shape))
+        whole = lambda *shape: pl.BlockSpec(  # noqa: E731
+            (1,) + shape, lambda b, t: (b,) + (0,) * len(shape))
     return pl.pallas_call(
         kernel,
-        grid=(B,),
-        in_specs=[row(R, N, L), row(R, L), row(R, L), row(N, G), row(N, G)],
+        grid=grid,
+        in_specs=[row(R, N, L), row(R, L), row(R, L), whole(N, G), whole(N, G)],
         out_specs=[row(R, N, L), row(R, L)],
         out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
                    jax.ShapeDtypeStruct((B, R, L), jnp.float32)],
         input_output_aliases={0: 0},  # the state is updated in place
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel",)),
+            dimension_semantics=("parallel",) * len(grid)),
         name=name,
     )(state, xdt, da, bm, cm)
 

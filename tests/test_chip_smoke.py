@@ -334,6 +334,34 @@ def test_aot_granite_kernels_at_published_widths(v5e_devices, as_on_tpu):
 
 
 @pytest.mark.slow
+def test_aot_granite_small_kernels_at_published_widths(v5e_devices, as_on_tpu):
+    """Granite-4.0-H-Small's widths: the Mamba-2 state update over 64 slots
+    at 128 heads of 64 (4 MiB a slot, taken in two tiles of rows: one block
+    would not fit the scoped VMEM), and the gated experts (36 held, three 768
+    x 4096 matrices each) at a decode tick's rows and at a chunk's."""
+    from paddle_tpu.ops import moe_experts as moe
+    from paddle_tpu.ops import ssm_update as ssm
+
+    one = _one_device(v5e_devices)
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    B, H, P, N, G = 64, 128, 64, 128, 1
+    R, _, L = ssm.state_shape(H, P, N, G)
+    assert (R, L) == (64, 128) and ssm._row_tiles(R, N, L, G) == 2
+    calls = _mosaic_calls(
+        lambda s, xdt, dA, bm, cm: ssm._state_pallas(s, xdt, dA, bm, cm, False),
+        one, ((B, R, N, L), f32), ((B, R, L), f32), ((B, R, L), f32),
+        ((B, G, N), f32), ((B, G, N), f32))
+    assert sum("ssm_update" in c for c in calls) == 1, calls
+    for T in (64, 256):
+        calls = _mosaic_calls(
+            lambda x, wg, w1, w2, idx, w: moe.moe_experts(x, w1, w2, idx, w, 0,
+                                                          w_gate=wg)[0],
+            one, ((T, 4096), bf), *[((36, 768, 4096), bf)] * 3,
+            ((T, 10), i32), ((T, 10), f32))
+        assert sum("moe_glu_experts" in c for c in calls) == 1, (T, calls)
+
+
+@pytest.mark.slow
 def test_aot_sparse_and_lightning_kernels_at_published_widths(v5e_devices, as_on_tpu):
     """MiniCPM-SALA's widths at the cell's sizes: the block-sparse decode pass
     (64 slots, 2 K/V heads of 16 query heads, lists of 128 blocks, pages of
@@ -493,3 +521,13 @@ def test_smoke_phases_at_tiny_size_on_cpu(smoke, monkeypatch):
     losses = smoke.train_phase(n_layers=2, batch=4, seq=128, **tiny)
     assert smoke.mesh_phase(losses[0], n_layers=2, batch=4, seq=128,
                             **tiny) is not None
+
+
+@pytest.mark.slow
+def test_smoke_granite_moe_phase_at_tiny_size_on_cpu(smoke):
+    """chip_smoke.py's Granite-4.0-H-Small phase at a tiny width: 4 held of
+    the router's 72 experts, 64-wide heads two a row of the page pool."""
+    assert smoke.granite_moe_phase(
+        held=4, hidden_size=256, num_attention_heads=4, num_key_value_heads=2,
+        mamba_n_heads=16, mamba_d_head=32, mamba_d_state=16, intermediate_size=128,
+        shared_intermediate_size=1024, vocab_size=512, torch_dtype="float32") < 0.01
